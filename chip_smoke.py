@@ -1,0 +1,573 @@
+#!/usr/bin/env python
+"""chip_smoke.py: does the streaming path run on the chip?
+
+Drives the system's main path once, through the entry points a user calls,
+at the full width of MobileNet-v2 (1.0, 224x224, 1001 classes, bf16), in ONE
+process that owns the chip from start to end.  Weights are random (seeded);
+every input (frames, labels file) is generated from a seed inside the
+checkout.  Not a benchmark: the seconds it prints say whether compiles hit
+the cache and whether anything compiled after warm-up, nothing more.
+
+    python chip_smoke.py                 # on the machine with the TPU
+    python chip_smoke.py --cpu-rehearsal # control-flow check, tiny sizes
+
+Exit codes: 0 every phase passed on a TPU; 1 a phase failed; 2 jax found no
+TPU (nothing is built, no result is printed); 3 the rehearsal finished (it
+cannot print a pass).  On success the last stdout line is one JSON object,
+``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": ...}}``.
+
+Phases (each fails the run on any mismatch):
+- labeling: videotestsrc → tensor_converter → tensor_transform (fused into
+  the model) → tensor_upload → queue → tensor_filter framework=jax →
+  tensor_decoder image_labeling → tensor_sink, with a tee on the filter's
+  output so the pipeline's own logits are checked too;
+- serving door: a QueryServer in this process answers a tensor_query_client
+  pipeline over loopback; replies equal the labeling logits;
+- multi-stream: 4 sources → tensor_mux → tensor_batch → the model at batch
+  4 → tensor_unbatch → tensor_demux; per-stream outputs equal batch-1;
+- decode session: ContinuousBatcher at its shipped defaults, two identical
+  sessions, prefill then feed/get;
+- kernels: every Pallas kernel the tree ships, compiled by Mosaic
+  (interpret=False) and compared with its jnp reference;
+- four chips (only when jax.device_count() >= 4): multi-stream again under
+  NNSTPU_MESH=dp:4, output shards on four distinct devices.
+"""
+
+import argparse
+import json
+import os
+import sys
+import time
+import traceback
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+NORMALIZE = "typecast:float32,add:-127.5,div:127.5"
+# bf16 logits against a float32 (precision=highest) forward of the same
+# frames: relative L2 error per frame.  bf16 keeps 8 mantissa bits and the
+# network is ~50 layers deep; 0.031 was the worst frame measured on a v5e
+# with these seeded weights (PERF.md Findings, PR 21), so 0.06 is 2x that.
+# Two bf16 programs of the same model (fused vs unfused normalize, batch 1
+# vs batch 4) differ by the same rounding noise and get the same bound.
+BF16_REL_L2 = 0.06
+
+FULL = dict(image=224, width=1.0, classes=1001, frames=32, query_frames=8,
+            streams=4, per_stream=8)
+REHEARSAL = dict(image=32, width=0.35, classes=1001, frames=8, query_frames=4,
+                 streams=4, per_stream=2)
+
+
+def say(*args):
+    print(*args, flush=True)
+
+
+def check(cond, why):
+    """``assert`` that survives ``python -O``."""
+    if not cond:
+        raise AssertionError(why)
+
+
+# -- compile accounting: jax's own monitoring events, so a silent jit retrace
+# -- counts the same as a backend compile the repo's counters know about
+
+class CompileWatch:
+    def __init__(self):
+        from jax import monitoring
+
+        self.count = 0
+        self.seconds = 0.0
+        self.cache_hits = 0
+        monitoring.register_event_duration_secs_listener(self._on_duration)
+        monitoring.register_event_listener(self._on_event)
+
+    def _on_duration(self, event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            self.count += 1
+            self.seconds += duration
+
+    def _on_event(self, event, **_):
+        if event == "/jax/compilation_cache/cache_hits":
+            self.cache_hits += 1
+
+    def snap(self):
+        return (self.count, self.seconds, self.cache_hits)
+
+
+def repo_compiles():
+    """{result: count} of the repo's own record_compile counters."""
+    from nnstreamer_tpu.obs.metrics import REGISTRY
+
+    c = REGISTRY.get("nnstpu_compile_total")
+    if c is None:
+        return {}
+    return {k[0]: int(v.value) for k, v in dict(c.children()).items()}
+
+
+class Smoke:
+    def __init__(self, sizes, rehearsal, queue_backend):
+        self.sizes = sizes
+        self.rehearsal = rehearsal
+        self.queue_backend = queue_backend
+        self.watch = CompileWatch()
+        self.phases = []      # per-phase summary rows, in order
+        self.failed = []
+        self.logits = None    # labeling phase: (frames, classes) float32
+
+    # -- phase runner -------------------------------------------------------
+
+    def phase(self, name, fn):
+        """Run one phase; a raise fails the run (exit 1, no result line)
+        after the remaining phases have had their turn."""
+        from nnstreamer_tpu.obs.export import degraded_snapshot
+
+        say(f"== {name}")
+        c0, r0, t0 = self.watch.snap(), repo_compiles(), time.perf_counter()
+        row = {"phase": name, "ok": False}
+        try:
+            row.update(fn() or {})
+            degraded = degraded_snapshot()
+            check(not degraded, f"degraded backend(s): {degraded}")
+            row["ok"] = True
+        except Exception:  # noqa: BLE001 — recorded as a FAILED phase
+            traceback.print_exc(file=sys.stdout)
+            self.failed.append(name)
+        c1, r1 = self.watch.snap(), repo_compiles()
+        row["xla_compiles"] = c1[0] - c0[0]
+        row["compile_s"] = round(c1[1] - c0[1], 3)
+        row["cache_hits"] = c1[2] - c0[2]
+        row["repo_compiles"] = {k: v - r0.get(k, 0) for k, v in r1.items()
+                                if v - r0.get(k, 0)}
+        row["wall_s"] = round(time.perf_counter() - t0, 3)
+        self.phases.append(row)
+        say(f"-- {name}: {'PASS' if row['ok'] else 'FAIL'} {json.dumps(row)}")
+
+    # -- shared pieces ------------------------------------------------------
+
+    def model(self, batch=None):
+        from nnstreamer_tpu.models import mobilenet_v2
+
+        s = self.sizes
+        return mobilenet_v2.build(num_classes=s["classes"],
+                                  width_mult=s["width"],
+                                  image_size=s["image"], batch=batch)
+
+    def source(self, p, n, seed, name=None):
+        """videotestsrc (seeded random frames) → tensor_converter."""
+        import nnstreamer_tpu as nns
+
+        s = self.sizes
+        src = p.add(nns.make("videotestsrc", name=name, num_buffers=n,
+                             pattern="random", seed=seed,
+                             width=s["image"], height=s["image"]))
+        conv = p.add(nns.make("tensor_converter"))
+        p.link(src, conv)
+        return conv
+
+    def source_frames(self, n, seed):
+        """The uint8 frames ``source(n, seed)`` emits, collected on host."""
+        import numpy as np
+
+        import nnstreamer_tpu as nns
+        from nnstreamer_tpu.elements.sink import TensorSink
+
+        p = nns.Pipeline(name="smoke_frames")
+        sink = p.add(TensorSink(collect=True))
+        p.link(self.source(p, n, seed), sink)
+        p.run(timeout=120)
+        return np.stack([np.asarray(f.tensor(0)) for f in sink.frames])
+
+    def check_close(self, got, want, what):
+        import numpy as np
+
+        got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+        check(got.shape == want.shape, (what, got.shape, want.shape))
+        check(np.isfinite(got).all(), f"{what}: non-finite values")
+        rel = (np.linalg.norm(got - want, axis=-1)
+               / np.linalg.norm(want, axis=-1))
+        worst = float(rel.max())
+        check(worst <= BF16_REL_L2,
+              f"{what}: relative L2 error {worst:.4f} > {BF16_REL_L2}")
+        return round(worst, 5)
+
+    def first_output_marker(self):
+        """``(mark, callback)``: the callback stamps the clock and both
+        compile counts when a sink sees its first frame."""
+        mark = {}
+
+        def on_frame(_frame):
+            if not mark:
+                mark.update(t=time.perf_counter(), xla=self.watch.count,
+                            xla_s=self.watch.seconds, repo=repo_compiles())
+
+        return mark, on_frame
+
+    def on_device(self, arr, what):
+        import jax
+
+        check(isinstance(arr, jax.Array), f"{what}: {type(arr)} on host")
+        plats = {d.platform for d in arr.devices()}
+        check(self.rehearsal or plats == {"tpu"},
+              f"{what}: lives on {plats}, expected a TPU")
+
+    # -- phases -------------------------------------------------------------
+
+    def labeling(self):
+        import numpy as np
+
+        import jax
+        import jax.numpy as jnp
+
+        import nnstreamer_tpu as nns
+        from nnstreamer_tpu.elements.filter import TensorFilter
+        from nnstreamer_tpu.elements.sink import TensorSink
+        from nnstreamer_tpu.models import mobilenet_v2
+
+        s = self.sizes
+        n = s["frames"]
+        model = self.model()
+        os.makedirs(os.path.join(HERE, "chiprun_out"), exist_ok=True)
+        labels_path = os.path.join(HERE, "chiprun_out", "smoke_labels.txt")
+        with open(labels_path, "w") as f:
+            f.write("\n".join(f"class_{i}" for i in range(s["classes"])))
+
+        p = nns.Pipeline(name="smoke_labeling")
+        conv = self.source(p, n, seed=0)
+        norm = p.add(nns.make("tensor_transform", mode="arithmetic",
+                              option=NORMALIZE))
+        up = p.add(nns.make("tensor_upload"))
+        q = p.add(nns.make("queue", max_size_buffers=16))
+        filt = p.add(TensorFilter(framework="jax", model=model))
+        tee = p.add(nns.make("tee"))
+        dec = p.add(nns.make("tensor_decoder", mode="image_labeling",
+                             option1=labels_path))
+        labels = p.add(TensorSink(collect=True, name="labels"))
+        logits = p.add(TensorSink(collect=True, name="logits"))
+        p.link_chain(conv, norm, up, q, filt, tee)
+        p.link_chain(tee, dec, labels)
+        p.link(tee, logits)
+        first, on_logits = self.first_output_marker()
+        logits.connect("new-data", on_logits)
+        xla_s0 = self.watch.seconds
+        t0 = time.perf_counter()
+        p.run(timeout=600)
+        jax.block_until_ready([f.tensor(0) for f in logits.frames])
+        t_end = time.perf_counter()
+
+        check("tensor_transform" not in " ".join(p.nodes),
+              "the normalize chain was not fused into the model")
+        check(q.backend_kind == self.queue_backend, q.backend_kind)
+        check(filt.backend._degraded is None, filt.backend._degraded)
+        check(len(logits.frames) == n and len(labels.frames) == n,
+              (len(logits.frames), len(labels.frames)))
+        pts = [f.pts for f in logits.frames]
+        check(pts == sorted(set(pts)), f"frames out of order: {pts}")
+        for f in logits.frames:
+            self.on_device(f.tensor(0), "filter output")
+        got = np.stack([np.asarray(f.tensor(0)) for f in logits.frames])
+        check(got.shape == (n, s["classes"]) and got.dtype == np.float32,
+              (got.shape, got.dtype))
+        for i, f in enumerate(labels.frames):
+            label = bytes(np.asarray(f.tensor(0))).decode()
+            check(label == f"class_{int(got[i].argmax())}", (i, label))
+        # no compile after the first frame came out, by jax's count and by
+        # the repo's own counters; exactly one executable was built
+        check(self.watch.count == first["xla"],
+              f"{self.watch.count - first['xla']} XLA compile(s) mid-stream")
+        check(repo_compiles() == first["repo"], (repo_compiles(), first))
+
+        # float32 reference of the same frames, on the same device
+        frames = self.source_frames(n, seed=0)
+        with jax.default_matmul_precision("highest"):
+            ref = jax.jit(lambda x: mobilenet_v2.apply(
+                model.params, (x.astype(jnp.float32) - 127.5) / 127.5,
+                dtype=jnp.float32))
+            want = np.concatenate([np.asarray(ref(frames[i:i + 8]))
+                                   for i in range(0, n, 8)])
+        worst = self.check_close(got, want, "bf16 pipeline vs float32")
+        self.logits = got
+        return {"frames": n, "first_frame_s": round(first["t"] - t0, 3),
+                "model_compile_s": round(first["xla_s"] - xla_s0, 3),
+                "stream_s": round(t_end - first["t"], 3),
+                "rel_l2_vs_f32": worst,
+                "distinct_labels": len({int(r.argmax()) for r in got})}
+
+    def serving_door(self):
+        import numpy as np
+
+        import nnstreamer_tpu as nns
+        from nnstreamer_tpu.elements.query import QueryServer, TensorQueryClient
+        from nnstreamer_tpu.elements.sink import TensorSink
+
+        n = self.sizes["query_frames"]
+        got, stamps = [], []
+        with QueryServer(framework="jax", model=self.model()) as srv:
+            p = nns.Pipeline(name="smoke_query")
+            conv = self.source(p, n, seed=0)
+            norm = p.add(nns.make("tensor_transform", mode="arithmetic",
+                                  option=NORMALIZE))
+            client = p.add(TensorQueryClient(port=srv.port,
+                                             request_timeout=600.0))
+            sink = p.add(TensorSink())
+
+            def on_reply(frame):
+                stamps.append((time.perf_counter(), self.watch.count))
+                got.append(np.asarray(frame.tensor(0)))
+
+            sink.connect("new-data", on_reply)
+            p.link_chain(conv, norm, client, sink)
+            t0 = time.perf_counter()
+            p.run(timeout=600)
+        check(len(got) == n, f"{len(got)} of {n} replies")
+        check(stamps[-1][1] == stamps[0][1], "XLA compile after first reply")
+        worst = self.check_close(np.stack(got), self.logits[:n],
+                                 "query replies vs labeling logits")
+        return {"requests": n, "first_frame_s": round(stamps[0][0] - t0, 3),
+                "stream_s": round(stamps[-1][0] - stamps[0][0], 3),
+                "rel_l2_vs_labeling": worst}
+
+    def multi_stream(self, shards=1):
+        import numpy as np
+
+        import jax
+
+        import nnstreamer_tpu as nns
+        from nnstreamer_tpu.elements.filter import TensorFilter
+        from nnstreamer_tpu.elements.sink import TensorSink
+
+        s = self.sizes
+        k, per = s["streams"], s["per_stream"]
+        p = nns.Pipeline(name=f"smoke_mux_x{shards}")
+        mux = p.add(nns.make("tensor_mux", sync_mode="nosync"))
+        for i in range(k):
+            # stream i replays labeling frames [i*per, (i+1)*per)
+            p.link(self.source(p, per, seed=i * per, name=f"cam{i}"),
+                   f"{mux.name}.sink_{i}")
+        batch = p.add(nns.make("tensor_batch"))
+        norm = p.add(nns.make("tensor_transform", mode="arithmetic",
+                              option=NORMALIZE))
+        filt = p.add(TensorFilter(framework="jax", model=self.model(batch=k)))
+        probe = p.add(nns.make("tee"))
+        batched = p.add(TensorSink(collect=True, name="batched"))
+        unbatch = p.add(nns.make("tensor_unbatch"))
+        demux = p.add(nns.make("tensor_demux"))
+        p.link_chain(mux, batch, norm, filt, probe, unbatch, demux)
+        p.link(probe, batched)
+        sinks = []
+        for i in range(k):
+            sinks.append(p.add(TensorSink(collect=True, name=f"out{i}")))
+            p.link(f"{demux.name}.src_{i}", sinks[i])
+        first, on_first = self.first_output_marker()
+        batched.connect("new-data", on_first)
+        t0 = time.perf_counter()
+        p.run(timeout=600)
+        jax.block_until_ready([f.tensor(0) for f in batched.frames])
+        t_end = time.perf_counter()
+
+        check(filt.backend._degraded is None, filt.backend._degraded)
+        check(len(batched.frames) == per, len(batched.frames))
+        check(self.watch.count == first["xla"], "XLA compile mid-stream")
+        for f in batched.frames:
+            out = f.tensor(0)
+            self.on_device(out, "batched filter output")
+            devs = {sh.device for sh in out.addressable_shards}
+            check(len(devs) == shards,
+                  f"output shards on {len(devs)} device(s), expected {shards}")
+        worst = 0.0
+        for i, sink in enumerate(sinks):
+            check(len(sink.frames) == per, (i, len(sink.frames)))
+            got = np.stack([np.asarray(f.tensor(0)).reshape(-1)
+                            for f in sink.frames])
+            worst = max(worst, self.check_close(
+                got, self.logits[i * per:(i + 1) * per],
+                f"stream {i} (batch {k}) vs batch-1 logits"))
+        return {"frames": k * per, "shards": shards,
+                "first_frame_s": round(first["t"] - t0, 3),
+                "stream_s": round(t_end - first["t"], 3),
+                "rel_l2_vs_batch1": worst}
+
+    def decode_session(self):
+        import numpy as np
+
+        from nnstreamer_tpu.serving import ContinuousBatcher
+
+        rng = np.random.default_rng(7)
+        steps = 4
+        t0 = time.perf_counter()
+        with ContinuousBatcher() as eng:  # shipped defaults
+            t_built = time.perf_counter()
+            prompt = rng.standard_normal((5, eng.d_in)).astype(np.float32)
+            feeds = rng.standard_normal((steps, eng.d_in)).astype(np.float32)
+            a, b = eng.open_session(timeout=60), eng.open_session(timeout=60)
+            outs = {id(a): [], id(b): []}
+            for sess in (a, b):
+                sess.prefill(prompt)
+            for sess in (a, b):
+                outs[id(sess)].append(sess.get(timeout=600))
+            warm = self.watch.count  # prefill bucket + step are built now
+            for x in feeds:
+                for sess in (a, b):
+                    sess.feed(x)
+                for sess in (a, b):
+                    outs[id(sess)].append(sess.get(timeout=600))
+            ticks = eng.ticks
+            n_out = eng.n_out
+        t_end = time.perf_counter()
+        check(self.watch.count == warm, "XLA compile after the first step")
+        ya, yb = np.stack(outs[id(a)]), np.stack(outs[id(b)])
+        check(ya.shape == (steps + 1, n_out), ya.shape)
+        check(np.isfinite(ya).all() and np.isfinite(yb).all(),
+              "non-finite decode outputs")
+        check(np.array_equal(ya, yb),
+              f"identical streams diverged: max|d|={np.abs(ya - yb).max()}")
+        check(not np.array_equal(ya[0], ya[-1]), "outputs do not evolve")
+        return {"requests": 2 * (steps + 1), "ticks": ticks,
+                "build_s": round(t_built - t0, 3),
+                "stream_s": round(t_end - t_built, 3)}
+
+    def kernels(self):
+        import numpy as np
+
+        import jax
+        import jax.numpy as jnp
+
+        from nnstreamer_tpu.decoders.bounding_boxes import PRE_NMS_TOP_K
+        from nnstreamer_tpu.ops.nms import nms_keep, pallas_nms_keep
+        from nnstreamer_tpu.ops.pallas_kernels import (
+            _apply_chain,
+            fused_arith,
+            int8_matmul,
+        )
+        from nnstreamer_tpu.ops.quant import (
+            quantize_activations,
+            quantize_weight,
+        )
+
+        interpret = self.rehearsal  # on the chip Mosaic compiles every one
+        rng = np.random.default_rng(11)
+        s = self.sizes
+        out = {}
+
+        # fused_arith: the tensor_transform acceleration=pallas kernel on
+        # one uint8 frame, the normalize chain
+        chain = (("typecast", jnp.float32), ("add", -127.5), ("div", 127.5))
+        x = rng.integers(0, 256, (s["image"], s["image"], 3)).astype(np.uint8)
+        got = np.asarray(jax.jit(
+            lambda a: fused_arith(a, chain, interpret=interpret))(x))
+        want = np.asarray(jax.jit(lambda a: _apply_chain(a, chain))(x))
+        np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+        out["fused_arith_max_abs_err"] = float(np.abs(got - want).max())
+
+        # int8_matmul at the MobileNet classifier head
+        w = rng.standard_normal((1280, s["classes"])).astype(np.float32) * .05
+        bias = rng.standard_normal(s["classes"]).astype(np.float32)
+        qw = quantize_weight(jnp.asarray(w), axis=-1)
+        wq, ws = np.asarray(qw.q), np.asarray(qw.scale).reshape(1, -1)
+        for m in (1, 8):
+            a = rng.standard_normal((m, 1280)).astype(np.float32)
+            aq, a_scale = quantize_activations(jnp.asarray(a))
+            got = np.asarray(int8_matmul(aq, qw.q, a_scale,
+                                         qw.scale.reshape(1, -1), bias,
+                                         interpret=interpret))
+            acc = np.asarray(aq).astype(np.int64) @ wq.astype(np.int64)
+            want = acc.astype(np.float32) * (float(a_scale) * ws) + bias
+            np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-4)
+            out[f"int8_matmul_m{m}_max_abs_err"] = float(
+                np.abs(got - want).max())
+
+        # NMS at the SSD decoder's K, bit for bit against the XLA form
+        k = PRE_NMS_TOP_K
+        bx = rng.integers(0, 60, (2, k)).astype(np.float32)
+        wh = rng.integers(1, 30, (2, k)).astype(np.float32)
+        valid = rng.random(k) >= 0.2
+        args = tuple(jnp.asarray(v) for v in (bx[0], bx[1], wh[0], wh[1],
+                                              valid))
+        got = np.asarray(jax.jit(
+            lambda *v: pallas_nms_keep(*v, interpret=interpret))(*args))
+        want = np.asarray(jax.jit(nms_keep)(*args))
+        check(np.array_equal(got, want), "pallas_nms_keep != nms_keep")
+        check(0 < int(got.sum()) < int(valid.sum()), "degenerate NMS case")
+        out["nms_kept"] = f"{int(got.sum())}/{k}"
+        out["compiled_by"] = "interpreter" if interpret else "mosaic"
+        return out
+
+    def four_chips(self):
+        from nnstreamer_tpu.parallel.mesh import reset_dispatch_mesh
+
+        os.environ["NNSTPU_MESH"] = "dp:4"
+        try:
+            return self.multi_stream(shards=4)
+        finally:
+            del os.environ["NNSTPU_MESH"]
+            reset_dispatch_mesh()
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--cpu-rehearsal", action="store_true",
+                    help="tiny sizes on whatever jax finds, kernels in the "
+                         "interpreter; checks control flow only and exits 3")
+    args = ap.parse_args(argv)
+
+    import jax
+
+    dev = jax.devices()[0]
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "count": len(jax.devices())}
+    if dev.platform != "tpu" and not args.cpu_rehearsal:
+        print(f"chip_smoke.py: jax found no TPU (platform={dev.platform!r}, "
+              f"JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS')!r})",
+              file=sys.stderr)
+        return 2
+
+    sys.path.insert(0, HERE)
+    import jaxlib
+
+    from nnstreamer_tpu import native
+    from nnstreamer_tpu.backends.exec_cache import ensure_compile_cache
+
+    try:
+        import libtpu
+        libtpu_version = libtpu.__version__
+    except ImportError:
+        libtpu_version = None
+    cache_dir = ensure_compile_cache()
+    smoke = Smoke(REHEARSAL if args.cpu_rehearsal else FULL,
+                  rehearsal=args.cpu_rehearsal,
+                  queue_backend=native.queue_backend())  # raises if broken
+    header = {
+        "device": device,
+        "versions": {"python": sys.version.split()[0], "jax": jax.__version__,
+                     "jaxlib": jaxlib.__version__, "libtpu": libtpu_version},
+        "compile_cache_dir": cache_dir,
+        "compile_cache_entries": len(os.listdir(cache_dir))
+        if os.path.isdir(cache_dir) else 0,
+        "queue_backend": smoke.queue_backend,
+        "sizes": smoke.sizes,
+        "rehearsal": args.cpu_rehearsal,
+    }
+    say(f"chip_smoke {json.dumps(header)}")
+
+    t0 = time.perf_counter()
+    smoke.phase("labeling", smoke.labeling)
+    smoke.phase("serving_door", smoke.serving_door)
+    smoke.phase("multi_stream", smoke.multi_stream)
+    smoke.phase("decode_session", smoke.decode_session)
+    smoke.phase("kernels", smoke.kernels)
+    if device["count"] >= 4:
+        smoke.phase("four_chips", smoke.four_chips)
+    else:
+        say(f"== four_chips: not run ({device['count']} device(s))")
+
+    say("SUMMARY " + json.dumps({
+        **header, "phases": smoke.phases, "failed": smoke.failed,
+        "total_s": round(time.perf_counter() - t0, 1)}))
+    if smoke.failed:
+        return 1
+    if args.cpu_rehearsal:
+        say("rehearsal finished: control flow only, not a pass")
+        return 3
+    say(json.dumps({"ok": True, "device": device}))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

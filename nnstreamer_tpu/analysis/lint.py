@@ -64,7 +64,10 @@ ALL_CHECKS = ("hooks", "metrics", "conf", "wire-codes", "threads",
 
 # dirs never scanned; per-check source-dir exclusions below
 _SKIP_DIRS = {".git", "__pycache__", ".pytest_cache", ".claude", "build",
-              "dist", ".eggs", "node_modules"}
+              "dist", ".eggs", "node_modules",
+              # git-ignored chip-tool work dirs (an exported copy of the
+              # tree lives under chip_work/ while a commit is being proven)
+              "chip_work", "chiprun_out", ".jax_cache"}
 # metric construction and thread hygiene are runtime-code contracts;
 # tests assert on metric names and join their threads ad hoc
 _NO_TEST_CHECKS = {"metrics", "threads"}

@@ -62,12 +62,10 @@ DEFAULTS: Dict[str, Dict[str, str]] = {
         # Device lane (obs/device.py): completion-probe queue bound for the
         # DeviceTracer reaper thread (overflow drops probes, counted).
         "device_probe_queue": "1024",
-        # Utilization lane (obs/util.py): MFU/roofline peaks (empty = the
-        # per-platform default, e.g. v5e bf16 197 TFLOP/s / 819 GB/s), the
-        # sliding window behind nnstpu_device_busy_fraction, and the
-        # minimum device idle gap that becomes a device_idle flight span.
-        "peak_tflops": "",
-        "peak_gbs": "",
+        # Utilization lane (obs/util.py): the sliding window behind
+        # nnstpu_device_busy_fraction, and the minimum device idle gap that
+        # becomes a device_idle flight span.  (MFU/roofline peaks are not
+        # knobs: obs.util.DEVICE_PEAKS, keyed by device_kind.)
         "busy_window_s": "10",
         "device_idle_gap_ms": "5",
         # Pipeline health watchdog (obs/watchdog.py, tracer "watchdog").
@@ -77,9 +75,9 @@ DEFAULTS: Dict[str, Dict[str, str]] = {
         "watchdog_device_deadline_s": "30", # device completion deadline
         "watchdog_recover": "false",        # escalate detection to recovery
         "watchdog_recover_budget": "3",     # max recovery attempts per target
-        # >0: the watchdog spot-checks the host->device wire every this
-        # many seconds and publishes nnstpu_wire_* gauges (obs/util.py) —
-        # sick tunnel regimes visible on /metrics during serving
+        # >0: the watchdog spot-checks the host->device wire (and every
+        # registered partition edge) every this many seconds and publishes
+        # nnstpu_wire_* gauges (obs/util.py)
         "watchdog_wire_probe_s": "0",
         # Cost observatory (obs/costmodel.py, tracer "costmodel"): the
         # persisted per-stage cost model the partitioner prices cuts
@@ -142,7 +140,7 @@ DEFAULTS: Dict[str, Dict[str, str]] = {
         "max_bytes": "67108864",    # total free-list bytes (64 MiB)
         "concat_threshold": "0",    # per-row bytes: skip host concat on the
                                     # CPU fallback above this (0=off; opt-in
-                                    # — see BENCH_NOTES zero-copy sweep)
+                                    # — see pool.DEFAULT_CONCAT_THRESHOLD)
     },
     # Compile-ahead serving (backends/exec_cache.py + graph/warmup.py +
     # ops/autotune.py): persistent executable/autotune caches and the AOT
@@ -311,7 +309,8 @@ DEFAULTS: Dict[str, Dict[str, str]] = {
         "window_s": "30",           # ... within this sliding window
         "backoff_ms": "50",         # first restart backoff (doubles)
         "backoff_cap_ms": "2000",   # backoff ceiling
-        "cpu_fallback": "true",     # degrade jax compile failures to CPU
+        "cpu_fallback": "false",    # opt in: degrade a jax compile failure
+                                    # to CPU instead of failing the stream
     },
 }
 
@@ -330,8 +329,6 @@ SHORT_ENV: Dict[str, Optional[tuple]] = {
     "NNSTPU_METRICS_PORT": ("common", "metrics_port"),
     "NNSTPU_METRICS_BUCKETS": ("obs", "buckets"),
     "NNSTPU_FLIGHT_RECORDS": ("obs", "flight_records"),
-    "NNSTPU_PEAK_TFLOPS": ("obs", "peak_tflops"),
-    "NNSTPU_PEAK_GBS": ("obs", "peak_gbs"),
     "NNSTPU_MESH": ("mesh", "spec"),
     "NNSTPU_FAULTS": ("faults", "spec"),
     "NNSTPU_LOCKDEP": ("analysis", "lockdep"),

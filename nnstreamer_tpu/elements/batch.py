@@ -19,8 +19,8 @@ streams at once, with the batch dim sharded over the device mesh by the
 Host-side assembly is **slot-wise into a pooled batch buffer** (each row
 copied once, directly into its slot of a recycled staging buffer —
 ``nnstreamer_tpu/pool.py``), never a fresh ``np.stack``: the cold
-multi-MB allocation per dispatch was 59% of 8-stream busy time on the CPU
-fallback (BENCH_NOTES.md "Mux per-stream overhead finding").  Above the
+multi-MB allocation per dispatch was 59% of 8-stream busy time on a CPU
+host (``tools/profile_mux_overhead.py``).  Above the
 payload/platform threshold (``pool.skip_host_concat``) host concat is
 skipped entirely: rows ride downstream as a deferred
 :class:`~nnstreamer_tpu.pool.RowBatch` and the jax filter invokes per

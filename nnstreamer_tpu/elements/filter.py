@@ -153,7 +153,7 @@ class TensorFilter(Node):
         # downstream host consumers (decoders, numpy sinks) will call
         # np.asarray on our outputs: start the device→host copy at emit
         # time so their blocking read finds local data instead of paying a
-        # full round trip per frame (matters on tunneled chips)
+        # full round trip per frame
         self._downstream_host = not self._downstream_device_resident()
         if self._fused_pre or self._fused_post:
             self._install_fusion(in_spec)  # validates model spec vs chain

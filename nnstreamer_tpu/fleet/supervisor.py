@@ -264,9 +264,10 @@ class SubprocWorkerFactory:
         if self.platform:
             argv += ["--platform", self.platform]
         try:
+            # stderr inherits ours: a worker that cannot get its device
+            # (the chip belongs to one process) must be able to say so
             proc = subprocess.Popen(
-                argv, stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,
-                text=True, env=self.env)
+                argv, stdout=subprocess.PIPE, text=True, env=self.env)
         except OSError as exc:  # bad binary / exec failure
             raise SpawnError(f"{wid}: spawn failed: {exc}") from exc
         line: Dict[str, str] = {}

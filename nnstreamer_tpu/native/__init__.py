@@ -3,13 +3,15 @@
 The reference's runtime substrate (GStreamer's queueing/threading) is native
 C; this package is the TPU framework's native layer.  The library is built
 from source on first use with the toolchain's ``g++`` (no external deps) and
-cached next to the source; set ``NNSTPU_COMMON_NATIVE_RUNTIME=off`` to force
-the pure-Python fallbacks.
+cached next to the source, keyed by the source's content hash — a stale
+``_build/*.so`` copied from elsewhere is rebuilt, never loaded.  Set
+``NNSTPU_COMMON_NATIVE_RUNTIME=off`` to choose the pure-Python twins.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -19,10 +21,11 @@ _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "frame_queue.cpp")
 _BUILD_DIR = os.path.join(_HERE, "_build")
 _SO = os.path.join(_BUILD_DIR, "libnns_runtime.so")
+_STAMP = _SO + ".stamp"
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
-_load_failed = False
+_load_error: Optional[Exception] = None
 
 # status codes (keep in sync with frame_queue.cpp)
 OK = 0
@@ -34,15 +37,34 @@ TIMEOUT = -2
 EVENT_BIT = 1 << 63
 
 
-def _build() -> None:
+def _source_hash() -> str:
+    with open(_SRC, "rb") as f:
+        return hashlib.sha256(f.read()).hexdigest()
+
+
+def _stamp_matches(key: str) -> bool:
+    try:
+        with open(_STAMP) as f:
+            return f.read().strip() == key
+    except OSError:
+        return False
+
+
+def _build(key: str) -> None:
     os.makedirs(_BUILD_DIR, exist_ok=True)
-    tmp = _SO + ".tmp"
+    # pid-unique tmp: two *processes* may build concurrently (_lock only
+    # covers threads); os.replace keeps the publish atomic either way
+    tmp = _SO + f".tmp.{os.getpid()}"
     cmd = [
         "g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-pthread",
         _SRC, "-o", tmp,
     ]
     subprocess.run(cmd, check=True, capture_output=True, text=True)
-    os.replace(tmp, _SO)  # atomic: concurrent importers see old or new
+    os.replace(tmp, _SO)
+    stamp_tmp = _STAMP + f".tmp.{os.getpid()}"
+    with open(stamp_tmp, "w") as f:
+        f.write(key)
+    os.replace(stamp_tmp, _STAMP)  # lands after the library it vouches for
 
 
 def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
@@ -67,21 +89,21 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
 
 
 def load() -> Optional[ctypes.CDLL]:
-    """The loaded library, building it if needed; None when unavailable."""
-    global _lib, _load_failed
-    if _lib is not None or _load_failed:
+    """The loaded library, (re)building it when the source's content hash
+    differs from the built library's stamp; None when unavailable."""
+    global _lib, _load_error
+    if _lib is not None or _load_error is not None:
         return _lib
     with _lock:
-        if _lib is not None or _load_failed:
+        if _lib is not None or _load_error is not None:
             return _lib
         try:
-            src_mtime = os.path.getmtime(_SRC)
-            if not os.path.exists(_SO) or os.path.getmtime(_SO) < src_mtime:
-                _build()
+            key = _source_hash()
+            if not (os.path.exists(_SO) and _stamp_matches(key)):
+                _build(key)
             _lib = _bind(ctypes.CDLL(_SO))
-        except (OSError, subprocess.CalledProcessError):
-            _load_failed = True
-            _lib = None
+        except (OSError, subprocess.CalledProcessError) as exc:
+            _load_error = exc
     return _lib
 
 
@@ -91,3 +113,21 @@ def available() -> bool:
     if not conf.get_bool("common", "native_runtime", True):
         return False
     return load() is not None
+
+
+def queue_backend() -> str:
+    """``"native"`` or ``"python"``: the ``queue`` element's backend as
+    configured.  Raises when ``[common] native_runtime`` is on but the
+    library failed to build or load — measurement entry points
+    (``chip_smoke.py``, ``bench.py``) call this so the host dispatch layer
+    cannot change under a benchmark without a word."""
+    from ..conf import conf
+
+    if not conf.get_bool("common", "native_runtime", True):
+        return "python"
+    if load() is None:
+        detail = getattr(_load_error, "stderr", None) or _load_error
+        raise RuntimeError(
+            "[common] native_runtime is on but libnns_runtime.so failed to "
+            f"build or load: {detail}")
+    return "native"

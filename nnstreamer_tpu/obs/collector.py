@@ -355,8 +355,8 @@ def attribute_trace(records: List[tuple]) -> Dict[str, float]:
       sum of the server legs that joined.  When NEITHER ``route`` nor
       ``serve`` made it into the join (ring overflow, a worker flight
       that was never collected), the old behavior charged the entire
-      RTT to ``wire`` — over-attribution that sent readers chasing
-      tunnel ghosts.  Now the uncovered remainder (rtt − queue −
+      RTT to ``wire`` — over-attribution that sent readers chasing a
+      wire problem that was not there.  Now the uncovered remainder (rtt − queue −
       device) is reported as explicitly UNKNOWN instead; the loadgen
       report surfaces it as ``unattributed_us``;
     - ``route_overhead``: route − serve (router forwarding cost);

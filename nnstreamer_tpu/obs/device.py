@@ -150,23 +150,20 @@ def _compile_metrics(registry: MetricsRegistry):
 
 
 def cost_info(compiled) -> dict:
-    """flops/bytes out of an AOT ``Compiled.cost_analysis()`` (shape
-    varies by jax version: a dict, or a per-program list of dicts); {}
-    when the runtime doesn't expose it."""
+    """flops/bytes out of an AOT ``Compiled.cost_analysis()`` (a dict
+    keyed ``"flops"`` / ``"bytes accessed"``); {} when the backend
+    doesn't implement it."""
     try:
         ca = compiled.cost_analysis()
     except Exception:  # noqa: BLE001 — optional on many backends
         return {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else None
     if not isinstance(ca, dict):
         return {}
     info = {}
     if ca.get("flops"):
         info["flops"] = float(ca["flops"])
-    by = ca.get("bytes accessed") or ca.get("bytes_accessed")
-    if by:
-        info["bytes"] = float(by)
+    if ca.get("bytes accessed"):
+        info["bytes"] = float(ca["bytes accessed"])
     return info
 
 
@@ -485,14 +482,14 @@ class DeviceTracer(Tracer):
         self._mfu_gauge = self._registry.gauge(
             "nnstpu_mfu",
             "Model FLOPs utilization of the last observed dispatch "
-            "(cost_analysis flops / device time / peak; see [obs] "
-            "peak_tflops / NNSTPU_PEAK_TFLOPS)",
+            "(cost_analysis flops / device time / the device_kind's peak "
+            "in obs.util.DEVICE_PEAKS; absent for an unknown device)",
             labelnames=("device", "node", "bucket"),
         )
         self._bound_counter = self._registry.counter(
             "nnstpu_roofline_dispatches_total",
             "Observed dispatches by roofline classification (arithmetic "
-            "intensity vs the peak_tflops/peak_gbs ridge point)",
+            "intensity vs the device's peak ridge point)",
             labelnames=("pipeline", "device", "bound"),
         )
         self._busy_gauge = self._registry.gauge(
@@ -863,7 +860,7 @@ class DeviceTracer(Tracer):
                 # None (not omission) when no dispatch carried cost info —
                 # count/device_ns stay exact either way
                 mfu = None
-                if flops_sum and ns > 0:
+                if flops_sum and ns > 0 and peak_tf:
                     mfu = float(
                         f"{flops_sum / (ns / 1e9) / (peak_tf * 1e12):.4g}")
                 entry = {"count": count, "device_ns": ns, "mfu": mfu,

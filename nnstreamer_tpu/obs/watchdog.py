@@ -144,8 +144,8 @@ class PipelineWatchdog(Tracer):
                 self._recover_budget = 3
         # >0: spot-check the host->device wire every this many seconds
         # and publish it live (obs/util.py nnstpu_wire_* gauges + the
-        # wire_health stats provider — the same probe bench.py uses), so
-        # a sick tunnel regime is visible on /metrics DURING serving
+        # wire_health stats provider), so a slow wire is visible on
+        # /metrics DURING serving
         self._wire_probe_s = self._conf_float("watchdog_wire_probe_s", 0.0)
         self._last_wire_probe = 0.0
         # [obs] profile_auto: when a dispatch's device time degrades
@@ -474,8 +474,8 @@ class PipelineWatchdog(Tracer):
         degraded = degraded_snapshot()
         if degraded:
             out["degraded"] = degraded
-        # last published wire-health probe (ours or bench's): the sick-
-        # tunnel regime next to the health verdict it often explains
+        # last published wire-health probe: the wire regime next to the
+        # health verdict it often explains
         from .util import last_wire_health
 
         wire = last_wire_health()

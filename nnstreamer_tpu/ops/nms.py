@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # pairwise width/height use the reference's inclusive-pixel convention
 # (x2 - x1 + 1, tensordec-boundingbox.c:744) — see decoders.bounding_boxes.iou
@@ -47,23 +48,27 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def suppression_matrix(x, y, w, h):
-    """(K, K) bool: ``iou(i, j) > 0.5`` under the host loop's exact
-    arithmetic.  Inputs are integer-valued float32 pixel boxes."""
-    x2 = x + w
-    y2 = y + h
-    ix1 = jnp.maximum(x[:, None], x[None, :])
-    iy1 = jnp.maximum(y[:, None], y[None, :])
-    ix2 = jnp.minimum(x2[:, None], x2[None, :])
-    iy2 = jnp.minimum(y2[:, None], y2[None, :])
-    iw = jnp.maximum(0.0, ix2 - ix1 + 1.0)
-    ih = jnp.maximum(0.0, iy2 - iy1 + 1.0)
+def _pairwise_suppression(xc, yc, wc, hc, xr, yr, wr, hr):
+    """``iou(i, j) > 0.5`` for column-form boxes *i* ``(K, 1)`` against
+    row-form boxes *j* ``(1, K)`` — the one copy of the arithmetic, shared
+    by the XLA form and the Pallas kernel body."""
+    iw = jnp.maximum(
+        0.0, jnp.minimum(xc + wc, xr + wr) - jnp.maximum(xc, xr) + 1.0)
+    ih = jnp.maximum(
+        0.0, jnp.minimum(yc + hc, yr + hr) - jnp.maximum(yc, yr) + 1.0)
     inter = iw * ih
-    area = w * h
-    union = area[:, None] + area[None, :] - inter
+    union = wc * hc + wr * hr - inter
     # iou > 0.5  ⟺  2·inter > union: exact on integer-valued floats,
     # immune to the float-division rounding the direct form would add
     return (union > 0.0) & (2.0 * inter > union)
+
+
+def suppression_matrix(x, y, w, h):
+    """(K, K) bool: ``iou(i, j) > 0.5`` under the host loop's exact
+    arithmetic.  Inputs are integer-valued float32 pixel boxes."""
+    cols = (v[:, None] for v in (x, y, w, h))
+    rows = (v[None, :] for v in (x, y, w, h))
+    return _pairwise_suppression(*cols, *rows)
 
 
 def greedy_keep(sup, valid):
@@ -91,7 +96,13 @@ def pallas_nms_keep(x, y, w, h, valid, interpret: Optional[bool] = None):
     once, the suppression matrix never materializes in HBM, and the
     sequential walk runs in-kernel.  Inputs/outputs match
     :func:`nms_keep` bit-for-bit (the kernel body *is* the same
-    arithmetic)."""
+    arithmetic).
+
+    Mosaic has no 1-D dynamic indexing, so everything in the kernel is
+    2-D: boxes arrive as a ``(4, Kp)`` row block *and* its ``(Kp, 4)``
+    transpose (the pairwise terms are then plain broadcasts), the 0/1
+    suppression matrix is parked in a VMEM scratch whose row *i* is a
+    dynamic sublane slice, and ``keep[i]`` is a masked lane reduction."""
     if interpret is None:
         interpret = _interpret()
     k = int(x.shape[0])
@@ -101,18 +112,34 @@ def pallas_nms_keep(x, y, w, h, valid, interpret: Optional[bool] = None):
     def _pad(v, fill=0.0):
         return jnp.pad(v.astype(jnp.float32), (0, pad), constant_values=fill)
 
-    def kernel(x_ref, y_ref, w_ref, h_ref, v_ref, out_ref):
-        sup = suppression_matrix(x_ref[:], y_ref[:], w_ref[:], h_ref[:])
-        keep = greedy_keep(sup, v_ref[:] != 0)
-        out_ref[:] = keep.astype(jnp.int32)
+    # padded rows: never valid, and placed where no real box overlaps them
+    rows = jnp.stack([_pad(x), _pad(y), _pad(w, fill=-1.0),
+                      _pad(h, fill=-1.0)])
+    seed = _pad(valid.astype(jnp.float32)).reshape(1, kp)
+
+    def kernel(row_ref, col_ref, v_ref, out_ref, sup_ref):
+        xr, yr, wr, hr = (row_ref[i:i + 1, :] for i in range(4))  # (1, Kp)
+        xc, yc, wc, hc = (col_ref[:, i:i + 1] for i in range(4))  # (Kp, 1)
+        sup_ref[...] = jnp.where(
+            _pairwise_suppression(xc, yc, wc, hc, xr, yr, wr, hr), 1.0, 0.0)
+        lane = lax.broadcasted_iota(jnp.int32, (1, kp), 1)
+
+        def body(i, keep):
+            keep_i = jnp.max(jnp.where(lane == i, keep, 0.0),
+                             axis=1, keepdims=True)
+            mask = (sup_ref[pl.ds(i, 1), :] > 0.0) & (lane > i) \
+                & (keep_i > 0.0)
+            return jnp.where(mask, 0.0, keep)
+
+        out_ref[...] = lax.fori_loop(0, k, body, v_ref[...]).astype(jnp.int32)
 
     out = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((kp,), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((1, kp), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((kp, kp), jnp.float32)],
         interpret=interpret,
-    )(_pad(x), _pad(y), _pad(w, fill=-1.0), _pad(h, fill=-1.0),
-      _pad(valid.astype(jnp.float32)))
-    return out[:k] != 0
+    )(rows, rows.T, seed)
+    return out[0, :k] != 0
 
 
 def keep_fn(use_pallas: bool):

@@ -10,51 +10,10 @@ offload per frame.  Here parallel invocation is first-class: a
 
 from __future__ import annotations
 
-import inspect
 from typing import Optional, Sequence, Tuple
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.8 exports shard_map top-level; older releases under
-    from jax import shard_map as _shard_map  # experimental
-except ImportError:  # pragma: no cover — version-dependent
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# the replication-check kwarg renamed check_rep → check_vma across jax
-# versions; resolve once at import
-_CHECK_KW = (
-    "check_vma"
-    if "check_vma" in inspect.signature(_shard_map).parameters
-    else "check_rep"
-)
-
-
-def shard_map(fn, mesh: Mesh, in_specs, out_specs,
-              check_vma: Optional[bool] = None):
-    """Version-portable :func:`jax.shard_map`: one import site for the
-    top-level vs ``jax.experimental`` move and the ``check_rep`` →
-    ``check_vma`` kwarg rename, so every ``parallel/`` module (and the
-    transformer model's ring-attention path) works across the jax
-    versions this repo meets in the wild."""
-    kwargs = {} if check_vma is None else {_CHECK_KW: check_vma}
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kwargs)
-
-
-def distributed_initialized() -> bool:
-    """Has ``jax.distributed.initialize`` already run in this process?
-    (``jax.distributed.is_initialized`` only exists on newer jax; older
-    releases expose the same fact through the global client state.)"""
-    probe = getattr(jax.distributed, "is_initialized", None)
-    if probe is not None:
-        return bool(probe())
-    try:  # pragma: no cover — version-dependent fallback
-        from jax._src.distributed import global_state
-
-        return global_state.client is not None
-    except Exception:  # noqa: BLE001 — no distributed support at all
-        return False
 
 
 def make_mesh(
@@ -96,7 +55,7 @@ def init_distributed(
     never had.  Returns the process count.  Idempotent: a second call is a
     no-op.
     """
-    if distributed_initialized():
+    if jax.distributed.is_initialized():
         return jax.process_count()  # already joined: no-op
     jax.distributed.initialize(
         coordinator_address=coordinator_address,

@@ -25,8 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .mesh import shard_map
-
 
 def stack_stage_params(per_stage_params):
     """[stage0_tree, stage1_tree, ...] → one tree with leading stage dim."""
@@ -63,10 +61,9 @@ def gpipe_apply(
     )
 
     # The scan carry starts replicated (zeros) but becomes device-varying
-    # after the first tick; relax the varying-axes check (the compat
-    # wrapper maps check_vma onto check_rep for older jax).
+    # after the first tick; relax the varying-axes check.
     @functools.partial(
-        shard_map, mesh=mesh, in_specs=in_specs, out_specs=P(axis),
+        jax.shard_map, mesh=mesh, in_specs=in_specs, out_specs=P(axis),
         check_vma=False,
     )
     def run(params_local, xs_all):

@@ -119,9 +119,7 @@ def ring_attention(
         return jnp.transpose(out, (0, 2, 1, 3))  # (B, Tq, H, D)
 
     spec = P(None, axis, None, None)
-    from .mesh import shard_map
-
-    return shard_map(
+    return jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(spec, spec, spec),

@@ -48,9 +48,7 @@ def ulysses_attention(
         return heads_to_seq(out)
 
     spec = P(None, axis, None, None)
-    from .mesh import shard_map
-
-    return shard_map(
+    return jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(spec, spec, spec),

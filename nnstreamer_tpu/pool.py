@@ -5,8 +5,8 @@ are the throughput levers of this framework, but their coalescing step was a
 fresh ``np.stack`` per dispatch: every batch paid one full memcpy pass PLUS
 a cold multi-MB allocation (mmap + page-fault zeroing — the hidden second
 pass).  ``tools/profile_mux_overhead.py`` attributed 59% of 8-stream busy
-time to exactly that memcpy on 602 KB frames (BENCH_NOTES.md "Mux
-per-stream overhead finding").  The reference's answer is recycled,
+time to exactly that memcpy on 602 KB frames on a CPU host.  The
+reference's answer is recycled,
 ref-counted buffers (``GstBufferPool`` + the ``allocate_in_invoke``
 zero-copy hand-off, ``tensor_filter.c:350-399``); this module is that
 discipline for the TPU-native hot path:
@@ -67,8 +67,8 @@ import numpy as np
 DEFAULT_MAX_PER_CLASS = 4
 DEFAULT_MAX_BYTES = 64 << 20        # 64 MiB of *free* (pooled) bytes
 # Per-row bytes above which the CPU-fallback batch elements skip host
-# concat and invoke per stream.  Default 0 = opt-in: the 602 KB identity
-# sweep (BENCH_NOTES "Zero-copy hot path") measured the per-row dispatch
+# concat and invoke per stream.  Default 0 = opt-in: a 602 KB identity
+# sweep on a CPU host measured the per-row dispatch
 # overhead costing MORE than the skipped memcpy saves on this runtime, so
 # pooled slot-wise assembly stays the default remedy; the knob remains
 # for payload/model mixes where per-stream invoke wins.
@@ -454,7 +454,7 @@ def skip_host_concat(row_nbytes: int, platform: Optional[str] = None) -> bool:
     True only when (a) the downstream consumer runs on the CPU fallback —
     on a real accelerator the batched transfer is the whole point — and
     (b) the per-row payload is at or above the threshold, the regime where
-    BENCH_NOTES measured coalescing costing more than it amortizes.
+    coalescing can cost more than it amortizes.
     ``platform`` is the consumer's ``jax.default_backend()`` string; pass
     None when the downstream backend is unknown (never skips: a non-jax
     consumer would just pay the stack later via ``np.asarray``).

@@ -244,12 +244,10 @@ class ContinuousBatcher:
 
         if capacity < 1:
             raise ValueError("capacity must be >= 1")
-        # compile-ahead: with [compile] cache_dir set, the step/prefill
-        # compiles below land in jax's persistent binary cache, so a
-        # restarted decode worker reconstructs instead of compiling
-        root = exec_cache.cache_dir()
-        if root:
-            exec_cache.wire_jax_compilation_cache(root)
+        # the step/prefill compiles below land in jax's persistent binary
+        # cache, so a restarted decode worker reconstructs instead of
+        # compiling
+        exec_cache.ensure_compile_cache()
         self.capacity = int(capacity)
         self.d_in, self.n_out, self.t_max = d_in, n_out, t_max
         self.window = window

@@ -178,7 +178,8 @@ class TestTraceJoin:
     def test_rtt_without_server_envelope_is_unattributed_not_wire(self):
         """When neither route nor serve joined (ring overflow, a worker
         flight never collected) the RTT gap is UNKNOWN: charging it to
-        ``wire`` would send readers chasing tunnel ghosts."""
+        ``wire`` would send readers chasing a wire problem that is not
+        there."""
         recs = [_rec(0, 100, "nnsq_rtt", 1, 1)]
         legs = attribute_trace(recs)
         assert "wire" not in legs

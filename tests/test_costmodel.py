@@ -287,17 +287,6 @@ class TestPerfdiff:
         (v,) = perfdiff.diff_cost_models(noisy, _doc_with(1500.0))
         assert v["verdict"] == "flat"
 
-    def test_ladder_bank_verdicts(self):
-        base = {"cell1": {"mfu": 0.10}, "cell2": {"mfu": 0.10},
-                "cell3": {"mfu": 0.10}, "unmeasured": {"mfu": None}}
-        cur = {"cell1": {"mfu": 0.101}, "cell2": {"mfu": 0.05},
-               "cell3": {"mfu": 0.20}, "unmeasured": {"mfu": None}}
-        verdicts = perfdiff.diff_ladder_banks(base, cur)
-        by_key = {v["key"]: v["verdict"] for v in verdicts}
-        assert by_key == {"cell1": "flat", "cell2": "regressed",
-                          "cell3": "improved"}
-        assert all(v["leg"] == "mfu" for v in verdicts)
-
     def test_cli_self_compare_exits_zero_flat(self, tmp_path, capsys):
         path = tmp_path / "cm.json"
         path.write_text(json.dumps(_doc_with(1000.0)))

@@ -521,9 +521,10 @@ class TestQueueRecover:
 
 
 class TestDegradedBackend:
-    def test_compile_failure_degrades_to_cpu_and_serves(self):
+    def test_compile_failure_degrades_to_cpu_when_asked(self, monkeypatch):
         from nnstreamer_tpu.obs.export import degraded_snapshot
 
+        monkeypatch.setenv("NNSTPU_RECOVERY_CPU_FALLBACK", "true")  # opt in
         eng = faults.install("compile_raise:count=1")
         model = JaxModel(apply=lambda p_, x: x * 3.0, input_spec=VEC4,
                          name="degrade_me")
@@ -550,19 +551,30 @@ class TestDegradedBackend:
         # close() withdrew the degraded reason: /healthz is clean again
         assert not degraded_snapshot()
 
-    def test_cpu_fallback_can_be_disabled(self, monkeypatch):
-        monkeypatch.setenv("NNSTPU_RECOVERY_CPU_FALLBACK", "false")
-        faults.install("compile_raise:count=1")
+    def test_compile_failure_fails_the_pipeline_by_default(self):
+        """No env, no ini: a compile that raises takes the stream down —
+        nothing is served from the CPU behind a healthy /healthz."""
+        from nnstreamer_tpu.conf import DEFAULTS
+        from nnstreamer_tpu.obs.export import degraded_snapshot
+
+        assert DEFAULTS["recovery"]["cpu_fallback"] == "false"
+        eng = faults.install("compile_raise:count=1")
         model = JaxModel(apply=lambda p_, x: x, input_spec=VEC4)
+        got = []
         p = Pipeline(name="faults_nodegrade")
         src = p.add(DataSrc(data=_frames(2)))
         filt = p.add(TensorFilter(framework="jax", model=model, name="f"))
-        p.link_chain(src, filt, p.add(TensorSink(name="out")))
-        with pytest.raises((PipelineError, InjectedFault, Exception)):
+        sink = p.add(TensorSink(name="out"))
+        sink.connect("new-data", got.append)
+        p.link_chain(src, filt, sink)
+        with pytest.raises((PipelineError, InjectedFault)):
             p.start()
             p.wait(timeout=60)
         p.stop()
+        assert eng.injections["compile_raise"] == 1
+        assert got == []
         assert filt.backend._degraded is None
+        assert not degraded_snapshot()
 
 
 # -- restart policy object -------------------------------------------------

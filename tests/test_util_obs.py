@@ -1,8 +1,8 @@
 """The device utilization lane (obs/util.py + device-lane wiring):
-roofline math over synthetic cost payloads, busy-fraction windowing over
-overlapping multi-device spans, per-dispatch MFU attribution on a CPU
-host (where ``cost_analysis()`` may be flaky), ``device_idle`` dead-time
-spans, live wire-health gauges, and the bench MFU-ladder evidence bank.
+roofline math over synthetic cost payloads, the ``device_kind`` peak table
+(a device outside it has no MFU), busy-fraction windowing over overlapping
+multi-device spans, per-dispatch cost attribution on a CPU host,
+``device_idle`` dead-time spans, and live wire-health gauges.
 """
 
 import threading
@@ -63,7 +63,8 @@ class TestRoofline:
         """Zero/missing flops (flaky CPU cost_analysis) degrade to
         mfu=None + unknown — never an exception."""
         for flops in (None, 0, 0.0):
-            rl = obs_util.roofline(flops, None, 0.5)
+            rl = obs_util.roofline(flops, None, 0.5, peak_tf=100.0,
+                                   peak_gb=100.0)
             assert rl["mfu"] is None
             assert rl["achieved_tflops"] is None
             assert rl["bound"] == "unknown"
@@ -80,16 +81,16 @@ class TestRoofline:
         assert obs_util.roofline("x", "y", "z")["mfu"] is None
 
     def test_cost_info_payload_shapes(self):
-        """cost_analysis() shapes across jax versions / fused wrappers:
-        a dict, a per-program list, missing keys, a raising backend."""
-
-        class ListCA:
-            def cost_analysis(self):
-                return [{"flops": 10.0, "bytes accessed": 20.0}]
+        """cost_analysis() as the installed jax returns it (one dict),
+        with missing keys, and from a backend that doesn't implement it."""
 
         class DictCA:
             def cost_analysis(self):
-                return {"flops": 0.0, "bytes_accessed": 7.0}
+                return {"flops": 10.0, "bytes accessed": 20.0}
+
+        class ZeroFlops:
+            def cost_analysis(self):
+                return {"flops": 0.0, "bytes accessed": 7.0}
 
         class NoneCA:
             def cost_analysis(self):
@@ -99,11 +100,62 @@ class TestRoofline:
             def cost_analysis(self):
                 raise RuntimeError("unimplemented")
 
-        assert cost_info(ListCA()) == {"flops": 10.0, "bytes": 20.0}
-        # zero flops drops out; the alternate bytes spelling resolves
-        assert cost_info(DictCA()) == {"bytes": 7.0}
+        assert cost_info(DictCA()) == {"flops": 10.0, "bytes": 20.0}
+        assert cost_info(ZeroFlops()) == {"bytes": 7.0}  # zero flops drops
         assert cost_info(NoneCA()) == {}
         assert cost_info(Raises()) == {}
+
+    def test_cost_info_of_a_real_executable(self):
+        import jax
+        import jax.numpy as jnp
+
+        compiled = jax.jit(lambda x: x @ x).lower(
+            jnp.ones((16, 16), jnp.float32)).compile()
+        info = cost_info(compiled)
+        assert info["flops"] > 0 and info["bytes"] > 0
+
+
+class TestPeakTable:
+    """One table keyed by device_kind; outside it there is no MFU."""
+
+    def test_v5e_row_is_the_published_peak(self):
+        assert obs_util.peak_tflops("TPU v5 lite") == 197.0
+        assert obs_util.peak_gbs("TPU v5 lite") == 819.0
+        rl = obs_util.roofline(197e12 / 2, 1e9, 1.0,
+                               peak_tf=obs_util.peak_tflops("TPU v5 lite"),
+                               peak_gb=obs_util.peak_gbs("TPU v5 lite"))
+        assert rl["mfu"] == pytest.approx(0.5)
+        assert rl["ridge"] == pytest.approx(197e12 / 819e9, rel=1e-3)
+        assert rl["bound"] == "compute_bound"
+
+    def test_unknown_kind_and_cpu_have_no_peak(self):
+        for kind in ("TPU v9 imaginary", "cpu", "NVIDIA H100", ""):
+            assert obs_util.peak_tflops(kind) is None
+            assert obs_util.peak_gbs(kind) is None
+        # this process runs on the CPU: the default lookup finds no row
+        assert obs_util.peak_tflops() is None
+        assert obs_util.peak_gbs() is None
+
+    def test_no_peak_means_no_mfu_and_no_roofline_share(self):
+        rl = obs_util.roofline(2e12, 1e9, 1.0)  # CPU host: peaks unknown
+        assert rl["mfu"] is None
+        assert rl["ridge"] is None
+        assert rl["bound"] == "unknown"
+        # what the dispatch did is still reported
+        assert rl["achieved_tflops"] == pytest.approx(2.0)
+        assert rl["achieved_gbs"] == pytest.approx(1.0)
+        assert rl["intensity"] == 2000.0
+
+    def test_no_peak_override_knobs_remain(self, monkeypatch):
+        """A peak is a fact about a device, not an option: the old env /
+        ini overrides are gone, so a CPU run cannot be given an MFU."""
+        from nnstreamer_tpu.conf import DEFAULTS, SHORT_ENV
+
+        monkeypatch.setenv("NNSTPU_PEAK_TFLOPS", "1.0")
+        monkeypatch.setenv("NNSTPU_OBS_PEAK_TFLOPS", "1.0")
+        assert obs_util.peak_tflops() is None
+        assert "peak_tflops" not in DEFAULTS["obs"]
+        assert "NNSTPU_PEAK_TFLOPS" not in SHORT_ENV
 
 
 class TestCostRegistry:
@@ -247,11 +299,12 @@ def _matmul_model(dim=64):
 
 
 class TestUtilizationLane:
-    def test_mfu_series_and_span_args_on_cpu(self):
+    def test_cost_stamped_spans_but_no_mfu_on_cpu(self):
         """The acceptance pipeline: a jax filter + DeviceTracer on a CPU
-        host yields nnstpu_mfu / nnstpu_device_busy_fraction series,
-        roofline-classified device_exec span args, and a by_device
-        summary carrying busy fraction + aggregate MFU."""
+        host yields nnstpu_device_busy_fraction series and cost-stamped
+        device_exec span args — but NO nnstpu_mfu series, no MFU in the
+        by_device summary and no roofline class: the CPU's device_kind
+        is not in the peak table."""
         reg = MetricsRegistry()
         p = Pipeline(name="util_lane")
         src = p.add(DataSrc(
@@ -265,7 +318,7 @@ class TestUtilizationLane:
         summ = tracer.summary()
         (label, dev), = summ["by_device"].items()
         assert dev["count"] == 6
-        assert dev["mfu"] is not None and dev["mfu"] > 0
+        assert dev["mfu"] is None
         assert 0.0 <= dev["busy_fraction"] <= 1.0
         assert dev["cost_missing"] == 0
 
@@ -274,12 +327,13 @@ class TestUtilizationLane:
         assert len(execs) == 6
         args = execs[-1][9]
         assert args["flops"] > 0 and args["bytes"] > 0
-        assert args["mfu"] is not None
-        assert args["roofline"] in ("compute_bound", "bandwidth_bound")
+        assert args["achieved_tflops"] > 0 and args["achieved_gbs"] > 0
+        assert args["mfu"] is None
+        assert args["roofline"] == "unknown"
         assert args["cost_key"]
 
         text = render_text(reg)
-        assert 'nnstpu_mfu{device="%s",node="f",bucket="64"}' % label in text
+        assert "nnstpu_mfu{" not in text
         assert 'nnstpu_device_busy_fraction{device="%s"}' % label in text
         assert "nnstpu_roofline_dispatches_total" in text
 
@@ -385,95 +439,3 @@ class TestBackendCostRegistration:
         assert key2 and key2 != key1
         be.reconfigure(spec)
         assert be.cost_key() == key1
-
-
-# -- the bench MFU-ladder campaign -------------------------------------------
-
-class TestMfuLadder:
-    @pytest.fixture
-    def bench_mod(self, tmp_path, monkeypatch):
-        import bench
-
-        cache = str(tmp_path / "cache.json")
-        monkeypatch.setattr(bench, "TPU_CACHE_PATH", cache)
-        # save_tpu_cache archives next to a REDIRECTED cache only when
-        # the env var is set — keep the append-only run archive out of
-        # the repo's BENCH_RUNS/
-        monkeypatch.setenv("BENCH_TPU_CACHE_PATH", cache)
-        return bench
-
-    def test_plumbing_matrix_off_accel(self, bench_mod):
-        """On a host with no accelerator every cell types itself
-        skipped{reason=no_accel}; the 12-cell matrix is complete."""
-        gates = []
-        res = bench_mod.measure_mfu_ladder(
-            lambda label: gates.append(label), on_accel=False)
-        assert len(res["cells"]) == 12
-        assert all(c["skipped"]["reason"] == "no_accel"
-                   for c in res["cells"].values())
-        assert gates == []  # no wire probes burned on skipped cells
-        assert res["banked_cells"] == 0
-
-    def test_sick_wire_cell_is_typed_skip(self, bench_mod, monkeypatch):
-        monkeypatch.setattr(bench_mod, "LADDER_BATCHES", (8,))
-        monkeypatch.setattr(bench_mod, "LADDER_DTYPES", ("fp32",))
-        monkeypatch.setattr(bench_mod, "LADDER_MESHES", (1,))
-        res = bench_mod.measure_mfu_ladder(
-            lambda label: {"put_150k_ms": 30.0, "dispatch_ms": 1.0},
-            on_accel=True)
-        (cell,) = res["cells"].values()
-        assert cell["skipped"]["reason"] == "wire"
-        assert cell["skipped"]["wire"]["put_150k_ms"] == 30.0
-
-    def test_bank_merge_idempotent_and_best_of(self, bench_mod):
-        key = bench_mod.ladder_cell_key(8, "fp32", 1, "fast")
-        cell = {"batch": 8, "dtype": "fp32", "mesh": 1, "mfu": 0.012,
-                "wire_regime": "fast", "measured_at": "t"}
-        b1 = bench_mod.merge_ladder_bank({key: cell})
-        b2 = bench_mod.merge_ladder_bank({key: cell})
-        assert b1 == b2 == bench_mod.load_ladder_bank()
-        # a worse later measurement never clobbers the banked evidence
-        bench_mod.merge_ladder_bank({key: dict(cell, mfu=0.001)})
-        assert bench_mod.load_ladder_bank()[key]["mfu"] == 0.012
-        # a better one replaces it
-        bench_mod.merge_ladder_bank({key: dict(cell, mfu=0.05)})
-        assert bench_mod.load_ladder_bank()[key]["mfu"] == 0.05
-
-    def test_save_tpu_cache_preserves_bank(self, bench_mod):
-        key = bench_mod.ladder_cell_key(32, "int8", 8, "fast")
-        bench_mod.merge_ladder_bank(
-            {key: {"batch": 32, "dtype": "int8", "mesh": 8, "mfu": 0.2}})
-        bench_mod.save_tpu_cache(
-            {"value": 1.0, "vs_baseline": None, "extra": {}})
-        assert bench_mod.load_ladder_bank()[key]["mfu"] == 0.2
-
-    def test_forced_cpu_cell_measures_and_banks(self, bench_mod,
-                                                monkeypatch):
-        """BENCH_MFU_LADDER_ON_CPU=1 exercises the real measurement +
-        banking path on the host backend (slow model shrunk to one tiny
-        cell via the grid monkeypatch)."""
-        monkeypatch.setenv("BENCH_MFU_LADDER_ON_CPU", "1")
-        monkeypatch.setattr(bench_mod, "LADDER_BATCHES", (8,))
-        monkeypatch.setattr(bench_mod, "LADDER_DTYPES", ("fp32",))
-        monkeypatch.setattr(bench_mod, "LADDER_MESHES", (1,))
-        monkeypatch.setattr(bench_mod, "LADDER_TARGETS", {8: 0.01})
-
-        orig_point = bench_mod.ladder_point
-
-        def tiny_point(batch, dtype, ndev, image_size=224):
-            return orig_point(batch, dtype, ndev, image_size=32)
-
-        monkeypatch.setattr(bench_mod, "ladder_point", tiny_point)
-        res = bench_mod.measure_mfu_ladder(lambda label: None,
-                                           on_accel=False)
-        (cell,) = res["cells"].values()
-        assert "skipped" not in cell, cell
-        assert cell["step_ms"] > 0 and cell["wire_regime"] == "local"
-        assert cell["roofline"] in ("compute_bound", "bandwidth_bound",
-                                    "unknown")
-        bank = bench_mod.load_ladder_bank()
-        assert len(bank) == 1
-        # second run re-reads the bank (idempotent across invocations)
-        res2 = bench_mod.measure_mfu_ladder(lambda label: None,
-                                            on_accel=False)
-        assert res2["banked_cells"] == 1

@@ -1,25 +1,21 @@
 #!/usr/bin/env python
 """perfdiff: typed regression verdicts over persisted performance evidence.
 
-Compares two snapshots of the repo's on-disk performance memory —
-``COST_MODEL.json`` (per-stage leg aggregates from the cost-observatory
-tracer) and the ``mfu_ladder`` bank inside ``BENCH_TPU_CACHE.json`` —
-and emits one typed verdict per comparable series:
+Compares two snapshots of ``COST_MODEL.json`` (per-stage leg aggregates
+from the cost-observatory tracer) and emits one typed verdict per
+comparable series:
 
 - ``flat``       — the delta sits inside the noise band;
-- ``improved``   — current is better by more than the band
-  (lower µs for stage legs, higher MFU for ladder cells);
+- ``improved``   — current is better (lower µs) by more than the band;
 - ``regressed``  — current is worse by more than the band; the verdict
   carries WHICH leg regressed (``dispatch`` / ``device_exec`` /
-  ``queue_wait`` / ``wire`` / ``mfu``), because "the pipeline got
-  slower" is not actionable and "the wire leg got slower" is.
+  ``queue_wait`` / ``wire``), because "the pipeline got slower" is not
+  actionable and "the wire leg got slower" is.
 
 The noise band is derived from the evidence itself: stage legs persist
 Welford aggregates (count/mean/m2), so the band is
 ``max(sigmas × sample-std, min_rel × baseline, min_abs)`` — a leg that
-historically swings 40% does not page anyone over a 10% delta.  Ladder
-cells bank single best-of measurements (no variance), so they use the
-relative band alone.
+historically swings 40% does not page anyone over a 10% delta.
 
 A self-compare (baseline == current) is ``flat`` by construction — the
 CI smoke pins that.  The report is NON-FATAL by default (exit 0, it is
@@ -33,8 +29,6 @@ Usage::
 
     python tools/perfdiff.py                       # self-compare (flat)
     python tools/perfdiff.py --baseline old.json --current new.json
-    python tools/perfdiff.py --bank-baseline old_cache.json \\
-                             --bank-current BENCH_TPU_CACHE.json
     python -m tools.perfdiff --json --strict
 """
 
@@ -44,7 +38,7 @@ import argparse
 import json
 import os
 import sys
-from typing import Dict, List, Optional
+from typing import Dict, List
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -61,7 +55,7 @@ def _regression_counter(registry=None):
     return registry.counter(
         "nnstpu_perf_regression_total",
         "Regressed perfdiff verdicts, by leg "
-        "(dispatch/device_exec/queue_wait/wire/mfu)", ("leg",))
+        "(dispatch/device_exec/queue_wait/wire)", ("leg",))
 
 
 def stage_band_us(leg_stat: dict, sigmas: float = DEFAULT_SIGMAS,
@@ -106,34 +100,6 @@ def diff_cost_models(baseline: dict, current: dict,
     return verdicts
 
 
-def diff_ladder_banks(baseline: dict, current: dict,
-                      min_rel: float = DEFAULT_MIN_REL) -> List[dict]:
-    """One verdict per ladder cell key present in BOTH banks (compared
-    on MFU; higher is better)."""
-    verdicts: List[dict] = []
-    for key in sorted(set(baseline) & set(current)):
-        b = (baseline[key] or {}).get("mfu")
-        c = (current[key] or {}).get("mfu")
-        if b is None or c is None:
-            continue
-        band = min_rel * abs(float(b))
-        delta = float(c) - float(b)
-        if abs(delta) <= band:
-            verdict = "flat"
-        elif delta > 0:
-            verdict = "improved"
-        else:
-            verdict = "regressed"
-        verdicts.append({
-            "kind": "ladder", "key": key, "leg": "mfu",
-            "baseline_mfu": round(float(b), 5),
-            "current_mfu": round(float(c), 5),
-            "delta_mfu": round(delta, 5), "band_mfu": round(band, 5),
-            "verdict": verdict,
-        })
-    return verdicts
-
-
 def overall_verdict(verdicts: List[dict]) -> str:
     kinds = {v["verdict"] for v in verdicts}
     if "regressed" in kinds:
@@ -163,35 +129,15 @@ def report(verdicts: List[dict], registry=None) -> dict:
     }
 
 
-def _load_bank(path: Optional[str]) -> Optional[dict]:
-    if not path:
-        return None
-    try:
-        with open(path) as f:
-            doc = json.load(f)
-    except Exception:  # noqa: BLE001 — absent evidence, empty comparison
-        return {}
-    if isinstance(doc, dict) and isinstance(doc.get("mfu_ladder"), dict):
-        return doc["mfu_ladder"]
-    return doc if isinstance(doc, dict) else {}
-
-
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
-        description="typed perf-regression verdicts over COST_MODEL.json "
-                    "+ the banked mfu ladder")
+        description="typed perf-regression verdicts over COST_MODEL.json")
     ap.add_argument("--baseline", default=None,
                     help="baseline COST_MODEL.json (default: the "
                          "configured live path — self-compare)")
     ap.add_argument("--current", default=None,
                     help="current COST_MODEL.json (default: the "
                          "configured live path)")
-    ap.add_argument("--bank-baseline", default=None,
-                    help="baseline BENCH_TPU_CACHE.json (or a bare "
-                         "mfu_ladder bank); ladder cells are only "
-                         "compared when both bank paths are given")
-    ap.add_argument("--bank-current", default=None,
-                    help="current BENCH_TPU_CACHE.json")
     ap.add_argument("--sigmas", type=float, default=DEFAULT_SIGMAS)
     ap.add_argument("--min-rel", type=float, default=DEFAULT_MIN_REL)
     ap.add_argument("--min-abs-us", type=float, default=DEFAULT_MIN_ABS_US)
@@ -208,24 +154,15 @@ def main(argv=None) -> int:
     verdicts = diff_cost_models(base_doc, cur_doc, sigmas=args.sigmas,
                                 min_rel=args.min_rel,
                                 min_abs_us=args.min_abs_us)
-    b_bank = _load_bank(args.bank_baseline)
-    c_bank = _load_bank(args.bank_current)
-    if b_bank is not None and c_bank is not None:
-        verdicts += diff_ladder_banks(b_bank, c_bank, min_rel=args.min_rel)
 
     rep = report(verdicts)
     if args.json:
         print(json.dumps(rep, indent=1, sort_keys=True))
     else:
         for v in verdicts:
-            if v["kind"] == "stage":
-                print(f"{v['verdict']:>9}  {v['key']} [{v['leg']}]  "
-                      f"{v['baseline_us']} -> {v['current_us']} us  "
-                      f"(band {v['band_us']})")
-            else:
-                print(f"{v['verdict']:>9}  {v['key']} [mfu]  "
-                      f"{v['baseline_mfu']} -> {v['current_mfu']}  "
-                      f"(band {v['band_mfu']})")
+            print(f"{v['verdict']:>9}  {v['key']} [{v['leg']}]  "
+                  f"{v['baseline_us']} -> {v['current_us']} us  "
+                  f"(band {v['band_us']})")
         print(f"# perfdiff: {rep['verdict']} — {rep['compared']} compared, "
               f"{rep['flat']} flat / {rep['improved']} improved / "
               f"{rep['regressed']} regressed"

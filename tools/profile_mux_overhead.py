@@ -1,9 +1,10 @@
 #!/usr/bin/env python
-"""Why does CPU-fallback mux throughput DECLINE as streams are added?
+"""Where does the mux path's host machinery spend its time per stream?
 
-VERDICT r5 item 4: `config5_scaling {1: 5.84 -> 8: 4.81}` — on the CPU
-fallback the mux->batch->filter->unbatch->demux path LOSES aggregate
-throughput per added stream, where batching should at worst be flat.
+Pinned to the CPU on purpose: with an identity model the
+mux->batch->filter->unbatch->demux path is pure host machinery, and on a
+CPU host its aggregate throughput DECLINES per added stream where
+batching should at worst be flat.
 This tool isolates where the per-stream cost lands:
 
 - sweeps STREAM COUNTS (1, 2, 4, 8 by default) at a fixed TOTAL frame
@@ -41,15 +42,12 @@ This tool isolates where the per-stream cost lands:
   columns ride the same sweep, so "8 streams decline" separates into
   "chip idle" vs "chip busy on machinery".
 
-Usage: ``python tools/profile_mux_overhead.py [--mesh[=SPEC]] [--ttff]
+Usage: ``python tools/profile_mux_overhead.py [--mesh[=SPEC]]
 [--lanes[=N]] [TOTAL_FRAMES] [SWEEP...]`` e.g. ``python
 tools/profile_mux_overhead.py 2000 1 2 4 8 16 32 64``.  ``--mesh``
 (default spec ``dp:8``) sweeps the mesh-sharded dispatch lane over a
 forced 8-device host mesh and adds chips-used / per-shard-batch
-columns.  ``--ttff`` prints cold-vs-warm time-to-first-frame columns
-instead of the sweep: two fresh processes against one persistent
-executable cache (``[compile] cache_dir`` + warmup), the warm row gated
-on zero compile misses.  ``--lanes`` (default ``auto``) runs the sweep
+columns.  ``--lanes`` (default ``auto``) runs the sweep
 on the dispatcher-lane runtime (``graph/lanes.py``) instead of
 thread-per-element; either way a ``lanes`` column reports the mode and
 the run ends with a lane-vs-thread A/B at the widest point (the other
@@ -57,8 +55,8 @@ mode re-measured) plus the 8→widest flatness verdict — thread mode
 multiplies host threads per stream and declines, lanes must hold the
 widest point within ~10% of the 8-stream point.
 ``NNSTPU_POOL_ENABLED=false NNSTPU_POOL_CONCAT_THRESHOLD=0`` reproduces
-the pre-pool behavior for an A/B.  Appends nothing; copy the table +
-verdict into BENCH_NOTES.md.
+the pre-pool behavior for an A/B.  A host-dispatch profile, never a
+device metric.
 """
 import os
 import sys
@@ -66,22 +64,7 @@ import threading
 import time
 from collections import defaultdict
 
-_T0 = time.perf_counter()  # process start for the --ttff-child probe
-
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-# --ttff: cold-vs-warm time-to-first-frame columns (process start →
-# first sink frame) — the compile-ahead lane's proof, run as two fresh
-# child processes against one persistent executable cache.
-TTFF = False
-TTFF_CHILD = False
-for _arg in list(sys.argv):
-    if _arg == "--ttff":
-        TTFF = True
-        sys.argv.remove(_arg)
-    elif _arg == "--ttff-child":
-        TTFF_CHILD = True
-        sys.argv.remove(_arg)
 
 # --lanes[=N|auto]: run the sweep on the dispatcher-lane runtime
 # ([dispatch] lanes); the A/B verdict at the end measures the other mode
@@ -292,7 +275,6 @@ def run_mux(streams, frames_per_stream, attribute=False, lanes=None,
             hooks.disconnect("dispatch_exit", attr)
     done = state["count"] - max(1, streams)  # exclude the clock-start frame(s)
     fps = done / (time.perf_counter() - state["t0"])
-    copies.t_first = state["t0"]  # absolute first-frame ts (--ttff-child)
     total_in = streams * frames_per_stream
     copies.per_frame = copies.nbytes / max(1, total_in)
     copies.allocs_per_frame = copies.allocs / max(1, total_in)
@@ -339,66 +321,7 @@ def run_mux(streams, frames_per_stream, attribute=False, lanes=None,
     return fps, wall, attr, copies
 
 
-def ttff_child() -> None:
-    """One cold/warm probe leg: 4-stream mux pipeline, JSON line out
-    (``ttff_s`` = process start → first sink frame)."""
-    import json
-
-    from nnstreamer_tpu.obs.metrics import REGISTRY
-
-    _, _, _, cp = run_mux(4, 8)
-    c = REGISTRY.get("nnstpu_compile_total")
-    compiles = ({k[0]: int(v.value) for k, v in dict(c.children()).items()}
-                if c else {})
-    print(json.dumps({"ttff_s": round(cp.t_first - _T0, 4),
-                      "compiles": compiles}))
-
-
-def ttff_sweep() -> None:
-    """Cold-vs-warm TTFF columns: the same pipeline in two fresh
-    processes against one persistent executable cache ([compile]
-    cache_dir).  The warm row must show zero compile misses."""
-    import json
-    import shutil
-    import subprocess
-    import tempfile
-
-    cache = tempfile.mkdtemp(prefix="nns_mux_ttff_")
-    try:
-        env = dict(os.environ,
-                   NNSTPU_COMPILE_CACHE_DIR=cache,
-                   NNSTPU_COMPILE_WARMUP="1")
-        print(f"{'run':>6} {'ttff s':>8} {'miss':>6} {'persist_hit':>12}")
-        rows = {}
-        for label in ("cold", "warm"):
-            proc = subprocess.run(
-                [sys.executable, os.path.abspath(__file__), "--ttff-child"],
-                env=env, capture_output=True, text=True, timeout=600)
-            if proc.returncode != 0:
-                print(f"{label}: FAILED\n{proc.stderr[-400:]}")
-                return
-            child = json.loads(proc.stdout.strip().splitlines()[-1])
-            rows[label] = child
-            c = child["compiles"]
-            print(f"{label:>6} {child['ttff_s']:>8.3f} "
-                  f"{c.get('miss', 0):>6} {c.get('persist_hit', 0):>12}")
-        misses = rows["warm"]["compiles"].get("miss", 0)
-        speedup = rows["cold"]["ttff_s"] / max(rows["warm"]["ttff_s"], 1e-9)
-        verdict = ("zero cold-start OK" if misses == 0
-                   else "COLD COMPILES ON THE REQUEST PATH")
-        print(f"warm misses = {misses} ({verdict}); "
-              f"ttff speedup = {speedup:.2f}x")
-    finally:
-        shutil.rmtree(cache, ignore_errors=True)
-
-
 def main():
-    if TTFF_CHILD:
-        ttff_child()
-        return
-    if TTFF:
-        ttff_sweep()
-        return
     ncpu = os.cpu_count()
     mode_lanes = LANES if LANES is not None else 0
     print(f"mux overhead sweep: total={TOTAL} frames, host cpus={ncpu}, "
