@@ -1,0 +1,221 @@
+"""The yardstick's arithmetic: rates and tails over all frames, work from
+shapes, peaks from one table, shares that cannot pass 100 % on honest time."""
+
+import os
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmark import arithmetic, manifest, peaks  # noqa: E402
+from benchmark.model_kinds import vit  # noqa: E402
+
+MS = 1_000_000
+MAN = manifest.load_manifest(ROOT)
+
+
+def steady(streams=4, frames=100, period_ms=100, latency_ms=200, stall=None):
+    """Synthetic stamps: each stream pushes every ``period_ms``; a label
+    comes back ``latency_ms`` later.  ``stall=(k, ms, n)``: the labels of
+    frames ``k .. k+n-1`` (the ones in flight) come ``ms`` late, and every
+    later push and label is held back by as much (closed loop)."""
+    push, sink = [], []
+    for _ in range(streams):
+        p, s = [], []
+        for k in range(frames):
+            late = stall[1] * MS if stall and k >= stall[0] else 0
+            held = late if stall and k >= stall[0] + stall[2] else 0
+            p.append(k * period_ms * MS + held)
+            s.append(k * period_ms * MS + latency_ms * MS + late)
+        push.append(p)
+        sink.append(s)
+    return push, sink
+
+
+@pytest.mark.parametrize("q,want", [(0, 1.0), (50, 2.5), (100, 4.0), (95, 3.85)])
+def test_percentile_interpolates(q, want):
+    assert arithmetic.percentile([4.0, 1.0, 3.0, 2.0], q) == pytest.approx(want)
+
+
+def test_percentile_of_nothing_raises():
+    with pytest.raises(ValueError):
+        arithmetic.percentile([], 50)
+
+
+def test_rate_and_latency_over_all_frames():
+    push, sink = steady()
+    w = arithmetic.window_metrics(push, sink, 1000 * MS, 6000 * MS)
+    assert w["attempted"] == 4 * 50 and w["arrived"] == w["attempted"]
+    assert w["frames_per_s"] == pytest.approx(40.0)
+    assert w["frame_latency_p50_ms"] == pytest.approx(200.0)
+    assert w["frame_latency_p95_ms"] == pytest.approx(200.0)
+
+
+def test_a_stall_moves_rate_and_p95_not_p50():
+    base = arithmetic.window_metrics(*steady(), 1000 * MS, 6000 * MS)
+    push, sink = steady(stall=(30, 1500, 5))
+    w = arithmetic.window_metrics(push, sink, 1000 * MS, 6000 * MS)
+    assert w["frames_per_s"] < base["frames_per_s"] * 0.8
+    assert w["frame_latency_p95_ms"] > base["frame_latency_p95_ms"] * 2
+    assert w["frame_latency_p50_ms"] == pytest.approx(base["frame_latency_p50_ms"])
+
+
+def test_late_and_missing_frames():
+    push, sink = steady(streams=1, frames=10)
+    sink[0] = sink[0][:8]                       # two labels never came
+    w = arithmetic.window_metrics(push, sink, 0, 950 * MS)
+    assert w["attempted"] == 10 and w["arrived"] == 8
+    # frames 0..7 arrive at 200..900 ms, all inside the 950 ms
+    assert w["window_s"] == pytest.approx(0.95)
+    assert w["frames_per_s"] == pytest.approx(8 / 0.95)
+    w = arithmetic.window_metrics(push, sink, 0, 450 * MS)
+    # pushed 0..4; labels of 0, 1, 2 inside; 3 and 4 come late, still counted
+    assert w["attempted"] == 5 and w["arrived"] == 5
+    assert w["frames_per_s"] == pytest.approx(3 / 0.45)
+    assert w["latency_samples"] == 5
+    # closed with the round in flight at 450 ms (frame 3's label at 500 ms)
+    close = arithmetic.round_close_ns(sink, 450 * MS)
+    assert close == 500 * MS
+    w = arithmetic.window_metrics(push, sink, 0, 450 * MS, close)
+    assert w["window_s"] == pytest.approx(0.5)
+    assert w["frames_per_s"] == pytest.approx(4 / 0.5)
+    assert w["attempted"] == 5
+
+
+def rounds(period_ms, n=80, streams=48, spread_ms=12):
+    """``streams`` labels a round, one round every ``period_ms``, the labels
+    of a round ``spread_ms`` apart from first to last."""
+    sink = [[(k + 1) * period_ms * MS - (streams - 1 - s) * spread_ms * MS // streams
+             for k in range(n)] for s in range(streams)]
+    push = [[k * period_ms * MS for k in range(n)]] * streams
+    return push, sink
+
+
+def test_the_rate_does_not_read_in_steps_of_one_batch():
+    """48 labels at a time every 465 ms: a window cut at 15 s reads 32 rounds
+    whether the step is 465 or 468 ms; closed with the round in flight it
+    reads the 0.64 % difference."""
+    def rate(period_ms, close=True):
+        push, sink = rounds(period_ms)
+        t0 = sink[-1][2]                        # the end of a round
+        t1 = t0 + 15_000 * MS
+        end = arithmetic.round_close_ns(sink, t1) if close else None
+        return arithmetic.window_metrics(push, sink, t0, t1, end)["frames_per_s"]
+
+    assert rate(465, close=False) == rate(468, close=False)
+    assert rate(465) == pytest.approx(48 / 0.465)
+    assert rate(465) / rate(468) == pytest.approx(468 / 465)
+
+
+@pytest.mark.parametrize("stall_ms", [400, 2000])
+def test_a_stall_at_the_tail_of_the_window_moves_the_rate(stall_ms):
+    """Nothing comes back from some point before ``t1`` until after it: the
+    window stays open until the round in flight ends, so all of the stall
+    that the window has seen is in the rate."""
+    push, sink = rounds(465, n=40)
+    t0 = sink[-1][2]
+    t1 = t0 + 10_000 * MS
+    k = next(k for k in range(40) if sink[-1][k] >= t1)     # in flight at t1
+    stalled = [[ts + (stall_ms * MS if i >= k else 0) for i, ts in enumerate(col)]
+               for col in sink]
+    base = arithmetic.window_metrics(push, sink, t0, t1,
+                                     arithmetic.round_close_ns(sink, t1))
+    w = arithmetic.window_metrics(push, stalled, t0, t1,
+                                  arithmetic.round_close_ns(stalled, t1))
+    lost = stall_ms / 1000 / (base["window_s"] + stall_ms / 1000)
+    assert w["frames_per_s"] == pytest.approx(base["frames_per_s"] * (1 - lost))
+    assert w["window_s"] == pytest.approx(base["window_s"] + stall_ms / 1000)
+
+
+def test_no_round_after_the_window_gives_no_close():
+    push, sink = rounds(465, n=10)
+    assert arithmetic.round_close_ns(sink, 60_000 * MS) is None
+    with pytest.raises(ValueError, match="closes before"):
+        arithmetic.window_metrics(push, sink, 0, 2000 * MS, 1500 * MS)
+
+
+def test_empty_window_raises():
+    with pytest.raises(ValueError):
+        arithmetic.window_metrics([[0]], [[1]], 5, 5)
+
+
+HAND = {  # the issue's hand figures, FLOPs per frame
+    "vit_l14_518": dict(tokens=1369, dense=831e9, attention=184e9, total=1.02e12,
+                        params=304e6),
+    "vit_h14_378": dict(tokens=729, dense=920e9, attention=87e9, total=1.01e12,
+                        params=632e6),
+    # 576 x (40 x 24 x 1536^2 + embedding + head); 40 x 4 x 576^2 x 1536
+    "siglip2_gopt16_384": dict(tokens=576, dense=1.308e12, attention=81.5e9,
+                               total=1.389e12, params=1.137e9),
+}
+# the issue's two towers have no cell (neither meets the memory floor,
+# PERF.md) and so no file; their sizes stand here so that the work functions
+# stay checked on them
+NO_CELL = {
+    "vit_l14_518": {"num_classes": 1000, "image_size": 518, "patch": 14,
+                    "d_model": 1024, "n_heads": 16, "n_layers": 24, "attn": "full"},
+    "vit_h14_378": {"num_classes": 1000, "image_size": 378, "patch": 14,
+                    "d_model": 1280, "n_heads": 16, "n_layers": 32, "attn": "full"},
+}
+
+
+def sizes_of(name):
+    if name in NO_CELL:
+        return dict(NO_CELL[name])
+    return vit.sizes(manifest.load_config(MAN, name, ROOT))
+
+
+@pytest.mark.parametrize("name", sorted(HAND))
+def test_work_functions_match_the_hand_figures(name):
+    s = sizes_of(name)
+    hand = HAND[name]
+    assert vit.tokens(s) == hand["tokens"]
+    flops = vit.frame_flops(s)
+    for key in ("dense", "attention", "total"):
+        assert flops[key] == pytest.approx(hand[key], rel=0.01), key
+    assert flops["total"] == flops["dense"] + flops["attention"]
+    assert vit.param_count(s) == pytest.approx(hand["params"], rel=0.01)
+    att = vit.attention_work(s)
+    assert att["flops"] == flops["attention"]
+    # q, k, v, o once in bf16: 4 T d values of 2 bytes a layer
+    assert att["bytes"] == s["n_layers"] * 4 * hand["tokens"] * s["d_model"] * 2
+    assert vit.score_dims(s) == (hand["tokens"], hand["tokens"])
+
+
+@pytest.mark.parametrize("name", sorted(HAND))
+def test_attention_is_compute_bound_on_the_v5e(name):
+    s = sizes_of(name)
+    att = vit.attention_work(s)
+    least = arithmetic.least_time_s(att["flops"], att["bytes"],
+                                    peaks.peak_for("TPU v5 lite"))
+    assert least["bound"] == "compute"
+    assert least["seconds"] == pytest.approx(att["flops"] / 197e12)
+
+
+@pytest.mark.parametrize("slack", [1.0, 1.001, 2.0, 50.0])
+def test_a_share_never_passes_100_on_honest_time(slack):
+    least = arithmetic.least_time_s(1e12, 1e9, peaks.peak_for("TPU v5 lite"))
+    share = arithmetic.share_pct(least["seconds"], least["seconds"] * slack)
+    assert 0 < share <= 100.0
+
+
+def test_a_share_is_not_clamped_and_never_zero():
+    assert arithmetic.share_pct(2.0, 1.0) == pytest.approx(200.0)
+    assert arithmetic.share_pct(1.0, 0.0) is None
+    assert arithmetic.share_pct(0.0, 1.0) is None
+
+
+def test_memory_bound_work_says_so():
+    least = arithmetic.least_time_s(1e9, 1e9, peaks.peak_for("TPU v5 lite"))
+    assert least["bound"] == "memory"
+    assert least["seconds"] == pytest.approx(1e9 / 819e9)
+
+
+def test_unknown_device_kind_raises():
+    with pytest.raises(KeyError, match="no published peak"):
+        peaks.peak_for("TPU v9 imaginary")
+    assert peaks.peak_for("TPU v5 lite").flops_per_s == 197e12
+    assert peaks.peak_for("TPU v5 lite").bytes_per_s == 819e9
