@@ -26,10 +26,13 @@ from __future__ import annotations
 
 import collections
 import threading
+import time
 from typing import Dict, List, Optional, Tuple
 
 from ..buffer import Event, Frame, NONE_TS, is_valid_ts
 from ..graph.node import Node, Pad
+from ..obs import hooks as _hooks
+from ..obs import spans as _spans
 
 
 class CollectNode(Node):
@@ -78,6 +81,20 @@ class CollectNode(Node):
         return [p for p in self.sink_pads.values() if p.peer is not None]
 
     def _dispatch(self, pad: Pad, item) -> None:
+        """The tracer hook points of :meth:`Node._dispatch` around this
+        element's own dispatch (one flag test with no tracer attached)."""
+        if _hooks.enabled:
+            t0 = time.perf_counter_ns()
+            _hooks.emit("dispatch_enter", self, pad, item, t0)
+            try:
+                self._dispatch_ordered(pad, item)
+            finally:
+                _hooks.emit("dispatch_exit", self, pad, item,
+                            time.perf_counter_ns() - t0)
+            return
+        self._dispatch_ordered(pad, item)
+
+    def _dispatch_ordered(self, pad: Pad, item) -> None:
         """Bookkeeping under the lock; emission outside it, ticket-ordered.
 
         Tickets are only booked when there is something to push downstream
@@ -118,8 +135,16 @@ class CollectNode(Node):
             ticket = self._ticket
             self._ticket += 1
         with self._emit_cv:
-            while self._emit_next != ticket:
-                self._emit_cv.wait()
+            if self._emit_next != ticket:
+                # a collected round queues here behind the round before
+                # it: a stage span of its own, so that the dispatch span
+                # does not read the wait as this element's work
+                tok = _spans.stage_begin(self.name + ".ticket_wait",
+                                         ticket=ticket) \
+                    if _hooks.enabled else None
+                while self._emit_next != ticket:
+                    self._emit_cv.wait()
+                _spans.stage_end(tok)
         try:
             if caps_item is not None:
                 if caps_item.kind == "caps":

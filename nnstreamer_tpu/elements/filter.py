@@ -14,8 +14,12 @@ GstBaseTransform at ``:132``):
   (``:316-436``); device-resident backends keep outputs on TPU (the
   ``allocate_in_invoke`` generalization).
 
-Per-invoke wall time is recorded when profiling is enabled
-(:mod:`nnstreamer_tpu.utils.profiling`).
+Timing never blocks the dispatching thread: while the hook bus has a
+listener each dispatch records a ``<filter>.invoke`` stage span (host side
+of upload + enqueue) and emits ``device_dispatch``, from which the device
+lane (:mod:`nnstreamer_tpu.obs.device`) observes the completion; that
+enqueue→done time is also the per-node latency of ``Pipeline.stats()``
+when profiling is enabled (:mod:`nnstreamer_tpu.utils.profiling`).
 """
 
 from __future__ import annotations
@@ -29,6 +33,7 @@ from ..buffer import Frame
 from ..graph.node import NegotiationError, Node, Pad
 from ..graph.registry import register_element
 from ..obs import hooks as _hooks
+from ..obs import spans as _spans
 from ..spec import TensorSpec, TensorsSpec
 
 
@@ -65,7 +70,7 @@ class TensorFilter(Node):
         self._fused_pre: list = []  # TensorTransforms folded in (optimize.py)
         self._fused_post: list = []
         self._fusion_dirty = False
-        self.invoke_ns: list = []  # per-invoke latency when profiling
+        self.dispatches = 0  # traced dispatches: the spans' round id
 
     def set_fused_transforms(self, pre: list, post: list) -> None:
         """Install transforms fused into this filter's XLA program (called
@@ -319,29 +324,26 @@ class TensorFilter(Node):
 
     def process(self, pad: Pad, frame: Frame):
         del pad
-        from ..utils import profiling
-
         if _faults.enabled:
             # chaos point "backend_invoke": invoke_delay/device_stall
             # sleep here, invoke_raise raises — an InjectedFault is then
             # handled exactly like a real one (restart policy or
             # post_error)
             _faults.maybe_invoke(self.name)
-        if profiling.enabled():
-            t0 = time.perf_counter_ns()
-            outs = self.backend.invoke(frame.tensors)
-            profiling.block_outputs(outs)
-            dt = time.perf_counter_ns() - t0
-            self.invoke_ns.append(dt)
-            profiling.record(self.name, dt)
-            if _hooks.enabled:
-                _hooks.emit("device_dispatch", self, frame, outs, t0)
-        elif _hooks.enabled:
-            # async dispatch: invoke() returns at ENQUEUE.  The device
+        if _hooks.enabled:
+            # async dispatch: invoke() returns at ENQUEUE.  The stage span
+            # bounds the host side (implicit upload + enqueue); the device
             # tracer's completion probe recovers the true device time —
-            # t0 here is the enqueue timestamp of its device_exec span.
+            # t0 here is the enqueue timestamp of its device_exec span,
+            # and the dispatch count joins the two.
+            self.dispatches += 1
             t0 = time.perf_counter_ns()
-            outs = self.backend.invoke(frame.tensors)
+            tok = _spans.stage_begin(self.name + ".invoke",
+                                     round=self.dispatches)
+            try:
+                outs = self.backend.invoke(frame.tensors)
+            finally:
+                _spans.stage_end(tok)
             _hooks.emit("device_dispatch", self, frame, outs, t0)
         else:
             outs = self.backend.invoke(frame.tensors)

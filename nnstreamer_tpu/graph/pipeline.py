@@ -104,6 +104,10 @@ class Pipeline:
         self._lock = threading.Lock()
         self._xplane_tracing = False
         self._tracers: List = []  # attached obs tracers (GST_TRACERS analog)
+        # the span and device lanes this run started by itself (a hook-bus
+        # listener at start(), or profiling on); stopped with the run and
+        # never part of stats()
+        self._lanes_started: List = []
         # supervised recovery (restart policies + watchdog escalation)
         self._restart_policies: Dict[str, RestartPolicy] = {}
         self._conf_policy: Optional[RestartPolicy] = None
@@ -527,8 +531,7 @@ class Pipeline:
                     node.stop()
                 except Exception:
                     pass
-            for tracer in self._tracers:
-                tracer.stop()  # failed start: no hook may stay connected
+            self._stop_tracers()  # failed start: no hook may stay connected
             from .segments import restore_segments
 
             restore_segments(self)
@@ -698,8 +701,7 @@ class Pipeline:
         restore_segments(self)
         # detach tracers from the hook bus (accumulated data stays readable
         # through stats(); a re-start reconnects them)
-        for tracer in self._tracers:
-            tracer.stop()
+        self._stop_tracers()
         if self._xplane_tracing:
             self._xplane_tracing = False
             # the deep-profiling lane owns the stop/parse/bank half too:
@@ -708,6 +710,13 @@ class Pipeline:
             from ..obs import profiler as _profiler
 
             _profiler.stop_whole_run(self)
+
+    def _stop_tracers(self) -> None:
+        for tracer in self._tracers:
+            tracer.stop()
+        for lane in self._lanes_started:
+            lane.stop()  # joins the reaper: every completion is recorded
+        self._lanes_started.clear()
 
     def run(self, timeout: Optional[float] = None) -> None:
         """start() + wait() + stop() — convenience for finite streams."""
@@ -721,7 +730,7 @@ class Pipeline:
     # -- introspection ------------------------------------------------------
 
     def _post_negotiate_hooks(self) -> None:
-        """Conf-driven observability at PLAYING: profiling enable + dot dump
+        """Conf-driven observability at PLAYING: xplane trace + dot dump
         (the GST_DEBUG_DUMP_DOT_DIR analog, ``tools/debugging/``)."""
         import warnings
 
@@ -730,10 +739,6 @@ class Pipeline:
         # observability must never take the pipeline down: any failure here
         # (bad conf values included) is a warning, not an error.
         try:
-            if conf.get_bool("common", "enable_profiling", False):
-                from ..utils import profiling
-
-                profiling.enable(True)
             trace_dir = conf.get_path("common", "xplane_trace_dir", "")
             if trace_dir:
                 # device-level xplane trace (jax.profiler) for the whole
@@ -756,12 +761,21 @@ class Pipeline:
         + the Prometheus scrape endpoint (``NNSTPU_METRICS_PORT``) — the
         GST_TRACERS analog, resolved at every start(), before
         negotiation (see the note in :meth:`start`)."""
+        from ..conf import conf
         from ..obs import (
             configured_metrics_port,
             configured_tracers,
             ensure_server,
         )
+        from ..utils import profiling
 
+        if conf.get_bool("common", "enable_profiling", False):
+            profiling.enable(True)
+        # someone listens on the hook bus already (a harness's callback, an
+        # operator's probe): this run records stage spans and device
+        # completions to the flight recorder, for the listener to read
+        # from obs.spans after stop()
+        listened = _hooks.enabled
         attached = {t.name for t in self._tracers}
         for name in configured_tracers():
             if name not in attached:
@@ -769,6 +783,20 @@ class Pipeline:
                 attached.add(name)
         for tracer in self._tracers:
             tracer.start(self)
+        if listened and "spans" not in attached:
+            from ..obs.spans import SpanTracer
+
+            self._lanes_started.append(SpanTracer(flows=False))
+        if (listened or profiling.enabled()) and "device" not in attached:
+            # the device lane is also what feeds the per-node latencies of
+            # stats() under profiling: enqueue -> done, nothing blocks
+            from ..obs.device import DeviceTracer
+            from ..obs.metrics import MetricsRegistry
+
+            self._lanes_started.append(
+                DeviceTracer(registry=MetricsRegistry()))
+        for lane in self._lanes_started:
+            lane.start(self)
         port = configured_metrics_port()
         if port is not None:
             ensure_server(port)

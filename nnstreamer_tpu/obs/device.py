@@ -17,7 +17,13 @@ module is the device lane of the obs subsystem:
   recorder on a dedicated device track (the reaper thread's row in
   Perfetto), with a flow arrow from the host dispatch span.  The queue
   is bounded so a wedged device can never grow host memory without
-  bound — overflow drops the probe and counts it.
+  bound — overflow drops the probe and counts it.  Each span carries the
+  filter's dispatch count as ``round`` (the join with that dispatch's
+  ``<filter>.invoke`` stage span), and with profiling enabled
+  (:mod:`nnstreamer_tpu.utils.profiling`) its duration is the per-node
+  latency of ``Pipeline.stats()``.  A pipeline starts this lane by itself,
+  on a registry of its own, when it starts while the hook bus has a
+  listener or with profiling on (``graph/pipeline.py``).
 - :func:`record_compile` is the sink for backend executable-cache
   events (``backends/jax_backend.py`` calls it on every hit/miss/evict):
   ``nnstpu_compile_total{result=...}`` counters, a compile wall-time
@@ -56,6 +62,7 @@ import threading
 import time
 from typing import Dict, List, Optional, Tuple
 
+from ..utils import profiling as _profiling
 from . import hooks as _hooks
 from . import spans
 from . import util as _util
@@ -606,8 +613,10 @@ class DeviceTracer(Tracer):
             self._sent += 1
             with _inflight_lock:
                 _inflight[pid] = (t0_ns, node.name)
+            # the filter's dispatch count: what joins this completion to
+            # the round's <filter>.invoke span without guessing from order
             self._q.append((pid, node.name, head, t0_ns, trace_id, parent,
-                            fid, cost_key))
+                            fid, cost_key, getattr(node, "dispatches", 0)))
             self._cv.notify()
 
     def _on_compile(self, backend, key, result, dur_ns, info) -> None:
@@ -628,13 +637,13 @@ class DeviceTracer(Tracer):
                 if not self._running and not self._q:
                     return
                 (pid, name, head, t0, trace_id, parent, fid,
-                 cost_key) = self._q.popleft()
+                 cost_key, round_id) = self._q.popleft()
             try:
                 shards = _mesh_shards(head)
                 if shards is not None:
                     dur = self._reap_sharded(
                         shards, name, t0, trace_id, parent, fid,
-                        pipeline_name, cost_key)
+                        pipeline_name, cost_key, round_id)
                 else:
                     try:
                         import jax
@@ -649,7 +658,8 @@ class DeviceTracer(Tracer):
                     label = _head_device_label(head)
                     track = threading.current_thread().name
                     sid = next(spans._ids)
-                    args = {"element": name, "device": label}
+                    args = {"element": name, "device": label,
+                            "round": round_id}
                     args.update(self._utilization(
                         label, track, name, t0, dur, trace_id, parent,
                         cost_key, pipeline_name))
@@ -663,6 +673,11 @@ class DeviceTracer(Tracer):
                     self._hist.observe(dur / 1e9, pipeline=pipeline_name,
                                        element=name, device=label)
                 self._dispatches.inc(1, pipeline=pipeline_name, element=name)
+                if _profiling.enabled():
+                    # the per-node latency of Pipeline.stats(): enqueue ->
+                    # done, observed here and never waited for in the
+                    # dispatching thread
+                    _profiling.record(name, dur)
                 with self._lock:
                     self._completed += 1
                     c = self._by_element.setdefault(name, [0, 0])
@@ -687,7 +702,7 @@ class DeviceTracer(Tracer):
                     pass
 
     def _reap_sharded(self, shards, name, t0, trace_id, parent, fid,
-                      pipeline_name, cost_key=None) -> int:
+                      pipeline_name, cost_key=None, round_id=0) -> int:
         """Per-mesh-device completion for a sharded dispatch: each shard's
         readiness is observed individually and recorded on its OWN
         ``device:<platform>:<ordinal>`` Perfetto track (the recorder keys
@@ -717,7 +732,7 @@ class DeviceTracer(Tracer):
                     trace_id, fid, 0, None))
                 flow_done = True
             sid = next(spans._ids)
-            args = {"element": name, "device": label}
+            args = {"element": name, "device": label, "round": round_id}
             args.update(self._utilization(
                 label, track, name, t0, shard_dur, trace_id, parent,
                 cost_key, pipeline_name, nshards=nshards))

@@ -69,6 +69,7 @@ enabled = False
 
 _lock = threading.Lock()
 _active = 0        # SpanTracer refcount
+_epoch = 0         # bumped each time recording goes from off to on
 _manual = False    # explicit enable() (serving surfaces without a pipeline)
 
 _ids = itertools.count(1)
@@ -85,9 +86,14 @@ now_ns = time.perf_counter_ns  # the one clock (see obs/hooks.py)
 
 
 def _tid() -> str:
-    override = getattr(_tls, "tid_override", None)
-    return override if override is not None \
-        else threading.current_thread().name
+    """The calling thread's logical identity: its name as it was at its
+    first record (kept on the thread: ``current_thread().name`` costs a
+    microsecond, three times a dispatch), or :func:`set_tid`'s override."""
+    try:
+        return _tls.tid
+    except AttributeError:
+        tid = _tls.tid = threading.current_thread().name
+        return tid
 
 
 def set_tid(name: Optional[str]) -> Optional[str]:
@@ -100,6 +106,10 @@ def set_tid(name: Optional[str]) -> Optional[str]:
     to the thread-per-element mode they replaced."""
     prev = getattr(_tls, "tid_override", None)
     _tls.tid_override = name
+    tid = _tls.tid = name if name is not None \
+        else threading.current_thread().name
+    if getattr(_tls, "stacks_epoch", None) == _epoch:
+        _tls.stack = _tls.stacks.setdefault(tid, [])
     return prev
 
 
@@ -126,11 +136,13 @@ def configured_flight_records() -> int:
 
 
 def _activate(capacity: Optional[int] = None) -> None:
-    global enabled, _active, _recorder
+    global enabled, _active, _epoch, _recorder
     with _lock:
         if _active == 0 and not _manual and capacity \
                 and capacity != _recorder.capacity:
             _recorder = FlightRecorder(capacity)
+        if _active == 0:
+            _epoch += 1
         _active += 1
         enabled = True
 
@@ -302,6 +314,83 @@ def record_instant(name: str, cat: str = "span",
          args)
 
 
+# -- stage spans (inside a traced dispatch) ----------------------------------
+
+# jax.profiler.TraceAnnotation, bound when the first SpanTracer installs
+# (importing this package does not import jax); a no-op outside a
+# jax.profiler session
+_TraceAnnotation = None
+
+# element class -> the one args dict its dispatch spans share
+_ELEMENT_ARGS: Dict[type, dict] = {}
+
+
+def _element_args(cls: type) -> dict:
+    args = _ELEMENT_ARGS.get(cls)
+    if args is None:
+        args = _ELEMENT_ARGS[cls] = {"element": cls.__name__}
+    return args
+
+
+def _annotate(name: str, **kwargs):
+    """An entered ``jax.profiler.TraceAnnotation``: the span on the xplane's
+    host plane, beside ``XLA Ops``."""
+    ann = _TraceAnnotation(name, **kwargs)
+    ann.__enter__()
+    return ann
+
+
+def _dispatch_stack() -> list:
+    """The calling thread's open dispatch spans, innermost last: the
+    stack of its *logical* tid, not of the OS thread — a lane running a
+    helped drain slice inside a producer's chain must not nest the
+    drained dispatches under the producer's spans (each task keeps the
+    stack its dedicated thread would have had; :func:`set_tid` swaps)."""
+    try:
+        if _tls.stacks_epoch == _epoch:
+            return _tls.stack
+    except AttributeError:
+        pass
+    # a new recording: what a dispatch cut off by the last one's stop
+    # left open on this thread is not this one's parent
+    _tls.stacks = {}
+    _tls.stacks_epoch = _epoch
+    stack = _tls.stack = _tls.stacks.setdefault(_tid(), [])
+    return stack
+
+
+def stage_begin(name: str, **kwargs) -> Optional[tuple]:
+    """Open a ``stage`` span inside the dispatch span the calling thread
+    is in: work an element does that its dispatch span does not separate
+    (a ticket wait, the backend invoke).  Returns the token for
+    :func:`stage_end`, or None where no traced dispatch is open (span
+    tracing off, or another pipeline's) — callers sit behind the hook
+    bus's gate.  ``kwargs`` become the span's args and the arguments of
+    its ``nns/<name>`` annotation on the profiler's clock; the span's own
+    start rides along as ``t0_ns``, so that one annotation pairs the
+    ring's clock with the xplane's."""
+    if not enabled:
+        return None
+    stack = _dispatch_stack()
+    if not stack:
+        return None
+    t0 = now_ns()
+    return (name, t0, stack[-1], kwargs,
+            _annotate("nns/" + name, t0_ns=t0, **kwargs))
+
+
+def stage_end(token: Optional[tuple]) -> None:
+    """Close a :func:`stage_begin` span (None: nothing was opened)."""
+    if token is None:
+        return
+    name, t0, parent, kwargs, ann = token
+    dur = now_ns() - t0
+    ann.__exit__(None, None, None)
+    ctx = parent[2]
+    _rec(PH_COMPLETE, t0, dur, name, "stage", ctx[0] if ctx else 0,
+         next(_ids), parent[0], kwargs or None)
+
+
 # -- the tracer --------------------------------------------------------------
 
 class SpanTracer(Tracer):
@@ -313,22 +402,32 @@ class SpanTracer(Tracer):
     frame's stamped context supplies the trace id.  Queue push/pop become
     counter tracks, queue drops and source pushes instants, and every pad
     push opens a flow that closes on whichever thread touches the frame
-    next.
+    next (``flows=False`` leaves the flows out: the lane a pipeline
+    starts by itself when the hook bus has a listener, one complete span
+    a dispatch and nothing else).  Each dispatch span is also a
+    ``jax.profiler.TraceAnnotation`` ``nns/<element>``.
     """
 
     name = "spans"
 
-    def __init__(self, registry=None, capacity: Optional[int] = None):
+    def __init__(self, registry=None, capacity: Optional[int] = None,
+                 flows: bool = True):
         super().__init__(registry)
         self._capacity = capacity
-        self._stacks = threading.local()
+        self._flows = flows
 
     def _install(self) -> None:
+        global _TraceAnnotation
+        if _TraceAnnotation is None:
+            from jax.profiler import TraceAnnotation
+
+            _TraceAnnotation = TraceAnnotation
         cap = self._capacity if self._capacity is not None \
             else configured_flight_records()
         _activate(cap)
         self._connect("source_push", self._on_source_push)
-        self._connect("pad_push", self._on_pad_push)
+        if self._flows:
+            self._connect("pad_push", self._on_pad_push)
         self._connect("dispatch_enter", self._on_dispatch_enter)
         self._connect("dispatch_exit", self._on_dispatch_exit)
         self._connect("queue_push", self._on_queue_push)
@@ -343,19 +442,6 @@ class SpanTracer(Tracer):
             _deactivate()
 
     # -- hook callbacks ------------------------------------------------------
-
-    def _stack(self) -> list:
-        # keyed by the *logical* tid, not the OS thread: a lane running
-        # a helped drain slice inside a producer's chain must not nest
-        # the drained dispatches under the producer's spans (each task
-        # keeps the stack its dedicated thread would have had)
-        stacks = getattr(self._stacks, "by_tid", None)
-        if stacks is None:
-            stacks = self._stacks.by_tid = {}
-        stack = stacks.get(_tid())
-        if stack is None:
-            stack = stacks[_tid()] = []
-        return stack
 
     def _on_source_push(self, pipeline, node, frame) -> None:
         if pipeline is not self._pipeline:
@@ -383,25 +469,31 @@ class SpanTracer(Tracer):
     def _on_dispatch_enter(self, node, pad, item, t0) -> None:
         if node.pipeline is not self._pipeline:
             return
-        ctx = context_of(item)
-        if ctx is not None:
+        meta = getattr(item, "meta", None)
+        ctx = meta.get(META_KEY) if meta is not None else None
+        if ctx is not None and ctx[2]:
             _consume_flow(ctx, t0)
-        self._stack().append((next(_ids), t0, ctx))
+        ann = _TraceAnnotation("nns/" + node.name)
+        ann.__enter__()
+        _dispatch_stack().append((next(_ids), t0, ctx, ann))
 
     def _on_dispatch_exit(self, node, pad, item, dur_ns) -> None:
         if node.pipeline is not self._pipeline:
             return
-        stack = self._stack()
+        stack = _dispatch_stack()
         if not stack:
             return  # tracer attached mid-dispatch: no matching enter
-        sid, t0, ctx = stack.pop()
+        sid, t0, ctx, ann = stack.pop()
+        ann.__exit__(None, None, None)
         if stack:
             parent = stack[-1][0]
         else:
             parent = ctx[1] if ctx else 0
-        trace_id = ctx[0] if ctx else 0
-        _rec(PH_COMPLETE, t0, dur_ns, node.name, "dispatch",
-             trace_id, sid, parent, None)
+        # straight into the ring: this runs twice a dispatch on every
+        # streaming thread, under one interpreter lock
+        _recorder.append((PH_COMPLETE, t0, dur_ns, _tid(), node.name,
+                          "dispatch", ctx[0] if ctx else 0, sid, parent,
+                          _element_args(type(node))))
 
     def _on_queue_push(self, node, depth) -> None:
         if node.pipeline is self._pipeline:

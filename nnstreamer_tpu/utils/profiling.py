@@ -6,6 +6,11 @@ profiling is built in: a process-global registry of per-node invoke
 latencies, toggled at runtime, plus helpers to bracket regions with
 ``jax.profiler`` traces.
 
+Nothing here waits for the device: a pipeline that starts with profiling
+enabled starts the device lane (:mod:`nnstreamer_tpu.obs.device`), whose
+reaper thread feeds :func:`record` with each filter dispatch's enqueue →
+completion time; ``Pipeline.stop()`` drains it.
+
 Recorded invoke latencies are additionally folded into the observability
 metrics registry (:mod:`nnstreamer_tpu.obs.metrics`) as the
 ``nnstpu_node_invoke_latency_ms`` histogram, so enabling profiling makes
@@ -46,14 +51,6 @@ def record(node_name: str, duration_ns: int) -> None:
         "is enabled",
         labelnames=("node",),
     ).observe(duration_ns / 1e6, node=node_name)
-
-
-def block_outputs(outs) -> None:
-    """Synchronize device outputs so recorded times are real (JAX dispatch is
-    async; without this, invoke times measure only dispatch)."""
-    for o in outs:
-        if hasattr(o, "block_until_ready"):
-            o.block_until_ready()
 
 
 def summarize_ns(ns: Sequence[int]) -> Dict[str, float]:
