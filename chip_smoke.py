@@ -29,8 +29,14 @@ Phases (each fails the run on any mismatch):
   sessions, prefill then feed/get;
 - kernels: every Pallas kernel the tree ships, compiled by Mosaic
   (interpret=False) and compared with its jnp reference;
+- attention paths: one ViT whose heads tile, compiled for the chip (every
+  layer the fused kernel) and under jax.default_device(cpu), as the backend's
+  cpu_fallback retry compiles it (every layer full_attention); same logits;
 - four chips (only when jax.device_count() >= 4): multi-stream again under
-  NNSTPU_MESH=dp:4, output shards on four distinct devices.
+  NNSTPU_MESH=dp:4, output shards on four distinct devices;
+- attention on four chips (the same condition): the ViT with its batch
+  sharded over the four (GSPMD partitions it: full_attention) and a pipelined
+  encoder (the kernel inside shard_map), each against one device.
 """
 
 import argparse
@@ -92,14 +98,30 @@ class CompileWatch:
         return (self.count, self.seconds, self.cache_hits)
 
 
-def repo_compiles():
-    """{result: count} of the repo's own record_compile counters."""
+def counts(counter):
+    """{first label: count} of one of the repo's counters."""
     from nnstreamer_tpu.obs.metrics import REGISTRY
 
-    c = REGISTRY.get("nnstpu_compile_total")
+    c = REGISTRY.get(counter)
     if c is None:
         return {}
     return {k[0]: int(v.value) for k, v in dict(c.children()).items()}
+
+
+def repo_compiles():
+    """{result: count} of the repo's own record_compile counters."""
+    return counts("nnstpu_compile_total")
+
+
+def attention_lowerings():
+    """{path: count} of the attention calls lowered so far."""
+    return counts("nnstpu_attention_lowerings_total")
+
+
+def risen(before):
+    """What attention_lowerings() has risen by since ``before``."""
+    return {k: v - before.get(k, 0) for k, v in attention_lowerings().items()
+            if v - before.get(k, 0)}
 
 
 class Smoke:
@@ -486,8 +508,66 @@ class Smoke:
         check(np.array_equal(got, want), "pallas_nms_keep != nms_keep")
         check(0 < int(got.sum()) < int(valid.sum()), "degenerate NMS case")
         out["nms_kept"] = f"{int(got.sum())}/{k}"
+
+        # fused attention at the ViT benchmark's head geometry (16 heads of
+        # 96 in groups of four, T = 576; tiny on the CPU) against
+        # full_attention in float32
+        from nnstreamer_tpu.ops.fused_attention import fused_attention
+        from nnstreamer_tpu.parallel.ring_attention import full_attention
+
+        t, h, dh = (16, 4, 32) if self.rehearsal else (576, 16, 96)
+        qkv = jnp.asarray(rng.standard_normal((2, t, 3 * h * dh)),
+                          jnp.bfloat16)
+        for causal in (False, True):
+            got = np.asarray(jax.jit(lambda a: fused_attention(
+                a, h, causal=causal, interpret=interpret))(qkv)
+                .astype(jnp.float32))
+            with jax.default_matmul_precision("highest"):
+                q, k_, v = (a.reshape(2, t, h, dh) for a in jnp.split(
+                    qkv.astype(jnp.float32), 3, axis=-1))
+                want = np.asarray(jax.jit(lambda *a: full_attention(
+                    *a, causal=causal))(q, k_, v)).reshape(2, t, h * dh)
+            np.testing.assert_allclose(got, want, rtol=2e-2, atol=2e-2)
+            out[f"fused_attention_causal{int(causal)}_max_abs_err"] = float(
+                np.abs(got - want).max())
         out["compiled_by"] = "interpreter" if interpret else "mosaic"
         return out
+
+    def tiling_vit(self):
+        """A fresh ViT (nothing traced or lowered for it yet) whose shape
+        tiles for the fused kernel (4 heads of 64, 400 tokens), and a batch
+        of 8."""
+        import jax.numpy as jnp
+        import numpy as np
+
+        from nnstreamer_tpu.models import vit
+
+        model = vit.build(num_classes=16, image_size=160, patch=8,
+                          d_model=256, n_heads=4, n_layers=2, batch=8,
+                          dtype=jnp.float32, seed=11)
+        frames = np.random.default_rng(7).standard_normal(
+            (8, 160, 160, 3)).astype(np.float32)
+        return model.fn(), frames
+
+    def attention_paths(self):
+        import jax
+        import numpy as np
+
+        chip = "plain" if self.rehearsal else "fused"
+        fn, frames = self.tiling_vit()
+        before = attention_lowerings()
+        on_chip = np.asarray(jax.jit(fn)(frames))
+        check(risen(before) == {chip: 2}, f"chip program: {risen(before)}")
+        # what JaxBackend's [recovery] cpu_fallback retry does on this host
+        fn, _ = self.tiling_vit()
+        before = attention_lowerings()
+        with jax.default_device(jax.devices("cpu")[0]):
+            on_cpu = jax.jit(fn)(frames)
+        check(risen(before) == {"plain": 2}, f"cpu program: {risen(before)}")
+        check({d.platform for d in on_cpu.devices()} == {"cpu"},
+              "cpu_fallback program did not run on the CPU")
+        return {"chip_path": chip, "rel_l2_chip_vs_cpu": self.check_close(
+            on_chip, np.asarray(on_cpu), "ViT on the chip vs on the CPU")}
 
     def four_chips(self):
         from nnstreamer_tpu.parallel.mesh import reset_dispatch_mesh
@@ -498,6 +578,46 @@ class Smoke:
         finally:
             del os.environ["NNSTPU_MESH"]
             reset_dispatch_mesh()
+
+    def attention_on_four_chips(self):
+        import jax
+        import numpy as np
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from nnstreamer_tpu.models import transformer
+        from nnstreamer_tpu.parallel.mesh import make_mesh
+
+        # the batch sharded over four chips: GSPMD partitions the program,
+        # which Mosaic refuses, so every layer is full_attention
+        chip = "plain" if self.rehearsal else "fused"
+        fn, frames = self.tiling_vit()
+        one = np.asarray(jax.jit(fn)(frames))
+        fn, _ = self.tiling_vit()
+        dp = make_mesh((4,), ("dp",), devices=jax.devices()[:4])
+        before = attention_lowerings()
+        sharded = jax.jit(fn, in_shardings=NamedSharding(dp, P("dp")))(frames)
+        check(risen(before) == {"plain": 2}, f"dp:4 program: {risen(before)}")
+        check(len(sharded.sharding.device_set) == 4, sharded.sharding)
+        out = {"rel_l2_dp4_vs_one_chip": self.check_close(
+            np.asarray(sharded), one, "ViT over dp:4 vs one chip")}
+
+        # a pipelined encoder: the blocks run inside shard_map over the whole
+        # mesh, where each chip's program is its own and holds the kernel
+        kw = dict(seq_len=384, d_in=32, n_out=8, d_model=256, n_heads=4,
+                  n_layers=4, causal=True, seed=5)
+        pp = make_mesh((4,), ("pp",), devices=jax.devices()[:4])
+        x = np.random.default_rng(9).standard_normal(
+            (8, 384, 32)).astype(np.float32)
+        before = attention_lowerings()
+        piped = transformer.build_pipelined(pp, "pp", batch=8, **kw)
+        got = np.asarray(jax.jit(piped.fn())(x))
+        check(risen(before) == {chip: 1},  # one scanned block a stage
+              f"pipelined program: {risen(before)}")
+        want = np.asarray(jax.jit(transformer.build(batch=8, **kw).fn())(x))
+        out["rel_l2_pp4_vs_one_chip"] = self.check_close(
+            got, want, "pipelined encoder over pp:4 vs one chip")
+        out["pipelined_path"] = chip
+        return out
 
 
 def main(argv=None):
@@ -552,8 +672,10 @@ def main(argv=None):
     smoke.phase("multi_stream", smoke.multi_stream)
     smoke.phase("decode_session", smoke.decode_session)
     smoke.phase("kernels", smoke.kernels)
+    smoke.phase("attention_paths", smoke.attention_paths)
     if device["count"] >= 4:
         smoke.phase("four_chips", smoke.four_chips)
+        smoke.phase("attention_on_four_chips", smoke.attention_on_four_chips)
     else:
         say(f"== four_chips: not run ({device['count']} device(s))")
 

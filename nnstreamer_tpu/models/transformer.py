@@ -112,12 +112,8 @@ def _block_apply(
     moe_axis: str = "ep",
 ):
     """One pre-LN encoder block (attention + FFN/MoE with residuals)."""
-    b, t, d = y.shape
     z = _layernorm(blk["ln1"], y)
-    qkv = _proj(blk["qkv"], z, dtype)
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    q, k, v = (a.reshape(b, t, h, d // h) for a in (q, k, v))
-    o = _attention(q, k, v, attn, mesh, axis, causal).reshape(b, t, d)
+    o = _attention(_proj(blk["qkv"], z, dtype), h, attn, mesh, axis, causal)
     y = y + _proj(blk["proj"], o, dtype)
     return _ffn_residual(blk, y, dtype, moe_mesh, moe_axis)
 
@@ -136,20 +132,31 @@ def _ffn_residual(blk: Params, y, dtype, moe_mesh=None, moe_axis: str = "ep"):
     return y + _proj(blk["ff2"], z, dtype)
 
 
-def _attention(q, k, v, attn: str, mesh, axis: str, causal: bool):
-    if attn == "full":
-        from ..parallel.ring_attention import full_attention
+def _attention(qkv, h: int, attn: str, mesh, axis: str, causal: bool):
+    """Attention over the fused projection ``[B, T, 3*d]`` → ``[B, T, d]``.
 
-        return full_attention(q, k, v, causal=causal)
+    ``full`` is one algorithm with two lowerings, and the program's lowering
+    chooses (:func:`nnstreamer_tpu.ops.fused_attention.attention`): the
+    Pallas kernel that keeps the scores on chip for a one-device TPU program
+    whose shape tiles, ``full_attention`` as XLA lowers it everywhere else."""
+    if attn == "full":
+        from ..ops.fused_attention import attention
+
+        return attention(qkv, h, causal)
+    b, t, d3 = qkv.shape
+    q, k, v = (a.reshape(b, t, h, d3 // (3 * h))
+               for a in jnp.split(qkv, 3, axis=-1))
     if attn == "ring":
         from ..parallel.ring_attention import ring_attention
 
-        return ring_attention(q, k, v, mesh, axis=axis, causal=causal)
-    if attn == "ulysses":
+        o = ring_attention(q, k, v, mesh, axis=axis, causal=causal)
+    elif attn == "ulysses":
         from ..parallel.sequence import ulysses_attention
 
-        return ulysses_attention(q, k, v, mesh, axis=axis, causal=causal)
-    raise ValueError(f"unknown attention mode {attn!r}")
+        o = ulysses_attention(q, k, v, mesh, axis=axis, causal=causal)
+    else:
+        raise ValueError(f"unknown attention mode {attn!r}")
+    return o.reshape(b, t, d3 // 3)
 
 
 def apply(

@@ -143,12 +143,16 @@ class TestChipSmokeCannotPassOffChip:
             ln for ln in lines if ln.startswith("SUMMARY "))[8:])
         assert summary["rehearsal"] is True and summary["failed"] == []
         assert summary["device"]["platform"] == "cpu"
-        assert [p["phase"] for p in summary["phases"]] == [
+        phases = {p["phase"]: p for p in summary["phases"]}
+        assert list(phases) == [
             "labeling", "serving_door", "multi_stream", "decode_session",
-            "kernels", "four_chips"]  # conftest exports 8 virtual devices
+            "kernels", "attention_paths", "four_chips",
+            "attention_on_four_chips"]  # conftest exports 8 virtual devices
         assert all(p["ok"] for p in summary["phases"])
-        assert summary["phases"][4]["compiled_by"] == "interpreter"
-        assert summary["phases"][5]["shards"] == 4
+        assert phases["kernels"]["compiled_by"] == "interpreter"
+        assert phases["attention_paths"]["chip_path"] == "plain"
+        assert phases["four_chips"]["shards"] == 4
+        assert phases["attention_on_four_chips"]["pipelined_path"] == "plain"
 
 
 class TestNativeLoaderBuildsFromSource:

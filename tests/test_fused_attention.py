@@ -1,0 +1,345 @@
+"""The fused attention kernel (``ops/fused_attention.py``) and the lowering
+rule that selects it.
+
+The kernel runs here in Pallas interpret mode against ``full_attention``.
+Which lowering a ``full`` attention call gets is decided when its program is
+lowered, so the selection is driven by lowering one trace for ``tpu`` and for
+``cpu`` from this CPU host, and by compiling for a described v5e (no chip
+attached): one device, and four with the batch sharded over them.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+from nnstreamer_tpu.models import transformer, vit
+from nnstreamer_tpu.obs.metrics import REGISTRY
+from nnstreamer_tpu.ops import fused_attention as fa
+from nnstreamer_tpu.parallel.mesh import make_mesh
+
+COUNTER = "nnstpu_attention_lowerings_total"
+
+
+def lowerings():
+    metric = REGISTRY.get(COUNTER)
+    if metric is None:
+        return {}
+    return {key[0]: int(child.value) for key, child in metric.children()}
+
+
+@pytest.fixture
+def counted():
+    """Counts of this test alone: ``counted()`` is the rise since it began."""
+    before = lowerings()
+
+    def since():
+        return {path: n - before.get(path, 0)
+                for path, n in lowerings().items() if n - before.get(path, 0)}
+
+    return since
+
+
+@pytest.fixture
+def kernel_everywhere(monkeypatch):
+    """The plain lowering runs the kernel too, interpreted: what a program
+    computes through the fused path, on a host with no TPU."""
+    monkeypatch.setattr(fa, "plain_attention", lambda qkv, n_heads, causal:
+                        fa.fused_attention(qkv, n_heads, causal,
+                                           interpret=True))
+
+
+def lowered_for(platform, fn, *args):
+    """StableHLO of ``fn`` lowered for ``platform`` from this host."""
+    return jax.jit(fn).trace(*args).lower(
+        lowering_platforms=(platform,)).as_text()
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("n_heads", [4, 16])
+@pytest.mark.parametrize("head_width", [32, 96, 128])
+@pytest.mark.parametrize("t", [16, 72, 200])
+def test_kernel_matches_full_attention(t, head_width, n_heads, dtype, causal):
+    qkv = jax.random.normal(jax.random.PRNGKey(t + head_width + n_heads),
+                            (1, t, 3 * n_heads * head_width),
+                            jnp.float32).astype(dtype)
+    want = fa.plain_attention(qkv.astype(jnp.float32), n_heads, causal)
+    got = fa.fused_attention(qkv, n_heads, causal=causal)
+    assert got.dtype == qkv.dtype and got.shape == want.shape
+    tol = 1e-5 if dtype == "float32" else 2e-2
+    np.testing.assert_allclose(np.asarray(got.astype(jnp.float32)),
+                               np.asarray(want), atol=tol, rtol=tol)
+
+
+def test_kernel_refuses_heads_that_do_not_group():
+    with pytest.raises(ValueError, match="does not tile"):
+        fa.fused_attention(jnp.zeros((1, 16, 3 * 2 * 32)), n_heads=2)
+
+
+@pytest.mark.parametrize("shape,dtype,n_heads,want", [
+    ((48, 576, 4608), "bfloat16", 16, True),    # the benchmark's cell
+    ((1, 576, 4608), "float32", 16, True),
+    ((2, 384, 384), "bfloat16", 4, True),       # 4 heads of 32: one group
+    ((2, 400, 2304), "bfloat16", 12, True),     # T need not tile
+    ((2, 1024, 3072), "bfloat16", 8, True),     # the longest T of 128 wide
+    ((2, 383, 384), "bfloat16", 4, False),      # under MIN_TOKENS
+    ((48, 196, 2304), "bfloat16", 12, False),   # ViT-B/16 at 224: XLA ahead
+    ((2, 384, 192), "float32", 2, False),       # 2 heads of 32: half a group
+    ((2, 384, 384), "float32", 8, False),       # head width 16
+    ((2, 2048, 4608), "bfloat16", 16, False),   # a row of scores past VMEM
+    ((2, 384, 384), "float16", 4, False),
+    ((384, 384), "bfloat16", 4, False),
+])
+def test_tiles_is_the_tiling_rule(shape, dtype, n_heads, want):
+    assert fa.tiles(shape, jnp.dtype(dtype), n_heads) is want
+
+
+def small_vit(n_heads=4, n_layers=3, d_model=128, batch=2, build=vit.build,
+              dtype=jnp.float32, image_size=140):
+    """A fresh model (so no trace or lowering of another test is cached for
+    it) whose heads tile, and a batch of frames."""
+    model = build(num_classes=10, image_size=image_size, patch=7,
+                  d_model=d_model, n_heads=n_heads, n_layers=n_layers,
+                  batch=batch, dtype=dtype, seed=3)  # 140 / 7: 400 tokens
+    frames = np.random.default_rng(5).standard_normal(
+        (batch, image_size, image_size, 3)).astype(np.float32)
+    return model, frames
+
+
+def test_cpu_build_lowers_every_layer_plain(counted):
+    model, frames = small_vit()
+    jax.jit(model.fn())(frames)
+    assert counted() == {"plain": 3}
+
+
+def test_shape_discovery_lowers_and_counts_nothing(counted):
+    model, frames = small_vit()
+    assert jax.eval_shape(model.fn(), frames).shape == (2, 10)
+    jax.jit(model.fn()).trace(frames)
+    assert counted() == {}
+
+
+def test_one_trace_lowers_fused_for_a_tpu_and_plain_for_a_cpu(counted):
+    """The backend's ``cpu_fallback`` retry on a TPU host lowers the same
+    program for the CPU: the choice is the lowering's, not the host's."""
+    model, frames = small_vit()
+    traced = jax.jit(model.fn()).trace(frames)
+    text = traced.lower(lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") == 3 and fa.KERNEL_NAME in text
+    assert counted() == {"fused": 3}
+    text = traced.lower(lowering_platforms=("cpu",)).as_text()
+    assert "tpu_custom_call" not in text
+    assert counted() == {"fused": 3, "plain": 3}
+
+
+def test_the_kernel_gives_a_vit_the_same_logits(counted, monkeypatch):
+    model, frames = small_vit()
+    want = np.asarray(jax.jit(model.fn())(frames))
+    monkeypatch.setattr(fa, "plain_attention", lambda qkv, n_heads, causal:
+                        fa.fused_attention(qkv, n_heads, causal,
+                                           interpret=True))
+    model, _ = small_vit()
+    got = np.asarray(jax.jit(model.fn())(frames))
+    assert counted() == {"plain": 6}
+    np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)
+
+
+def test_the_kernel_gives_a_causal_encoder_the_same_result(kernel_everywhere):
+    params = transformer.init_params(jax.random.PRNGKey(0), d_model=128,
+                                     n_heads=4, n_layers=2, d_in=8, n_out=4)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 384, 8))
+    got = jax.jit(lambda a: transformer.apply(params, a, causal=True))(x)
+    want = transformer.apply(params, x, attn="ulysses", causal=True,
+                             mesh=make_mesh((1,), ("sp",),
+                                            devices=jax.devices()[:1]))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=1e-5, rtol=1e-5)
+
+
+def test_quantized_build_takes_the_same_path(counted):
+    model, frames = small_vit(n_layers=2, build=vit.build_quantized,
+                              dtype=jnp.bfloat16)
+    assert lowered_for("tpu", model.fn(), frames).count("tpu_custom_call") == 2
+    assert counted() == {"fused": 2}
+
+
+@pytest.mark.parametrize("shape", [
+    dict(n_heads=2, d_model=64),  # two heads of 32 are half a lane tile
+    dict(image_size=28),          # 16 tokens: XLA is ahead under MIN_TOKENS
+], ids=["half_a_head_group", "short_sequence"])
+def test_a_shape_that_does_not_tile_stays_plain_for_a_tpu(shape, counted):
+    model, frames = small_vit(n_layers=2, **shape)
+    assert "tpu_custom_call" not in lowered_for("tpu", model.fn(), frames)
+    assert counted() == {"plain": 2}
+
+
+def test_forty_layers_lowered_once_count_forty(counted):
+    model, frames = small_vit(n_layers=40, batch=1, dtype=jnp.bfloat16)
+    traced = jax.jit(model.fn()).trace(frames)
+    traced.lower(lowering_platforms=("tpu",))
+    assert counted() == {"fused": 40}
+    traced.lower(lowering_platforms=("cpu",))
+    assert counted() == {"fused": 40, "plain": 40}
+
+
+def test_sequence_parallel_modes_are_not_counted(counted):
+    params = transformer.init_params(jax.random.PRNGKey(0), d_model=32,
+                                     n_heads=4, n_layers=1, d_in=8, n_out=4)
+    x = jax.random.normal(jax.random.PRNGKey(1), (1, 16, 8))
+    mesh = make_mesh((4,), ("sp",), devices=jax.devices()[:4])
+    transformer.apply(params, x, attn="ring", mesh=mesh, causal=False)
+    assert counted() == {}
+
+
+# -- programs over more than one device: Mosaic refuses what GSPMD partitions
+
+def batch_over_a_mesh(model, frames):
+    mesh = make_mesh((4,), ("dp",), devices=jax.devices()[:4])
+    return jax.jit(model.fn(), in_shardings=NamedSharding(mesh, P("dp"))), (
+        frames,)
+
+
+def committed_sharded_input(model, frames):
+    mesh = make_mesh((4,), ("dp",), devices=jax.devices()[:4])
+    return jax.jit(model.fn()), (
+        jax.device_put(frames, NamedSharding(mesh, P("dp"))),)
+
+
+def manual_over_one_of_two_axes(model, frames):
+    mesh = make_mesh((2, 2), ("dp", "tp"), devices=jax.devices()[:4])
+    return jax.jit(jax.shard_map(
+        model.fn(), mesh=mesh, in_specs=P("dp"), out_specs=P("dp"),
+        axis_names={"dp"}, check_vma=False)), (frames,)
+
+
+@pytest.mark.parametrize("program", [
+    batch_over_a_mesh, committed_sharded_input, manual_over_one_of_two_axes])
+def test_a_partitioned_tpu_program_stays_plain(program, counted):
+    jitted, args = program(*small_vit(batch=8))
+    text = jitted.trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" not in text
+    assert counted() == {"plain": 3}
+
+
+def test_the_body_of_a_whole_mesh_shard_map_is_fused(counted):
+    """Mosaic lowers inside a ``shard_map`` over every axis of its mesh:
+    each device runs the kernel on its own rows."""
+    model, frames = small_vit(batch=8)
+    mesh = make_mesh((4,), ("dp",), devices=jax.devices()[:4])
+    rows = jax.shard_map(model.fn(), mesh=mesh, in_specs=P("dp"),
+                         out_specs=P("dp"), check_vma=False)
+    assert lowered_for("tpu", rows, frames).count("tpu_custom_call") == 3
+    assert counted() == {"fused": 3}
+
+
+def test_an_expert_parallel_encoder_stays_plain_for_a_tpu(counted):
+    """``moe_mesh`` makes GSPMD partition the program through ``moe_ffn``'s
+    sharding constraints; nothing on the way marks the trace."""
+    ep = make_mesh((4,), ("ep",), devices=jax.devices()[:4])
+    model = transformer.build(seq_len=384, d_in=8, n_out=4, d_model=128,
+                              n_heads=4, n_layers=2, attn="full",
+                              moe_experts=4, moe_mesh=ep, batch=4)
+    x = np.zeros((4, 384, 8), np.float32)
+    assert "tpu_custom_call" not in lowered_for("tpu", model.fn(), x)
+    assert counted() == {"plain": 2}
+
+
+def test_mesh_sharded_filter_compiles_its_program_plain(counted):
+    from nnstreamer_tpu import Pipeline
+    from nnstreamer_tpu.elements.filter import TensorFilter
+    from nnstreamer_tpu.elements.sink import TensorSink
+    from nnstreamer_tpu.elements.testsrc import DataSrc
+
+    model, frames = small_vit(n_layers=2, batch=8)
+    p = Pipeline()
+    src = p.add(DataSrc(data=[frames]))
+    filt = p.add(TensorFilter(framework="jax-sharded", model=model,
+                              custom="devices=8,axis=dp"))
+    sink = p.add(TensorSink(collect=True))
+    p.link_chain(src, filt, sink)
+    p.run(timeout=60)
+    assert len(sink.frames[0].tensor(0).sharding.device_set) == 8
+    assert counted() == {"plain": 2}
+
+
+# -- under jax's transformations -------------------------------------------
+
+def test_derivatives_are_full_attentions():
+    qkv = jax.random.normal(jax.random.PRNGKey(2), (2, 32, 384))
+    got = jax.grad(lambda a: (fa.attention(a, 4, True) ** 2).sum())(qkv)
+    want = jax.grad(lambda a: (fa.plain_attention(a, 4, True) ** 2).sum())(qkv)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-6)
+
+
+def test_a_mapped_axis_is_more_batch_rows(counted):
+    qkv = jax.random.normal(jax.random.PRNGKey(2), (2, 3, 384, 384))
+    mapped = jax.vmap(lambda a: fa.attention(a, 4, False), in_axes=1,
+                      out_axes=1)
+    assert lowered_for("tpu", mapped, qkv).count("tpu_custom_call") == 1
+    assert counted() == {"fused": 1}
+    np.testing.assert_allclose(
+        np.asarray(mapped(qkv)[:, 1]),
+        np.asarray(fa.plain_attention(qkv[:, 1], 4)), atol=1e-5)
+
+
+# -- compiled for a described v5e, no chip attached -------------------------
+
+@pytest.fixture(scope="module")
+def v5e_2x2():
+    """The four devices of a described v5e host."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler in this install
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # an executable for a chip that is not attached can be written to the
+    # persistent cache but not read back: keep these compiles out of it
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo.devices
+    jax.config.update("jax_enable_compilation_cache", cache_was_on)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("shape,dtype,n_heads,causal", [
+    ((48, 576, 4608), "bfloat16", 16, False),   # siglip2_gopt16_384.mux48
+    ((2, 576, 4608), "float32", 16, True),
+    ((2, 384, 768), "bfloat16", 4, True),       # 2 heads of 64 a group
+])
+def test_mosaic_compiles_the_kernel(v5e_2x2, shape, dtype, n_heads, causal,
+                                    counted):
+    from jax.sharding import SingleDeviceSharding
+
+    qkv = jax.ShapeDtypeStruct(shape, jnp.dtype(dtype),
+                               sharding=SingleDeviceSharding(v5e_2x2[0]))
+    compiled = jax.jit(lambda a: fa.attention(a, n_heads, causal)).lower(
+        qkv).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and fa.KERNEL_NAME in text
+    assert counted() == {"fused": 1}
+
+
+def test_four_chips_compile_a_batch_sharded_tower_plain(v5e_2x2, counted):
+    """A Mosaic kernel in a program GSPMD partitions is refused by jax
+    ("Mosaic kernels cannot be automatically partitioned"): the benchmark
+    tower's widths, two layers, its batch sharded over the four chips."""
+    from jax.sharding import Mesh
+
+    model = vit.build(num_classes=10, image_size=96, patch=16, d_model=1536,
+                      n_heads=16, n_layers=2, batch=8,
+                      dtype=jnp.bfloat16, seed=3)
+    mesh = Mesh(np.array(v5e_2x2), ("dp",))
+    frames = jax.ShapeDtypeStruct((8, 96, 96, 3), jnp.float32,
+                                  sharding=NamedSharding(mesh, P("dp")))
+    text = jax.jit(model.fn()).lower(frames).compile().as_text()
+    assert "tpu_custom_call" not in text
+    assert counted() == {"plain": 2}
