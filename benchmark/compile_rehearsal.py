@@ -30,7 +30,6 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     import jax
-    import jax.numpy as jnp
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
@@ -39,17 +38,20 @@ def main(argv=None) -> int:
     cfg = manifest.load_config(man, cell["config"])
     mix = manifest.load_traffic(cell["traffic"])
     kind = manifest.module("model_kinds", cfg["kind"])
+    traffic = manifest.module("traffic_kinds", mix["kind"])
     sizes = kind.sizes(cfg)
     batch = args.streams or int(mix["streams"])
     weights = kind.init_weights(sizes, int(cfg["weights_seed"]))
     fn = kind.build_program(sizes, weights, batch).fn()
-    add, div = cfg["normalize"]["add"], cfg["normalize"]["div"]
+    # what this traffic feeds the executable, and what the pipeline fuses in
+    # front of the model (a camera's normalise)
+    shape, dtype, front = traffic.example_input(mix, cfg, kind, sizes, batch)
 
-    def program(x):  # the normalise the pipeline fuses in front of the model
-        return fn((x.astype(jnp.float32) + add) / div)
+    def program(x):
+        return fn(front(x))
 
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
-    x = jax.ShapeDtypeStruct((batch,) + kind.frame_shape(sizes), jnp.uint8,
+    x = jax.ShapeDtypeStruct(shape, dtype,
                              sharding=SingleDeviceSharding(topo.devices[0]))
     t = time.perf_counter()
     lowered = jax.jit(program).lower(x)

@@ -29,20 +29,30 @@ def step_mfu(ctx) -> Optional[float]:
         for s in ctx.slices)
 
 
-def attention_roofline(ctx) -> Optional[float]:
-    """Least time for attention's work (the larger of its FLOPs over peak
-    FLOP/s and q, k, v, o once over peak bytes/s) over the summed device
-    time of the ops that read or write a ``T x T``-shaped array."""
-    if not ctx.slices:
+def roofline(ctx, label: str) -> Optional[float]:
+    """The part of the program its kind marks ``label``: the least time for
+    its work (``kind.<label>_work(sizes)``: FLOPs and bytes one frame needs;
+    the larger of FLOPs over peak FLOP/s and bytes over peak bytes/s) over
+    the summed device time of the ops that carry the mark.  Nothing where
+    the kind has no such work function or no traced op carries the mark."""
+    work_of = getattr(ctx.kind, f"{label}_work", None) if ctx.slices else None
+    if work_of is None:
         return None
-    work = ctx.kind.attention_work(ctx.sizes)
+    work = work_of(ctx.sizes)
     frames = ctx.frames_per_step / ctx.chips
     least = arithmetic.least_time_s(work["flops"] * frames,
                                     work["bytes"] * frames, ctx.peak)
-    ctx.notes["attention_bound"] = least["bound"]
+    ctx.notes[f"{label}_bound"] = least["bound"]
     return _mean(arithmetic.share_pct(s.steps * least["seconds"],
-                                      s.marked_ns / 1e9)
+                                      s.marked_ns.get(label, 0.0) / 1e9)
                  for s in ctx.slices)
+
+
+def attention_roofline(ctx) -> Optional[float]:
+    """Attention's work (4 T^2 d FLOPs a layer; q, k, v, o once) over the
+    device time of the ops marked ``attention``: the fused kernel by its
+    name, and the ops that hold a ``T x T`` array where a program has them."""
+    return roofline(ctx, "attention")
 
 
 def device_idle_pct(ctx) -> Optional[float]:

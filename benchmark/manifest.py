@@ -10,6 +10,59 @@ is found by the name the manifest gives it,
   ``benchmark/traffic_kinds/<kind>.py``),
 - ``benchmark/layer_metrics/<metric>.json`` (``reader`` names
   ``benchmark/layer_metrics/<reader>.py``, ``function`` the callable in it).
+
+What each has to offer, whatever the model and whatever the traffic
+(``MODEL_KIND`` ... ``TRAFFIC_KEYS`` below; ``tests/benchmark`` holds every
+entry to them, and ``tests/benchmark/fixtures/second_kind`` is a kind of each
+that is no ViT and no camera):
+
+A configuration file: ``source``, ``kind``, ``reference``, ``dtype`` (of the
+served weights), ``weights_seed``, ``reduced``, ``assumed``, ``limits`` and
+``rehearsal_limits`` (name -> limit of each number ``check`` compares),
+and whatever its kind reads (the ``vit`` kind: ``build``, ``rehearsal``).
+
+A model kind, over ``sizes = kind.sizes(cfg, rehearsal)``:
+
+- ``init_weights(sizes, seed)``: a pytree of host ``numpy`` arrays in the
+  configuration's ``dtype`` (plain ints beside them are fine), the same for
+  the same seed; ``param_count(sizes)``: their sizes' sum;
+- ``build_program(sizes, weights, batch, control=False)``: the system under
+  test, a ``JaxModel`` at batch ``batch``; ``control=True`` the step below
+  the configuration's precision, there to be refused;
+- ``frame_shape(sizes)``: one client's frame, without the batch;
+- ``frame_flops(sizes)["total"]``: FLOPs a frame needs (``step_mfu``);
+- ``marks(sizes)``: label -> ``{"names": [...], "dims": [[...]]}``, what
+  marks an op of the trace as that part's (``trace_reduce.carries``); for a
+  label ``x`` with a roofline, ``x_work(sizes)`` -> ``{"flops", "bytes"}`` a
+  frame (``layer_metrics/device_trace.roofline``).  May be empty.  The
+  metric ``x_roofline`` lists under ``workloads`` the cells whose kind marks
+  ``x``, so that no cell of another kind is asked for it.
+
+A reference: ``forward(sizes, cfg, weights, frames)`` -> float32 rows, one a
+frame; imports nothing of the program.
+
+A traffic file: ``kind``, ``streams`` (the model's batch and the frames a
+step finishes), ``rehearsal`` (what a CPU run overrides), and whatever its
+kind reads.  A traffic kind:
+
+- ``run(mix, model, cfg, kind, sizes, seed, seconds, trace_dir,
+  break_output)`` -> a result with ``t0_ns``, ``t1_ns``, ``push_ns``,
+  ``window`` (the end-to-end values and ``attempted``), ``failed``,
+  ``fail_notes``, ``drained``, ``degraded``, ``trace_dir``
+  (``traffic_kinds/closed_loop.Result`` is one);
+- ``per_frame_faults(result)`` and ``sample(result, mix, seed)`` ->
+  ``(frames, program_rows, picks)``: what is compared once the window has
+  closed (``closed_loop`` has ``sample`` for any closed loop, and a
+  ``per_frame_faults`` for graphs that end in the ``image_labeling`` decoder:
+  label and score against the argmax of the frame's own logits row; a kind
+  with another decoder counts its routing faults itself);
+- ``example_input(mix, cfg, kind, sizes, batch)`` -> ``(shape, dtype,
+  front)``: what the executable takes (``compile_rehearsal.py``).
+
+A reader: ``function(ctx)`` -> a number, or ``None`` where it finds nothing
+to read (never 0 for a share).  ``ctx``: ``result``, ``kind``, ``sizes``,
+``chips``, ``frames_per_step``, ``notes``, and after the trace is reduced
+``slices`` (``trace_reduce.Slice``, ``marked_ns`` by label) and ``peak``.
 """
 
 from __future__ import annotations
@@ -21,6 +74,15 @@ from typing import Any, Dict, List
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
+
+# what run.py, compile_rehearsal.py and the readers call, by module
+MODEL_KIND = ("sizes", "init_weights", "param_count", "build_program",
+              "frame_shape", "frame_flops", "marks")
+TRAFFIC_KIND = ("run", "per_frame_faults", "sample", "example_input")
+REFERENCE = ("forward",)
+CONFIG_KEYS = ("source", "kind", "reference", "dtype", "weights_seed",
+               "reduced", "assumed", "limits", "rehearsal_limits")
+TRAFFIC_KEYS = ("kind", "streams", "rehearsal")
 
 
 def load_json(*parts: str) -> Dict[str, Any]:

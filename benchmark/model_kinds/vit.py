@@ -57,11 +57,13 @@ def attention_work(s: Dict[str, int]) -> Dict[str, float]:
             "bytes": float(layers * 4 * t * d * BYTES_PER_VALUE)}
 
 
-def score_dims(s: Dict[str, int]) -> Tuple[int, int]:
-    """The trailing dims of attention's score arrays: what marks an op of
-    the trace as attention's.  ``T`` is unique per configuration."""
+def marks(s: Dict[str, int]) -> Dict[str, Dict[str, list]]:
+    """What marks an op of the trace as a part of this program, by label
+    (``trace_reduce.carries``).  ``attention``: the fused kernel by its name,
+    and on the plain path the ops that read or write a score array, whose
+    trailing dims are ``T x T`` (``T`` is unique per configuration)."""
     t = tokens(s)
-    return (t, t)
+    return {"attention": {"names": ["nns_fused_attention"], "dims": [[t, t]]}}
 
 
 def param_count(s: Dict[str, int]) -> int:

@@ -66,15 +66,17 @@ def _head(params, y):
     return _dense(params["head"], _layernorm(params["ln_f"], y)).mean(axis=-2)
 
 
-def forward(sizes: Dict[str, Any], normalize: Dict[str, float], weights,
+def forward(sizes: Dict[str, Any], cfg: Dict[str, Any], weights,
             frames_u8: np.ndarray, chunk: int = 4) -> np.ndarray:
-    """Logits ``(n, classes)`` float32 of ``frames_u8`` ``(n, H, W, 3)``.
-    ``weights`` are the harness's host arrays: each block goes to the device
-    when its turn comes and every chunk of frames passes through it there."""
+    """Logits ``(n, classes)`` float32 of ``frames_u8`` ``(n, H, W, 3)``,
+    normalised as ``cfg["normalize"]`` says.  ``weights`` are the harness's
+    host arrays: each block goes to the device when its turn comes and every
+    chunk of frames passes through it there."""
     import jax
     import jax.numpy as jnp
 
     heads = int(weights["n_heads"])
+    normalize = cfg["normalize"]
     embed = jax.jit(lambda p, x: _embed(p, x, sizes["patch"],
                                         normalize["add"], normalize["div"]))
     block = jax.jit(lambda blk, y: _block(blk, y, heads))

@@ -107,7 +107,7 @@ def traced_metrics(line, man, workload, res, ctx) -> None:
     if res.trace_dir is not None:
         ctx.slices = trace_reduce.reduce_trace(
             trace_reduce.load(trace_reduce.find_xplane(res.trace_dir)),
-            ctx.kind.score_dims(ctx.sizes))
+            ctx.kind.marks(ctx.sizes))
         shutil.rmtree(res.trace_dir, ignore_errors=True)
     ctx.peak = peaks.peak_for(device["kind"]) if ctx.slices else None
     line["metrics"] = layer_metrics(
@@ -166,8 +166,7 @@ def run_cell(args, break_output=None):
     if args.trace:
         trace_dir = os.path.join(WORK_DIR, f"trace_{args.workload}")
         shutil.rmtree(trace_dir, ignore_errors=True)
-    res = traffic.run(mix, model, cfg["normalize"], kind.frame_shape(sizes),
-                      int(sizes["num_classes"]), args.seed, args.seconds,
+    res = traffic.run(mix, model, cfg, kind, sizes, args.seed, args.seconds,
                       trace_dir, break_output)
     device = device_report(devices, cell["chips"])
     say_memory(devices, "after the window")
@@ -190,7 +189,7 @@ def run_cell(args, break_output=None):
         numbers["logit_err"] = float("inf")
     else:
         reference = manifest.module("references", cfg["reference"]).forward(
-            sizes, cfg["normalize"], weights, frames)
+            sizes, cfg, weights, frames)
         numbers["logit_err"] = check.logit_err(program, reference)
     say(f"reference over {len(picks)} frames: {time.perf_counter() - t:.1f} s")
     compared = check.verdict(numbers, limits)

@@ -160,21 +160,35 @@ def has_trailing_dims(text: str, dims: Tuple[int, ...]) -> bool:
     return re.search(r"\[(?:\d+,)*" + re.escape(want) + r"\]", text) is not None
 
 
+def carries(op: Op, mark: Dict[str, Sequence]) -> bool:
+    """Does ``op`` carry ``mark``?  A mark names a part of the program by
+    what the trace shows of it: ``names``, prefixes of an op's short name (a
+    kernel's own name, as ``nns_fused_attention``), and/or ``dims``, the
+    trailing dims of an array the op reads or writes (attention's ``T x T``
+    scores where XLA keeps them in HBM).  Either is enough."""
+    return (any(op.name.startswith(n) for n in mark.get("names", ()))
+            or any(has_trailing_dims(op.text, tuple(d))
+                   for d in mark.get("dims", ())))
+
+
 class Slice(NamedTuple):
     steps: int
     window_ns: float
     busy_ns: float
     model_ns: float          # summed device time of the model's executable
-    marked_ns: float         # summed device time of the ops marked by dims
+    marked_ns: Dict[str, float]  # label -> summed device time of its ops
     device_ops: List[Tuple[str, float]]
     idle_gaps: List[Tuple[str, float]]
 
 
-def reduce_device(dev: DeviceTrace, marked_dims: Optional[Tuple[int, ...]] = None,
+def reduce_device(dev: DeviceTrace,
+                  marks: Optional[Dict[str, Dict[str, Sequence]]] = None,
                   top: int = 10) -> Optional[Slice]:
     """The numbers of one device over the whole steps its trace holds, or
     ``None`` where it holds fewer than two whole starts of the model's
-    executable after the first, which the trace's start may have cut."""
+    executable after the first, which the trace's start may have cut.
+    ``marks`` (a model kind's ``marks(sizes)``) maps a label to what marks an
+    op as that part's; a label no op carries is left out of ``marked_ns``."""
     runs = model_runs(dev.modules)[1:]
     if len(runs) < 2:
         return None
@@ -183,12 +197,14 @@ def reduce_device(dev: DeviceTrace, marked_dims: Optional[Tuple[int, ...]] = Non
     ops = [o for o in dev.ops if w0 <= o.start_ns < w1]
     busy_from = ops if ops else [m for m in dev.modules
                                  if w0 <= m.start_ns < w1]
-    marked = 0.0
+    marks = marks or {}
+    marked: Dict[str, float] = {}
     by_name: Dict[str, float] = {}
     for o in ops:
         by_name[o.name] = by_name.get(o.name, 0.0) + o.dur_ns
-        if marked_dims and has_trailing_dims(o.text, marked_dims):
-            marked += o.dur_ns
+        for label, mark in marks.items():
+            if carries(o, mark):
+                marked[label] = marked.get(label, 0.0) + o.dur_ns
     ranked = sorted(by_name.items(), key=lambda kv: -kv[1])[:top]
     return Slice(
         steps=len(steps), window_ns=w1 - w0,
@@ -201,6 +217,7 @@ def reduce_device(dev: DeviceTrace, marked_dims: Optional[Tuple[int, ...]] = Non
 
 
 def reduce_trace(devices: Sequence[DeviceTrace],
-                 marked_dims: Optional[Tuple[int, ...]] = None) -> List[Slice]:
-    return [s for s in (reduce_device(d, marked_dims) for d in devices)
+                 marks: Optional[Dict[str, Dict[str, Sequence]]] = None
+                 ) -> List[Slice]:
+    return [s for s in (reduce_device(d, marks) for d in devices)
             if s is not None]

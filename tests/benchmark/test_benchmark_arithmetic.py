@@ -182,7 +182,9 @@ def test_work_functions_match_the_hand_figures(name):
     assert att["flops"] == flops["attention"]
     # q, k, v, o once in bf16: 4 T d values of 2 bytes a layer
     assert att["bytes"] == s["n_layers"] * 4 * hand["tokens"] * s["d_model"] * 2
-    assert vit.score_dims(s) == (hand["tokens"], hand["tokens"])
+    assert vit.marks(s) == {"attention": {
+        "names": ["nns_fused_attention"],
+        "dims": [[hand["tokens"], hand["tokens"]]]}}
 
 
 @pytest.mark.parametrize("name", sorted(HAND))

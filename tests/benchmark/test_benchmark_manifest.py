@@ -53,12 +53,33 @@ def test_configuration_entry(cfg):
     assert data["assumed"], "sizes the source does not give are listed"
     assert any(w["config"] == cfg["name"] for w in MAN["workloads"])
     assert [c["file"] for c in MAN["configs"]].count(cfg["file"]) == 1
-    # its kind and its plain reference are found by name
-    kind = manifest.module("model_kinds", data["kind"])
-    for fn in ("sizes", "init_weights", "build_program", "frame_flops"):
-        assert callable(getattr(kind, fn)), fn
-    assert callable(manifest.module("references", data["reference"]).forward)
+    for key in manifest.CONFIG_KEYS:
+        assert key in data, key
     assert data["limits"] and data["rehearsal_limits"]
+    # its kind and its plain reference are found by name, and offer what
+    # run.py, compile_rehearsal.py and the readers call
+    kind = manifest.module("model_kinds", data["kind"])
+    for fn in manifest.MODEL_KIND:
+        assert callable(getattr(kind, fn)), fn
+    reference = manifest.module("references", data["reference"])
+    for fn in manifest.REFERENCE:
+        assert callable(getattr(reference, fn)), fn
+    for rehearsal in (False, True):
+        sizes = kind.sizes(data, rehearsal)
+        assert kind.param_count(sizes) > 0
+        assert kind.frame_flops(sizes)["total"] > 0
+        assert all(int(n) > 0 for n in kind.frame_shape(sizes))
+        # a mark is names and/or dims, and a label with a work function
+        # counts FLOPs and bytes
+        for label, mark in kind.marks(sizes).items():
+            assert NAME.match(label), label
+            assert set(mark) <= {"names", "dims"} and any(mark.values()), label
+            assert all(isinstance(n, str) and n for n in mark.get("names", ()))
+            assert all(len(d) >= 1 and all(int(n) > 0 for n in d)
+                       for d in mark.get("dims", ()))
+            work = getattr(kind, f"{label}_work", None)
+            if work is not None:
+                assert set(work(sizes)) >= {"flops", "bytes"}, label
 
 
 @pytest.mark.parametrize("cell", MAN["workloads"], ids=CELLS)
@@ -70,8 +91,20 @@ def test_cell_entry(cell):
     assert 1 <= len(cell["why"]) <= 200 and "\n" not in cell["why"]
     assert cell["config"] in ids(MAN["configs"])
     mix = manifest.load_traffic(cell["traffic"])
-    assert callable(manifest.module("traffic_kinds", mix["kind"]).run)
+    for key in manifest.TRAFFIC_KEYS:
+        assert key in mix, key
+    traffic = manifest.module("traffic_kinds", mix["kind"])
+    for fn in manifest.TRAFFIC_KIND:
+        assert callable(getattr(traffic, fn)), fn
     assert mix["rehearsal"], "a mix carries its tiny rehearsal size"
+    # what the executable takes in this traffic: the batch, then the
+    # kind's frame
+    data = manifest.load_config(MAN, cell["config"], ROOT)
+    kind = manifest.module("model_kinds", data["kind"])
+    sizes = kind.sizes(data)
+    shape, dtype, front = traffic.example_input(mix, data, kind, sizes, 3)
+    assert tuple(shape) == (3,) + tuple(kind.frame_shape(sizes))
+    assert callable(front) and dtype is not None
     pairs = [(w["config"], w["traffic"]) for w in MAN["workloads"]]
     assert pairs.count((cell["config"], cell["traffic"])) == 1
     # every cell reports setup_s, another end-to-end and a per-layer metric
@@ -125,3 +158,23 @@ def test_a_roofline_stands_beside_the_whole_steps_mfu():
         if m["name"].endswith("_roofline"):
             assert any("mfu" in o["name"].split("_") and o["moves"] == m["moves"]
                        for o in per_layer.values()), m["name"]
+
+
+ROOFLINES = [m for m in MAN["per_layer"] if m["name"].endswith("_roofline")]
+
+
+@pytest.mark.parametrize("metric", ROOFLINES, ids=ids(ROOFLINES))
+def test_a_kernels_roofline_lists_the_cells_whose_kind_marks_it(metric):
+    """A metric with no ``workloads`` is asked of every later cell that
+    reports what it moves, of kinds that have no such kernel too.  So
+    ``<label>_roofline`` lists its cells, and in each of them the kind marks
+    ``label`` and counts its work."""
+    label = metric["name"][:-len("_roofline")]
+    assert metric.get("workloads"), "a kernel's roofline lists its cells"
+    for name in metric["workloads"]:
+        cell = manifest.find(MAN["workloads"], name, "cell")
+        data = manifest.load_config(MAN, cell["config"], ROOT)
+        kind = manifest.module("model_kinds", data["kind"])
+        assert label in kind.marks(kind.sizes(data)), (label, name)
+        assert set(getattr(kind, f"{label}_work")(kind.sizes(data))) >= {
+            "flops", "bytes"}, (label, name)

@@ -199,7 +199,10 @@ def test_compile_s_is_the_histograms_sum():
     assert REGISTRY.get("nnstpu_compile_seconds") is not None
 
 
-def rehearse(trace):
+def rehearse(trace, monkeypatch, tmp_path):
+    # a trace directory of this test's own: the rehearsal tests' CLI run
+    # traces the same cell from the same checkout, perhaps at the same time
+    monkeypatch.setattr(bench_run, "WORK_DIR", str(tmp_path))
     cell = MAN["workloads"][0]["name"]
     args = bench_run.parse_args([
         "--workload", cell, "--seed", str(2**31 + 29), "--seconds", "0.5",
@@ -207,10 +210,10 @@ def rehearse(trace):
     return bench_run.run_cell(args)
 
 
-def test_rehearsal_traced_run_reports_the_seven():
+def test_rehearsal_traced_run_reports_the_seven(monkeypatch, tmp_path):
     from nnstreamer_tpu.obs import hooks, spans
 
-    code, report = rehearse(1)
+    code, report = rehearse(1, monkeypatch, tmp_path)
     assert code == 3 and report.line["correct"] is True
     metrics = report.line["metrics"]
     assert set(SEVEN) <= set(metrics)
@@ -228,10 +231,10 @@ def test_rehearsal_traced_run_reports_the_seven():
     assert spans.recorder_stats()["dropped"] == 0
 
 
-def test_rehearsal_untraced_run_records_nothing():
+def test_rehearsal_untraced_run_records_nothing(monkeypatch, tmp_path):
     from nnstreamer_tpu.obs import hooks, spans
 
-    code, report = rehearse(0)
+    code, report = rehearse(0, monkeypatch, tmp_path)
     assert code == 3 and report.line["correct"] is True
     assert spans.snapshot() == [] and not hooks.enabled
     assert set(report.line["metrics"]) == {m["name"] for m in MAN["end_to_end"]}

@@ -104,31 +104,54 @@ def test_the_lower_precision_control_comes_out_not_correct(seed):
             > 1.5 * sound.line["compared"]["logit_err"]["value"])
 
 
+def host_arrays(tree):
+    """The arrays of a weights pytree; plain ints beside them (a head count)
+    are no weights."""
+    import jax
+
+    return [x for x in jax.tree_util.tree_leaves(tree)
+            if not isinstance(x, int)]
+
+
 @pytest.mark.parametrize("cfg", [c["name"] for c in MAN["configs"]])
 def test_the_harness_weights_stay_on_the_host_and_follow_their_seed(cfg):
-    """Nothing of the harness's may sit on the device while the window runs
+    """What the harness needs of any kind's weights.  Nothing of the
+    harness's may sit on the device while the window runs
     (``memory_peak_bytes`` is the program's alone): the weights are numpy
-    arrays in the served type, as a checkpoint's ``np.load`` leaves them."""
-    import jax
-    import ml_dtypes
+    arrays in the configuration's served type, as a checkpoint's ``np.load``
+    leaves them, as many values as the kind counts, and follow their seed."""
+    import ml_dtypes  # noqa: F401  (gives numpy bfloat16 by name)
     import numpy as np
 
     data = manifest.load_config(MAN, cfg, ROOT)
     kind = manifest.module("model_kinds", data["kind"])
     sizes = kind.sizes(data, rehearsal=True)
-    a, b, c = (kind.init_weights(sizes, seed) for seed in (30, 30, 31))
-    leaves = jax.tree_util.tree_leaves(a)
-    arrays = [x for x in leaves if not isinstance(x, int)]
-    assert len(arrays) == 12 * sizes["n_layers"] + 7
-    assert all(type(x) is np.ndarray and x.dtype == ml_dtypes.bfloat16
-               for x in arrays)
-    assert sum(x.size for x in arrays) == kind.param_count(sizes)
-    same = jax.tree_util.tree_map(np.array_equal, a, b)
-    other = jax.tree_util.tree_map(np.array_equal, a, c)
-    assert all(jax.tree_util.tree_leaves(same))
-    assert not any(x for x, leaf in zip(jax.tree_util.tree_leaves(other), leaves)
-                   if not isinstance(leaf, int))
-    # no two blocks alike, and a LayerNorm gain is about 1, a bias about 0
+    a, b, c = (host_arrays(kind.init_weights(sizes, seed))
+               for seed in (30, 30, 31))
+    dtype = np.dtype(data["dtype"])
+    assert a and all(type(x) is np.ndarray and x.dtype == dtype for x in a)
+    assert sum(x.size for x in a) == kind.param_count(sizes)
+    assert [x.shape for x in a] == [x.shape for x in b] == [x.shape for x in c]
+    assert all(np.array_equal(x, y) for x, y in zip(a, b))
+    assert not any(np.array_equal(x, y) for x, y in zip(a, c))
+
+
+VITS = [c["name"] for c in MAN["configs"]
+        if manifest.load_config(MAN, c["name"], ROOT)["kind"] == "vit"]
+
+
+@pytest.mark.parametrize("cfg", VITS)
+def test_the_vit_kinds_pytree(cfg):
+    """The ``vit`` kind alone: the pytree ``vit.build`` takes, 12 arrays a
+    block and 7 around them, no two blocks alike, a LayerNorm gain about 1."""
+    import numpy as np
+
+    data = manifest.load_config(MAN, cfg, ROOT)
+    kind = manifest.module("model_kinds", data["kind"])
+    sizes = kind.sizes(data, rehearsal=True)
+    a = kind.init_weights(sizes, 30)
+    assert len(host_arrays(a)) == 12 * sizes["n_layers"] + 7
+    assert a["n_heads"] == sizes["n_heads"]
     w0, w1 = (np.asarray(blk["ff1"]["w"], np.float32) for blk in a["blocks"][:2])
     assert not np.array_equal(w0, w1)
     assert abs(np.asarray(a["ln_f"]["scale"], np.float32).mean() - 1) < 0.1

@@ -1,5 +1,6 @@
 """The trace reduction on hand-built events: busy union, idle share, the
-model executable's time, the T x T classifier, the longest gaps."""
+model executable's time, marks by label (an op's name, an array's trailing
+dims), the longest gaps; and on two recorded chip traces."""
 
 import os
 import sys
@@ -16,6 +17,7 @@ from benchmark.layer_metrics import device_trace  # noqa: E402
 from benchmark.model_kinds import vit  # noqa: E402
 
 MS = 1e6
+SCORES = {"attention": {"dims": [[1369, 1369]]}}
 
 
 def device(steps=3, period=100, lead=5):
@@ -56,17 +58,62 @@ def test_model_runs_are_the_module_with_most_time():
 
 
 def test_slice_covers_whole_steps_only():
-    s = tr.reduce_device(device(steps=3), (1369, 1369))
+    s = tr.reduce_device(device(steps=3), SCORES)
     assert s.steps == 3
     assert s.window_ns == pytest.approx(300 * MS)
     # each step: ops cover [0, 90) and [94, 96) of 100 ms
     assert s.busy_ns == pytest.approx(3 * 92 * MS)
     assert s.model_ns == pytest.approx(3 * 90 * MS)
-    assert s.marked_ns == pytest.approx(3 * 40 * MS)
+    assert s.marked_ns == {"attention": pytest.approx(3 * 40 * MS)}
+
+
+@pytest.mark.parametrize("mark,ms", [
+    ({"names": ["fusion"]}, 40),                      # by the op's name
+    ({"dims": [[1369, 1369]]}, 40),                   # by an array it holds
+    ({"names": ["fusion"], "dims": [[1369, 1369]]}, 40),   # both: counted once
+    ({"names": ["dot", "tail"]}, 60),                 # several prefixes
+    ({"names": ["fusion"], "dims": [[1369, 1024]]}, 90),   # either is enough
+    ({"names": ["usion"]}, None),                     # a prefix, not a part
+    ({"dims": [[729, 729]]}, None),
+    ({}, None),
+])
+def test_a_mark_is_an_ops_name_or_an_arrays_trailing_dims(mark, ms):
+    s = tr.reduce_device(device(steps=3), {"part": mark})
+    if ms is None:  # a label no op carries is left out, not 0
+        assert s.marked_ns == {}
+    else:
+        assert s.marked_ns == {"part": pytest.approx(3 * ms * MS)}
+
+
+def test_two_labels_on_one_trace_and_a_label_no_op_carries():
+    marks = {"attention": {"names": ["nns_fused_attention"],
+                           "dims": [[1369, 1369]]},
+             "matmul": {"names": ["dot"]},
+             "experts": {"names": ["grouped_matmul"], "dims": [[256, 512]]}}
+    s = tr.reduce_device(device(steps=3), marks)
+    assert s.marked_ns == {"attention": pytest.approx(3 * 40 * MS),
+                           "matmul": pytest.approx(3 * 50 * MS)}
+    kind = SimpleNamespace(
+        marks=lambda sizes: marks,
+        attention_work=lambda sizes: {"flops": 197e12 * 0.004, "bytes": 1.0},
+        matmul_work=lambda sizes: {"flops": 1.0, "bytes": 819e9 * 0.04},
+        experts_work=lambda sizes: {"flops": 1.0, "bytes": 1.0})
+    ctx = SimpleNamespace(slices=[s], kind=kind, sizes={}, chips=1, notes={},
+                          frames_per_step=1, peak=peaks.peak_for("TPU v5 lite"))
+    # 4 ms of FLOPs over 40 ms, 40 ms of bytes over 50 ms
+    assert device_trace.roofline(ctx, "attention") == pytest.approx(10.0)
+    assert device_trace.roofline(ctx, "matmul") == pytest.approx(80.0)
+    assert ctx.notes == {"attention_bound": "compute", "matmul_bound": "memory"}
+    # marked by the kind, carried by no op of this trace: nothing, never 0
+    assert device_trace.roofline(ctx, "experts") is None
+    # no such mark, or a mark with no work function: nothing, and no raise
+    assert device_trace.roofline(ctx, "router") is None
+    del kind.matmul_work
+    assert device_trace.roofline(ctx, "matmul") is None
 
 
 def test_idle_share_and_gaps():
-    s = tr.reduce_device(device(steps=3), (1369, 1369))
+    s = tr.reduce_device(device(steps=3), SCORES)
     ctx = SimpleNamespace(slices=[s])
     assert device_trace.device_idle_pct(ctx) == pytest.approx(8.0)
     names = [g[0] for g in s.idle_gaps]
@@ -81,7 +128,7 @@ def test_device_ops_ranked_by_summed_time():
     s = tr.reduce_device(device(steps=3), None)
     assert s.device_ops[0] == ("dot.1", pytest.approx(0.150))
     assert s.device_ops[1] == ("fusion.7", pytest.approx(0.120))
-    assert s.marked_ns == 0
+    assert s.marked_ns == {}
     assert len(tr.reduce_device(device(), None, top=2).device_ops) == 2
 
 
@@ -171,22 +218,23 @@ def test_reading_an_xspace_as_the_chip_writes_it():
     assert [d.device for d in devs] == ["/device:TPU:0"]
     assert len(devs[0].modules) == 5 and len(devs[0].ops) == 4
     assert devs[0].ops[0].name == "fusion bf16[2,16,9,9]"
-    (s,) = tr.reduce_trace(devs, (9, 9))
+    (s,) = tr.reduce_trace(devs, {"scores": {"dims": [[9, 9]]},
+                                  "matmul": {"names": ["dot"]}})
     assert s.steps == 2 and s.window_ns == pytest.approx(200e3)
     assert s.busy_ns == pytest.approx(180e3)
     assert s.model_ns == pytest.approx(180e3)
-    assert s.marked_ns == pytest.approx(80e3)
+    assert s.marked_ns == {"scores": pytest.approx(80e3),
+                           "matmul": pytest.approx(100e3)}
     assert s.idle_gaps[0] == ("after:dot bf16[2,9,64]", pytest.approx(10e-6))
 
 
-def recorded():
+def recorded(fixture):
     """The device lines of a recorded chip trace, trimmed to the first steps
     (``fixtures/``; its ``source`` says which run)."""
     import gzip
     import json
 
-    path = os.path.join(os.path.dirname(__file__), "fixtures",
-                        "vit_h14_378.mux48.trace.json.gz")
+    path = os.path.join(os.path.dirname(__file__), "fixtures", fixture)
     with gzip.open(path, "rt", encoding="utf-8") as f:
         fx = json.load(f)
     names = fx["names"]
@@ -198,22 +246,25 @@ def recorded():
     return tr.DeviceTrace("/device:TPU:0", ops(fx["modules"]), ops(fx["ops"]))
 
 
-def test_the_reduction_on_a_recorded_chip_trace():
-    """What the chip wrote for ``vit_h14_378.mux48`` (PR 30's first tower,
-    ViT-H/14 at 378 x 378; its cell went out under the memory floor and the
-    trace stays as the reduction's recorded case): the first run of the
-    model is cut by the trace's start and left out; three whole steps of
-    432.4 ms follow, a period of about 462 ms; attention's three fusions (the
-    ops with a 729 x 729 operand or result) take 30.5 % of the device time."""
-    dev = recorded()
+def test_the_reduction_on_a_recorded_trace_of_the_plain_path():
+    """What the chip wrote for a tower that has no cell (PR 30's first,
+    ViT-H/14 at 378 x 378 under 48 cameras; its cell went out under the
+    memory floor) while attention was XLA's: the recorded case of marks by
+    dims.  The first run of the model is cut by the trace's start and left
+    out; three whole steps of 432.4 ms follow, a period of about 462 ms;
+    attention's three fusions (the ops with a 729 x 729 operand or result)
+    take 30.5 % of the device time."""
+    dev = recorded("no_cell.vit_h14_378_plain_attention.trace.json.gz")
+    sizes = {"image_size": 378, "patch": 14, "d_model": 1280,
+             "n_heads": 16, "n_layers": 32, "num_classes": 1000}
     runs = tr.model_runs(dev.modules)
     assert {m.name for m in runs} == {"jit_flat_fn"} and len(runs) == 5
     assert runs[0].dur_ns < 0.9 * runs[1].dur_ns            # the cut one
-    s = tr.reduce_device(dev, (729, 729))
+    s = tr.reduce_device(dev, vit.marks(sizes))
     assert s.steps == 3
     assert s.model_ns / s.steps == pytest.approx(432.4e6, rel=0.002)
     assert s.window_ns / s.steps == pytest.approx(462e6, rel=0.03)
-    assert s.marked_ns / s.model_ns == pytest.approx(0.305, abs=0.003)
+    assert s.marked_ns["attention"] / s.model_ns == pytest.approx(0.305, abs=0.003)
     assert 0.03 < 1 - s.busy_ns / s.window_ns < 0.10
     assert s.busy_ns <= s.model_ns * 1.001
     assert s.device_ops[0][0] == "convert_reduce_fusion (f32[48,729], bf16[48,729,1280])"
@@ -223,10 +274,96 @@ def test_the_reduction_on_a_recorded_chip_trace():
     assert s.idle_gaps[0][1] == pytest.approx(0.03, abs=0.02)
     ctx = SimpleNamespace(slices=[s], kind=vit, chips=1, frames_per_step=48,
                           peak=peaks.peak_for("TPU v5 lite"), notes={},
-                          sizes={"image_size": 378, "patch": 14, "d_model": 1280,
-                                 "n_heads": 16, "n_layers": 32, "num_classes": 1000})
+                          sizes=sizes)
     assert device_trace.step_mfu(ctx) == pytest.approx(56.76, abs=0.1)
     assert device_trace.attention_roofline(ctx) == pytest.approx(16.1, abs=0.1)
+
+
+CELL_SIZES = {"image_size": 384, "patch": 16, "d_model": 1536, "n_heads": 16,
+              "n_layers": 40, "num_classes": 1000}
+# (ledger, PR 32, ``breakdown.device_ops`` of siglip2_gopt16_384.mux48: the
+# ten ops that took most time, seconds over 11 traced steps), under the
+# names the benchmark gives them (PERF.md section 5)
+PR32_OPS = [
+    ("convert_reduce_fusion", "(f32[48,576]{1,0}, bf16[48,576,1536]{2,1,0})", 1.64536463),
+    ("convolution_add_fusion", "bf16[48,576,6144]{2,1,0}", 1.222316919),
+    ("convolution_add_fusion", "bf16[48,576,4608]{2,1,0}", 0.948587863),
+    ("nns_fused_attention", "bf16[48,576,1536]{2,1,0}", 0.422850018),
+    ("convert_reduce_fusion", "f32[48,576]{1,0}", 0.069776077),
+    ("reshape", "bf16[48,24,16,24,16,3]{5,4,3,2,1,0}", 0.058055718),
+    ("copy", "bf16[48,24,24,16,16,3]{5,4,3,2,1,0}", 0.036391841),
+    ("convert_reduce_fusion", "f32[48,1000]{1,0}", 0.005159322),
+    ("copy", "bf16[48,576,768]{2,1,0}", 0.002727411),
+    ("copy-done", "bf16[48,576,1536]{2,1,0}", 0.001222707),
+]
+
+
+def test_attention_roofline_reads_the_kernel_by_its_name():
+    """PR 32's program as the ledger's breakdown has it, built by hand: 11
+    whole steps of the ten ops back to back, a 30 ms gap after each.  No op
+    holds a ``[..., 576, 576]`` array, so the mark by dims alone (the
+    benchmark before PR 33) reads nothing; by the kernel's name the share is
+    3.914 TFLOP over 197 TFLOP/s = 19.87 ms over 38.44 ms a step."""
+    steps = 11
+    modules, ops, t = [], [], 0.0
+    for i in range(steps + 2):
+        start = t
+        for j, (root, shape, total_s) in enumerate(PR32_OPS):
+            text = f"%{root}.{7 * i + j} = {shape} fusion(bf16[48,576,4608]{{2,1,0}} %p)"
+            ops.append(tr.Op(tr.short_name(text), t, total_s / steps * 1e9, text))
+            t += total_s / steps * 1e9
+        modules.append(tr.Op("jit_flat_fn", start, t - start))
+        t += 30 * MS
+    dev = tr.DeviceTrace("/device:TPU:0", modules, ops)
+    s = tr.reduce_device(dev, vit.marks(CELL_SIZES))
+    assert s.steps == steps
+    assert s.marked_ns == {"attention": pytest.approx(0.422850018e9)}
+    assert s.device_ops[3] == ("nns_fused_attention bf16[48,576,1536]",
+                               pytest.approx(0.422850018))
+    ctx = SimpleNamespace(slices=[s], kind=vit, chips=1, frames_per_step=48,
+                          peak=peaks.peak_for("TPU v5 lite"), notes={},
+                          sizes=CELL_SIZES)
+    assert device_trace.attention_roofline(ctx) == pytest.approx(51.69, abs=0.05)
+    assert ctx.notes["attention_bound"] == "compute"
+    assert device_trace.step_mfu(ctx) == pytest.approx(84.38, abs=0.05)
+    assert device_trace.device_idle_pct(ctx) == pytest.approx(
+        100 * 30 / (30 + 4412.452506 / steps), abs=0.01)
+    by_dims = tr.reduce_device(dev, {"attention": {"dims": [[576, 576]]}})
+    assert by_dims.marked_ns == {}
+    ctx.slices = [by_dims]
+    assert device_trace.attention_roofline(ctx) is None
+
+
+def test_the_reduction_on_a_recorded_trace_of_the_cell():
+    """What the chip wrote for ``siglip2_gopt16_384.mux48`` in PR 33's first
+    traced run: three whole steps of 401.18 ms every 432.5 ms; attention is
+    ``nns_fused_attention``, 40 calls a step, 38.44 ms of it, marked by name
+    (no op holds a 576 x 576 array): 51.68 % of its roofline."""
+    dev = recorded("siglip2_gopt16_384.mux48.trace.json.gz")
+    runs = tr.model_runs(dev.modules)
+    assert {m.name for m in runs} == {"jit_flat_fn"} and len(runs) == 5
+    assert runs[0].dur_ns < 0.9 * runs[1].dur_ns            # the cut one
+    s = tr.reduce_device(dev, vit.marks(CELL_SIZES))
+    assert s.steps == 3
+    assert s.model_ns / s.steps == pytest.approx(401.18e6, rel=0.0005)
+    assert s.window_ns / s.steps == pytest.approx(432.5e6, rel=0.001)
+    assert s.marked_ns["attention"] / s.steps == pytest.approx(38.44e6, rel=0.0005)
+    kernel = [o for o in dev.ops if o.name.startswith("nns_fused_attention")]
+    assert len(kernel) == 3 * 40 + 11                 # and 11 of the cut run
+    assert "custom-call" in kernel[0].text and "576,576" not in kernel[0].text
+    assert tr.reduce_device(dev, {"attention": {"dims": [[576, 576]]}}).marked_ns == {}
+    assert [n for n, _ in s.device_ops[:4]] == [
+        "convert_reduce_fusion (f32[48,576], bf16[48,576,1536])",
+        "convolution_add_fusion bf16[48,576,6144]",
+        "convolution_add_fusion bf16[48,576,4608]",
+        "nns_fused_attention bf16[48,576,1536]"]
+    assert s.idle_gaps[0][1] == pytest.approx(0.0417, abs=0.001)
+    ctx = SimpleNamespace(slices=[s], kind=vit, chips=1, frames_per_step=48,
+                          peak=peaks.peak_for("TPU v5 lite"), notes={},
+                          sizes=CELL_SIZES)
+    assert device_trace.step_mfu(ctx) == pytest.approx(84.376, abs=0.01)
+    assert device_trace.attention_roofline(ctx) == pytest.approx(51.68, abs=0.01)
+    assert device_trace.device_idle_pct(ctx) == pytest.approx(7.25, abs=0.01)
 
 
 def test_shares_from_a_slice():
@@ -239,8 +376,9 @@ def test_shares_from_a_slice():
     att_s = 32 * vit.attention_work(sizes)["flops"] / peak.flops_per_s
 
     def ctx(model_s, marked_s):
+        marked = {"attention": 4 * marked_s * 1e9} if marked_s else {}
         s = tr.Slice(steps=4, window_ns=0, busy_ns=0, model_ns=4 * model_s * 1e9,
-                     marked_ns=4 * marked_s * 1e9, device_ops=[], idle_gaps=[])
+                     marked_ns=marked, device_ops=[], idle_gaps=[])
         return SimpleNamespace(slices=[s], kind=vit, sizes=sizes, chips=1,
                                frames_per_step=32, peak=peak, notes={})
 
