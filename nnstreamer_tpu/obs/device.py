@@ -244,6 +244,39 @@ def record_compile(backend, key, result: str, dur_ns: int = 0,
             "compile accounting failed")
 
 
+def record_weights_upload(backend, placed, dur_ns: int,
+                          registry: Optional[MetricsRegistry] = None) -> None:
+    """Account one upload of a model's weights (a backend's ``open``): the
+    ``nnstpu_weights_upload_seconds`` / ``nnstpu_weights_device_bytes``
+    metrics whatever the hook gate, and a ``weights_upload`` span when span
+    tracing is active.  Never raises."""
+    try:
+        reg = registry if registry is not None else REGISTRY
+        model = str(getattr(backend.model, "name", "") or "")
+        nbytes = sum(int(a.nbytes) for a in placed)
+        reg.histogram(
+            "nnstpu_weights_upload_seconds",
+            "Wall time spent putting a model's weights on its devices "
+            "(seconds, device_put to ready), one observation an upload",
+            labelnames=("model",), buckets=COMPILE_BUCKETS_S,
+        ).observe(dur_ns / 1e9, model=model)
+        reg.gauge(
+            "nnstpu_weights_device_bytes",
+            "Bytes of a model's weights as its programs' arguments hold "
+            "them (one replica)", labelnames=("model",),
+        ).set(nbytes, model=model)
+        if spans.enabled:
+            spans.record_span(
+                "weights_upload", now_ns() - dur_ns, dur_ns, cat="compile",
+                trace=(0, 0), args={"model": model, "bytes": nbytes,
+                                    "arrays": len(placed)})
+    except Exception:  # noqa: BLE001
+        import logging
+
+        logging.getLogger("nnstreamer_tpu.obs").exception(
+            "weights upload accounting failed")
+
+
 # -- device memory gauges ----------------------------------------------------
 
 # memory_stats() keys worth exposing (allocator implementations differ;

@@ -182,7 +182,7 @@ class _Metric:
         self.name = name
         self.help = help
         self.labelnames = tuple(labelnames)
-        self._lock = threading.Lock()
+        self._lock = threading.RLock()
         self._children: Dict[Tuple[str, ...], object] = {}
 
     def _make_child(self):
@@ -218,7 +218,7 @@ class _Value:
 
     def __init__(self):
         self._v = 0.0
-        self._lock = threading.Lock()
+        self._lock = threading.RLock()
 
     @property
     def value(self) -> float:
@@ -261,7 +261,7 @@ class _HistogramChild:
         # straight to its Perfetto trace; None until a traced observe hits
         self._exemplars: List[Optional[Tuple[int, float, float]]] = \
             [None] * (len(bounds) + 1)
-        self._lock = threading.Lock()
+        self._lock = threading.RLock()
 
     def observe(self, value: float) -> None:
         i = bisect.bisect_left(self._bounds, value)
@@ -342,7 +342,7 @@ class MetricsRegistry:
     """Named metrics + scrape-time collectors."""
 
     def __init__(self):
-        self._lock = threading.Lock()
+        self._lock = threading.RLock()
         self._metrics: Dict[str, _Metric] = {}
         self._collectors: List[Callable[[], None]] = []
 

@@ -19,11 +19,20 @@ and returns ``[B, T, d]``, token-major on both sides:
   q.  A whole row of scores is resident, so there is no online softmax and no
   loop over K blocks; :func:`tiles` is what says when that holds.
 
-``models/transformer.py`` calls :func:`attention`, one primitive with two
-lowerings.  Which one a call gets is decided when its program is lowered,
+Past what :func:`tiles` admits a second kernel, :func:`blocked_attention`,
+walks key blocks with a running softmax: causal, over separate q and k, v
+projections with grouped heads of width 128 (a head is one lane tile of the
+token-major arrays), with a window if the layer has one; it skips the key
+blocks above the diagonal and outside the band, and holds a key/value head's
+whole K and V in VMEM, fetched once a head group.
+
+``models/transformer.py`` and ``models/laguna.py`` call :func:`attention`, one
+primitive.  Which lowering a call gets is decided when its program is lowered,
 from what it is lowered for: the kernel for a TPU program that runs on one
 device (or is the all-manual body of a ``shard_map``) where :func:`tiles`
-holds, ``parallel.ring_attention.full_attention`` through XLA everywhere else
+(the fused projection) or :func:`blocked_tiles` (separate projections) holds,
+``parallel.ring_attention.full_attention`` with the explicit mask through XLA
+everywhere else
 — a CPU program on a TPU host, a program GSPMD partitions over a mesh, a shape
 the kernel does not tile.  ``full_attention`` stays the reference.  Called
 directly off-TPU, :func:`fused_attention` executes in Pallas interpret mode
@@ -173,7 +182,168 @@ def plain_attention(qkv, n_heads: int, causal: bool = False):
     return full_attention(q, k, v, causal=causal).reshape(b, t, d3 // 3)
 
 
-# -- one primitive, two lowerings -------------------------------------------
+# -- grouped heads, causal, past what tiles() admits: key blocks ------------
+
+BLOCKED_KERNEL_NAME = "nns_blocked_attention"
+# Query rows and key rows a step of the walk takes.  On the v5e at 16 x 4096
+# tokens 512 x 512 ran fastest for both kinds of layer (34.6 ms with 48 heads
+# and no window, 25.9 ms with 64 heads and a window of 512); 256 x 256, which
+# computes less of what a window's mask throws away, took 59.7 and 33.6 ms:
+# a step's fixed cost outweighs the masked half.
+BLOCK_Q = 512
+BLOCK_K = 512
+
+
+def _round_up(n: int, to: int) -> int:
+    return -(-n // to) * to
+
+
+def blocked_tiles(q_shape, kv_shape, dtype, n_heads: int, n_kv_heads: int,
+                  causal: bool) -> bool:
+    """Whether :func:`blocked_attention` is the lowering for these shapes:
+    causal, heads of exactly one lane tile that group evenly over the
+    key/value heads, bf16 or f32, and a (batch row, key/value head)'s whole
+    K and V, double buffered, within :data:`VMEM_BUDGET` (T <= 12 k in
+    bf16)."""
+    if not causal or len(q_shape) != 3 or len(kv_shape) != 3:
+        return False
+    dtype = jnp.dtype(dtype)
+    if dtype not in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32)):
+        return False
+    t = q_shape[1]
+    return (n_kv_heads > 0 and n_heads % n_kv_heads == 0
+            and q_shape[-1] == n_heads * LANES
+            and kv_shape[-1] == n_kv_heads * LANES
+            and 2 * 2 * _round_up(t, BLOCK_K) * LANES * dtype.itemsize
+            <= VMEM_BUDGET)
+
+
+def _blocked_kernel(q_ref, k_ref, v_ref, o_ref, *, bq: int, bk: int,
+                    window: Optional[int]):
+    """One (batch row, query head, block of query rows): walk the key blocks
+    this block's rows may see with a running max, row sum and output."""
+    q0 = pl.program_id(2) * bq
+    q = q_ref[0] * (LANES ** -0.5)  # a weak scalar: q keeps its type
+    rows = q0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+
+    def step(j, carry, masked: bool):
+        m, l, acc = carry
+        k0 = pl.multiple_of(j * bk, bk)
+        s = jax.lax.dot_general(q, k_ref[0, pl.ds(k0, bk), :],
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        if masked:
+            at = k0 + cols
+            seen = at <= rows
+            if window is not None:
+                seen &= at > rows - window
+            s = jnp.where(seen, s, MASKED)
+        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+        e = jnp.exp(s - m_new)
+        a = jnp.exp(m - m_new)
+        v = v_ref[0, pl.ds(k0, bk), :]
+        acc = a * acc + jnp.dot(e.astype(v.dtype), v,
+                                preferred_element_type=jnp.float32)
+        return m_new, a * l + e.sum(axis=-1, keepdims=True), acc
+
+    carry = (jnp.full((bq, 1), MASKED, jnp.float32),
+             jnp.zeros((bq, 1), jnp.float32),
+             jnp.zeros((bq, LANES), jnp.float32))
+    # key blocks wholly under the diagonal (and inside the band) need no
+    # mask: [lo, inner) masked at the band's lower edge, [inner, whole)
+    # bare, [whole, hi) masked at the diagonal
+    hi = (q0 + bq - 1) // bk + 1
+    whole = (q0 + 1) // bk  # blocks whose last key is <= the first row
+    if window is None:
+        lo = inner = 0
+    else:
+        lo = jnp.maximum(q0 - window + 1, 0) // bk
+        # first block whose first key every row of the block still sees
+        inner = jnp.minimum(
+            jnp.maximum(q0 + bq - window, 0) // bk
+            + (jnp.maximum(q0 + bq - window, 0) % bk > 0), whole)
+        inner = jnp.maximum(inner, lo)
+    carry = jax.lax.fori_loop(lo, inner, functools.partial(step, masked=True),
+                              carry)
+    carry = jax.lax.fori_loop(inner, whole,
+                              functools.partial(step, masked=False), carry)
+    _, l, acc = jax.lax.fori_loop(whole, hi,
+                                  functools.partial(step, masked=True), carry)
+    o_ref[0] = (acc / l).astype(o_ref.dtype)
+
+
+def blocked_attention(q, k, v, n_heads: int, n_kv_heads: int,
+                      window: Optional[int] = None,
+                      block_q: Optional[int] = None,
+                      block_k: Optional[int] = None,
+                      interpret: Optional[bool] = None):
+    """Causal softmax attention with grouped heads of width 128, token-major.
+
+    ``q``: ``[B, T, n_heads * 128]``; ``k``, ``v``: ``[B, T, n_kv_heads *
+    128]``, query head ``h`` reading key/value head ``h // (n_heads /
+    n_kv_heads)``; with ``window`` query ``i`` sees keys ``i - window + 1 ...
+    i``.  Returns ``[B, T, n_heads * 128]``.  A head is one 128-lane column
+    block, so the ``BlockSpec`` index maps pick heads and groups out of the
+    projections as the matmuls left them.  A grid step holds one block of
+    query rows and the whole K and V of its key/value head (fetched once a
+    head group: the block index does not change from one query head or row
+    block to the next), and walks only the key blocks that lie under the
+    diagonal and, with a window, inside the band.  T is padded to whole
+    blocks: the padded keys come after every real row, and the padded rows
+    are cut off.
+    """
+    b, t, _ = q.shape
+    if interpret is None:
+        interpret = _interpret()
+    bq, bk = block_q or BLOCK_Q, block_k or BLOCK_K
+    bq, bk = min(bq, _round_up(t, 128)), min(bk, _round_up(t, 128))
+    tp = _round_up(t, math.lcm(bq, bk))
+    if tp != t:
+        q, k, v = (jnp.pad(a, ((0, 0), (0, tp - t), (0, 0)))
+                   for a in (q, k, v))
+    group = n_heads // n_kv_heads
+    itemsize = jnp.dtype(q.dtype).itemsize
+    seen = tp * (tp + 1) // 2 if window is None else tp * min(window, tp)
+    kv_spec = pl.BlockSpec((1, tp, LANES), lambda i, h, r: (i, 0, h // group))
+    out = pl.pallas_call(
+        functools.partial(_blocked_kernel, bq=bq, bk=bk, window=window),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        grid=(b, n_heads, tp // bq),
+        in_specs=[pl.BlockSpec((1, bq, LANES), lambda i, h, r: (i, r, h)),
+                  kv_spec, kv_spec],
+        out_specs=pl.BlockSpec((1, bq, LANES), lambda i, h, r: (i, r, h)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
+        cost_estimate=pl.CostEstimate(
+            flops=4 * b * n_heads * seen * LANES,
+            transcendentals=b * n_heads * seen,
+            bytes_accessed=2 * b * tp * (n_heads + n_kv_heads) * LANES
+            * itemsize),
+        interpret=interpret,
+        name=BLOCKED_KERNEL_NAME,
+    )(q, k, v)
+    return out[:, :t] if tp != t else out
+
+
+def plain_grouped_attention(q, k, v, n_heads: int, n_kv_heads: int,
+                            causal: bool = False,
+                            window: Optional[int] = None):
+    """``full_attention`` with the explicit mask over separate projections:
+    the key/value heads repeated over their groups, as XLA lowers it."""
+    from ..parallel.ring_attention import full_attention
+
+    b, t, _ = q.shape
+    dh = q.shape[-1] // n_heads
+    q = q.reshape(b, t, n_heads, dh)
+    k, v = (jnp.repeat(a.reshape(b, t, n_kv_heads, dh),
+                       n_heads // n_kv_heads, axis=2) for a in (k, v))
+    return full_attention(q, k, v, causal=causal,
+                          window=window).reshape(b, t, n_heads * dh)
+
+
+# -- one primitive, its lowerings -------------------------------------------
 #
 # A trace does not know what it will be lowered for: the same jaxpr goes to
 # the TPU, to the CPU under ``jax.default_device`` (the backend's
@@ -183,15 +353,41 @@ def plain_attention(qkv, n_heads: int, causal: bool = False):
 attention_p = Primitive("nns_full_attention")
 
 
-def attention(qkv, n_heads: int, causal: bool = False):
-    """Softmax attention over the fused projection ``[B, T, 3*d]`` →
-    ``[B, T, d]``; see the module's docstring for which lowering it gets."""
-    return attention_p.bind(qkv, n_heads=n_heads, causal=causal)
+def attention(q, n_heads: int, causal: bool = False, k=None, v=None,
+              n_kv_heads: Optional[int] = None, window: Optional[int] = None):
+    """Softmax attention, token-major.  Over the fused projection ``q`` =
+    ``[B, T, 3*d]`` → ``[B, T, d]``, or with ``k`` and ``v`` over separate
+    projections ``[B, T, n_heads*dh]`` and ``[B, T, n_kv_heads*dh]``
+    (grouped heads), ``window`` keys back under ``causal``; see the module's
+    docstring for which lowering a call gets."""
+    if window is not None and not causal:
+        raise ValueError("a window needs causal=True")
+    if k is None:
+        if window is not None or n_kv_heads not in (None, n_heads):
+            raise ValueError("the fused projection has neither a window "
+                             "nor grouped heads")
+        return attention_p.bind(q, n_heads=n_heads, n_kv_heads=n_heads,
+                                causal=causal, window=None)
+    return attention_p.bind(q, k, v, n_heads=n_heads,
+                            n_kv_heads=n_kv_heads or n_heads, causal=causal,
+                            window=window)
 
 
-attention_p.def_impl(jax.jit(attention, static_argnames=("n_heads", "causal")))
-attention_p.def_abstract_eval(
-    lambda qkv, **_: qkv.update(shape=(*qkv.shape[:-1], qkv.shape[-1] // 3)))
+def _plain(*operands, n_heads, n_kv_heads, causal, window):
+    if len(operands) == 1:
+        return plain_attention(operands[0], n_heads, causal)
+    return plain_grouped_attention(*operands, n_heads, n_kv_heads, causal,
+                                   window)
+
+
+def _abstract(q, *kv, **_):
+    return q if kv else q.update(shape=(*q.shape[:-1], q.shape[-1] // 3))
+
+
+attention_p.def_impl(jax.jit(
+    lambda *operands, **kw: attention_p.bind(*operands, **kw),
+    static_argnames=("n_heads", "n_kv_heads", "causal", "window")))
+attention_p.def_abstract_eval(_abstract)
 
 
 def _count_lowering(path: str) -> None:
@@ -199,16 +395,17 @@ def _count_lowering(path: str) -> None:
 
     REGISTRY.counter(
         "nnstpu_attention_lowerings_total",
-        "full-attention calls lowered into a program, by the path chosen "
-        "(fused = the Pallas kernel, plain = full_attention through XLA)",
+        "attention calls lowered into a program, by the path chosen (fused "
+        "= the whole-row Pallas kernel, blocked = the key-block walk with "
+        "grouped heads, plain = full_attention through XLA)",
         labelnames=("path",),
     ).inc(path=path)
 
 
-def _lower_plain(ctx, qkv, *, n_heads, causal):
+def _lower_plain(ctx, *operands, **kw):
     _count_lowering("plain")
-    return mlir.lower_fun(lambda a: plain_attention(a, n_heads, causal),
-                          multiple_results=False)(ctx, qkv)
+    return mlir.lower_fun(functools.partial(_plain, **kw),
+                          multiple_results=False)(ctx, *operands)
 
 
 def _on_one_device(axis_context) -> bool:
@@ -223,15 +420,26 @@ def _on_one_device(axis_context) -> bool:
         == set(mesh.axis_names))
 
 
-def _lower_tpu(ctx, qkv, *, n_heads, causal):
-    aval, = ctx.avals_in
-    if not (tiles(aval.shape, aval.dtype, n_heads)
-            and _on_one_device(ctx.module_context.axis_context)):
-        return _lower_plain(ctx, qkv, n_heads=n_heads, causal=causal)
-    _count_lowering("fused")
-    return mlir.lower_fun(
-        lambda a: fused_attention(a, n_heads, causal, interpret=False),
-        multiple_results=False)(ctx, qkv)
+def _lower_tpu(ctx, *operands, n_heads, n_kv_heads, causal, window):
+    kw = dict(n_heads=n_heads, n_kv_heads=n_kv_heads, causal=causal,
+              window=window)
+    if not _on_one_device(ctx.module_context.axis_context):
+        return _lower_plain(ctx, *operands, **kw)
+    avals = ctx.avals_in
+    if len(avals) == 1 and tiles(avals[0].shape, avals[0].dtype, n_heads):
+        _count_lowering("fused")
+        return mlir.lower_fun(
+            lambda a: fused_attention(a, n_heads, causal, interpret=False),
+            multiple_results=False)(ctx, *operands)
+    if len(avals) == 3 and blocked_tiles(avals[0].shape, avals[1].shape,
+                                         avals[0].dtype, n_heads, n_kv_heads,
+                                         causal):
+        _count_lowering("blocked")
+        return mlir.lower_fun(
+            lambda q, k, v: blocked_attention(q, k, v, n_heads, n_kv_heads,
+                                              window, interpret=False),
+            multiple_results=False)(ctx, *operands)
+    return _lower_plain(ctx, *operands, **kw)
 
 
 # not cacheable: every call site is lowered, and counted, on its own
@@ -240,19 +448,19 @@ mlir.register_lowering(attention_p, _lower_tpu, platform="tpu",
                        cacheable=False)
 
 
-def _jvp(primals, tangents, *, n_heads, causal):
+def _jvp(primals, tangents, **kw):
     # derivatives are full_attention's: training runs the plain path
-    (qkv,), (dqkv,) = primals, tangents
-    return jax.jvp(lambda a: plain_attention(a, n_heads, causal),
-                   (qkv,), (ad.instantiate_zeros(dqkv),))
+    return jax.jvp(functools.partial(_plain, **kw), primals,
+                   tuple(ad.instantiate_zeros(t) for t in tangents))
 
 
-def _batch(args, dims, *, n_heads, causal):
+def _batch(args, dims, **kw):
     # a mapped axis is more batch rows
-    (qkv,), (dim,) = args, dims
-    qkv = jnp.moveaxis(qkv, dim, 0)
-    out = attention(qkv.reshape(-1, *qkv.shape[2:]), n_heads, causal)
-    return out.reshape(*qkv.shape[:2], *out.shape[1:]), 0
+    moved = [jnp.moveaxis(a, d, 0) for a, d in zip(args, dims)]
+    lead = moved[0].shape[:2]
+    out = attention_p.bind(*(a.reshape(-1, *a.shape[2:]) for a in moved),
+                           **kw)
+    return out.reshape(*lead, *out.shape[1:]), 0
 
 
 ad.primitive_jvps[attention_p] = _jvp

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.layers import Params, _normal, dense_init
+from ..ops.quant import QuantizedWeight, matmul_int8
 
 
 def init_moe_params(
@@ -70,6 +71,7 @@ def moe_ffn(
     shards over ``axis`` (sharding constraints; XLA places the
     all_to_all); without, it is an ordinary local einsum chain.
     """
+    _count_moe_lowering("switch")
     orig_shape = x.shape
     d = orig_shape[-1]
     t = 1
@@ -145,3 +147,98 @@ def place_moe_params(params: Params, mesh, axis: str = "ep") -> Params:
         "w2": shard_expert(params["w2"], 3),
         "b2": shard_expert(params["b2"], 2),
     }
+
+
+# -- top-k of many experts, nothing dropped ----------------------------------
+#
+# The switch layer above sends a token to one expert and drops what exceeds a
+# capacity.  This one keeps every assignment at static shapes: the ``tokens x
+# k`` (token, expert) pairs are sorted by expert, so that each expert's rows
+# lie together, and one grouped matrix product runs every expert over its own
+# rows, whatever their number.
+
+def matmul(x, w):
+    """``x @ w`` in ``x``'s type; a ``QuantizedWeight`` (``ops/quant``) runs
+    W8A8."""
+    if isinstance(w, QuantizedWeight):
+        return matmul_int8(x, w, x.dtype)
+    return x @ w.astype(x.dtype)
+
+
+def swiglu(x, w_in, w_out):
+    """``(silu(x @ gate) * (x @ up)) @ w_out``, ``w_in`` = ``[gate | up]``."""
+    gate, up = jnp.split(matmul(x, w_in), 2, axis=-1)
+    return matmul(jax.nn.silu(gate) * up, w_out)
+
+
+def route_top_k(x, router, top_k: int, scaling: float = 1.0):
+    """``(weights, experts)``, both ``[tokens, top_k]``: sigmoid scores of
+    ``x @ router`` in float32, the ``top_k`` highest a token, renormalised
+    to sum 1 and times ``scaling``."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    top, experts = jax.lax.top_k(scores, top_k)
+    return top / top.sum(axis=-1, keepdims=True) * scaling, experts
+
+
+def routed_experts(x, weights, experts, w_in, w_out):
+    """Every (token, expert) pair of ``experts`` ``[tokens, k]`` through its
+    expert's SwiGLU (``w_in`` ``[E, d, 2f]``, ``w_out`` ``[E, f, d]``), the
+    ``k`` results of a token summed under ``weights``.  ``[tokens, d]``."""
+    n, k = experts.shape
+    e = w_in.shape[0]
+    flat = experts.reshape(-1)
+    order = jnp.argsort(flat, stable=True)      # assignments, by expert
+    sizes = jnp.bincount(flat, length=e).astype(jnp.int32)
+    rows = x[order // k]                        # [n*k, d], expert-major
+    gate, up = jnp.split(jax.lax.ragged_dot(rows, w_in, sizes), 2, axis=-1)
+    out = jax.lax.ragged_dot((jax.nn.silu(gate) * up).astype(x.dtype), w_out,
+                             sizes)
+    back = jnp.argsort(order)                   # where each pair's row went
+    out = out[back].reshape(n, k, -1)
+    return jnp.einsum("nkd,nk->nd", out, weights.astype(out.dtype),
+                      preferred_element_type=jnp.float32).astype(x.dtype)
+
+
+def _count_moe_lowering(path: str) -> None:
+    from ..obs.metrics import REGISTRY
+
+    REGISTRY.counter(
+        "nnstpu_moe_lowerings_total",
+        "expert layers traced into a program, by the path chosen (grouped = "
+        "top-k without drops over a grouped matrix product, switch = top-1 "
+        "with a capacity)", labelnames=("path",),
+    ).inc(path=path)
+
+
+def moe_top_k(params: Params, x, top_k: int, scaling: float = 1.0,
+              token_chunk: Optional[int] = None):
+    """Top-``top_k`` of ``E`` SwiGLU experts beside a shared one.
+
+    ``params``: ``router`` ``[d, E]``, ``w_in`` ``[E, d, 2f]``, ``w_out``
+    ``[E, f, d]`` and, if the layer has one, ``shared`` (``w_in`` ``[d,
+    2f]``, ``w_out`` ``[f, d]``), which every token goes through unweighted.
+    ``x``: ``[..., d]`` → the same.  Router scores and the choice are float32
+    whatever ``x`` is.  ``token_chunk`` walks the tokens in chunks of that
+    many (a ``lax.scan``), so that the ``k``-fold copies of the activations
+    that the grouped product reads and writes stay a chunk's size.
+    """
+    _count_moe_lowering("grouped")
+    shape = x.shape
+    xt = x.reshape(-1, shape[-1])
+
+    def chunk(xc):
+        w, experts = route_top_k(xc, params["router"], top_k, scaling)
+        out = routed_experts(xc, w, experts, params["w_in"], params["w_out"])
+        if "shared" in params:
+            out = out + swiglu(xc, params["shared"]["w_in"],
+                               params["shared"]["w_out"])
+        return out
+
+    n = xt.shape[0]
+    if token_chunk is None or token_chunk >= n or n % token_chunk:
+        return chunk(xt).reshape(shape)
+    _, out = jax.lax.scan(lambda c, xc: (c, chunk(xc)), None,
+                          xt.reshape(n // token_chunk, token_chunk, -1))
+    return out.reshape(shape)

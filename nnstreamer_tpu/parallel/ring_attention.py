@@ -46,13 +46,17 @@ def _online_block(q, k, v, m, l, acc, q_pos, k_pos, scale, causal):
     return m_new, l_new, acc_new
 
 
-def full_attention(q, k, v, causal: bool = False):
-    """Reference single-device attention (the golden path for tests)."""
+def full_attention(q, k, v, causal: bool = False, window=None):
+    """Reference single-device attention (the golden path for tests).
+    ``window`` (with ``causal``): query i sees keys i - window + 1 ... i."""
     scale = q.shape[-1] ** -0.5
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
     if causal:
         tq, tk = s.shape[-2], s.shape[-1]
-        mask = jnp.arange(tk)[None, :] > jnp.arange(tq)[:, None]
+        rows, cols = jnp.arange(tq)[:, None], jnp.arange(tk)[None, :]
+        mask = cols > rows
+        if window is not None:
+            mask |= cols <= rows - window
         s = jnp.where(mask[None, None], -jnp.inf, s)
     w = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", w, v)

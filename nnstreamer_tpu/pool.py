@@ -150,7 +150,7 @@ class BufferPool:
             max_bytes = _conf_int("max_bytes", DEFAULT_MAX_BYTES)
         self.max_per_class = int(max_per_class)
         self.max_bytes = int(max_bytes)
-        self._lock = threading.Lock()
+        self._lock = threading.RLock()
         self._free: Dict[Tuple[Tuple[int, ...], str], deque] = {}
         self._order: deque = deque()  # recycle-order mirror of _free entries
         # id(raw) -> [(weakref(raw), inflight), ...]: async transfers still

@@ -343,3 +343,82 @@ def test_four_chips_compile_a_batch_sharded_tower_plain(v5e_2x2, counted):
     text = jax.jit(model.fn()).lower(frames).compile().as_text()
     assert "tpu_custom_call" not in text
     assert counted() == {"plain": 2}
+
+
+def test_no_weight_argument_is_prefetched_across_program_runs(v5e_2x2):
+    """What ``TPU_COMPILER_OPTIONS`` is for: with its weights as arguments
+    the tower's program would hold two of them in VMEM from one run to the
+    next, and in-step prefetches of other weights lose their room (the
+    benchmark's widths, two layers; PERF.md has the 40-layer count)."""
+    from jax.sharding import SingleDeviceSharding
+
+    from nnstreamer_tpu.backends.jax_backend import (TPU_COMPILER_OPTIONS,
+                                                     split_params)
+
+    model = vit.build(num_classes=1000, image_size=384, patch=16,
+                      d_model=1536, n_heads=16, n_layers=2, batch=48,
+                      dtype=jnp.bfloat16, seed=3)
+    arrays, merge = split_params(model.params)
+    one = SingleDeviceSharding(v5e_2x2[0])
+    weights = [jax.ShapeDtypeStruct(a.shape, jnp.bfloat16, sharding=one)
+               for a in arrays]  # as the benchmark's checkpoint holds them
+    frames = jax.ShapeDtypeStruct((48, 384, 384, 3), jnp.bfloat16,
+                                  sharding=one)
+
+    def entry(w, x):
+        return model.apply(merge(w), x)
+
+    plain = jax.jit(entry).lower(weights, frames).compile().as_text()
+    ours = jax.jit(entry, compiler_options=TPU_COMPILER_OPTIONS).lower(
+        weights, frames).compile().as_text()
+    assert "cross_program_prefetch" in plain
+    assert "cross_program_prefetch" not in ours
+
+
+# -- Laguna-XS.2's published widths (the cell laguna_xs2_l5.ctx16x4k) --------
+#
+# Compile-only, beside the tower's above because one process describes the
+# topology: a second file of such tests could land on another worker.
+
+@pytest.mark.parametrize("heads,window", [(48, None), (64, 512)],
+                         ids=["full_attention", "sliding_attention"])
+def test_mosaic_compiles_the_blocked_kernel_at_16_windows_of_4096(
+        v5e_2x2, heads, window, counted):
+    from jax.sharding import SingleDeviceSharding
+
+    one = SingleDeviceSharding(v5e_2x2[0])
+    q = jax.ShapeDtypeStruct((16, 4096, heads * 128), jnp.bfloat16, sharding=one)
+    kv = jax.ShapeDtypeStruct((16, 4096, 8 * 128), jnp.bfloat16, sharding=one)
+    compiled = jax.jit(lambda q, k, v: fa.attention(
+        q, heads, True, k=k, v=v, n_kv_heads=8, window=window)).lower(
+        q, kv, kv).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and fa.BLOCKED_KERNEL_NAME in text
+    assert counted() == {"blocked": 1}
+    # the scores never reach HBM: no temporary of the [16, heads, T, T] kind
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+
+
+def test_the_chip_compiles_the_grouped_expert_product_at_65536_x_8(v5e_2x2):
+    """The expert layer as the cell runs it: 65 536 tokens in chunks of
+    32 768, top-8 of 256 SwiGLU experts of width 512 and the shared one,
+    its 262 144 (token, expert) rows a chunk through XLA's ragged dot."""
+    from jax.sharding import SingleDeviceSharding
+
+    from nnstreamer_tpu.parallel import moe
+
+    one = SingleDeviceSharding(v5e_2x2[0])
+
+    def struct(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    params = {"router": struct(2048, 256), "w_in": struct(256, 2048, 1024),
+              "w_out": struct(256, 512, 2048),
+              "shared": {"w_in": struct(2048, 1024),
+                         "w_out": struct(512, 2048)}}
+    compiled = jax.jit(lambda p, x: moe.moe_top_k(p, x, 8, 2.5, 32768)).lower(
+        params, struct(65536, 2048)).compile()
+    text = compiled.as_text()
+    assert "ragged-dot" in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 6 * 2 ** 30  # fits beside 7.74 GB

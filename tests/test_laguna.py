@@ -1,0 +1,310 @@
+"""The ``laguna`` token model against its plain reference
+(``benchmark/references/laguna_plain.py``, float32 at ``highest``, nothing of
+the program imported), at tiny widths on the CPU with seeded weights; its
+expert layer against a masked dense sum; its attention lowering in interpret
+mode against ``full_attention`` with the explicit mask; and token frames
+through a launch-string pipeline."""
+
+import json
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmark.references import laguna_plain  # noqa: E402
+from nnstreamer_tpu import parse_launch  # noqa: E402
+from nnstreamer_tpu.models import laguna  # noqa: E402
+from nnstreamer_tpu.obs.metrics import REGISTRY  # noqa: E402
+from nnstreamer_tpu.ops import fused_attention as fa  # noqa: E402
+from nnstreamer_tpu.parallel import moe  # noqa: E402
+from nnstreamer_tpu.parallel.ring_attention import full_attention  # noqa: E402
+from nnstreamer_tpu.utils.checkpoint import save_state  # noqa: E402
+
+FULL = {"rope_theta": 500000, "rope_type": "yarn", "factor": 64,
+        "original_max_position_embeddings": 16, "beta_slow": 1,
+        "beta_fast": 64, "attention_factor": 1.4158883083359672,
+        "partial_rotary_factor": 0.5}
+SLIDING = {"rope_type": "default", "rope_theta": 10000,
+           "partial_rotary_factor": 1}
+
+
+def config(layers, kinds=None, mlps=None, heads=None):
+    """The published keys at tiny widths; ``layers`` deep."""
+    period = ["full_attention"] + ["sliding_attention"] * 3
+    return {
+        "vocab_size": 96, "hidden_size": 32, "intermediate_size": 64,
+        "num_hidden_layers": layers, "num_key_value_heads": 2, "head_dim": 16,
+        "rms_norm_eps": 1e-6, "num_experts": 16, "num_experts_per_tok": 4,
+        "moe_intermediate_size": 16, "shared_expert_intermediate_size": 16,
+        "sliding_window": 8, "moe_routed_scaling_factor": 2.5,
+        "rope_parameters": {"full_attention": FULL,
+                            "sliding_attention": SLIDING,
+                            "original_max_position_embeddings": 16},
+        "layer_types": kinds or (period * 3)[:max(layers, 8)],
+        "mlp_layer_types": mlps or (["dense"] + ["sparse"] * 11),
+        "num_attention_heads_per_layer": heads or [12, 16, 16, 16] * 3,
+    }
+
+
+CASES = {
+    "a_full_layer_alone": config(1),
+    "a_sliding_layer_alone": config(1, ["sliding_attention"], ["sparse"], [16]),
+    "the_five_layer_stack": config(5),
+}
+
+
+def both(cfg, t, dtype, batch=3, seed=0):
+    params = laguna.init_params(cfg, seed, dtype)
+    model = laguna.build(cfg, seq=t, batch=batch, dtype=dtype, params=params)
+    ids = np.random.default_rng(seed).integers(
+        0, cfg["vocab_size"], (batch, t), dtype=np.int32)
+    with jax.default_matmul_precision("highest"):
+        got = np.asarray(jax.jit(model.fn())(ids))
+    host = jax.tree_util.tree_map(np.asarray, params)
+    want = laguna_plain.forward(dict(cfg, seq=t), {}, host, ids)
+    return got, want
+
+
+@pytest.mark.parametrize("t", [24, 6], ids=["past_the_window", "under_it"])
+@pytest.mark.parametrize("case", CASES)
+def test_float32_matches_the_plain_reference(case, t):
+    got, want = both(CASES[case], t, jnp.float32)
+    assert got.shape == want.shape == (3, 96) and got.dtype == np.float32
+    assert np.abs(got - want).max() < 1e-4
+
+
+@pytest.mark.parametrize("t", [24, 6], ids=["past_the_window", "under_it"])
+@pytest.mark.parametrize("case", CASES)
+def test_bfloat16_stays_near_the_plain_reference(case, t):
+    """bf16 weights and activations against the float32 walk over the same
+    bf16 weights: rounding, and at these widths now and then a top-4 choice
+    that falls the other way on a near-tie, so the bound is loose."""
+    got, want = both(CASES[case], t, jnp.bfloat16)
+    assert np.isfinite(got).all()
+    assert np.linalg.norm(got - want) < 0.3 * np.linalg.norm(want)
+
+
+def test_the_depth_cuts_the_per_layer_lists_and_nothing_else():
+    cfg = config(2)
+    params = laguna.init_params(cfg, 0, jnp.float32)
+    assert len(params["layers"]) == 2
+    assert "mlp" in params["layers"][0] and "moe" in params["layers"][1]
+    assert params["layers"][0]["wq"].shape == (32, 12 * 16)
+    assert params["layers"][1]["wq"].shape == (32, 16 * 16)
+    assert params["layers"][1]["moe"]["w_in"].shape == (16, 32, 32)
+    assert params["layers"][1]["moe"]["w_out"].shape == (16, 16, 32)
+
+
+# -- the expert layer ---------------------------------------------------------
+
+def counted(name, path):
+    """A labelled counter's value, 0 before its first count."""
+    metric = REGISTRY.get(name)
+    child = dict(metric.children()).get((path,)) if metric else None
+    return child.value if child else 0
+
+
+def moe_params(key, d=32, f=16, e=12, router=None):
+    ks = jax.random.split(key, 6)
+    return {"router": (jax.random.normal(ks[0], (d, e))
+                       if router is None else router),
+            "w_in": jax.random.normal(ks[1], (e, d, 2 * f)) * 0.2,
+            "w_out": jax.random.normal(ks[2], (e, f, d)) * 0.2,
+            "shared": {"w_in": jax.random.normal(ks[3], (d, 2 * f)) * 0.2,
+                       "w_out": jax.random.normal(ks[4], (f, d)) * 0.2}}
+
+
+def masked_dense_sum(p, x, k, scaling):
+    """Every expert over every token, kept where the router chose it."""
+    scores = jax.nn.sigmoid(x @ p["router"])
+    chosen = jnp.argsort(-scores, axis=-1, stable=True)[:, :k]
+    top = jnp.take_along_axis(scores, chosen, axis=-1)
+    w = top / top.sum(-1, keepdims=True) * scaling
+    out = moe.swiglu(x, p["shared"]["w_in"], p["shared"]["w_out"])
+    for e in range(p["w_in"].shape[0]):
+        out += ((w * (chosen == e)).sum(-1)[:, None]
+                * moe.swiglu(x, p["w_in"][e], p["w_out"][e]))
+    return out, chosen
+
+
+ROUTERS = {
+    "seeded": None,
+    # every token to the same three experts: the others get no rows at all
+    "every_token_to_one_group": jnp.zeros((32, 12)).at[:, jnp.array([2, 7, 9])].set(
+        jnp.abs(jax.random.normal(jax.random.PRNGKey(5), (32, 3))) + 1),
+    # all scores equal: top-k takes the lowest indices, as the stable sort does
+    "ties": jnp.zeros((32, 12)),
+}
+
+
+@pytest.mark.parametrize("chunk", [None, 10], ids=["whole", "in_chunks"])
+@pytest.mark.parametrize("router", ROUTERS)
+def test_top_k_routing_drops_nothing(router, chunk):
+    p = moe_params(jax.random.PRNGKey(0), router=ROUTERS[router])
+    x = jnp.abs(jax.random.normal(jax.random.PRNGKey(1), (50, 32)))
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(lambda p, x: moe.moe_top_k(p, x, 3, 2.5, chunk))(p, x)
+        want, chosen = masked_dense_sum(p, x, 3, 2.5)
+    assert np.abs(np.asarray(got - want)).max() < 1e-5
+    if router == "every_token_to_one_group":
+        assert set(np.asarray(chosen).ravel()) == {2, 7, 9}
+    if router == "ties":
+        assert set(np.asarray(chosen).ravel()) == {0, 1, 2}
+
+
+def test_router_arithmetic_is_float32_whatever_the_tokens_are():
+    p = moe_params(jax.random.PRNGKey(2))
+    x = jax.random.normal(jax.random.PRNGKey(3), (20, 32), jnp.bfloat16)
+    w, experts = moe.route_top_k(x, p["router"].astype(jnp.bfloat16), 3, 2.5)
+    assert w.dtype == jnp.float32 and experts.shape == (20, 3)
+    np.testing.assert_allclose(np.asarray(w.sum(-1)), 2.5, rtol=1e-6)
+
+
+def test_the_choice_is_float32s_wherever_the_scores_lie_apart():
+    """The routing itself, held apart from any logits: over bfloat16 tokens
+    and a plain ``N(0, 1/d)`` bfloat16 router, the experts chosen are those
+    of the exact scores of the same values wherever the 8th and the 9th
+    score lie further apart than float32 rounds.  A router or a top-k in
+    bfloat16 would choose otherwise for some tokens, as the last lines show:
+    that is the fault a comparison of logits under decisive routers
+    (``benchmark/model_kinds/laguna.init_weights``) cannot see."""
+    d, e, k = 64, 256, 8
+    x = jax.random.normal(jax.random.PRNGKey(7), (4096, d), jnp.bfloat16)
+    router = (jax.random.normal(jax.random.PRNGKey(8), (d, e))
+              * d ** -0.5).astype(jnp.bfloat16)
+    _, experts = jax.jit(lambda x, r: moe.route_top_k(x, r, k))(x, router)
+    exact = np.asarray(x, np.float64) @ np.asarray(router, np.float64)
+    order = np.argsort(-exact, axis=-1, kind="stable")
+    ranked = np.take_along_axis(exact, order, axis=-1)
+    clear = ranked[:, k - 1] - ranked[:, k] > 1e-3
+    assert clear.mean() > 0.9
+    want = np.sort(order[:, :k], axis=-1)
+    assert np.array_equal(np.sort(np.asarray(experts), axis=-1)[clear],
+                          want[clear])
+    _, low = jax.lax.top_k(jax.nn.sigmoid(x @ router), k)  # all in bfloat16
+    assert not np.array_equal(np.sort(np.asarray(low), axis=-1)[clear],
+                              want[clear])
+
+
+def test_expert_layers_are_counted_by_path():
+    name = "nnstpu_moe_lowerings_total"
+    p = moe_params(jax.random.PRNGKey(0))
+    before = counted(name, "grouped")
+    jax.jit(lambda x: moe.moe_top_k(p, x, 3)).lower(jnp.ones((8, 32)))
+    assert counted(name, "grouped") == before + 1
+    sw = moe.init_moe_params(jax.random.PRNGKey(0), 8, 16, 4)
+    before = counted(name, "switch")
+    jax.jit(lambda x: moe.moe_ffn(sw, x)).lower(jnp.ones((8, 8)))
+    assert counted(name, "switch") == before + 1
+
+
+# -- the attention lowering ---------------------------------------------------
+
+def qkv(t, hq, hkv, dtype=jnp.float32, b=2):
+    ks = jax.random.split(jax.random.PRNGKey(t + hq), 3)
+    return (jax.random.normal(ks[0], (b, t, hq * 128), dtype),
+            jax.random.normal(ks[1], (b, t, hkv * 128), dtype),
+            jax.random.normal(ks[2], (b, t, hkv * 128), dtype))
+
+
+def masked_reference(q, k, v, hq, hkv, window):
+    b, t, _ = q.shape
+    q = q.reshape(b, t, hq, 128)
+    k, v = (jnp.repeat(a.reshape(b, t, hkv, 128), hq // hkv, axis=2)
+            for a in (k, v))
+    return full_attention(q, k, v, causal=True,
+                          window=window).reshape(b, t, hq * 128)
+
+
+@pytest.mark.parametrize("t,hq,hkv,window,bq,bk", [
+    (300, 6, 1, None, 128, 128),    # groups of 6, T no multiple of the block
+    (300, 8, 1, 100, 128, 128),     # groups of 8, a window across blocks
+    (640, 12, 2, 96, 256, 128),     # two key/value heads, unequal blocks
+    (200, 8, 1, 512, None, None),   # T under the window: the band is all
+    (700, 6, 1, None, None, None),  # the module's own blocks, T padded
+])
+def test_blocked_attention_in_interpret_mode(t, hq, hkv, window, bq, bk):
+    q, k, v = qkv(t, hq, hkv)
+    with jax.default_matmul_precision("highest"):
+        got = fa.blocked_attention(q, k, v, hq, hkv, window, bq, bk,
+                                   interpret=True)
+        want = masked_reference(q, k, v, hq, hkv, window)
+    assert got.shape == q.shape
+    assert np.abs(np.asarray(got - want)).max() < 2e-5
+
+
+def test_grouped_attention_is_one_primitive_whose_lowering_chooses():
+    q, k, v = qkv(256, 6, 1, jnp.bfloat16, b=1)
+    fn = jax.jit(lambda q, k, v: fa.attention(q, 6, True, k=k, v=v,
+                                              n_kv_heads=1, window=64))
+    traced = fn.trace(q, k, v)
+    assert "nns_full_attention" in str(traced.jaxpr)
+    count = lambda path: counted("nnstpu_attention_lowerings_total", path)
+    blocked, plain = count("blocked"), count("plain")
+    on_tpu = traced.lower(lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" in on_tpu and fa.BLOCKED_KERNEL_NAME in on_tpu
+    assert count("blocked") == blocked + 1
+    on_cpu = traced.lower(lowering_platforms=("cpu",)).as_text()
+    assert "tpu_custom_call" not in on_cpu and count("plain") == plain + 1
+    with jax.default_matmul_precision("highest"):
+        got = np.asarray(fn(q, k, v), np.float32)
+        want = np.asarray(masked_reference(q, k, v, 6, 1, 64), np.float32)
+    assert np.abs(got - want).max() < 0.05
+
+
+def test_what_the_blocked_kernel_does_not_tile_lowers_plain():
+    assert fa.blocked_tiles((16, 4096, 48 * 128), (16, 4096, 8 * 128),
+                            jnp.bfloat16, 48, 8, True)
+    assert not fa.blocked_tiles((16, 4096, 48 * 128), (16, 4096, 8 * 128),
+                                jnp.bfloat16, 48, 8, False)   # not causal
+    assert not fa.blocked_tiles((16, 4096, 48 * 96), (16, 4096, 8 * 96),
+                                jnp.bfloat16, 48, 8, True)    # heads of 96
+    assert not fa.blocked_tiles((1, 65536, 128), (1, 65536, 128),
+                                jnp.bfloat16, 1, 1, True)     # K, V past VMEM
+    with pytest.raises(ValueError):
+        fa.attention(jnp.ones((1, 8, 128)), 1, False, k=jnp.ones((1, 8, 128)),
+                     v=jnp.ones((1, 8, 128)), window=4)
+
+
+# -- the streaming path -------------------------------------------------------
+
+def test_token_frames_through_a_launch_string_at_batch_n_equal_n_single(tmp_path):
+    """``tensor_filter framework=jax`` opens the model from a checkpoint and
+    the published config by the builder's name, like the other zoo models;
+    a batch of N windows gives the N rows that N single windows give."""
+    cfg = config(5)
+    (tmp_path / "config.json").write_text(json.dumps(cfg))
+    params = laguna.init_params(cfg, 3, jnp.float32)
+    save_state(params, str(tmp_path / "laguna.npz"))
+    ids = np.random.default_rng(4).integers(0, 96, (4, 24), dtype=np.int32)
+
+    def run(frames, custom):
+        got = []
+        p = parse_launch(
+            "datasrc name=s ! tensor_filter framework=jax name=f "
+            f"model={tmp_path / 'laguna.npz'} custom={custom} "
+            "! tensor_sink name=out")
+        p["s"].data = [f.copy() for f in frames]
+        p["out"].connect("new-data",
+                         lambda f: got.append(np.asarray(f.tensor(0))))
+        p.run(timeout=120)
+        return got
+
+    custom = (f"builder=laguna:build,config={tmp_path / 'config.json'},"
+              "seq=24,dtype=float32")
+    with jax.default_matmul_precision("highest"):
+        singles = run(list(ids), custom)
+        batched, = run([ids], custom + ",batch=4")
+    assert batched.shape == (4, 96) and batched.dtype == np.float32
+    assert [s.shape for s in singles] == [(96,)] * 4
+    np.testing.assert_allclose(np.stack(singles), batched, atol=1e-5)
+    want = laguna_plain.forward(dict(cfg, seq=24), {},
+                                jax.tree_util.tree_map(np.asarray, params), ids)
+    assert np.abs(batched - want).max() < 1e-4
