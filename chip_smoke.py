@@ -530,6 +530,31 @@ class Smoke:
             np.testing.assert_allclose(got, want, rtol=2e-2, atol=2e-2)
             out[f"fused_attention_causal{int(causal)}_max_abs_err"] = float(
                 np.abs(got - want).max())
+        # the grouped experts (ops/grouped_experts): uneven groups, one of
+        # them empty, the last tile cut off, against the two ragged dots
+        from nnstreamer_tpu.ops.grouped_experts import grouped_experts
+
+        d, f, tile, sizes = ((32, 16, 16, [20, 0, 7, 23]) if self.rehearsal
+                             else (256, 128, 512, [700, 0, 1500, 300]))
+        m, sizes = sum(sizes), jnp.asarray(sizes, jnp.int32)
+        rows = jnp.asarray(rng.standard_normal((m, d)), jnp.bfloat16)
+        w_in = jnp.asarray(rng.standard_normal((4, d, 2 * f)) * d ** -0.5,
+                           jnp.bfloat16)
+        w_out = jnp.asarray(rng.standard_normal((4, f, d)) * f ** -0.5,
+                            jnp.bfloat16)
+        pair_w = jnp.asarray(rng.random(m), jnp.float32)
+        got = np.asarray(jax.jit(lambda *a: grouped_experts(
+            *a, tile_rows=tile, interpret=interpret))(
+            rows, w_in, w_out, sizes, pair_w).astype(jnp.float32))
+        gate_up = jax.lax.ragged_dot(rows, w_in, sizes,
+                                     preferred_element_type=jnp.float32)
+        hidden = (jax.nn.silu(gate_up[:, :f]) * gate_up[:, f:]).astype(
+            jnp.bfloat16)
+        want = np.asarray(jax.lax.ragged_dot(
+            hidden, w_out, sizes, preferred_element_type=jnp.float32)
+            * pair_w[:, None])
+        np.testing.assert_allclose(got, want, rtol=2e-2, atol=2e-2)
+        out["grouped_experts_max_abs_err"] = float(np.abs(got - want).max())
         out["compiled_by"] = "interpreter" if interpret else "mosaic"
         return out
 

@@ -22,11 +22,14 @@ pin that equivalence.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.extend.core import Primitive
+from jax.interpreters import ad, mlir
 
 from ..models.layers import Params, _normal, dense_init
 from ..ops.quant import QuantizedWeight, matmul_int8
@@ -182,10 +185,9 @@ def route_top_k(x, router, top_k: int, scaling: float = 1.0):
     return top / top.sum(axis=-1, keepdims=True) * scaling, experts
 
 
-def routed_experts(x, weights, experts, w_in, w_out):
-    """Every (token, expert) pair of ``experts`` ``[tokens, k]`` through its
-    expert's SwiGLU (``w_in`` ``[E, d, 2f]``, ``w_out`` ``[E, f, d]``), the
-    ``k`` results of a token summed under ``weights``.  ``[tokens, d]``."""
+def _routed_grouped(x, weights, experts, w_in, w_out):
+    """Through XLA: a ``ragged_dot`` for gate|up, a fusion, a ``ragged_dot``
+    for down, each over the pairs' rows in HBM."""
     n, k = experts.shape
     e = w_in.shape[0]
     flat = experts.reshape(-1)
@@ -201,14 +203,97 @@ def routed_experts(x, weights, experts, w_in, w_out):
                       preferred_element_type=jnp.float32).astype(x.dtype)
 
 
+def _routed_fused(x, weights, experts, w_in, w_out, **kernel):
+    """Through ``ops/grouped_experts``: one kernel over the pairs' rows, which
+    also weighs each row in float32, so a token's ``k`` rows are only summed.
+    The same stable sort by expert, with the pairs' weights carried along as
+    an operand of it and the groups' sizes read off the sorted experts: on
+    the v5e a gather of 262 144 scalars takes 1.9 ms and ``bincount``'s
+    scatter 2.3 ms, a sixth of the kernel's time each (PERF.md, PR 35).
+    ``kernel``: its tile and interpret arguments (the tests')."""
+    from ..ops.grouped_experts import grouped_experts
+
+    n, k = experts.shape
+    e = w_in.shape[0]
+    by_expert, order, pair_weights = jax.lax.sort(
+        (experts.reshape(-1).astype(jnp.int32),
+         jnp.arange(n * k, dtype=jnp.int32),
+         weights.reshape(-1).astype(jnp.float32)), num_keys=1, is_stable=True)
+    sizes = jnp.diff(jnp.searchsorted(
+        by_expert, jnp.arange(e + 1, dtype=jnp.int32))).astype(jnp.int32)
+    out = grouped_experts(x[order // k], w_in, w_out, sizes, pair_weights,
+                          **kernel)
+    back = jnp.argsort(order)
+    return out[back].reshape(n, k, -1).sum(
+        axis=1, dtype=jnp.float32).astype(x.dtype)
+
+
+# One primitive, as ``ops/fused_attention.attention_p``: a trace does not know
+# what it will be lowered for, so which of the two runs the products is the
+# lowering rule's choice.
+
+routed_experts_p = Primitive("nns_routed_experts")
+routed_experts_p.def_impl(jax.jit(routed_experts_p.bind))
+routed_experts_p.def_abstract_eval(lambda x, *_: x)
+
+
+def routed_experts(x, weights, experts, w_in, w_out):
+    """Every (token, expert) pair of ``experts`` ``[tokens, k]`` through its
+    expert's SwiGLU (``w_in`` ``[E, d, 2f]``, ``w_out`` ``[E, f, d]``), the
+    ``k`` results of a token summed under ``weights``.  ``[tokens, d]``.
+
+    The pairs are sorted by expert either way.  A TPU program for one device
+    whose shapes the grouped kernel tiles (``ops/grouped_experts.tiles``)
+    runs the experts in that kernel; any other program (the CPU's, one that
+    GSPMD partitions, small or odd shapes, experts that are no plain arrays)
+    runs them as two ``ragged_dot`` products through XLA."""
+    if isinstance(w_in, QuantizedWeight) or isinstance(w_out, QuantizedWeight):
+        _count_moe_lowering("grouped")
+        return _routed_grouped(x, weights, experts, w_in, w_out)
+    return routed_experts_p.bind(x, weights, experts, w_in, w_out)
+
+
+def _lower_grouped(ctx, *operands):
+    _count_moe_lowering("grouped")
+    return mlir.lower_fun(_routed_grouped, multiple_results=False)(
+        ctx, *operands)
+
+
+def _lower_tpu(ctx, *operands):
+    from ..ops.fused_attention import _on_one_device
+    from ..ops.grouped_experts import tiles
+
+    x, _, experts, w_in, w_out = ctx.avals_in
+    if not (_on_one_device(ctx.module_context.axis_context)
+            and x.dtype == w_in.dtype == w_out.dtype
+            and tiles((experts.size, x.shape[-1]), w_in.shape, w_out.shape,
+                      x.dtype)):
+        return _lower_grouped(ctx, *operands)
+    _count_moe_lowering("fused")
+    return mlir.lower_fun(functools.partial(_routed_fused, interpret=False),
+                          multiple_results=False)(ctx, *operands)
+
+
+# not cacheable: every layer is lowered, and counted, on its own
+mlir.register_lowering(routed_experts_p, _lower_grouped, cacheable=False)
+mlir.register_lowering(routed_experts_p, _lower_tpu, platform="tpu",
+                       cacheable=False)
+# derivatives are the XLA path's (no vmap: ``ragged_dot`` has none over its
+# group sizes either)
+ad.primitive_jvps[routed_experts_p] = lambda primals, tangents: jax.jvp(
+    _routed_grouped, primals,
+    tuple(ad.instantiate_zeros(t) for t in tangents))
+
+
 def _count_moe_lowering(path: str) -> None:
     from ..obs.metrics import REGISTRY
 
     REGISTRY.counter(
         "nnstpu_moe_lowerings_total",
-        "expert layers traced into a program, by the path chosen (grouped = "
-        "top-k without drops over a grouped matrix product, switch = top-1 "
-        "with a capacity)", labelnames=("path",),
+        "expert layers lowered into a program, by the path chosen (fused = "
+        "top-k without drops through the grouped Pallas kernel, grouped = "
+        "the same through XLA's grouped matrix product, switch = top-1 with "
+        "a capacity)", labelnames=("path",),
     ).inc(path=path)
 
 
@@ -224,7 +309,6 @@ def moe_top_k(params: Params, x, top_k: int, scaling: float = 1.0,
     many (a ``lax.scan``), so that the ``k``-fold copies of the activations
     that the grouped product reads and writes stay a chunk's size.
     """
-    _count_moe_lowering("grouped")
     shape = x.shape
     xt = x.reshape(-1, shape[-1])
 

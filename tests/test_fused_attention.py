@@ -399,12 +399,21 @@ def test_mosaic_compiles_the_blocked_kernel_at_16_windows_of_4096(
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
 
 
+def moe_lowerings():
+    metric = REGISTRY.get("nnstpu_moe_lowerings_total")
+    if metric is None:
+        return {}
+    return {key[0]: int(child.value) for key, child in metric.children()}
+
+
 def test_the_chip_compiles_the_grouped_expert_product_at_65536_x_8(v5e_2x2):
     """The expert layer as the cell runs it: 65 536 tokens in chunks of
     32 768, top-8 of 256 SwiGLU experts of width 512 and the shared one,
-    its 262 144 (token, expert) rows a chunk through XLA's ragged dot."""
+    its 262 144 (token, expert) rows a chunk through the grouped kernel, the
+    experts' hidden rows in VMEM."""
     from jax.sharding import SingleDeviceSharding
 
+    from nnstreamer_tpu.ops import grouped_experts
     from nnstreamer_tpu.parallel import moe
 
     one = SingleDeviceSharding(v5e_2x2[0])
@@ -416,9 +425,54 @@ def test_the_chip_compiles_the_grouped_expert_product_at_65536_x_8(v5e_2x2):
               "w_out": struct(256, 512, 2048),
               "shared": {"w_in": struct(2048, 1024),
                          "w_out": struct(512, 2048)}}
+    before = moe_lowerings()
     compiled = jax.jit(lambda p, x: moe.moe_top_k(p, x, 8, 2.5, 32768)).lower(
         params, struct(65536, 2048)).compile()
     text = compiled.as_text()
-    assert "ragged-dot" in text
+    assert "tpu_custom_call" in text and grouped_experts.KERNEL_NAME in text
+    assert "ragged-dot" not in text
+    after = moe_lowerings()
+    assert after["fused"] == before.get("fused", 0) + 1
+    assert after.get("grouped", 0) == before.get("grouped", 0)
+    # 2.079 GiB through ragged_dot (PR 34): the peak is the gathered rows
+    # beside the kernel's result and the unsorted copy, which stay; the two
+    # hidden arrays (0.75 GB) were never alive at that peak, so it falls by
+    # megabytes, not by them.  Still far under the 6 GiB beside 7.74 GB.
     mem = compiled.memory_analysis()
-    assert mem.temp_size_in_bytes < 6 * 2 ** 30  # fits beside 7.74 GB
+    assert mem.temp_size_in_bytes < 2.08 * 2 ** 30
+
+
+def test_a_partitioned_program_and_a_toy_take_xlas_grouped_product(v5e_2x2):
+    """Shapes the kernel tiles in a program GSPMD partitions over the four
+    chips (a Mosaic kernel cannot be partitioned), and a ``[8, 32]`` toy on
+    one chip: both lower the ``ragged_dot`` code."""
+    from jax.sharding import Mesh, SingleDeviceSharding
+
+    from nnstreamer_tpu.parallel import moe
+
+    mesh = Mesh(np.array(v5e_2x2), ("dp",))
+
+    def layer(d, f, e, place):
+        return {"router": jax.ShapeDtypeStruct((d, e), jnp.bfloat16,
+                                               sharding=place),
+                "w_in": jax.ShapeDtypeStruct((e, d, 2 * f), jnp.bfloat16,
+                                             sharding=place),
+                "w_out": jax.ShapeDtypeStruct((e, f, d), jnp.bfloat16,
+                                              sharding=place)}
+
+    run = jax.jit(lambda p, x: moe.moe_top_k(p, x, 2))
+    before = moe_lowerings()
+    text = run.lower(
+        layer(128, 128, 2, NamedSharding(mesh, P())),
+        jax.ShapeDtypeStruct((1024, 128), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, P("dp")))
+    ).compile().as_text()
+    assert "ragged-dot" in text or "ragged_dot" in text
+    assert "nns_grouped_experts" not in text
+    one = SingleDeviceSharding(v5e_2x2[0])
+    text = run.lower(layer(32, 16, 4, one), jax.ShapeDtypeStruct(
+        (8, 32), jnp.bfloat16, sharding=one)).compile().as_text()
+    assert "nns_grouped_experts" not in text
+    after = moe_lowerings()
+    assert after["grouped"] == before.get("grouped", 0) + 2
+    assert after.get("fused", 0) == before.get("fused", 0)

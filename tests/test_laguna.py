@@ -5,6 +5,7 @@ expert layer against a masked dense sum; its attention lowering in interpret
 mode against ``full_attention`` with the explicit mask; and token frames
 through a launch-string pipeline."""
 
+import functools
 import json
 import os
 import sys
@@ -23,6 +24,7 @@ from nnstreamer_tpu import parse_launch  # noqa: E402
 from nnstreamer_tpu.models import laguna  # noqa: E402
 from nnstreamer_tpu.obs.metrics import REGISTRY  # noqa: E402
 from nnstreamer_tpu.ops import fused_attention as fa  # noqa: E402
+from nnstreamer_tpu.ops import grouped_experts  # noqa: E402
 from nnstreamer_tpu.parallel import moe  # noqa: E402
 from nnstreamer_tpu.parallel.ring_attention import full_attention  # noqa: E402
 from nnstreamer_tpu.utils.checkpoint import save_state  # noqa: E402
@@ -144,9 +146,23 @@ ROUTERS = {
 }
 
 
+@pytest.fixture
+def kernel_everywhere(monkeypatch):
+    """The XLA lowering runs the grouped kernel too, interpreted, in tiles of
+    16 rows and blocks of 8: what a program computes through the fused path,
+    on a host with no TPU."""
+    monkeypatch.setattr(moe, "_routed_grouped", functools.partial(
+        moe._routed_fused, interpret=True, tile_rows=16, block_rows=8))
+
+
+@pytest.mark.parametrize("path", ["ragged_dot", "kernel"])
 @pytest.mark.parametrize("chunk", [None, 10], ids=["whole", "in_chunks"])
 @pytest.mark.parametrize("router", ROUTERS)
-def test_top_k_routing_drops_nothing(router, chunk):
+def test_top_k_routing_drops_nothing(router, chunk, path, request):
+    """50 tokens x 3 are 150 rows, and a chunk's 10 x 3 are 30: neither is a
+    whole number of the kernel's 16-row tiles."""
+    if path == "kernel":
+        request.getfixturevalue("kernel_everywhere")
     p = moe_params(jax.random.PRNGKey(0), router=ROUTERS[router])
     x = jnp.abs(jax.random.normal(jax.random.PRNGKey(1), (50, 32)))
     with jax.default_matmul_precision("highest"):
@@ -157,6 +173,90 @@ def test_top_k_routing_drops_nothing(router, chunk):
         assert set(np.asarray(chosen).ravel()) == {2, 7, 9}
     if router == "ties":
         assert set(np.asarray(chosen).ravel()) == {0, 1, 2}
+
+
+@pytest.mark.parametrize("router", ROUTERS)
+def test_the_kernel_in_bfloat16_is_within_rounding_of_the_ragged_dot_path(
+        router, request):
+    p = jax.tree_util.tree_map(
+        lambda a: a.astype(jnp.bfloat16),
+        moe_params(jax.random.PRNGKey(0), router=ROUTERS[router]))
+    x = jnp.abs(jax.random.normal(jax.random.PRNGKey(1), (50, 32),
+                                  jnp.bfloat16))
+    layer = lambda p, x: moe.moe_top_k(p, x, 3, 2.5)
+    want = np.asarray(jax.jit(layer)(p, x), np.float32)
+    request.getfixturevalue("kernel_everywhere")
+    got = jax.jit(lambda p, x: layer(p, x))(p, x)
+    assert got.dtype == jnp.bfloat16
+    # gate|up and the weighted rows are rounded once where the ragged_dot
+    # path rounds them twice: a few of bfloat16's 2**-8 steps apart
+    assert (np.abs(np.asarray(got, np.float32) - want).max()
+            < 2 ** -6 * np.abs(want).max())
+
+
+def ragged_reference(rows, w_in, w_out, sizes, pair_weights):
+    f = w_out.shape[1]
+    gate_up = jax.lax.ragged_dot(rows, w_in, sizes,
+                                 preferred_element_type=jnp.float32)
+    h = (jax.nn.silu(gate_up[:, :f]) * gate_up[:, f:]).astype(rows.dtype)
+    out = jax.lax.ragged_dot(h, w_out, sizes,
+                             preferred_element_type=jnp.float32)
+    return (out * pair_weights[:, None]).astype(rows.dtype)
+
+
+@pytest.mark.parametrize("sizes,tile,block", [
+    ([16, 0, 32, 16], 16, 16),          # every group ends on a tile's edge
+    ([16, 0, 32, 16], 32, 8),           # and on a block's edge inside a tile
+    ([5, 11, 0, 0, 21, 3, 10], 16, 8),  # 50 rows: the last tile is cut off
+    ([0, 0, 37], 16, 8),                # only the last expert has rows
+    ([37, 0, 0], 16, 8),                # only the first: the rest are skipped
+    ([1, 1, 1, 1, 1, 1, 1, 1, 1], 8, 8),  # nine visits of one tile, then one
+    ([3, 70, 2], 32, 16),               # a group over three tiles
+], ids=["tile_edges", "block_edges", "cut_off", "last_expert_only",
+        "first_expert_only", "one_row_each", "over_three_tiles"])
+def test_the_grouped_kernel_in_interpret_mode(sizes, tile, block):
+    m, e, d, f = sum(sizes), len(sizes), 32, 16
+    ks = jax.random.split(jax.random.PRNGKey(m), 4)
+    rows = jax.random.normal(ks[0], (m, d))
+    w_in = jax.random.normal(ks[1], (e, d, 2 * f)) * 0.2
+    w_out = jax.random.normal(ks[2], (e, f, d)) * 0.2
+    pair_weights = jax.random.uniform(ks[3], (m,))
+    sizes = jnp.asarray(sizes, jnp.int32)
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(functools.partial(
+            grouped_experts.grouped_experts, tile_rows=tile, block_rows=block,
+            interpret=True))(rows, w_in, w_out, sizes, pair_weights)
+        want = ragged_reference(rows, w_in, w_out, sizes, pair_weights)
+    assert got.shape == rows.shape and got.dtype == rows.dtype
+    assert np.abs(np.asarray(got - want)).max() < 1e-5
+
+
+def test_the_visits_cover_every_row_once_and_nothing_else():
+    """The grid's metadata by hand: tiles of 4 rows, groups of 6, 0, 2, 5."""
+    offsets, expert, tile, count = grouped_experts.visits(
+        jnp.array([6, 0, 2, 5], jnp.int32), 13, 4)
+    assert list(np.asarray(offsets)) == [0, 6, 6, 8, 13]
+    assert int(count[0]) == 5 and expert.shape == (4 + 4 - 1,)
+    # expert 0 over tiles 0 and 1, expert 2 the rest of tile 1, expert 3
+    # tiles 2 and 3; the steps left over repeat the last visit
+    assert list(np.asarray(expert)) == [0, 0, 2, 3, 3, 3, 3]
+    assert list(np.asarray(tile)) == [0, 1, 1, 2, 3, 3, 3]
+
+
+def test_derivatives_are_the_ragged_dot_paths():
+    """The primitive under ``grad``: what the layer gave before it was one."""
+    p = moe_params(jax.random.PRNGKey(0))
+    x = jax.random.normal(jax.random.PRNGKey(1), (20, 32))
+    w, experts = moe.route_top_k(x, p["router"], 3, 2.5)
+
+    def through(fn):
+        return lambda x, w: fn(x, w, experts, p["w_in"], p["w_out"]).sum()
+
+    got = jax.grad(through(moe.routed_experts), argnums=(0, 1))(x, w)
+    want = jax.grad(through(moe._routed_grouped), argnums=(0, 1))(x, w)
+    for g, h in zip(got, want):
+        assert np.abs(np.asarray(h)).max() > 0
+        np.testing.assert_allclose(np.asarray(g), np.asarray(h), rtol=1e-6)
 
 
 def test_router_arithmetic_is_float32_whatever_the_tokens_are():
@@ -194,11 +294,28 @@ def test_the_choice_is_float32s_wherever_the_scores_lie_apart():
 
 
 def test_expert_layers_are_counted_by_path():
+    """By the lowering rule that chose: a trace counts nothing, a program
+    for this host takes XLA's grouped product, and one trace lowered for a
+    TPU takes the kernel where its shapes tile and XLA's where they do not."""
     name = "nnstpu_moe_lowerings_total"
     p = moe_params(jax.random.PRNGKey(0))
-    before = counted(name, "grouped")
-    jax.jit(lambda x: moe.moe_top_k(p, x, 3)).lower(jnp.ones((8, 32)))
-    assert counted(name, "grouped") == before + 1
+    before = counted(name, "grouped"), counted(name, "fused")
+    traced = jax.jit(lambda x: moe.moe_top_k(p, x, 3)).trace(jnp.ones((8, 32)))
+    assert "nns_routed_experts" in str(traced.jaxpr)
+    assert (counted(name, "grouped"), counted(name, "fused")) == before
+    traced.lower()
+    traced.lower(lowering_platforms=("tpu",))  # [24, 32] rows do not tile
+    assert counted(name, "grouped") == before[0] + 2
+    assert counted(name, "fused") == before[1]
+    big = moe_params(jax.random.PRNGKey(0), d=128, f=128, e=2)
+    traced = jax.jit(lambda x: moe.moe_top_k(big, x, 2, token_chunk=512)).trace(
+        jnp.ones((1024, 128)))
+    text = traced.lower(lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" in text and "ragged_dot" not in text
+    assert "tpu_custom_call" not in traced.lower().as_text()
+    # the chunks' scan is lowered once: one layer, one count a program
+    assert counted(name, "fused") == before[1] + 1
+    assert counted(name, "grouped") == before[0] + 3
     sw = moe.init_moe_params(jax.random.PRNGKey(0), 8, 16, 4)
     before = counted(name, "switch")
     jax.jit(lambda x: moe.moe_ffn(sw, x)).lower(jnp.ones((8, 8)))
