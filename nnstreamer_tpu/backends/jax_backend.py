@@ -56,7 +56,7 @@ import jax.numpy as jnp
 from .. import faults as _faults
 from ..buffer import WireTensor
 from ..obs import hooks as _hooks
-from ..pool import RowBatch, fence as _pool_fence
+from ..pool import fence as _pool_fence
 from ..spec import TensorSpec, TensorsSpec
 from . import exec_cache
 from .base import FilterBackend, register_backend
@@ -292,10 +292,9 @@ class JaxBackend(FilterBackend):
         self._cache: "OrderedDict[tuple, tuple]" = OrderedDict()
         self._cache_size = DEFAULT_COMPILE_CACHE
         self._donate_wire = False
-        # zero-copy hot-path state (nnstreamer_tpu/pool.py): batch-1
-        # executable for deferred RowBatch inputs, and pooled ping-pong
-        # staging for non-contiguous host frames on the flat wire entry
-        self._row_jit = None
+        # zero-copy hot-path state (nnstreamer_tpu/pool.py): pooled
+        # ping-pong staging for non-contiguous host frames on the flat
+        # wire entry
         self._host_stager = None
         # opt-in degradation ([recovery] cpu_fallback): a compile that
         # fails on the configured device retries on CPU and keeps serving
@@ -422,7 +421,6 @@ class JaxBackend(FilterBackend):
         self._flat_compiled = None
         self._expected = None
         self._cache.clear()
-        self._row_jit = None
         self._host_stager = None
         if self._degraded_key is not None:
             from ..obs.export import unregister_degraded
@@ -465,7 +463,6 @@ class JaxBackend(FilterBackend):
         self._wrapper = wrapper
         self._compiled = None
         self._flat_compiled = None
-        self._row_jit = None
         if wrapper is None:
             self._drift_hook = None
         if invalidate:
@@ -926,8 +923,7 @@ class JaxBackend(FilterBackend):
             # Per-frame drift guard on the cached fast-path token: np/jax
             # arrays and WireTensor all expose ``.shape`` as a tuple and
             # ``.dtype`` as np.dtype, so the common case is a handful of
-            # C-level comparisons — the old per-tensor tuple()/np.dtype()
-            # rebuild cost showed up in the hot-loop profile (r4 weak #7).
+            # C-level comparisons, no per-tensor tuple()/np.dtype() rebuild.
             exp = self._expected
             drift = exp is not None and len(tensors) != len(exp)
             if exp is not None and not drift:
@@ -958,18 +954,6 @@ class JaxBackend(FilterBackend):
                     self._drift_hook(drifted)
                 else:
                     self.reconfigure(drifted)
-        if tensors and isinstance(tensors[0], RowBatch):
-            # deferred batch from tensor_batch's over-threshold path: keep
-            # the zero-concat promise by invoking per row (batch-1
-            # executable); outputs ride back as RowBatches so the whole
-            # batch→filter→unbatch chain never assembles a host batch.
-            # Fused programs bake batched geometry into their stages, and
-            # multi-input frames would need row alignment — both fall back
-            # to one real stack + the normal path (correctness is never
-            # conditional on the fast path).
-            if len(tensors) == 1 and self._wrapper is None:
-                return self._invoke_rows(tensors[0])
-            return self.invoke(tuple(np.asarray(t) for t in tensors))
         if tensors and isinstance(tensors[0], WireTensor):
             # tensor_upload already moved the bytes (wire layout, upstream
             # thread): dispatch-only here — the transfer/dispatch overlap
@@ -1049,42 +1033,6 @@ class JaxBackend(FilterBackend):
         if self._single_output:
             return (out,)
         return tuple(out)
-
-    def _invoke_rows(self, rb: RowBatch) -> Tuple:
-        """Per-row dispatch for a deferred :class:`RowBatch`.
-
-        The negotiated ``(N, *row)`` spec stays the pad contract; each row
-        runs through a batch-1 executable (plain ``jax.jit`` — batch 1
-        cannot shard, and this path only triggers on the CPU fallback where
-        ``pool.skip_host_concat`` decided coalescing loses) and the outputs
-        ride back as RowBatches with the negotiated batched geometry."""
-        if self._row_jit is None:
-            self._row_jit = self._bind(self._entry(wrapped=False), None)
-        jit = self._row_jit
-        per_out: Optional[list] = None
-        single = True
-        for i in range(len(rb)):
-            row = rb.row(i)[None]  # [None]: a view, keeps the batch dim
-            o = jit(row)
-            single = not isinstance(o, (tuple, list))
-            outs = (o,) if single else tuple(o)
-            if isinstance(row, np.ndarray):
-                _pool_fence(row, outs[0])  # rows may view a pooled buffer
-            if per_out is None:
-                per_out = [[] for _ in outs]
-            for j, oj in enumerate(outs):
-                per_out[j].append(oj)
-        out_specs = self._out_spec.tensors if self._out_spec is not None else ()
-        results = []
-        for j, rows in enumerate(per_out):
-            if j < len(out_specs) and out_specs[j].is_fixed:
-                row_shape = tuple(out_specs[j].shape)[1:]
-                dtype = out_specs[j].dtype
-            else:
-                row_shape = tuple(rows[0].shape)[1:]
-                dtype = rows[0].dtype
-            results.append(RowBatch(rows, row_shape=row_shape, dtype=dtype))
-        return tuple(results)
 
 
 @register_backend("jax-sharded")

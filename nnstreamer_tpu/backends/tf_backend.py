@@ -5,10 +5,9 @@ Functional parity with the reference's two headline subplugins:
 - ``tensorflow-lite`` (``tensor_filter_tensorflow_lite_core.cc``): loads a
   ``.tflite`` flatbuffer via ``tf.lite.Interpreter`` (the same runtime the
   reference embeds), reads I/O dims from the interpreter
-  (``_core.cc:272-278``) and invokes into preallocated buffers.  Also the
-  benchmark **baseline backend**: BASELINE.md's comparison point is
-  tflite-CPU.  A keras model object converts on open (weights stay local —
-  zero-egress environments can't download pretrained ones).
+  (``_core.cc:272-278``) and invokes into preallocated buffers.  A keras
+  model object converts on open (weights stay local — zero-egress
+  environments can't download pretrained ones).
 - ``tensorflow`` (``tensor_filter_tensorflow_core.cc``): wraps a TF
   SavedModel / keras model / ``tf.function`` as a stream filter.
 

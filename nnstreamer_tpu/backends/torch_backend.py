@@ -1,8 +1,6 @@
-"""Torch-CPU filter backend: the comparison-baseline backend.
+"""Torch-CPU filter backend.
 
-The reference's measurement plan benchmarks its TPU path against tflite-CPU
-(``BASELINE.md``); in this environment torch-CPU plays that role.  Also
-provides functional parity with the reference's ``pytorch`` subplugin
+Functional parity with the reference's ``pytorch`` subplugin
 (``tensor_filter_pytorch``): TorchScript files load via ``torch.jit.load``,
 ``nn.Module`` objects are used directly.
 """

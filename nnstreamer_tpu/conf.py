@@ -138,9 +138,6 @@ DEFAULTS: Dict[str, Dict[str, str]] = {
         "enabled": "true",          # false = every lease allocates fresh
         "max_per_class": "4",       # free buffers kept per (shape, dtype)
         "max_bytes": "67108864",    # total free-list bytes (64 MiB)
-        "concat_threshold": "0",    # per-row bytes: skip host concat on the
-                                    # CPU fallback above this (0=off; opt-in
-                                    # — see pool.DEFAULT_CONCAT_THRESHOLD)
     },
     # Compile-ahead serving (backends/exec_cache.py + graph/warmup.py +
     # ops/autotune.py): persistent executable/autotune caches and the AOT

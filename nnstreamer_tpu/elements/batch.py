@@ -18,14 +18,11 @@ streams at once, with the batch dim sharded over the device mesh by the
 
 Host-side assembly is **slot-wise into a pooled batch buffer** (each row
 copied once, directly into its slot of a recycled staging buffer —
-``nnstreamer_tpu/pool.py``), never a fresh ``np.stack``: the cold
-multi-MB allocation per dispatch was 59% of 8-stream busy time on a CPU
-host (``tools/profile_mux_overhead.py``).  Above the
-payload/platform threshold (``pool.skip_host_concat``) host concat is
-skipped entirely: rows ride downstream as a deferred
-:class:`~nnstreamer_tpu.pool.RowBatch` and the jax filter invokes per
-stream — the regime where coalescing 602 KB host rows costs more than the
-dispatch amortization saves.
+``nnstreamer_tpu/pool.py``), never a fresh ``np.stack``, which pays a
+cold multi-MB allocation per dispatch.  A mesh-sharded consumer takes the
+same buffer: ``(N, *row)`` is the per-shard slot layout its batch-axis
+``NamedSharding`` scatters (N divisible by the mesh shards evenly;
+otherwise the backend falls back to a single-device executable).
 """
 
 from __future__ import annotations
@@ -49,8 +46,6 @@ class TensorBatch(Node):
         self.add_src_pad("src")
         self._n = 0
         self._pool = pool  # default shared pool unless injected (tests)
-        self._per_stream = False  # skip host concat (pool.skip_host_concat)
-        self._mesh_dev = 1  # downstream dispatch-mesh width (configure)
 
     def _pool_or_default(self):
         if self._pool is None:
@@ -72,23 +67,6 @@ class TensorBatch(Node):
                 )
         self._n = spec.num_tensors
         out = TensorSpec(dtype=first.dtype, shape=(self._n,) + tuple(first.shape))
-        # payload/platform-aware host-concat decision: on the CPU fallback
-        # with large rows, hand the filter a RowBatch (per-stream invoke)
-        # instead of coalescing — the consumer's platform decides, so a
-        # real accelerator always gets the batched transfer.  A
-        # mesh-sharded consumer also always gets it: the pooled (N, *row)
-        # buffer is exactly the per-shard slot layout its batch-axis
-        # NamedSharding scatters (N divisible by the mesh shards evenly;
-        # otherwise the backend falls back to a single-device executable),
-        # and a per-row RowBatch invoke would defeat the sharding.
-        from ..graph.residency import consumer_mesh_devices, consumer_platform
-        from ..pool import skip_host_concat
-
-        self._mesh_dev = consumer_mesh_devices(self)
-        self._per_stream = (
-            self._mesh_dev == 1 and first.is_fixed
-            and skip_host_concat(first.nbytes, consumer_platform(self))
-        )
         return {"src": TensorsSpec(tensors=(out,), rate=spec.rate)}
 
     def process(self, pad: Pad, frame: Frame):
@@ -100,14 +78,6 @@ class TensorBatch(Node):
 
             # device-resident inputs: stack on device, stays resident
             return frame.with_tensors((jnp.stack(frame.tensors, axis=0),))
-        if self._per_stream:
-            # zero host concat: rows ride as-is; the jax filter invokes
-            # per row and tensor_unbatch splits without materializing
-            from ..pool import RowBatch
-
-            return frame.with_tensors(
-                (RowBatch([np.asarray(t) for t in frame.tensors]),)
-            )
         # host inputs: each row copied ONCE, directly into its slot of a
         # recycled pooled batch buffer — the downstream jax filter's flat
         # wire path then moves the whole batch in a single cheap transfer
@@ -186,6 +156,5 @@ class TensorUnbatch(Node):
                 batched = np.asarray(batched)
             else:
                 return frame.with_tensors(self._device_split(batched))
-        # numpy: row views share the parent buffer; RowBatch: the deferred
-        # rows come back out individually — no copies either way
+        # numpy: row views share the parent buffer, no copies
         return frame.with_tensors(tuple(batched[i] for i in range(batched.shape[0])))

@@ -18,8 +18,8 @@ Hot-path discipline: queue bookkeeping and round selection happen under the
 node lock, but **emission runs outside it** (ticket-ordered, so output order
 still matches collection order).  The downstream chain — batch assembly,
 filter dispatch — therefore never blocks the other source threads from
-delivering their next frame (round 2 benched the under-lock version 2.4×
-*slower* than unbatched streaming; this is the fix).
+delivering their next frame (emission under the lock serializes every
+source behind the device dispatch).
 """
 
 from __future__ import annotations

@@ -95,7 +95,6 @@ class DynBatch(Node):
         self.batches_emitted = 0  # observability: how often we coalesced
         self.frames_in = 0
         self._pool = None  # shared staging pool, resolved lazily
-        self._skip_concat = False  # pool.skip_host_concat at configure
         self._mesh_dev = 1  # downstream dispatch-mesh width (configure)
 
     def configure(self, in_specs: Dict[str, TensorsSpec]) -> Dict[str, TensorsSpec]:
@@ -108,20 +107,11 @@ class DynBatch(Node):
             TensorSpec(dtype=t.dtype, shape=(None,) + tuple(t.shape))
             for t in spec.tensors
         )
-        # payload/platform-aware threshold (same rule as tensor_batch): on
-        # the CPU fallback with large frames, coalescing costs more host
-        # memcpy than the dispatch amortization saves — emit batch-1 views
-        # (zero concat) instead of stacking the pile-up
-        from ..graph.residency import consumer_mesh_devices, consumer_platform
-        from ..pool import skip_host_concat
+        from ..graph.residency import consumer_mesh_devices
 
         # mesh-sharded consumer: buckets grow in per-shard multiples so one
-        # invoke spreads the pile-up across every chip, and the per-stream
-        # RowBatch escape is off — per-row invoke would defeat the sharding
+        # invoke spreads the pile-up across every chip
         self._mesh_dev = consumer_mesh_devices(self)
-        self._skip_concat = self._mesh_dev == 1 and skip_host_concat(
-            sum(t.nbytes for t in spec.tensors), consumer_platform(self)
-        )
         # batch dim None → downstream pads skip per-frame sig checks and the
         # jax backend treats each new bucket as spec drift (LRU-cached)
         return {"src": TensorsSpec(tensors=out, rate=spec.rate)}
@@ -142,16 +132,11 @@ class DynBatch(Node):
         if warm is None:
             return []
         ndev = max(1, self._mesh_dev)
-        if self._skip_concat:
-            # over-threshold CPU regime: every emission is a batch-1
-            # view, so bucket 1 is the only runtime geometry
-            buckets = [1]
-        else:
-            buckets = []
-            b = 1
-            while b <= self.max_batch:
-                buckets.append(b * ndev)
-                b <<= 1
+        buckets = []
+        b = 1
+        while b <= self.max_batch:
+            buckets.append(b * ndev)
+            b <<= 1
         ensure = getattr(filt.backend, "ensure_cache_capacity", None)
         if ensure is not None:
             # the ladder plus the negotiated entry must coexist in the
@@ -246,13 +231,6 @@ class DynBatch(Node):
         return self._pool
 
     def _emit_batch(self, frames: List[Frame]) -> None:
-        if self._skip_concat:
-            # over-threshold CPU regime: each frame leaves as a batch-1
-            # reshape VIEW (zero concat, zero padding); the polymorphic
-            # downstream spec already admits bucket 1
-            for f in frames:
-                self._emit_one(f)
-            return
         n = len(frames)
         b = mesh_bucket(n, self.max_batch, self._mesh_dev)
         pad_rows = b - n
@@ -296,28 +274,6 @@ class DynBatch(Node):
             _hooks.emit("dynbatch_flush", self, n, b)
         self.push(Frame(tensors=tuple(stacked), pts=frames[0].pts,
                         duration=frames[0].duration, meta=meta))
-
-    def _emit_one(self, f: Frame) -> None:
-        """Batch-1 emission (over-threshold path): reshape views, no copy;
-        the dynbatch meta/span discipline stays identical so dynunbatch and
-        the tracers cannot tell the paths apart."""
-        tensors = tuple(np.asarray(t)[None] for t in f.tensors)
-        meta = {
-            "dynbatch": {
-                "n": 1,
-                "pts": [f.pts],
-                "duration": [f.duration],
-                "meta": [f.meta],
-            }
-        }
-        if _spans.enabled:
-            _spans.merge_context([f], meta, self.name)
-        self.frames_in += 1
-        self.batches_emitted += 1
-        if _hooks.enabled:
-            _hooks.emit("dynbatch_flush", self, 1, 1)
-        self.push(Frame(tensors=tensors, pts=f.pts, duration=f.duration,
-                        meta=meta))
 
     def _worker(self) -> None:
         q = self._q

@@ -17,6 +17,7 @@ import numpy as np
 from ..buffer import Frame
 from ..graph.node import NegotiationError
 from ..graph.registry import register_element
+from ..obs import hooks as _hooks
 from ..spec import TensorSpec, TensorsSpec
 from .collect import CollectNode
 
@@ -85,6 +86,18 @@ class TensorMerge(CollectNode):
 
             merged = jnp.concatenate(arrays, axis=self._axis)
         else:
-            merged = np.concatenate([np.asarray(a) for a in arrays], axis=self._axis)
+            # into a recycled pooled buffer (nnstreamer_tpu/pool.py), as
+            # tensor_batch assembles: a fresh multi-MB result is a new
+            # mapping whose pages fault in under the copy
+            from ..pool import default_pool
+
+            arrays = [np.asarray(a) for a in arrays]
+            shape = list(arrays[0].shape)
+            shape[self._axis] = sum(a.shape[self._axis] for a in arrays)
+            merged = default_pool().lease(shape, arrays[0].dtype)
+            np.concatenate(arrays, axis=self._axis, out=merged)
+            if _hooks.enabled:
+                _hooks.emit("copy", self, merged.nbytes,
+                            1 if merged.pool_fresh else 0)
         pts, dur = self.output_timing(frames)
         return Frame.of(merged, pts=pts, duration=dur)

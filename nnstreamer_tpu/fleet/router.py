@@ -239,6 +239,10 @@ class Router:
                                     10.0)
         self.migrate_check_s = _f("migrate_check_s", migrate_check_s, 0.25)
         self._mig_seq = 0  # repo-slot key sequence (per-router namespace)
+        # one handoff at a time, and none across a worker's drain mark: a
+        # handoff that picked its target before the target was marked
+        # draining lands first, so the drain's own pass moves it on
+        self._handoff_lock = threading.Lock()
         self._mig_thread: Optional[threading.Thread] = None
         self._mig_stop = threading.Event()
         from ..obs.metrics import REGISTRY
@@ -920,8 +924,9 @@ class Router:
         for sess in sessions:
             if sess.broken or sess.migrating:
                 continue
-            if self._migrate_session(sess):
-                n += 1
+            with self._handoff_lock:
+                if self._migrate_session(sess):
+                    n += 1
         return n
 
     def _migrate_monitor(self) -> None:
@@ -981,7 +986,8 @@ class Router:
         number of force-broken sessions (0 = clean drain)."""
         deadline_s = (self.drain_deadline_s if deadline_s is None
                       else float(deadline_s))
-        self.membership.drain(worker_id)
+        with self._handoff_lock:
+            self.membership.drain(worker_id)
         if migrate is None:
             migrate = self.stateful and self.migrate_enabled \
                 and bool(self.repo_addr)

@@ -3,10 +3,9 @@
 The reference inherits GStreamer's one-task-thread-per-source model
 (``README.md:41-44``), and this reproduction kept it: every source, every
 ``queue``/``tensor_dynbatch`` element, the device reaper, and the watchdog
-owns a host thread.  ``tools/profile_mux_overhead.py`` shows the cost: on
-a GIL'd host, per-stream throughput *declines* as streams are added —
-context switches and lock handoffs, not compute.  At the fleet tier
-(64–128 streams per host) thread-per-element is the scaling ceiling.
+owns a host thread.  On a GIL'd host every added stream adds context
+switches and lock handoffs, not compute; at the fleet tier (64–128
+streams per host) thread-per-element is the scaling ceiling.
 
 This module collapses that into a small pool of **run-to-completion
 event-loop lanes**:

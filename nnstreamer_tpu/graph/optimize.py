@@ -1,6 +1,6 @@
 """Graph optimization: fuse adjacent transforms into XLA-backed filters.
 
-The north-star requirement (BASELINE.json): ``tensor_transform``'s
+The north-star requirement: ``tensor_transform``'s
 arithmetic/typecast/transpose ops fuse into the model's XLA graph.  The
 reference accelerates transforms with hand-written Orc SIMD
 (``tensor_transform.c:330-405``); the TPU-native answer is compiler-grade —

@@ -75,29 +75,10 @@ def downstream_backend(node: Node, max_hops: int = 4):
     """The first filter backend downstream of ``node``, hopping over
     queue/upload plumbing (None when the chain ends, branches, or lands on
     a non-filter).  Shared by ``tensor_upload`` (wire-rule/sharding
-    discovery) and the batch elements (the host-concat threshold is
-    platform-aware: it needs the CONSUMER's platform, not the producer's).
+    discovery) and the batch elements (the consumer's mesh width).
     """
     filt = downstream_filter_node(node, max_hops)
     return getattr(filt, "backend", None) if filt is not None else None
-
-
-def consumer_platform(node: Node, max_hops: int = 4):
-    """``jax.default_backend()`` string when the downstream consumer is a
-    jax-family filter backend, else None.  Used by the batch elements'
-    payload/platform-aware host-concat threshold (``pool.skip_host_concat``):
-    only a jax consumer understands the deferred ``RowBatch`` fast path,
-    and only the CPU fallback benefits from it."""
-    backend = downstream_backend(node, max_hops)
-    if backend is None:
-        return None
-    from ..backends.jax_backend import JaxBackend
-
-    if not isinstance(backend, JaxBackend):
-        return None
-    import jax
-
-    return jax.default_backend()
 
 
 def consumer_mesh_devices(node: Node, max_hops: int = 4) -> int:

@@ -1,5 +1,5 @@
-"""Built-in model zoo: the networks behind the five benchmark configs
-(BASELINE.md): MobileNet-v2 labeling, SSD-MobileNet boxes, PoseNet
-heatmaps, LSTM recurrence, and batched multi-stream classification."""
+"""Built-in model zoo: MobileNet-v2 labeling, SSD-MobileNet boxes, PoseNet
+heatmaps, LSTM recurrence, batched multi-stream classification, and the
+benchmark's two configurations (``vit``, ``laguna``)."""
 
 from . import audio_cnn, lstm, mobilenet_v2, posenet, ssd_mobilenet, transformer  # noqa: F401

@@ -118,9 +118,8 @@ def available() -> bool:
 def queue_backend() -> str:
     """``"native"`` or ``"python"``: the ``queue`` element's backend as
     configured.  Raises when ``[common] native_runtime`` is on but the
-    library failed to build or load — measurement entry points
-    (``chip_smoke.py``, ``bench.py``) call this so the host dispatch layer
-    cannot change under a benchmark without a word."""
+    library failed to build or load — ``chip_smoke.py`` calls this so the
+    host dispatch layer cannot change under a measurement without a word."""
     from ..conf import conf
 
     if not conf.get_bool("common", "native_runtime", True):

@@ -30,8 +30,8 @@ observability plane.  This module is the measure+cache half:
   leg}`` gauges and a ``cost_model`` provider in ``/stats.json``.
 
 - :func:`merge_cost_model` persists the model to ``COST_MODEL.json``
-  (``[obs] costmodel_path``), schema-versioned and idempotently merged
-  like ``bench.merge_ladder_bank``: each stage entry banks a bounded
+  (``[obs] costmodel_path``), schema-versioned and idempotently
+  merged: each stage entry banks a bounded
   per-run history (re-merging the same run's snapshot *replaces* that
   run's contribution — a flush is safe to repeat) and re-pools the
   cross-run aggregate the partitioner prices candidate cuts against

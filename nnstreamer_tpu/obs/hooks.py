@@ -112,7 +112,7 @@ must stay allocation-light):
                    ``hbm_over_capacity``; ``detail`` carries the
                    capture id plus the op/frame counts (or the failure
                    reason).  ``pipeline_name`` may be empty for
-                   backend-level windows (bench, ``device_trace``).
+                   backend-level windows (``device_trace``).
 =================  ====================================================
 
 Timestamps passed through hooks are ``time.perf_counter_ns()`` — every

@@ -576,7 +576,7 @@ def _captures_counter(registry: MetricsRegistry):
     return registry.counter(
         "nnstpu_profile_captures_total",
         "Deep-profiling XPlane captures, by trigger "
-        "(manual/http/watchdog/bench/fleet/whole_run) and outcome",
+        "(manual/http/watchdog/fleet/whole_run) and outcome",
         labelnames=("trigger", "outcome"),
     )
 
@@ -746,7 +746,7 @@ def capture_profile(seconds: Optional[float] = None,
 def profiled_window(label: str = "window", logdir: Optional[str] = None,
                     trigger: str = "manual", parse: bool = True):
     """Low-level capture bracket for code that drives its own workload
-    (bench ladder cells, ``utils.profiling.device_trace``): serialized
+    (``utils.profiling.device_trace``): serialized
     on the same process-wide capture lock (typed busy, never a
     concurrent ``start_trace`` crash), artifacts in the gallery (or the
     caller's ``logdir``).  Yields a dict that carries ``summary`` after

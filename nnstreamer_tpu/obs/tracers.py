@@ -291,10 +291,10 @@ class CopiesTracer(Tracer):
     forced WireTensor materialization) folds into per-element byte/copy/
     alloc counters; source pushes count frames so ``summary()`` can report
     **bytes copied per source frame** — the number the CI copy-regression
-    gate and ``tools/profile_mux_overhead.py`` watch.  Copies emitted by
+    gate watches.  Copies emitted by
     backend objects (no ``pipeline`` attribute) are attributed by type
     name: they belong to whichever pipeline's filter invoked them, which a
-    single-pipeline process (the bench/CI shape) makes unambiguous.
+    single-pipeline process (the CI shape) makes unambiguous.
     """
 
     name = "copies"

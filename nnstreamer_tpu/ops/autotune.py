@@ -1,11 +1,10 @@
 """Persistent Pallas autotune cache: tune once per machine, not per
 process.
 
-The benched int8 autotune win is 7.1× over the kernel's default block
-split — but the search ran inside ``bench.py`` and its winner died with
-the process.  TVM's discipline (PAPERS.md) is the model: **search
-offline, serve from the cache**.  This module is that cache plus the
-search driver:
+A block split found by searching on the chip dies with the process that
+searched.  TVM's discipline (PAPERS.md) is the model: **search offline,
+serve from the cache**.  This module is that cache plus the search
+driver:
 
 - winners are keyed by ``(kernel, shapes, dtype, platform)`` and stored
   as JSON under ``<[compile] cache_dir>/autotune/<kernel>.json`` — one
@@ -14,10 +13,10 @@ search driver:
 - :func:`cached_int8_blocks` is the hot-path consult:
   :func:`~nnstreamer_tpu.ops.pallas_kernels.int8_matmul` calls it (at
   trace time — zero per-dispatch cost) whenever the caller left
-  ``block_m``/``block_n`` unset, so the 7.1× tile split survives process
+  ``block_m``/``block_n`` unset, so the tuned tile split survives process
   restarts without any call-site change;
-- :func:`autotune_int8_matmul` runs the on-chip search (the same
-  candidate grid ``bench.py`` sweeps) and records the winner.  It
+- :func:`autotune_int8_matmul` runs the on-chip search over the
+  candidate grid below and records the winner.  It
   refuses to tune in interpret mode — interpret-mode timings would
   poison the cache with host-CPU noise — unless explicitly forced.
 
@@ -136,8 +135,7 @@ def record(kernel: str, key: str, config: dict,
 # -- int8_matmul -------------------------------------------------------------
 
 INT8_KERNEL = "int8_matmul"
-# the same candidate grid bench.py sweeps on-chip; None = the kernel's
-# adaptive whole-M heuristic
+# the candidate grid; None = the kernel's adaptive whole-M heuristic
 INT8_BLOCK_M = (None, 128)
 INT8_BLOCK_N = (128, 256, 512, 1024)
 

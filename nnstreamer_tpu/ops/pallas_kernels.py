@@ -142,7 +142,7 @@ def int8_matmul(
 
     Default tiles are adaptive: a persistent autotune winner for this
     exact ``(m, k, n)`` on this platform when one exists
-    (:mod:`nnstreamer_tpu.ops.autotune` — the benched 7.1× int8 tile
+    (:mod:`nnstreamer_tpu.ops.autotune` — the tuned int8 tile
     split survives process restarts; consulted at TRACE time, zero
     per-dispatch cost), else the whole M dim in one block when it fits a
     VMEM budget (classifier heads have small M — one pass over the

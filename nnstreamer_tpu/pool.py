@@ -4,12 +4,10 @@ The batched front doors (``tensor_mux → tensor_batch``, ``tensor_dynbatch``)
 are the throughput levers of this framework, but their coalescing step was a
 fresh ``np.stack`` per dispatch: every batch paid one full memcpy pass PLUS
 a cold multi-MB allocation (mmap + page-fault zeroing — the hidden second
-pass).  ``tools/profile_mux_overhead.py`` attributed 59% of 8-stream busy
-time to exactly that memcpy on 602 KB frames on a CPU host.  The
-reference's answer is recycled,
-ref-counted buffers (``GstBufferPool`` + the ``allocate_in_invoke``
-zero-copy hand-off, ``tensor_filter.c:350-399``); this module is that
-discipline for the TPU-native hot path:
+pass).  The reference's answer is recycled, ref-counted buffers
+(``GstBufferPool`` + the ``allocate_in_invoke`` zero-copy hand-off,
+``tensor_filter.c:350-399``); this module is that discipline for the
+TPU-native hot path:
 
 - :class:`BufferPool` — a size-classed, bounded pool of host staging
   buffers keyed by ``(shape, dtype)``.  ``lease()`` hands out a
@@ -18,11 +16,6 @@ discipline for the TPU-native hot path:
   the last frame/view referencing it is dropped (a GC finalizer — the
   GstBuffer unref analog).  Explicit :meth:`BufferPool.recycle` exists for
   owners that know the buffer is theirs alone (staging loops).
-- :class:`RowBatch` — a deferred batch: N equally-shaped rows presented as
-  one ``(N, *row)`` tensor **without any host concatenation**.  The jax
-  filter recognizes it and invokes per row; ``tensor_unbatch`` splits it
-  back without materializing; any other consumer's ``np.asarray`` falls
-  back to a real stack (correctness is never conditional on the fast path).
 - :class:`WireStager` — double-buffered (ping-pong) pooled staging for
   host→device wire copies: frame N+1's host copy proceeds while frame N's
   ``device_put``/dispatch is still in flight; a slot is only rewritten
@@ -36,16 +29,9 @@ discipline for the TPU-native hot path:
   before handing the recycled memory back out for rewriting.  (Merely
   *dropping* the buffer is always safe — jax pins the source for the
   copy's duration; only rewrite-after-recycle needs the gate.)
-- :func:`skip_host_concat` — the payload/platform-aware threshold: on the
-  CPU fallback, coalescing large host rows costs more than the dispatch
-  amortization saves (the 602 KB config5 regime), so the batch elements
-  skip host concat entirely above the threshold and let the filter invoke
-  per stream.  On a real accelerator the batched transfer is what beats
-  the wire, so the threshold never triggers there.
 
 Knobs (env ``NNSTPU_POOL_*`` > ini ``[pool]`` > defaults, the standard
-conf precedence): ``enabled``, ``max_per_class``, ``max_bytes``,
-``concat_threshold``.
+conf precedence): ``enabled``, ``max_per_class``, ``max_bytes``.
 
 Observability: the default pool publishes ``nnstpu_pool_*`` metrics
 (hit/miss/eviction/recycle counters, leased/free-bytes gauges) on the obs
@@ -66,13 +52,6 @@ import numpy as np
 
 DEFAULT_MAX_PER_CLASS = 4
 DEFAULT_MAX_BYTES = 64 << 20        # 64 MiB of *free* (pooled) bytes
-# Per-row bytes above which the CPU-fallback batch elements skip host
-# concat and invoke per stream.  Default 0 = opt-in: a 602 KB identity
-# sweep on a CPU host measured the per-row dispatch
-# overhead costing MORE than the skipped memcpy saves on this runtime, so
-# pooled slot-wise assembly stays the default remedy; the knob remains
-# for payload/model mixes where per-stream invoke wins.
-DEFAULT_CONCAT_THRESHOLD = 0
 
 
 def _conf_int(key: str, default: int) -> int:
@@ -436,112 +415,6 @@ def fence(arr: Any, inflight: Any) -> bool:
         return False
     owner._fence_raw(node._pool_raw, inflight)
     return True
-
-
-# -- host-concat threshold ---------------------------------------------------
-
-def host_concat_threshold() -> int:
-    """Per-row payload bytes above which host batch assembly is skipped on
-    the CPU fallback (``NNSTPU_POOL_CONCAT_THRESHOLD`` / ini ``[pool]
-    concat_threshold``; ``0`` or negative disables the skip)."""
-    return _conf_int("concat_threshold", DEFAULT_CONCAT_THRESHOLD)
-
-
-def skip_host_concat(row_nbytes: int, platform: Optional[str] = None) -> bool:
-    """Should a batch element skip host concatenation for rows of
-    ``row_nbytes`` and hand the filter a :class:`RowBatch` instead?
-
-    True only when (a) the downstream consumer runs on the CPU fallback —
-    on a real accelerator the batched transfer is the whole point — and
-    (b) the per-row payload is at or above the threshold, the regime where
-    coalescing can cost more than it amortizes.
-    ``platform`` is the consumer's ``jax.default_backend()`` string; pass
-    None when the downstream backend is unknown (never skips: a non-jax
-    consumer would just pay the stack later via ``np.asarray``).
-    """
-    if platform != "cpu":
-        return False
-    thr = host_concat_threshold()
-    return thr > 0 and row_nbytes >= thr
-
-
-# -- deferred batches --------------------------------------------------------
-
-class RowBatch:
-    """N equally-shaped rows presented as one ``(N, *row)`` tensor without
-    host concatenation.
-
-    Producers: the batch elements above :func:`skip_host_concat`'s
-    threshold.  Fast-path consumers: the jax backend (per-row invoke) and
-    ``tensor_unbatch`` (row split).  Every other consumer materializes via
-    ``np.asarray`` (one real stack) — the fallback that keeps correctness
-    unconditional.  Rows may carry a leading 1 (per-row invoke outputs);
-    :meth:`row` normalizes to the logical row shape (a view).
-    """
-
-    __slots__ = ("rows", "row_shape", "shape", "dtype")
-
-    def __init__(self, rows: Sequence[Any], row_shape: Optional[Tuple[int, ...]] = None,
-                 dtype=None):
-        self.rows: Tuple[Any, ...] = tuple(rows)
-        if not self.rows:
-            raise ValueError("RowBatch needs at least one row")
-        r0 = self.rows[0]
-        self.row_shape = (tuple(row_shape) if row_shape is not None
-                          else tuple(r0.shape))
-        self.shape = (len(self.rows),) + self.row_shape
-        self.dtype = np.dtype(dtype if dtype is not None else r0.dtype)
-
-    # -- ndarray duck typing (spec/signature checks, generic consumers) ------
-
-    @property
-    def ndim(self) -> int:
-        return len(self.shape)
-
-    @property
-    def size(self) -> int:
-        n = 1
-        for d in self.shape:
-            n *= d
-        return n
-
-    @property
-    def nbytes(self) -> int:
-        return self.size * self.dtype.itemsize
-
-    def __len__(self) -> int:
-        return self.shape[0]
-
-    def row(self, i: int) -> np.ndarray:
-        """Row ``i`` as a host array of the logical row shape (a reshape
-        view when the stored row carries a leading batch-1 dim)."""
-        a = np.asarray(self.rows[i])
-        return a.reshape(self.row_shape) if a.shape != self.row_shape else a
-
-    def __getitem__(self, key):
-        if isinstance(key, (int, np.integer)):
-            n = len(self.rows)
-            i = int(key)
-            if i < 0:
-                i += n
-            if not 0 <= i < n:
-                raise IndexError(f"row {key} out of range for {n} rows")
-            return self.row(i)
-        return np.asarray(self)[key]
-
-    def __array__(self, dtype=None, copy=None):
-        if copy is False:
-            raise ValueError(
-                "RowBatch cannot be materialized without a copy "
-                "(rows are separate buffers)"
-            )
-        arr = np.stack([self.row(i) for i in range(len(self.rows))], axis=0)
-        if dtype is not None and np.dtype(dtype) != arr.dtype:
-            return arr.astype(dtype)
-        return arr
-
-    def __repr__(self) -> str:
-        return f"RowBatch({self.dtype}{self.shape})"
 
 
 # -- ping-pong wire staging --------------------------------------------------

@@ -32,15 +32,6 @@ ENTRY_POINTS = {
         "from nnstreamer_tpu.serving import ContinuousBatcher\n"
         "ContinuousBatcher(capacity=1, t_max=8, d_in=4, n_out=2, d_model=8,"
         " n_heads=2, n_layers=1).stop()\n"),
-    # main() with a stand-in device and every leg filtered out
-    "bench.py": (
-        "import os, tempfile, bench\n"
-        "d = tempfile.mkdtemp()\n"
-        "os.environ.update(BENCH_LEGS='none', BENCH_PARTIAL_PATH=d + '/p.json',"
-        " BENCH_NOTES_PATH=d + '/n.md')\n"
-        "bench.require_tpu = lambda: {'platform': 'tpu', 'kind': 'x',"
-        " 'count': 1}\n"
-        "assert bench.main()[1] == 0\n"),
     "python -m nnstreamer_tpu": (
         "from nnstreamer_tpu.__main__ import main\n"
         "main(['videotestsrc num-buffers=1 width=8 height=8 ! "
@@ -74,7 +65,7 @@ class TestCompileCachePlacement:
         env.pop("JAX_COMPILATION_CACHE_DIR", None)
         # a different cwd and a different entry point: still one place
         a = _cache_dir_after(ENTRY_POINTS["jax backend"], env)
-        b = _cache_dir_after(ENTRY_POINTS["bench.py"], env)
+        b = _cache_dir_after(ENTRY_POINTS["python -m nnstreamer_tpu"], env)
         assert a == b == os.path.join(REPO, ".jax_cache")
 
     def test_repo_stores_do_not_move_the_xla_cache(self, tmp_path):
@@ -92,17 +83,17 @@ class TestCompileCachePlacement:
             for path in pathlib.Path(REPO, root).rglob("*.py"):
                 if "jax_compilation_cache_dir" in path.read_text():
                     hits.append(str(path.relative_to(REPO)))
-        for name in ("bench.py", "chip_smoke.py", "__graft_entry__.py"):
+        for name in ("chip_smoke.py", "__graft_entry__.py"):
             if "jax_compilation_cache_dir" in pathlib.Path(
                     REPO, name).read_text():
                 hits.append(name)
         assert hits == ["nnstreamer_tpu/backends/exec_cache.py"]
 
     def test_measurement_entry_points_name_no_cache_directory(self):
-        """No BENCH_COMPILE_CACHE, no [compile] cache_dir pointed at a
-        temporary name: they take what the one function gives them."""
-        for name in ("bench.py", "chip_smoke.py",
-                     "tools/profile_mux_overhead.py"):
+        """No [compile] cache_dir pointed at a temporary name: they take
+        what the one function gives them."""
+        for name in ("chip_smoke.py", "__graft_entry__.py",
+                     "benchmark/run.py"):
             assert "COMPILE_CACHE" not in pathlib.Path(
                 REPO, name).read_text(), name
 
@@ -208,7 +199,7 @@ class TestNativeLoaderBuildsFromSource:
         assert native.load() is None  # elements still get their Python twin
         assert native.available() is False
         with pytest.raises(RuntimeError, match="native_runtime is on"):
-            native.queue_backend()  # chip_smoke.py / bench.py stop here
+            native.queue_backend()  # chip_smoke.py stops here
         monkeypatch.setenv("NNSTPU_COMMON_NATIVE_RUNTIME", "off")
         assert native.queue_backend() == "python"  # asked for: fine
 

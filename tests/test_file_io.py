@@ -1,6 +1,6 @@
 """filesrc / filesink: the SSAT backbone endpoints (raw-byte streams in,
 byte-exact golden capture out — ``runTest.sh`` pipelines are built on
-these).  Was the one 0%-covered module in COVERAGE.txt."""
+these)."""
 
 import numpy as np
 import pytest

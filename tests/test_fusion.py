@@ -1,5 +1,5 @@
 """Transform-fusion tests: transform chains fold into the jax filter's XLA
-program (the north-star fusion requirement, BASELINE.json)."""
+program (the north-star fusion requirement)."""
 
 import numpy as np
 import pytest

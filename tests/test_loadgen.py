@@ -220,25 +220,53 @@ class TestTailForensicsUnderChaos:
 
     def test_invoke_delay_yields_device_verdicts_and_slo_cycle(
             self, tmp_path, monkeypatch):
-        gdir = tmp_path / "gallery"
-        monkeypatch.setenv("NNSTPU_OBS_FORENSICS_DIR", str(gdir))
         monkeypatch.setenv("NNSTPU_OBS_FORENSICS_MIN_SAMPLES", "24")
+        # the gallery keeps the slowest K: on a busy host K slower wire
+        # outliers must not evict the device captures looked for below
+        monkeypatch.setenv("NNSTPU_OBS_FORENSICS_KEEP", "64")
         from nnstreamer_tpu import faults
+        from nnstreamer_tpu.obs.costmodel import leg_band_us
 
-        faults.install(
-            "invoke_delay@filter:after=60,every=40,count=6,ms=80", seed=7)
-        try:
-            report = loadgen.run_scenario("ci-slo", seed=7,
-                                          duration_s=2.5)
-        finally:
-            faults.deactivate()
+        def gate_ms(fx):
+            """Where the engine's own outlier gate stood: mean + band of
+            the totals it scored."""
+            total = fx["baseline"]["total"]
+            return (total["mean_us"] + leg_band_us(total)) / 1e3
+
+        # the stall has to stand clear of this host's own jitter, which
+        # the engine's band follows: measure a quiet second of the same
+        # scenario first and inject twice its gate (80 ms on an idle host,
+        # more beside five other busy test workers).  A run whose own gate
+        # rose over the stall met a noisier stretch than the quiet second:
+        # it could not have shown the stall, so inject clear of that one
+        monkeypatch.setenv("NNSTPU_OBS_FORENSICS_DIR",
+                           str(tmp_path / "quiet"))
+        quiet = loadgen.run_scenario("ci-slo", seed=7, duration_s=1.0)
+        noise_ms = gate_ms(quiet["forensics"])
+        for attempt in range(3):
+            stall_ms = max(80, int(2 * noise_ms))
+            gdir = tmp_path / f"gallery{attempt}"
+            monkeypatch.setenv("NNSTPU_OBS_FORENSICS_DIR", str(gdir))
+            faults.install(
+                "invoke_delay@filter:after=60,every=40,count=6,"
+                f"ms={stall_ms}", seed=7)
+            try:
+                report = loadgen.run_scenario("ci-slo", seed=7,
+                                              duration_s=2.5)
+            finally:
+                faults.deactivate()
+            noise_ms = gate_ms(report["forensics"])
+            if report["forensics"]["outliers"].get("device") \
+                    or noise_ms < stall_ms:
+                break
         # the ledger stays exact even with the chaos engine stalling
         # invokes mid-flight
         assert report["ledger"]["exact"]
         fx = report["forensics"]
         assert fx["pipeline"] == "lg-ci-slo"
         assert fx["scored"] > 24 and not fx["warming"]
-        assert fx["outliers"].get("device", 0) >= 1, fx["outliers"]
+        assert fx["outliers"].get("device", 0) >= 1, \
+            (fx["outliers"], stall_ms, noise_ms)
         assert fx["gallery"]["entries"] >= 1
         caps = sorted(gdir.glob("*.forensic.json"))
         docs = [json.load(open(c)) for c in caps]
@@ -252,7 +280,8 @@ class TestTailForensicsUnderChaos:
                    for e in dev["flight"]["traceEvents"])
 
         # burn-rate cycle over the same run's client-observed histogram:
-        # the injected 80ms stalls blow a 50ms@99.9% objective...
+        # the injected stalls (80 ms at least) blow a 50ms@99.9%
+        # objective...
         from nnstreamer_tpu.obs.metrics import REGISTRY
         from nnstreamer_tpu.obs.slo import Objective, SloEngine
 

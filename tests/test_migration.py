@@ -577,6 +577,54 @@ class TestRouterHandoff:
         assert f.worker(victim).engine.stats()["active_sessions"] == 0
         c.close()
 
+    def test_handoff_onto_a_worker_drained_meanwhile_is_moved_on(self):
+        """Two drains at once (a fleet scaling 3 → 1): the first one's
+        handoff has picked the second victim as its target when that
+        victim's own drain starts.  The session must end on the survivor,
+        token-identical, not stranded on (or broken with) a drained
+        worker."""
+        f = _MigFleet(n=3)
+        try:
+            prompt, steps = _prompt(seed=29), _steps(4, base=290)
+            ctl = _control_run(prompt, steps)
+            c = RawClient(f.router.port)
+            out = self._stream(c, prompt, steps[:2])
+            first = f.pinned()
+            second, survivor = (w.name for w in f.workers
+                                if w.name != first)
+            real_pick = f.membership.pick
+            second_drain = []
+
+            def pick_then_drain_the_target(exclude=()):
+                if not second_drain:  # the handoff's own pick
+                    t = threading.Thread(
+                        target=lambda: second_drain.append(
+                            f.router.drain_worker(second, deadline_s=5.0)))
+                    second_drain.append(t)
+                    t.start()
+                    time.sleep(0.2)  # the other drain is under way
+                    return f.membership.get(second)
+                return real_pick(exclude)
+
+            f.membership.pick = pick_then_drain_the_target
+            assert f.router.drain_worker(first, deadline_s=5.0) == 0
+            second_drain[0].join(timeout=15)
+            assert second_drain[1:] == [0], "second drain broke a session"
+            for s in steps[2:]:
+                out.append(np.asarray(c.request((s,))[0][0]))
+            for x, y in zip(ctl, out):
+                np.testing.assert_array_equal(x, y)
+            assert f.router.session_count(survivor) == 1
+            assert f.router.session_count(first) == 0
+            assert f.router.session_count(second) == 0
+            st = f.router.stats()
+            assert st["sessions_broken"] == 0
+            assert st["sessions_migrated"] == 2
+            assert st["session_ledger_exact"], st
+            c.close()
+        finally:
+            f.close()
+
     def test_old_worker_falls_back_to_typed_session(self):
         """Version gate end to end: workers whose DecodeServer predates
         the migration ops answer the control frame with a plain error —

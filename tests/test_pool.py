@@ -6,8 +6,8 @@ drops — tee fan-out must not recycle early), bounded free-list accounting
 (per-class and total-byte eviction, renegotiated size classes draining
 out instead of leaking), the async-transfer fence (recycled memory is
 never rewritten while a ``device_put``/dispatch issued from it is still
-reading), the deferred ``RowBatch``, ping-pong ``WireStager`` staging,
-and the ``copies`` tracer the CI regression gate reads.
+reading), ping-pong ``WireStager`` staging, and the ``copies`` tracer the
+CI regression gate reads.
 """
 
 import numpy as np
@@ -17,10 +17,8 @@ from nnstreamer_tpu.buffer import Frame
 from nnstreamer_tpu.pool import (
     BufferPool,
     PooledArray,
-    RowBatch,
     WireStager,
     fence,
-    skip_host_concat,
 )
 
 
@@ -302,45 +300,6 @@ class TestShardedFence:
             np.testing.assert_array_equal(np.asarray(put), expect)
 
 
-class TestRowBatch:
-    def test_geometry_and_rows(self):
-        rows = [np.arange(4, dtype=np.float32) + i for i in range(3)]
-        rb = RowBatch(rows)
-        assert rb.shape == (3, 4) and rb.dtype == np.float32
-        assert len(rb) == 3 and rb.ndim == 2
-        assert rb.size == 12 and rb.nbytes == 48
-        np.testing.assert_array_equal(rb[1], rows[1])
-        np.testing.assert_array_equal(rb[-1], rows[2])
-        assert "RowBatch" in repr(rb)
-
-    def test_row_normalizes_leading_one(self):
-        """Per-row invoke outputs carry a (1, *row) batch dim; row() views
-        them back to the logical row shape."""
-        rb = RowBatch([np.zeros((1, 4), np.float32)], row_shape=(4,))
-        assert rb.shape == (1, 4)
-        assert rb.row(0).shape == (4,)
-
-    def test_materialize_fallback(self):
-        rows = [np.full(4, i, np.float32) for i in range(3)]
-        rb = RowBatch(rows)
-        np.testing.assert_array_equal(np.asarray(rb), np.stack(rows))
-        assert rb.__array__(dtype=np.int32).dtype == np.int32
-        # fancy subscripts go through one real stack
-        np.testing.assert_array_equal(rb[:, 1], np.stack(rows)[:, 1])
-
-    def test_refuses_zero_copy_materialize(self):
-        rb = RowBatch([np.zeros(4, np.float32)])
-        with pytest.raises(ValueError, match="copy"):
-            np.asarray(rb, copy=False)
-
-    def test_index_bounds(self):
-        rb = RowBatch([np.zeros(4, np.float32)])
-        with pytest.raises(IndexError):
-            rb[1]
-        with pytest.raises(ValueError):
-            RowBatch([])
-
-
 class TestWireStager:
     def test_ping_pong_alternates_and_gates_reuse(self):
         pool = BufferPool(max_per_class=8, max_bytes=1 << 20)
@@ -372,20 +331,6 @@ class TestWireStager:
         stager.stage(0, np.zeros((2, 2), np.float32).T, (4,))
         stager.reset()
         assert pool.stats()["recycles"] == 1
-
-
-class TestSkipHostConcat:
-    def test_platform_and_payload_gating(self, monkeypatch):
-        monkeypatch.setenv("NNSTPU_POOL_CONCAT_THRESHOLD", str(256 << 10))
-        big, small = 602 << 10, 4 << 10
-        assert skip_host_concat(big, "cpu") is True  # the config5 regime
-        assert skip_host_concat(small, "cpu") is False
-        assert skip_host_concat(big, "tpu") is False  # accelerator: batch!
-        assert skip_host_concat(big, None) is False  # unknown consumer
-
-    def test_threshold_zero_disables(self, monkeypatch):
-        monkeypatch.setenv("NNSTPU_POOL_CONCAT_THRESHOLD", "0")
-        assert skip_host_concat(1 << 30, "cpu") is False
 
 
 class TestPipelineIntegration:
@@ -443,47 +388,39 @@ class TestPipelineIntegration:
         sink.frames.clear()
         assert pool.stats()["recycles"] == 4
 
-    def test_per_stream_rowbatch_path_correct_and_copyless(self, monkeypatch):
-        """Above the host-concat threshold on the CPU fallback the chain
-        batch→filter→unbatch must produce identical results WITHOUT ever
-        leasing a batch buffer (the deferred RowBatch path)."""
-        monkeypatch.setenv("NNSTPU_POOL_CONCAT_THRESHOLD", "8")
+    def test_merge_assembles_into_recycled_pooled_buffers(self, monkeypatch):
+        """tensor_merge's host result is a pooled lease like tensor_batch's:
+        concatenated once into it, recycled when the sink is done, so a
+        steady stream allocates no fresh multi-MB result a round."""
         from nnstreamer_tpu import Pipeline
-        from nnstreamer_tpu.backends.jax_backend import JaxModel
-        from nnstreamer_tpu.elements.batch import TensorBatch, TensorUnbatch
-        from nnstreamer_tpu.elements.filter import TensorFilter
+        from nnstreamer_tpu.elements.merge import TensorMerge
         from nnstreamer_tpu.elements.sink import TensorSink
         from nnstreamer_tpu.elements.testsrc import DataSrc
-        from nnstreamer_tpu.spec import TensorSpec, TensorsSpec
 
         pool = BufferPool(max_per_class=4, max_bytes=1 << 20)
-        frames = [
-            Frame.of(np.full(4, 2 * i, np.float32),
-                     np.full(4, 2 * i + 1, np.float32), pts=i)
-            for i in range(5)
-        ]
-        model = JaxModel(
-            apply=lambda p_, x: x * 3.0,
-            input_spec=TensorsSpec.of(
-                TensorSpec(dtype=np.float32, shape=(2, 4))),
-        )
+        monkeypatch.setattr("nnstreamer_tpu.pool.default_pool", lambda: pool)
         p = Pipeline()
-        src = p.add(DataSrc(data=frames))
-        batch = p.add(TensorBatch(pool=pool))
-        filt = p.add(TensorFilter(framework="jax", model=model))
-        unb = p.add(TensorUnbatch())
+        merge = p.add(TensorMerge(option="1", sync_mode="nosync"))
+        for k in range(3):
+            src = p.add(DataSrc(data=[
+                Frame.of(np.full((2, 4), 10 * i + k, np.float32), pts=i)
+                for i in range(6)]))
+            p.link(src, f"{merge.name}.sink_{k}")
+        got, leased = [], []
         sink = p.add(TensorSink())
-        got = []
-        sink.connect("new-data",
-                     lambda f: got.append([np.asarray(t) for t in f.tensors]))
-        p.link_chain(src, batch, filt, unb, sink)
+        sink.connect("new-data", lambda f: (
+            leased.append(isinstance(f.tensor(0), PooledArray)),
+            got.append(np.array(f.tensor(0)))))
+        p.link(merge, sink)
         p.run(timeout=120)
-        assert len(got) == 5
-        for i, (r0, r1) in enumerate(got):
-            np.testing.assert_allclose(r0, 3.0 * 2 * i)
-            np.testing.assert_allclose(r1, 3.0 * (2 * i + 1))
+        assert len(got) == 6 and all(leased)
+        for i, a in enumerate(got):
+            np.testing.assert_array_equal(
+                a, np.concatenate([np.full((2, 4), 10 * i + k, np.float32)
+                                   for k in range(3)], axis=0))
         st = pool.stats()
-        assert st["misses"] == 0 and st["hits"] == 0  # zero host concat
+        assert st["misses"] == 1 and st["hits"] == 5
+        assert st["recycles"] == 6 and st["leased_bytes"] == 0
 
     def test_dynbatch_padding_path_pools_and_stays_correct(self):
         from nnstreamer_tpu import Pipeline

@@ -17,7 +17,7 @@ answers:
   spike / diurnal offered-load profiles, each tenant declaring its
   identity on the wire (``FLAG_TENANT``) so server-side admission and
   the ``tenant``-labeled metrics see the same split this report does;
-- a machine-readable **report** (``BENCH_*``-style JSON): client-side
+- a machine-readable **report** (JSON): client-side
   p50/p99/p99.9 vs offered load (windowed curves), per-tenant goodput
   under overload (one flooding tenant + N well-behaved tenants — does
   DRR + admission + deadline expiry hold the well-behaved p99?), an
@@ -1218,6 +1218,19 @@ def _warm(fleet: "InProcFleet", tenants: List[dict], d_in: int) -> None:
                 #       worker may shed it; the run proper still measures)
 
 
+def _settle(router, deadline_s: float = 5.0) -> None:
+    """The router counts a delivery AFTER the reply's bytes went out, so
+    the last client can be done before its request is: wait (bounded) for
+    offered == delivered + shed before the ledger is read.  A ledger that
+    is still open at the deadline is reported as it stands."""
+    deadline = time.monotonic() + deadline_s
+    while time.monotonic() < deadline:
+        st = router.stats()
+        if st["offered"] == st["delivered"] + st["shed_total"]:
+            return
+        time.sleep(0.005)
+
+
 def run_scenario(name: str, seed: int = 7,
                  duration_s: Optional[float] = None,
                  windows: int = 6, max_workers: int = 64,
@@ -1240,6 +1253,7 @@ def run_scenario(name: str, seed: int = 7,
             _warm(fleet, sc["tenants"], d_in)
             _spans.clear()  # warmup spans out of the report
         records = lg.run(d_in=d_in)
+        _settle(fleet.router)
         # tail forensics rides along when a gallery dir is configured:
         # every joined trace is scored against the cost-model baseline
         fengine = None

@@ -8,7 +8,7 @@
 #   `pip install` unavailable);
 # - every step runs on the CPU (JAX_PLATFORMS=cpu); the chip is reached
 #   only through the builder's tool, where `python chip_smoke.py` goes
-#   first (bench.py exits non-zero without a TPU, so CI has no bench step).
+#   first (CI has no benchmark step: `benchmark/run.py` needs the chip).
 # Exit code 0 = the workflow would have passed.
 set -uo pipefail
 cd "$(dirname "$0")/.."
@@ -39,7 +39,7 @@ run_step "Run test suite with coverage gate" \
 
 run_step "Coverage floor check" python - <<'PY'
 floor = 75.0
-last = open("COVERAGE.txt").read().strip().splitlines()[-1]
+last = open("build/coverage.txt").read().strip().splitlines()[-1]
 pct = float(last.split()[-1].rstrip("%"))
 print(f"coverage {pct:.1f}% (floor {floor}%)")
 raise SystemExit(0 if pct >= floor else 1)

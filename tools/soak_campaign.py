@@ -157,11 +157,55 @@ def run_mux(rng):
 
 
 def run_repo(rng):
-    import bench
+    """The reference's tests/nnstreamer_repo_lstm topology: (h, c) cycle
+    through two repo slots, x from a source; every step's h must equal the
+    cell applied in sequence outside the pipeline."""
+    import nnstreamer_tpu as nns
+    from nnstreamer_tpu.buffer import Frame
+    from nnstreamer_tpu.elements.filter import TensorFilter
+    from nnstreamer_tpu.elements.repo import (GLOBAL_REPO, TensorRepoSink,
+                                              TensorRepoSrc)
+    from nnstreamer_tpu.elements.sink import TensorSink
+    from nnstreamer_tpu.elements.tee import Tee
+    from nnstreamer_tpu.elements.testsrc import DataSrc
+    from nnstreamer_tpu.models import lstm
+    from nnstreamer_tpu.spec import TensorSpec, TensorsSpec
 
     steps = int(rng.integers(10, 40))
-    sps = bench.run_lstm_recurrence_fps(steps, hidden=int(rng.integers(8, 64)))
-    assert sps > 0
+    hidden = int(rng.integers(8, 64))
+    model = lstm.build_cell(input_size=hidden, hidden_size=hidden)
+    caps = TensorsSpec(tensors=(TensorSpec(dtype=np.float32, shape=(hidden,)),))
+    xs = [np.full((hidden,), 0.01 * i, np.float32) for i in range(steps)]
+    p = nns.Pipeline()
+    h_src = p.add(TensorRepoSrc(name="h", slot_index=90, caps=caps))
+    c_src = p.add(TensorRepoSrc(name="c", slot_index=91, caps=caps))
+    x_src = p.add(DataSrc(name="x", data=[Frame.of(x, pts=i)
+                                          for i, x in enumerate(xs)]))
+    mux = p.add(nns.make("tensor_mux", sync_mode="nosync"))
+    filt = p.add(TensorFilter(framework="jax", model=model))
+    demux = p.add(nns.make("tensor_demux"))
+    tee = p.add(Tee())
+    out = p.add(TensorSink(collect=True))
+    p.link(h_src, f"{mux.name}.sink_0")
+    p.link(c_src, f"{mux.name}.sink_1")
+    p.link(x_src, f"{mux.name}.sink_2")
+    p.link_chain(mux, filt, demux)
+    p.link(f"{demux.name}.src_0", tee)
+    p.link(tee, p.add(TensorRepoSink(name="hs", slot_index=90)))
+    p.link(tee, out)
+    p.link(f"{demux.name}.src_1",
+           p.add(TensorRepoSink(name="cs", slot_index=91)))
+    try:
+        p.run(timeout=120)
+    finally:
+        GLOBAL_REPO.reset(90)
+        GLOBAL_REPO.reset(91)
+    assert len(out.frames) == steps, f"repo: {len(out.frames)}/{steps}"
+    h = c = np.zeros((hidden,), np.float32)
+    for x, frame in zip(xs, out.frames):
+        h, c = lstm.cell_step(model.params, h, c, x)
+        np.testing.assert_allclose(np.asarray(frame.tensor(0)),
+                                   np.asarray(h), rtol=1e-5, atol=1e-6)
 
 
 def run_trainer(rng):
