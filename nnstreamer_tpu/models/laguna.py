@@ -10,8 +10,11 @@ engine calls a prefill-only request).  The model is read from the published
   ``partial_rotary_factor`` of each head (YaRN frequencies, cos/sin scaled
   by ``attention_factor``), a ``sliding_attention`` layer sees
   ``sliding_window`` keys back, plain rotary on the whole head.  Query heads
-  differ per layer; all share ``num_key_value_heads`` key/value heads
-  (``ops/fused_attention.attention``, grouped);
+  differ per layer; all share ``num_key_value_heads`` key/value heads.  The
+  projections go to ``ops/fused_attention.attention`` (grouped) unrotated,
+  with the layer kind's tables: the rotation is float32 and rounded once
+  wherever it runs, in VMEM on the blocked kernel's own blocks or through
+  ``ops/fused_attention.rotate`` where the plain path is lowered;
 - ``mlp_layer_types``: ``dense`` is a SwiGLU of ``intermediate_size``,
   ``sparse`` the top ``num_experts_per_tok`` of ``num_experts`` SwiGLU
   experts times ``moe_routed_scaling_factor`` beside a shared expert
@@ -71,19 +74,6 @@ def rotary_tables(rope: Dict[str, Any], head_dim: int, t: int):
             jnp.asarray(np.sin(angles) * scale, jnp.float32))
 
 
-def rotate(x, cos, sin, n_heads: int):
-    """Rotary embedding on ``[B, T, n_heads * dh]``: the first ``rot`` dims
-    of each head as two halves ``(a, b)`` → ``(a cos - b sin, b cos + a
-    sin)``, the rest passed through; float32 inside."""
-    b, t, _ = x.shape
-    half = cos.shape[-1]
-    h = x.reshape(b, t, n_heads, -1).astype(jnp.float32)
-    a, bb, rest = h[..., :half], h[..., half:2 * half], h[..., 2 * half:]
-    cos, sin = cos[None, :, None, :], sin[None, :, None, :]
-    out = jnp.concatenate([a * cos - bb * sin, bb * cos + a * sin, rest], -1)
-    return out.reshape(x.shape).astype(x.dtype)
-
-
 def rms_norm(x, gain, eps: float):
     h = x.astype(jnp.float32)
     h = h * jax.lax.rsqrt(jnp.mean(h * h, axis=-1, keepdims=True) + eps)
@@ -96,13 +86,13 @@ def layer(cfg: Dict[str, Any], i: int, p, x, tables, token_chunk=None):
     heads = cfg["num_attention_heads_per_layer"][i]
     kv = cfg["num_key_value_heads"]
     eps = cfg["rms_norm_eps"]
-    cos, sin = tables[kind]
     h = rms_norm(x, p["attn_norm"], eps)
-    q = rotate(matmul(h, p["wq"]), cos, sin, heads)
-    k = rotate(matmul(h, p["wk"]), cos, sin, kv)
-    o = attention(q, heads, True, k=k, v=matmul(h, p["wv"]), n_kv_heads=kv,
+    # q and k go in as the products left them: the lowering rotates them
+    o = attention(matmul(h, p["wq"]), heads, True, k=matmul(h, p["wk"]),
+                  v=matmul(h, p["wv"]), n_kv_heads=kv,
                   window=(cfg["sliding_window"]
-                          if kind == "sliding_attention" else None))
+                          if kind == "sliding_attention" else None),
+                  rotary=tables[kind])
     x = x + matmul(o, p["wo"])
     h = rms_norm(x, p["mlp_norm"], eps)
     if cfg["mlp_layer_types"][i] == "dense":

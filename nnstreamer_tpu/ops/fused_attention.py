@@ -24,7 +24,11 @@ walks key blocks with a running softmax: causal, over separate q and k, v
 projections with grouped heads of width 128 (a head is one lane tile of the
 token-major arrays), with a window if the layer has one; it skips the key
 blocks above the diagonal and outside the band, and holds a key/value head's
-whole K and V in VMEM, fetched once a head group.
+whole K and V in VMEM, fetched once a head group.  Given a layer's rotary
+tables it rotates q and k itself, on the blocks it already holds: float32 in
+VMEM only, where XLA's :func:`rotate` around the projections re-tiles each
+``[tokens, heads * 128]`` array to ``[heads, 128]`` through float32 copies in
+HBM.
 
 ``models/transformer.py`` and ``models/laguna.py`` call :func:`attention`, one
 primitive.  Which lowering a call gets is decided when its program is lowered,
@@ -32,7 +36,7 @@ from what it is lowered for: the kernel for a TPU program that runs on one
 device (or is the all-manual body of a ``shard_map``) where :func:`tiles`
 (the fused projection) or :func:`blocked_tiles` (separate projections) holds,
 ``parallel.ring_attention.full_attention`` with the explicit mask through XLA
-everywhere else
+(after :func:`rotate`, where the call came with tables) everywhere else
 — a CPU program on a TPU host, a program GSPMD partitions over a mesh, a shape
 the kernel does not tile.  ``full_attention`` stays the reference.  Called
 directly off-TPU, :func:`fused_attention` executes in Pallas interpret mode
@@ -198,39 +202,104 @@ def _round_up(n: int, to: int) -> int:
     return -(-n // to) * to
 
 
+def rotate(x, cos, sin, n_heads: int):
+    """Rotary embedding on ``[B, T, n_heads * dh]``: the first ``rot`` dims
+    of each head as two halves ``(a, b)`` → ``(a cos - b sin, b cos + a
+    sin)``, the rest passed through; ``cos``, ``sin``: ``[T, rot/2]``
+    float32.  Float32 inside and rounded to ``x``'s type once: through XLA
+    that is a float32 copy of ``x`` in HBM, which is why the blocked kernel
+    does the same arithmetic on its own blocks."""
+    b, t, _ = x.shape
+    half = cos.shape[-1]
+    h = x.reshape(b, t, n_heads, -1).astype(jnp.float32)
+    a, bb, rest = h[..., :half], h[..., half:2 * half], h[..., 2 * half:]
+    cos, sin = cos[None, :, None, :], sin[None, :, None, :]
+    out = jnp.concatenate([a * cos - bb * sin, bb * cos + a * sin, rest], -1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
 def blocked_tiles(q_shape, kv_shape, dtype, n_heads: int, n_kv_heads: int,
-                  causal: bool) -> bool:
+                  causal: bool, rotary_shape=None) -> bool:
     """Whether :func:`blocked_attention` is the lowering for these shapes:
     causal, heads of exactly one lane tile that group evenly over the
     key/value heads, bf16 or f32, and a (batch row, key/value head)'s whole
     K and V, double buffered, within :data:`VMEM_BUDGET` (T <= 12 k in
-    bf16)."""
+    bf16).  With ``rotary_shape``, the ``[T, rot/2]`` of a call's tables,
+    the rotated K's scratch and a row block of both full-width tables,
+    double buffered, count too (T <= 9 k in bf16), and ``rot`` is at most
+    the head."""
     if not causal or len(q_shape) != 3 or len(kv_shape) != 3:
         return False
     dtype = jnp.dtype(dtype)
     if dtype not in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32)):
         return False
     t = q_shape[1]
+    rows = _round_up(t, BLOCK_K) * LANES * dtype.itemsize
+    vmem = 2 * 2 * rows
+    if rotary_shape is not None:
+        if (len(rotary_shape) != 2 or rotary_shape[0] != t
+                or not 0 < 2 * rotary_shape[1] <= LANES):
+            return False
+        vmem += rows + 2 * 2 * BLOCK_Q * LANES * 4
     return (n_kv_heads > 0 and n_heads % n_kv_heads == 0
             and q_shape[-1] == n_heads * LANES
             and kv_shape[-1] == n_kv_heads * LANES
-            and 2 * 2 * _round_up(t, BLOCK_K) * LANES * dtype.itemsize
-            <= VMEM_BUDGET)
+            and vmem <= VMEM_BUDGET)
 
 
-def _blocked_kernel(q_ref, k_ref, v_ref, o_ref, *, bq: int, bk: int,
-                    window: Optional[int]):
+def _lane_tables(cos, sin, rows: int):
+    """``rotate``'s arithmetic as ``x * C + partner * S`` over whole lane
+    tiles: ``C`` = ``[cos | cos | 1 ...]``, ``S`` = ``[-sin | sin | 0 ...]``,
+    both ``[rows, 128]`` float32, zero past the tables' T (padded rows)."""
+    t, half = cos.shape
+    cos, sin = cos.astype(jnp.float32), sin.astype(jnp.float32)
+    rest = (t, LANES - 2 * half)
+    return tuple(jnp.pad(jnp.concatenate(parts, -1), ((0, rows - t), (0, 0)))
+                 for parts in ((cos, cos, jnp.ones(rest, jnp.float32)),
+                               (-sin, sin, jnp.zeros(rest, jnp.float32))))
+
+
+def _blocked_kernel(q_ref, k_ref, v_ref, *refs, bq: int, bk: int,
+                    window: Optional[int], group: int, half: Optional[int]):
     """One (batch row, query head, block of query rows): walk the key blocks
-    this block's rows may see with a running max, row sum and output."""
-    q0 = pl.program_id(2) * bq
-    q = q_ref[0] * (LANES ** -0.5)  # a weak scalar: q keeps its type
+    this block's rows may see with a running max, row sum and output.  With
+    tables (``half`` lanes a rotary half) q's block is rotated first, and so
+    is k's block of the same rows, into the scratch every walk reads, while
+    the head group's first head passes: a walk reads no key past its own
+    rows' block that the mask does not throw away."""
+    q0 = pl.multiple_of(pl.program_id(2) * bq, bq)
+    if half is None:
+        o_ref, = refs
+        q = q_ref[0]
+        keys = k_ref.at[0]
+    else:
+        c_ref, s_ref, o_ref, keys = refs
+        lane = jax.lax.broadcasted_iota(jnp.int32, (bq, LANES), 1)
+
+        def rotated(x):
+            # float32 here and rounded once, as rotate() does; the partner
+            # of lane j is j + half in the first half, j - half in the second
+            f = x.astype(jnp.float32)
+            partner = pltpu.roll(f, half, 1)
+            if 2 * half != LANES:
+                partner = jnp.where(lane < half,
+                                    pltpu.roll(f, LANES - half, 1), partner)
+            return (f * c_ref[...] + partner * s_ref[...]).astype(x.dtype)
+
+        q = rotated(q_ref[0])
+
+        @pl.when(pl.program_id(1) % group == 0)
+        def _():
+            keys[pl.ds(q0, bq), :] = rotated(k_ref[0, pl.ds(q0, bq), :])
+
+    q = q * (LANES ** -0.5)  # a weak scalar: q keeps its type
     rows = q0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
 
     def step(j, carry, masked: bool):
         m, l, acc = carry
         k0 = pl.multiple_of(j * bk, bk)
-        s = jax.lax.dot_general(q, k_ref[0, pl.ds(k0, bk), :],
+        s = jax.lax.dot_general(q, keys[pl.ds(k0, bk), :],
                                 (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         if masked:
@@ -277,7 +346,8 @@ def blocked_attention(q, k, v, n_heads: int, n_kv_heads: int,
                       window: Optional[int] = None,
                       block_q: Optional[int] = None,
                       block_k: Optional[int] = None,
-                      interpret: Optional[bool] = None):
+                      interpret: Optional[bool] = None,
+                      rotary=None):
     """Causal softmax attention with grouped heads of width 128, token-major.
 
     ``q``: ``[B, T, n_heads * 128]``; ``k``, ``v``: ``[B, T, n_kv_heads *
@@ -292,6 +362,16 @@ def blocked_attention(q, k, v, n_heads: int, n_kv_heads: int,
     diagonal and, with a window, inside the band.  T is padded to whole
     blocks: the padded keys come after every real row, and the padded rows
     are cut off.
+
+    ``rotary`` = ``(cos, sin)``, each ``[T, rot/2]`` float32 (``rot`` <=
+    128): q and k come unrotated and the kernel applies :func:`rotate`'s
+    arithmetic, the same roundings in the same order.  A step's q block is
+    one head's ``[block_q, 128]``, so the rotation is a lane roll and two
+    products against full-width tables (``_lane_tables``), whose row block
+    arrives with q's; k is rotated once a (batch row, key/value head), a
+    row block a step of the group's first head, into a VMEM scratch that
+    every walk reads in k's place.  The grid runs a batch row's heads and
+    row blocks in order for that (both ``arbitrary``).
     """
     b, t, _ = q.shape
     if interpret is None:
@@ -305,16 +385,28 @@ def blocked_attention(q, k, v, n_heads: int, n_kv_heads: int,
     group = n_heads // n_kv_heads
     itemsize = jnp.dtype(q.dtype).itemsize
     seen = tp * (tp + 1) // 2 if window is None else tp * min(window, tp)
+    q_spec = pl.BlockSpec((1, bq, LANES), lambda i, h, r: (i, r, h))
     kv_spec = pl.BlockSpec((1, tp, LANES), lambda i, h, r: (i, 0, h // group))
+    operands, in_specs = [q, k, v], [q_spec, kv_spec, kv_spec]
+    scratch, half = [], None
+    if rotary is not None:
+        half = rotary[0].shape[-1]
+        table_spec = pl.BlockSpec((bq, LANES), lambda i, h, r: (r, 0))
+        operands += _lane_tables(*rotary, tp)
+        in_specs += [table_spec, table_spec]
+        scratch = [pltpu.VMEM((tp, LANES), k.dtype)]
     out = pl.pallas_call(
-        functools.partial(_blocked_kernel, bq=bq, bk=bk, window=window),
+        functools.partial(_blocked_kernel, bq=bq, bk=bk, window=window,
+                          group=group, half=half),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         grid=(b, n_heads, tp // bq),
-        in_specs=[pl.BlockSpec((1, bq, LANES), lambda i, h, r: (i, r, h)),
-                  kv_spec, kv_spec],
-        out_specs=pl.BlockSpec((1, bq, LANES), lambda i, h, r: (i, r, h)),
+        in_specs=in_specs,
+        out_specs=q_spec,
+        scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel",
+                                 "parallel" if rotary is None else "arbitrary",
+                                 "arbitrary"),
             vmem_limit_bytes=VMEM_LIMIT),
         cost_estimate=pl.CostEstimate(
             flops=4 * b * n_heads * seen * LANES,
@@ -323,7 +415,7 @@ def blocked_attention(q, k, v, n_heads: int, n_kv_heads: int,
             * itemsize),
         interpret=interpret,
         name=BLOCKED_KERNEL_NAME,
-    )(q, k, v)
+    )(*operands)
     return out[:, :t] if tp != t else out
 
 
@@ -354,34 +446,41 @@ attention_p = Primitive("nns_full_attention")
 
 
 def attention(q, n_heads: int, causal: bool = False, k=None, v=None,
-              n_kv_heads: Optional[int] = None, window: Optional[int] = None):
+              n_kv_heads: Optional[int] = None, window: Optional[int] = None,
+              rotary=None):
     """Softmax attention, token-major.  Over the fused projection ``q`` =
     ``[B, T, 3*d]`` → ``[B, T, d]``, or with ``k`` and ``v`` over separate
     projections ``[B, T, n_heads*dh]`` and ``[B, T, n_kv_heads*dh]``
-    (grouped heads), ``window`` keys back under ``causal``; see the module's
+    (grouped heads), ``window`` keys back under ``causal``; with ``rotary``
+    = ``(cos, sin)``, each ``[T, rot/2]`` float32, q and k come unrotated
+    and the lowering applies :func:`rotate` to both.  See the module's
     docstring for which lowering a call gets."""
     if window is not None and not causal:
         raise ValueError("a window needs causal=True")
     if k is None:
-        if window is not None or n_kv_heads not in (None, n_heads):
-            raise ValueError("the fused projection has neither a window "
-                             "nor grouped heads")
+        if (window is not None or rotary is not None
+                or n_kv_heads not in (None, n_heads)):
+            raise ValueError("the fused projection has neither a window, "
+                             "grouped heads nor rotary tables")
         return attention_p.bind(q, n_heads=n_heads, n_kv_heads=n_heads,
                                 causal=causal, window=None)
-    return attention_p.bind(q, k, v, n_heads=n_heads,
+    return attention_p.bind(q, k, v, *(rotary or ()), n_heads=n_heads,
                             n_kv_heads=n_kv_heads or n_heads, causal=causal,
                             window=window)
 
 
-def _plain(*operands, n_heads, n_kv_heads, causal, window):
-    if len(operands) == 1:
-        return plain_attention(operands[0], n_heads, causal)
-    return plain_grouped_attention(*operands, n_heads, n_kv_heads, causal,
+def _plain(q, *rest, n_heads, n_kv_heads, causal, window):
+    if not rest:
+        return plain_attention(q, n_heads, causal)
+    k, v, *tables = rest
+    if tables:
+        q, k = rotate(q, *tables, n_heads), rotate(k, *tables, n_kv_heads)
+    return plain_grouped_attention(q, k, v, n_heads, n_kv_heads, causal,
                                    window)
 
 
-def _abstract(q, *kv, **_):
-    return q if kv else q.update(shape=(*q.shape[:-1], q.shape[-1] // 3))
+def _abstract(q, *rest, **_):
+    return q if rest else q.update(shape=(*q.shape[:-1], q.shape[-1] // 3))
 
 
 attention_p.def_impl(jax.jit(
@@ -390,20 +489,27 @@ attention_p.def_impl(jax.jit(
 attention_p.def_abstract_eval(_abstract)
 
 
-def _count_lowering(path: str) -> None:
+def _count(name: str, text: str, **label) -> None:
     from ..obs.metrics import REGISTRY
 
-    REGISTRY.counter(
-        "nnstpu_attention_lowerings_total",
-        "attention calls lowered into a program, by the path chosen (fused "
-        "= the whole-row Pallas kernel, blocked = the key-block walk with "
-        "grouped heads, plain = full_attention through XLA)",
-        labelnames=("path",),
-    ).inc(path=path)
+    REGISTRY.counter(name, text, labelnames=tuple(label)).inc(**label)
+
+
+def _count_lowering(path: str, rotary: Optional[str] = None) -> None:
+    _count("nnstpu_attention_lowerings_total",
+           "attention calls lowered into a program, by the path chosen (fused "
+           "= the whole-row Pallas kernel, blocked = the key-block walk with "
+           "grouped heads, plain = full_attention through XLA)", path=path)
+    if rotary is not None:
+        _count("nnstpu_attention_rotary_total",
+               "attention calls lowered with rotary tables, by where q and k "
+               "are rotated (kernel = on the blocked kernel's own blocks in "
+               "VMEM, outside = rotate() through XLA before the attention)",
+               where=rotary)
 
 
 def _lower_plain(ctx, *operands, **kw):
-    _count_lowering("plain")
+    _count_lowering("plain", "outside" if len(operands) == 5 else None)
     return mlir.lower_fun(functools.partial(_plain, **kw),
                           multiple_results=False)(ctx, *operands)
 
@@ -431,13 +537,15 @@ def _lower_tpu(ctx, *operands, n_heads, n_kv_heads, causal, window):
         return mlir.lower_fun(
             lambda a: fused_attention(a, n_heads, causal, interpret=False),
             multiple_results=False)(ctx, *operands)
-    if len(avals) == 3 and blocked_tiles(avals[0].shape, avals[1].shape,
-                                         avals[0].dtype, n_heads, n_kv_heads,
-                                         causal):
-        _count_lowering("blocked")
+    rotary = len(avals) == 5
+    if len(avals) >= 3 and blocked_tiles(
+            avals[0].shape, avals[1].shape, avals[0].dtype, n_heads,
+            n_kv_heads, causal, avals[3].shape if rotary else None):
+        _count_lowering("blocked", "kernel" if rotary else None)
         return mlir.lower_fun(
-            lambda q, k, v: blocked_attention(q, k, v, n_heads, n_kv_heads,
-                                              window, interpret=False),
+            lambda q, k, v, *tables: blocked_attention(
+                q, k, v, n_heads, n_kv_heads, window, interpret=False,
+                rotary=tables or None),
             multiple_results=False)(ctx, *operands)
     return _lower_plain(ctx, *operands, **kw)
 
@@ -455,11 +563,13 @@ def _jvp(primals, tangents, **kw):
 
 
 def _batch(args, dims, **kw):
-    # a mapped axis is more batch rows
-    moved = [jnp.moveaxis(a, d, 0) for a, d in zip(args, dims)]
+    # a mapped axis is more batch rows; rotary tables are every row's
+    if any(d is not None for d in dims[3:]):
+        raise NotImplementedError("attention: mapped rotary tables")
+    moved = [jnp.moveaxis(a, d, 0) for a, d in zip(args[:3], dims)]
     lead = moved[0].shape[:2]
     out = attention_p.bind(*(a.reshape(-1, *a.shape[2:]) for a in moved),
-                           **kw)
+                           *args[3:], **kw)
     return out.reshape(*lead, *out.shape[1:]), 0
 
 

@@ -381,9 +381,14 @@ class TestBusyDecay:
             return all(c.value == 0.0 for _, c in gauge.children())
 
         assert _wait_for(decayed, timeout=5.0)
-        # the decay collector removed itself once the window aged out
-        reg.collect()
-        assert tracer._busy_decay_handle is None
+        # the decay collector removes itself once the window has aged out:
+        # the gauge can read 0 a moment before that (the last interval ended
+        # before stop() set the deadline)
+        def removed():
+            reg.collect()
+            return tracer._busy_decay_handle is None
+
+        assert _wait_for(removed, timeout=5.0)
 
     def test_restart_replaces_leftover_decay_collector(self, monkeypatch):
         monkeypatch.setenv("NNSTPU_OBS_BUSY_WINDOW_S", "30")

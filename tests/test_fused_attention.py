@@ -24,8 +24,9 @@ from nnstreamer_tpu.parallel.mesh import make_mesh
 COUNTER = "nnstpu_attention_lowerings_total"
 
 
-def lowerings():
-    metric = REGISTRY.get(COUNTER)
+def lowerings(name=COUNTER):
+    """A one-label counter's values by label, nothing before its first count."""
+    metric = REGISTRY.get(name)
     if metric is None:
         return {}
     return {key[0]: int(child.value) for key, child in metric.children()}
@@ -380,30 +381,97 @@ def test_no_weight_argument_is_prefetched_across_program_runs(v5e_2x2):
 # Compile-only, beside the tower's above because one process describes the
 # topology: a second file of such tests could land on another worker.
 
-@pytest.mark.parametrize("heads,window", [(48, None), (64, 512)],
-                         ids=["full_attention", "sliding_attention"])
+def laguna_config():
+    from nnstreamer_tpu.models import laguna
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    return laguna.load_config(os.path.join(root, "benchmark", "configs",
+                                           "laguna_xs2_l5.json"))
+
+
+def rotary_counts():
+    return lowerings("nnstpu_attention_rotary_total")
+
+
+@pytest.mark.parametrize("rotary", [False, True], ids=["rotated_outside",
+                                                       "with_the_tables"])
+@pytest.mark.parametrize("heads,window,kind", [
+    (48, None, "full_attention"),        # rot 64 of 128, YaRN frequencies
+    (64, 512, "sliding_attention"),      # rot 128
+], ids=["full_attention", "sliding_attention"])
 def test_mosaic_compiles_the_blocked_kernel_at_16_windows_of_4096(
-        v5e_2x2, heads, window, counted):
+        v5e_2x2, heads, window, kind, rotary, counted):
     from jax.sharding import SingleDeviceSharding
+
+    from nnstreamer_tpu.models import laguna
 
     one = SingleDeviceSharding(v5e_2x2[0])
     q = jax.ShapeDtypeStruct((16, 4096, heads * 128), jnp.bfloat16, sharding=one)
     kv = jax.ShapeDtypeStruct((16, 4096, 8 * 128), jnp.bfloat16, sharding=one)
+    tables = (laguna.rotary_tables(laguna_config()["rope_parameters"][kind],
+                                   128, 4096) if rotary else None)
+    before = rotary_counts()
     compiled = jax.jit(lambda q, k, v: fa.attention(
-        q, heads, True, k=k, v=v, n_kv_heads=8, window=window)).lower(
-        q, kv, kv).compile()
+        q, heads, True, k=k, v=v, n_kv_heads=8, window=window,
+        rotary=tables)).lower(q, kv, kv).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and fa.BLOCKED_KERNEL_NAME in text
     assert counted() == {"blocked": 1}
+    assert (rotary_counts().get("kernel", 0)
+            == before.get("kernel", 0) + int(rotary))
+    assert rotary_counts().get("outside", 0) == before.get("outside", 0)
     # the scores never reach HBM: no temporary of the [16, heads, T, T] kind
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
 
 
+@pytest.mark.parametrize("layer", [0, 1], ids=["full_attention",
+                                               "sliding_attention"])
+def test_no_projection_goes_through_float32_or_a_view_by_heads(v5e_2x2, layer):
+    """``models/laguna.layer`` at the published widths (16 windows of 4096;
+    after the attention half a dense MLP of a width no projection has),
+    compiled for the chip: q and k
+    reach the kernel as the products left them.  Rotated through XLA, the
+    program held a float32 copy of each projection, a re-tiling of
+    ``[tokens, heads * 128]`` to ``[heads, 128]`` and arrays of half heads
+    (``f32[16,4096,8192]``, ``copy f32[8192,8,64,128]``,
+    ``bf16[16,4096,64,64]``, ``bf16[16,4096,48,32]``): 137 ms of the token
+    cell's 721 ms step (PERF.md)."""
+    import re
+
+    from jax.sharding import SingleDeviceSharding
+
+    from nnstreamer_tpu.models import laguna
+
+    cfg = dict(laguna_config(), mlp_layer_types=["dense"] * 5,
+               intermediate_size=1536)
+    heads = cfg["num_attention_heads_per_layer"][layer]
+    kind = cfg["layer_types"][layer]
+    assert (heads, kind) == ((48, "full_attention"),
+                             (64, "sliding_attention"))[layer]
+    one = SingleDeviceSharding(v5e_2x2[0])
+
+    def struct(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one)
+
+    d, ff = cfg["hidden_size"], cfg["intermediate_size"]
+    p = {"attn_norm": struct(d), "wq": struct(d, heads * 128),
+         "wk": struct(d, 1024), "wv": struct(d, 1024),
+         "wo": struct(heads * 128, d), "mlp_norm": struct(d),
+         "mlp": {"w_in": struct(d, 2 * ff), "w_out": struct(ff, d)}}
+    tables = {kind: laguna.rotary_tables(cfg["rope_parameters"][kind], 128,
+                                         4096)}
+    text = jax.jit(lambda p, x: laguna.layer(cfg, layer, p, x, tables)).lower(
+        p, struct(16, 4096, d)).compile().as_text()
+    assert fa.BLOCKED_KERNEL_NAME in text
+    assert f"bf16[16,4096,{heads * 128}]" in text
+    # no float32 array as wide as a projection, and no array whose trailing
+    # dims split the projections' columns into heads or half heads
+    assert not re.search(r"f32\[16,4096,(1024|6144|8192)\]", text)
+    assert not re.search(r"\[(\d+,)+(8|48|64),(32|64|128)\]", text)
+
+
 def moe_lowerings():
-    metric = REGISTRY.get("nnstpu_moe_lowerings_total")
-    if metric is None:
-        return {}
-    return {key[0]: int(child.value) for key, child in metric.children()}
+    return lowerings("nnstpu_moe_lowerings_total")
 
 
 def test_the_chip_compiles_the_grouped_expert_product_at_65536_x_8(v5e_2x2):

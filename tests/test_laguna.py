@@ -390,6 +390,186 @@ def test_what_the_blocked_kernel_does_not_tile_lowers_plain():
                      v=jnp.ones((1, 8, 128)), window=4)
 
 
+# -- rotary inside the lowering ------------------------------------------------
+
+def tables(rope, t):
+    return laguna.rotary_tables(rope, 128, t)
+
+
+ROTARY_CASES = {
+    # t, hq, hkv, window, bq, bk, rope
+    "the_whole_head_under_a_window": (300, 8, 1, 100, 128, 128, SLIDING),
+    "half_the_head_and_no_window": (300, 6, 1, None, 128, 128, FULL),
+    "two_key_value_heads_unequal_blocks": (640, 12, 2, 96, 256, 128, SLIDING),
+    "key_blocks_longer_than_row_blocks": (640, 12, 2, None, 128, 256, FULL),
+    "the_modules_own_blocks_t_padded": (700, 6, 1, None, None, None, FULL),
+}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", ROTARY_CASES)
+def test_the_blocked_kernel_rotates_as_rotate_does(case, dtype):
+    """The kernel given the tables against the kernel over ``rotate``d q
+    and k: the same roundings in the same order.  Op by op the two
+    arithmetics are equal to the bit (the test below); inside a compiled
+    program XLA's CPU backend contracts ``a * c + b * s`` to a fused
+    multiply-add in one place and not in another, one float32 step apart,
+    which moves one bfloat16 rounding in some ten thousand."""
+    t, hq, hkv, window, bq, bk, rope = ROTARY_CASES[case]
+    q, k, v = qkv(t, hq, hkv, jnp.dtype(dtype))
+    cos, sin = tables(rope, t)
+    kernel = functools.partial(fa.blocked_attention, n_heads=hq,
+                               n_kv_heads=hkv, window=window, block_q=bq,
+                               block_k=bk, interpret=True)
+    with jax.default_matmul_precision("highest"):
+        got = np.asarray(jax.jit(functools.partial(
+            kernel, rotary=(cos, sin)))(q, k, v), np.float32)
+        rotated = lambda q, k: (fa.rotate(q, cos, sin, hq),
+                                fa.rotate(k, cos, sin, hkv))
+        want = np.asarray(jax.jit(lambda q, k, v: kernel(*rotated(q, k), v))(
+            q, k, v), np.float32)
+        ref = np.asarray(masked_reference(*rotated(q, k), v, hq, hkv, window),
+                         np.float32)
+    assert got.shape == q.shape
+    if dtype == "float32":
+        assert np.abs(got - want).max() < 2e-6
+        assert np.abs(got - ref).max() < 2e-5
+    else:
+        assert (got != want).mean() < 1e-2
+        assert np.abs(got - want).max() <= 2 ** -7 * np.abs(want).max()
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("rope", [SLIDING, FULL], ids=["rot_128", "rot_64"])
+def test_the_lane_tables_are_rotates_arithmetic_to_the_bit(rope, dtype):
+    """``x * C + partner * S`` over whole 128-lane heads, evaluated op by op
+    as the kernel writes it (``jnp.roll`` for Mosaic's lane roll)."""
+    t, heads = 40, 3
+    x = jax.random.normal(jax.random.PRNGKey(9), (2, t, heads * 128),
+                          jnp.dtype(dtype))
+    cos, sin = tables(rope, t)
+    half = cos.shape[-1]
+    c, s = fa._lane_tables(cos, sin, 48)
+    assert c.shape == s.shape == (48, 128) and c.dtype == jnp.float32
+    assert not np.asarray(c[t:]).any() and not np.asarray(s[t:]).any()
+    with jax.disable_jit():
+        h = x.reshape(2, t, heads, 128).astype(jnp.float32)
+        partner = jnp.where(jnp.arange(128) < half,
+                            jnp.roll(h, 128 - half, -1), jnp.roll(h, half, -1))
+        got = (h * c[:t, None] + partner * s[:t, None]).astype(x.dtype)
+        want = fa.rotate(x, cos, sin, heads)
+    assert np.array_equal(np.asarray(got.reshape(x.shape), np.float32),
+                          np.asarray(want, np.float32))
+
+
+def rotary_counts():
+    return tuple(counted(name, label) for name, label in (
+        ("nnstpu_attention_rotary_total", "kernel"),
+        ("nnstpu_attention_rotary_total", "outside"),
+        ("nnstpu_attention_lowerings_total", "blocked"),
+        ("nnstpu_attention_lowerings_total", "plain")))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("rope,window", [(SLIDING, 64), (FULL, None)],
+                         ids=["rot_128_window", "rot_64"])
+def test_a_call_with_tables_lowers_plain_here_and_is_rotate_then_attention(
+        rope, window, dtype):
+    """On this host the primitive with tables is ``rotate`` on q and k and
+    the plain path, as ``models/laguna.layer`` wrote it out before: the same
+    bits."""
+    q, k, v = qkv(96, 6, 2, jnp.dtype(dtype))
+    cos, sin = tables(rope, 96)
+    before = rotary_counts()
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(lambda q, k, v: fa.attention(
+            q, 6, True, k=k, v=v, n_kv_heads=2, window=window,
+            rotary=(cos, sin)))(q, k, v)
+        assert rotary_counts() == (before[0], before[1] + 1, before[2],
+                                   before[3] + 1)
+        want = jax.jit(lambda q, k, v: fa.attention(
+            fa.rotate(q, cos, sin, 6), 6, True, k=fa.rotate(k, cos, sin, 2),
+            v=v, n_kv_heads=2, window=window))(q, k, v)
+    # a call without tables counts no rotation
+    assert rotary_counts() == (before[0], before[1] + 1, before[2],
+                               before[3] + 2)
+    assert got.dtype == q.dtype
+    assert np.array_equal(np.asarray(got, np.float32),
+                          np.asarray(want, np.float32))
+
+
+def test_rotations_are_counted_by_where_they_run():
+    """One trace lowered for a TPU rotates in the kernel, for this host
+    outside; heads the kernel does not tile (96 wide) go outside on a TPU
+    too, and the fused projection takes no tables at all."""
+    q, k, v = qkv(256, 6, 1, jnp.bfloat16, b=1)
+    cos, sin = tables(FULL, 256)
+    call = lambda q, k, v: jax.jit(lambda q, k, v: fa.attention(
+        q, 6, True, k=k, v=v, n_kv_heads=1, window=64,
+        rotary=(cos, sin))).trace(q, k, v)
+    traced = call(q, k, v)
+    assert str(traced.jaxpr).count("nns_full_attention") == 1
+    before = rotary_counts()
+    on_tpu = traced.lower(lowering_platforms=("tpu",)).as_text()
+    assert fa.BLOCKED_KERNEL_NAME in on_tpu
+    assert rotary_counts() == (before[0] + 1, before[1], before[2] + 1,
+                               before[3])
+    assert "tpu_custom_call" not in traced.lower(
+        lowering_platforms=("cpu",)).as_text()
+    assert rotary_counts() == (before[0] + 1, before[1] + 1, before[2] + 1,
+                               before[3] + 1)
+    narrow = call(q[..., :6 * 96], k[..., :96], v[..., :96])
+    assert "tpu_custom_call" not in narrow.lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert rotary_counts() == (before[0] + 1, before[1] + 2, before[2] + 1,
+                               before[3] + 2)
+    with pytest.raises(ValueError):
+        fa.attention(jnp.ones((1, 8, 384)), 1, True, rotary=(cos, sin))
+
+
+def test_tables_count_in_what_the_blocked_kernel_tiles():
+    shapes = ((16, 4096, 48 * 128), (16, 4096, 8 * 128), jnp.bfloat16, 48, 8,
+              True)
+    assert fa.blocked_tiles(*shapes, (4096, 32))
+    assert fa.blocked_tiles(*shapes, (4096, 64))
+    assert not fa.blocked_tiles(*shapes, (4096, 96))   # rot past the head
+    assert not fa.blocked_tiles(*shapes, (2048, 64))   # not every position
+    long = ((1, 10240, 128), (1, 10240, 128), jnp.bfloat16, 1, 1, True)
+    assert fa.blocked_tiles(*long)                      # K and V fit alone
+    assert not fa.blocked_tiles(*long, (10240, 64))     # not with K's scratch
+
+
+def test_tables_are_every_mapped_rows_and_derivatives_the_plain_paths():
+    q, k, v = qkv(48, 4, 2, b=3)
+    cos, sin = tables(SLIDING, 48)
+
+    def layer(q, k, v, rotary=(cos, sin)):
+        return fa.attention(q, 4, True, k=k, v=v, n_kv_heads=2, window=16,
+                            rotary=rotary)
+
+    def written_out(q, k, v):
+        return fa.plain_grouped_attention(
+            fa.rotate(q, cos, sin, 4), fa.rotate(k, cos, sin, 2), v, 4, 2,
+            True, 16)
+
+    stacked = [jnp.stack([a, a[::-1]], 1) for a in (q, k, v)]
+    mapped = jax.vmap(layer, in_axes=1, out_axes=1)(*stacked)
+    np.testing.assert_allclose(np.asarray(mapped[:, 0]),
+                               np.asarray(layer(q, k, v)), atol=1e-6)
+    np.testing.assert_allclose(np.asarray(mapped[:, 1]),
+                               np.asarray(layer(*(a[::-1] for a in (q, k, v)))),
+                               atol=1e-6)
+    with pytest.raises(NotImplementedError):
+        jax.vmap(lambda c: layer(q, k, v, (c, sin)))(jnp.stack([cos, cos]))
+    got = jax.grad(lambda *a: (layer(*a) ** 2).sum(), argnums=(0, 1, 2))(
+        q, k, v)
+    want = jax.grad(lambda *a: (written_out(*a) ** 2).sum(),
+                    argnums=(0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        assert np.abs(np.asarray(w)).max() > 0
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=1e-6)
+
+
 # -- the streaming path -------------------------------------------------------
 
 def test_token_frames_through_a_launch_string_at_batch_n_equal_n_single(tmp_path):
