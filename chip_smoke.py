@@ -555,6 +555,41 @@ class Smoke:
             * pair_w[:, None])
         np.testing.assert_allclose(got, want, rtol=2e-2, atol=2e-2)
         out["grouped_experts_max_abs_err"] = float(np.abs(got - want).max())
+        # the selection and the attention under it (ops/sparse_attention):
+        # both kernels against the plain walks, the selection bit for bit
+        from nnstreamer_tpu.ops import sparse_attention as sa
+
+        t, h, dn, dr, dv, hi, top = ((256, 2, 12, 4, 16, 2, 8)
+                                     if self.rehearsal
+                                     else (2048, 4, 192, 64, 256, 4, 256))
+
+        def bf16(*shape):
+            return jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+
+        q_i, k_i = bf16(2, t, hi * 128), bf16(2, t, 128)
+        w_i = jnp.asarray(rng.standard_normal((2, t, hi)), jnp.float32)
+        want = np.asarray(jax.jit(lambda *a: sa._select(*a, top_k=top))(
+            q_i, k_i, w_i))
+        got = np.asarray(jax.jit(lambda *a: sa.index_select(
+            *a, top, interpret=interpret))(q_i, k_i, w_i))
+        # the kernel's products and XLA's need not round alike: a key at
+        # the edge may fall the other way, and no more
+        out["selection_keys_apart"] = int((got != want).sum())
+        check(out["selection_keys_apart"] <= want.sum() // 1000,
+              "nns_index_select selects other keys than the plain walk")
+        mask = jnp.asarray(want)
+        q, k_n = bf16(2, t, h * (dn + dr)), bf16(2, t, h * dn)
+        k_r, v = bf16(2, t, dr), bf16(2, t, h * dv)
+        got = np.asarray(jax.jit(lambda *a: sa.sparse_attention_kernel(
+            a[0], sa._head_keys(a[1], a[2], h), a[3], a[4], h,
+            interpret=interpret))(q, k_n, k_r, v, mask).astype(jnp.float32))
+        with jax.default_matmul_precision("highest"):
+            want = np.asarray(jax.jit(lambda *a: sa._plain(
+                *a, n_heads=h))(*(a.astype(jnp.float32)
+                                  for a in (q, k_n, k_r, v)), mask))
+        np.testing.assert_allclose(got, want, rtol=2e-2, atol=2e-2)
+        out["latent_sparse_attention_max_abs_err"] = float(
+            np.abs(got - want).max())
         out["compiled_by"] = "interpreter" if interpret else "mosaic"
         return out
 

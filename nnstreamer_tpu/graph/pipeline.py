@@ -563,7 +563,17 @@ class Pipeline:
                     t.daemon = True
                     self.threads.append(t)
                     t.start()
-        for node in self.nodes.values():
+        # Sources start last-added first.  Of the sources that feed one
+        # collect element the last to start completes the first round, and
+        # the thread that completes a round carries it to the sinks, so it
+        # is back first and completes the next one as well: started in this
+        # order, the carrier of every round is the source on the element's
+        # first pad.  A demux hands that stream its answer first, so every
+        # other stream has its answer, and sends its next frame, after the
+        # carrier's (in the other order the first pad's stream sends its
+        # next frame within microseconds of the round's last answer, before
+        # it in one run and after it in the next).
+        for node in reversed(list(self.nodes.values())):
             if isinstance(node, SourceNode):
                 if _hooks.enabled:
                     _hooks.emit("source_spawn", self, node)

@@ -1,5 +1,5 @@
 """Built-in model zoo: MobileNet-v2 labeling, SSD-MobileNet boxes, PoseNet
 heatmaps, LSTM recurrence, batched multi-stream classification, and the
-benchmark's two configurations (``vit``, ``laguna``)."""
+benchmark's three configurations (``vit``, ``laguna``, ``glm_dsa``)."""
 
 from . import audio_cnn, lstm, mobilenet_v2, posenet, ssd_mobilenet, transformer  # noqa: F401

@@ -173,17 +173,15 @@ def build(config, seq: int, batch: Optional[int] = None,
     )
 
 
-def build_quantized(**kwargs) -> JaxModel:
-    """The step below bfloat16: the projections, the dense MLP, the shared
-    experts and the head W8A8 (``ops/quant``); the routed experts and the
-    router stay as they are.  Takes :func:`build`'s kwargs."""
+def quantize_weights(params, keep=("router", "embed")):
+    """``params`` with every 2-D weight W8A8 (``ops/quant``) but the
+    subtrees under the keys in ``keep`` and the routed experts (a ``moe``
+    dict's own ``w_in`` / ``w_out``)."""
     from ..ops.quant import quantize_weight
-
-    model = build(**kwargs)
 
     def walk(p, inside_moe=False):
         if isinstance(p, dict):
-            return {k: (v if k in ("router", "embed") or
+            return {k: (v if k in keep or
                         (inside_moe and k in ("w_in", "w_out"))
                         else walk(v, k == "moe"))
                     for k, v in p.items()}
@@ -191,5 +189,13 @@ def build_quantized(**kwargs) -> JaxModel:
             return [walk(v) for v in p]
         return quantize_weight(p) if getattr(p, "ndim", 0) == 2 else p
 
-    model.params = walk(model.params)
+    return walk(params)
+
+
+def build_quantized(**kwargs) -> JaxModel:
+    """The step below bfloat16: the projections, the dense MLP, the shared
+    experts and the head W8A8 (``ops/quant``); the routed experts and the
+    router stay as they are.  Takes :func:`build`'s kwargs."""
+    model = build(**kwargs)
+    model.params = quantize_weights(model.params)
     return model

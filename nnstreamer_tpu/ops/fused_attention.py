@@ -202,20 +202,24 @@ def _round_up(n: int, to: int) -> int:
     return -(-n // to) * to
 
 
-def rotate(x, cos, sin, n_heads: int):
-    """Rotary embedding on ``[B, T, n_heads * dh]``: the first ``rot`` dims
-    of each head as two halves ``(a, b)`` → ``(a cos - b sin, b cos + a
+def rotate(x, cos, sin, n_heads: int, at: int = 0):
+    """Rotary embedding on ``[B, T, n_heads * dh]``: each head's dims ``at
+    ... at + rot`` as two halves ``(a, b)`` → ``(a cos - b sin, b cos + a
     sin)``, the rest passed through; ``cos``, ``sin``: ``[T, rot/2]``
     float32.  Float32 inside and rounded to ``x``'s type once: through XLA
-    that is a float32 copy of ``x`` in HBM, which is why the blocked kernel
-    does the same arithmetic on its own blocks."""
+    that is a pass over ``x`` in HBM and a re-tiling of it by head, which is
+    why the blocked kernel does the same arithmetic on its own blocks."""
     b, t, _ = x.shape
     half = cos.shape[-1]
-    h = x.reshape(b, t, n_heads, -1).astype(jnp.float32)
-    a, bb, rest = h[..., :half], h[..., half:2 * half], h[..., 2 * half:]
+    h = x.reshape(b, t, n_heads, -1)
+    # only the halves turn to float32: a cast of all of h is one more copy
+    a, bb = (h[..., at + i * half:at + (i + 1) * half].astype(jnp.float32)
+             for i in (0, 1))
     cos, sin = cos[None, :, None, :], sin[None, :, None, :]
-    out = jnp.concatenate([a * cos - bb * sin, bb * cos + a * sin, rest], -1)
-    return out.reshape(x.shape).astype(x.dtype)
+    out = jnp.concatenate(
+        [h[..., :at], (a * cos - bb * sin).astype(x.dtype),
+         (bb * cos + a * sin).astype(x.dtype), h[..., at + 2 * half:]], -1)
+    return out.reshape(x.shape)
 
 
 def blocked_tiles(q_shape, kv_shape, dtype, n_heads: int, n_kv_heads: int,
@@ -499,7 +503,10 @@ def _count_lowering(path: str, rotary: Optional[str] = None) -> None:
     _count("nnstpu_attention_lowerings_total",
            "attention calls lowered into a program, by the path chosen (fused "
            "= the whole-row Pallas kernel, blocked = the key-block walk with "
-           "grouped heads, plain = full_attention through XLA)", path=path)
+           "grouped heads, plain = full_attention through XLA; "
+           "ops/sparse_attention: latent_sparse = the selected-keys kernel, "
+           "latent_sparse_plain = its walk through XLA, index_select[_plain] "
+           "= a selection's scoring pass)", path=path)
     if rotary is not None:
         _count("nnstpu_attention_rotary_total",
                "attention calls lowered with rotary tables, by where q and k "

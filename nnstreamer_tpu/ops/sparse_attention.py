@@ -1,0 +1,511 @@
+"""Latent attention under a learned selection of keys.
+
+Two ops of one mechanism (multi-head latent attention whose softmax runs
+over the ``top_k`` keys a small second attention, the *indexer*, scores
+highest for each query):
+
+- :func:`select_keys`: the indexer's scores ``I[t, s] = sum_h w[t, h] *
+  relu(q_I[t, h] . k_I[s])`` over the causal pairs, float32, and of each
+  query the ``top_k`` highest keys (every causal key while ``t < top_k``;
+  among equal scores the earlier key), as a ``[B, T, T]`` int8 mask.  The
+  mask is what later layers that share the selection are handed;
+- :func:`latent_sparse_attention`: per head ``softmax_{s in S_t}((q_n . k_n
+  + q_r . k_r) / sqrt(d)) v`` with the rotary key part ``k_r`` common to
+  every head, over exactly the selected keys.
+
+Both walk the queries in row blocks, so that neither the ``T x T`` scores
+nor a gather of the selected rows is ever whole in HBM.  The attention is
+computed in the per-head form under the mask (every causal key block is
+read, the unselected keys masked out of the softmax), not over gathered
+latent rows: gathering ``top_k`` rows of 1152 bytes a query is 77 GB a
+layer at two windows of 16 k tokens, and a per-row gather runs at a quarter
+of the chip's bandwidth (PERF.md).
+
+:func:`latent_sparse_attention` is one primitive, like
+``fused_attention.attention``: a one-device TPU program whose shapes
+:func:`sparse_tiles` admits lowers the Pallas kernel
+``nns_latent_sparse_attention`` (key blocks of the causal half streamed
+past a resident query block with a running softmax, the mask block beside
+them), every other program (the CPU's, one GSPMD partitions, odd shapes)
+the plain walk through XLA.  :func:`select_keys` is a primitive of the same
+kind: on the same condition the Pallas kernel ``nns_index_select`` scores a
+block of query rows (the heads' products, ReLU and weighted sum in VMEM) and
+selects there too, finding each row's ``top_k``-th highest score a bit at a
+time by counting instead of sorting, so that neither the per-head scores
+nor the summed ones reach HBM; elsewhere XLA's products and ``lax.top_k``.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.extend.core import Primitive
+from jax.interpreters import mlir
+
+from .fused_attention import (MASKED, _count, _count_lowering,
+                              _on_one_device)
+from .pallas_kernels import LANES, _interpret
+
+KERNEL_NAME = "nns_latent_sparse_attention"
+INDEX_KERNEL_NAME = "nns_index_select"
+# Query rows and key rows a grid step of the attention kernel takes: on the
+# v5e, one window of 16 384 tokens and 64 heads of 256, 1024 x 1024 ran in
+# 64.8 ms, 2048 x 512 64.9, 1024 x 512 68.2, 512 x 1024 70.4, 512 x 512 77.4
+# and 256 x 512 108.3 (PERF.md).  And the rows the walks through XLA take.
+BLOCK_Q = 1024
+BLOCK_K = 1024
+SELECT_ROWS = 512
+# The selection kernel: query rows a grid step scores and selects for, the
+# keys it scores at a time; its VMEM holds a window's indexer keys twice,
+# the rows' scores against every key once and their one-byte selection twice
+# (4 + 4 + 16 + 8 MiB at 16 k tokens).
+INDEX_ROWS = 256
+INDEX_BLOCK_K = 1024
+VMEM_LIMIT = 64 * 2 ** 20
+INDEX_VMEM_LIMIT = 100 * 2 ** 20
+
+
+def row_blocks(t: int, rows: int) -> int:
+    """How many blocks of ``rows`` query rows a walk over ``t`` takes: one
+    where ``t`` is no whole number of them."""
+    return t // rows if t > rows and t % rows == 0 else 1
+
+
+# -- the selection -----------------------------------------------------------
+
+def _index_scores_plain(q_i, k_i, w):
+    """One block of queries against every key: ``q_i`` ``[R, H, D]``, ``k_i``
+    ``[T, D]``, ``w`` ``[R, H]`` float32 -> ``[R, T]`` float32."""
+    s = jnp.einsum("rhd,sd->rhs", q_i, k_i,
+                   preferred_element_type=jnp.float32)
+    return (jax.nn.relu(s) * w[:, :, None]).sum(axis=1)
+
+
+INT_MIN = -2 ** 31
+
+
+def _ordered(x):
+    """float32 -> int32 whose signed order is the floats' order."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+    return jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
+
+
+def _select_kernel(q_ref, k_ref, w_ref, o_ref, keys_ref, *, heads: int,
+                   width: int, top_k: int, rows: int, bk: int):
+    """One block of query rows: its scores against every key block that
+    holds a causal key, kept in VMEM as order-preserving int32; each row's
+    ``top_k``-th highest found a bit at a time by counting (31 passes over
+    the block's scores, no sort), the earliest of its equals by counting
+    columns the same way; the selection written as int8."""
+    row0 = pl.program_id(1) * rows
+    blocks = k_ref.shape[1]
+    live = (row0 + rows - 1) // bk + 1      # key blocks with a causal key
+    w = w_ref[0]
+    row = row0 + jax.lax.broadcasted_iota(jnp.int32, (rows, bk), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, bk), 1)
+
+    def score(j, carry):
+        k = k_ref[0, j]
+        acc = jnp.zeros((rows, bk), jnp.float32)
+        for h in range(heads):  # static: a head is a lane tile of the block
+            s = jax.lax.dot_general(q_ref[0, :, h * width:(h + 1) * width], k,
+                                    (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            acc = acc + jnp.maximum(s, 0.0) * w[:, h:h + 1]
+        acc = jnp.where(acc == 0, 0.0, acc)  # one zero, as _selected()
+        keys_ref[j] = _ordered(jnp.where(j * bk + lane <= row, acc, -jnp.inf))
+        return carry
+
+    jax.lax.fori_loop(0, live, score, 0)
+
+    def count(holds):
+        """Per row, the keys of the live blocks that ``holds(key, j)``."""
+        def body(j, acc):
+            p = holds(keys_ref[j], j).astype(jnp.float32)
+            return acc + sum(p[:, c * LANES:(c + 1) * LANES]
+                             for c in range(bk // LANES))
+
+        return jax.lax.fori_loop(
+            0, live, body, jnp.zeros((rows, LANES), jnp.float32)
+        ).sum(axis=-1, keepdims=True)
+
+    k_f = jnp.float32(top_k)
+    # the top_k-th highest key: the sign, then 31 bits from the top down
+    # (INT_MIN, below every key, where the live blocks hold fewer than top_k)
+    least = jnp.where(count(lambda key, j: key >= 0) >= k_f,
+                      jnp.int32(0), jnp.int32(INT_MIN))
+
+    def value_bit(b, least):
+        cand = least | jnp.left_shift(jnp.int32(1), 30 - b)
+        return jnp.where(count(lambda key, j: key >= cand) >= k_f, cand,
+                         least)
+
+    least = jax.lax.fori_loop(0, 31, value_bit, least)
+    # of its equals the earliest ``need``: the column of the last one taken
+    need = k_f - count(lambda key, j: key > least)
+    bits = max(1, (blocks * bk - 1).bit_length())
+
+    def column_bit(b, last):
+        cand = last | jnp.left_shift(jnp.int32(1), bits - 1 - b)
+        before = count(lambda key, j: (key == least)
+                       & (j * bk + lane < cand))
+        return jnp.where(before < need, cand, last)
+
+    last = jax.lax.fori_loop(0, bits, column_bit,
+                             jnp.zeros((rows, 1), jnp.int32))
+    for j in range(blocks):  # static: the output's columns
+        at = slice(j * bk, (j + 1) * bk)
+
+        @pl.when(j < live)
+        def _(j=j, at=at):
+            key, col = keys_ref[j], j * bk + lane
+            taken = (key > least) | ((key == least) & (col <= last))
+            o_ref[0, :, at] = (taken & (col <= row)).astype(jnp.int8)
+
+        @pl.when(j >= live)
+        def _(at=at):
+            o_ref[0, :, at] = jnp.zeros((rows, bk), jnp.int8)
+
+
+def index_select(q_i, k_i, w, top_k: int, rows: Optional[int] = None,
+                 block_k: Optional[int] = None,
+                 interpret: Optional[bool] = None):
+    """:func:`select_keys` as one kernel: ``q_i`` ``[B, T, H * D]``, ``k_i``
+    ``[B, T, D]``, ``w`` ``[B, T, H]`` float32 -> ``[B, T, T]`` int8.  A grid
+    step takes a block of query rows against a window's resident keys;
+    neither the per-head scores nor the summed ones reach HBM."""
+    b, t, _ = q_i.shape
+    width = k_i.shape[-1]
+    heads = q_i.shape[-1] // width
+    rows, bk = min(rows or INDEX_ROWS, t), min(block_k or INDEX_BLOCK_K, t)
+    if interpret is None:
+        interpret = _interpret()
+    return pl.pallas_call(
+        functools.partial(_select_kernel, heads=heads, width=width,
+                          top_k=top_k, rows=rows, bk=bk),
+        out_shape=jax.ShapeDtypeStruct((b, t, t), jnp.int8),
+        grid=(b, t // rows),
+        in_specs=[
+            pl.BlockSpec((1, rows, heads * width), lambda i, r: (i, r, 0)),
+            pl.BlockSpec((1, t // bk, bk, width), lambda i, r: (i, 0, 0, 0)),
+            pl.BlockSpec((1, rows, heads), lambda i, r: (i, r, 0))],
+        out_specs=pl.BlockSpec((1, rows, t), lambda i, r: (i, r, 0)),
+        scratch_shapes=[pltpu.VMEM((t // bk, rows, bk), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=INDEX_VMEM_LIMIT),
+        cost_estimate=pl.CostEstimate(
+            flops=b * t * (t + 1) * heads * width, transcendentals=0,
+            bytes_accessed=q_i.size * q_i.dtype.itemsize
+            + k_i.size * k_i.dtype.itemsize + b * t * t),
+        interpret=interpret,
+        name=INDEX_KERNEL_NAME,
+    )(q_i, k_i.reshape(b, t // bk, bk, width), w)
+
+
+def index_tiles(q_shape, k_shape, dtype, top_k: int) -> bool:
+    """Whether :func:`index_select` is the lowering: heads of whole lane
+    tiles, bf16 or f32, whole blocks of rows and of keys, a selection that
+    cuts."""
+    dtype = jnp.dtype(dtype)
+    t, width = k_shape[-2], k_shape[-1]
+    return (dtype in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32))
+            and width % LANES == 0 and q_shape[-1] % width == 0
+            and top_k < t and t % INDEX_ROWS == 0 and t % INDEX_BLOCK_K == 0)
+
+
+def _selected(scores, row0, top_k: int):
+    """The ``[R, T]`` int8 mask of one block of queries ``row0 ...``: of each
+    row's causal scores the ``top_k`` highest, the earlier key among equals
+    (``lax.top_k``'s order: what lies above the last value taken, and of
+    its equals those up to the last index taken)."""
+    r, t = scores.shape
+    rows = row0 + jnp.arange(r, dtype=jnp.int32)[:, None]
+    cols = jnp.arange(t, dtype=jnp.int32)[None, :]
+    causal = cols <= rows
+    if top_k >= t:
+        return causal.astype(jnp.int8)
+    # one zero: a sort may tell -0.0 from 0.0, and "equal" has to mean equal
+    scores = jnp.where(causal, jnp.where(scores == 0, 0.0, scores), -jnp.inf)
+    top, at = jax.lax.top_k(scores, top_k)
+    least, last = top[:, -1:], at[:, -1:]
+    taken = (scores > least) | ((scores == least) & (cols <= last))
+    return (taken & causal).astype(jnp.int8)
+
+
+def _select(q_i, k_i, w, *, top_k: int):
+    """Through XLA: a block of query rows at a time, the per-head scores
+    through HBM, ``lax.top_k`` (a sort of each row with its indices)."""
+    b, t, _ = q_i.shape
+    width = k_i.shape[-1]
+    heads = q_i.shape[-1] // width
+    blocks = row_blocks(t, SELECT_ROWS)
+    rows = t // blocks
+    w = w.astype(jnp.float32)
+
+    def window(q_w, k_w, w_w):
+        def block(i):
+            row0 = i * rows
+            q_b = jax.lax.dynamic_slice_in_dim(q_w, row0, rows)
+            w_b = jax.lax.dynamic_slice_in_dim(w_w, row0, rows)
+            scores = _index_scores_plain(q_b.reshape(rows, heads, width),
+                                         k_w, w_b)
+            return _selected(scores, row0, top_k)
+
+        return jax.lax.map(
+            block, jnp.arange(blocks, dtype=jnp.int32)).reshape(t, t)
+
+    return jax.lax.map(lambda a: window(*a), (q_i, k_i, w))
+
+
+select_keys_p = Primitive("nns_select_keys")
+
+
+def select_keys(q_i, k_i, w, top_k: int):
+    """The indexer's choice: ``q_i`` ``[B, T, H * D]`` and ``k_i`` ``[B, T,
+    D]`` (one key head, both rotated), ``w`` ``[B, T, H]`` the heads'
+    weights.  Returns ``[B, T, T]`` int8, 1 where key ``s`` is among query
+    ``t``'s ``top_k`` highest causal scores."""
+    return select_keys_p.bind(q_i, k_i, w, top_k=top_k)
+
+
+select_keys_p.def_impl(jax.jit(select_keys_p.bind, static_argnames=("top_k",)))
+select_keys_p.def_abstract_eval(
+    lambda q_i, *_, **__: q_i.update(shape=(*q_i.shape[:2], q_i.shape[1]),
+                                     dtype=jnp.dtype(jnp.int8)))
+
+
+def _count_indexer(role: str) -> None:
+    _count("nnstpu_indexer_lowerings_total",
+           "attention layers lowered into a program by where their key "
+           "selection comes from (full = the layer's own indexer scores and "
+           "selects, shared = it reuses an earlier layer's selection)",
+           role=role)
+
+
+def _lower_select(ctx, *operands, top_k):
+    _count_indexer("full")
+    _count_lowering("index_select_plain")
+    return mlir.lower_fun(functools.partial(_select, top_k=top_k),
+                          multiple_results=False)(ctx, *operands)
+
+
+def _lower_select_tpu(ctx, *operands, top_k):
+    q_i, k_i, _ = ctx.avals_in
+    if not (_on_one_device(ctx.module_context.axis_context)
+            and q_i.dtype == k_i.dtype
+            and index_tiles(q_i.shape, k_i.shape, q_i.dtype, top_k)):
+        return _lower_select(ctx, *operands, top_k=top_k)
+    _count_indexer("full")
+    _count_lowering("index_select")
+    return mlir.lower_fun(
+        lambda q_i, k_i, w: index_select(q_i, k_i, w.astype(jnp.float32),
+                                         top_k, interpret=False),
+        multiple_results=False)(ctx, *operands)
+
+
+mlir.register_lowering(select_keys_p, _lower_select, cacheable=False)
+mlir.register_lowering(select_keys_p, _lower_select_tpu, platform="tpu",
+                       cacheable=False)
+
+
+shared_selection_p = Primitive("nns_shared_selection")
+
+
+def shared_selection(mask):
+    """``mask`` as a later layer takes it over from the layer that selected:
+    the identity, there to be counted where programs are lowered."""
+    return shared_selection_p.bind(mask)
+
+
+def _lower_shared(ctx, mask):
+    _count_indexer("shared")
+    return [mask]
+
+
+shared_selection_p.def_impl(lambda mask: mask)
+shared_selection_p.def_abstract_eval(lambda mask: mask)
+mlir.register_lowering(shared_selection_p, _lower_shared, cacheable=False)
+
+
+# -- the attention -----------------------------------------------------------
+
+def _head_keys(k_nope, k_rope, n_heads: int):
+    """``[B, T, H * (dn + dr)]``: each head's own part beside the part every
+    head shares."""
+    b, t, _ = k_nope.shape
+    shared = jnp.broadcast_to(k_rope[:, :, None, :],
+                              (b, t, n_heads, k_rope.shape[-1]))
+    return jnp.concatenate([k_nope.reshape(b, t, n_heads, -1), shared],
+                           axis=-1).reshape(b, t, -1)
+
+
+def _plain(q, k_nope, k_rope, v, mask, *, n_heads: int):
+    """Through XLA: a block of query rows against every key, the mask laid
+    over the scores, a whole-row softmax."""
+    b, t, _ = q.shape
+    k = _head_keys(k_nope, k_rope, n_heads).reshape(b, t, n_heads, -1)
+    vh = v.reshape(b, t, n_heads, -1)
+    scale = k.shape[-1] ** -0.5
+    blocks = row_blocks(t, SELECT_ROWS)
+    rows = t // blocks
+
+    def block(args):
+        q_b, m_b = args  # [b, rows, H * d], [b, rows, t]
+        s = jnp.einsum("brhd,bshd->bhrs", q_b.reshape(b, rows, n_heads, -1),
+                       k, preferred_element_type=jnp.float32) * scale
+        p = jax.nn.softmax(jnp.where(m_b[:, None] != 0, s, -jnp.inf), axis=-1)
+        return jnp.einsum("bhrs,bshd->brhd", p.astype(v.dtype), vh,
+                          preferred_element_type=jnp.float32
+                          ).astype(q.dtype).reshape(b, rows, -1)
+
+    def split(a):  # [b, t, x] -> [blocks, b, rows, x]
+        return jnp.moveaxis(a.reshape(b, blocks, rows, -1), 1, 0)
+
+    out = jax.lax.map(block, (split(q), split(mask)))
+    return jnp.moveaxis(out, 0, 1).reshape(b, t, -1)
+
+
+def _sparse_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, m_ref, l_ref,
+                   acc_ref, *, bq: int, bk: int, scale: float):
+    """One (batch row, head, block of query rows) against one key block of
+    its causal half: the running max, row sum and output live in scratch
+    across the key blocks."""
+    i, j = pl.program_id(2), pl.program_id(3)
+    last = (i * bq + bq - 1) // bk  # the last key block a row here may see
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, MASKED, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    @pl.when(j <= last)
+    def _():
+        q = q_ref[0] * scale  # a weak scalar: q keeps its type
+        s = jax.lax.dot_general(q, k_ref[0], (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        s = jnp.where(mask_ref[0].astype(jnp.float32) > 0, s, MASKED)
+        m = m_ref[...]
+        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+        e = jnp.exp(s - m_new)
+        a = jnp.exp(m - m_new)
+        v = v_ref[0]
+        l_ref[...] = a * l_ref[...] + e.sum(axis=-1, keepdims=True)
+        acc_ref[...] = a * acc_ref[...] + jnp.dot(
+            e.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+
+    @pl.when(j == last)
+    def _():
+        o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+def sparse_attention_kernel(q, k, v, mask, n_heads: int,
+                            block_q: Optional[int] = None,
+                            block_k: Optional[int] = None,
+                            interpret: Optional[bool] = None):
+    """Softmax attention over the keys ``mask`` lets through, token-major:
+    ``q``, ``k`` ``[B, T, H * d]``, ``v`` ``[B, T, H * dv]``, ``mask`` ``[B,
+    T, T]`` int8 with nothing above the diagonal.  A head is a column block
+    of whole lane tiles; key blocks past a query block's last row are
+    neither fetched nor computed."""
+    b, t, _ = q.shape
+    d, dv = q.shape[-1] // n_heads, v.shape[-1] // n_heads
+    bq, bk = min(block_q or BLOCK_Q, t), min(block_k or BLOCK_K, t)
+    if interpret is None:
+        interpret = _interpret()
+
+    def keys(i, h, r, j):
+        return (i, jnp.minimum(j, (r * bq + bq - 1) // bk), h)
+
+    itemsize = jnp.dtype(q.dtype).itemsize
+    seen = t * (t + 1) // 2
+    return pl.pallas_call(
+        functools.partial(_sparse_kernel, bq=bq, bk=bk, scale=d ** -0.5),
+        out_shape=jax.ShapeDtypeStruct(v.shape, q.dtype),
+        grid=(b, n_heads, t // bq, t // bk),
+        in_specs=[pl.BlockSpec((1, bq, d), lambda i, h, r, j: (i, r, h)),
+                  pl.BlockSpec((1, bk, d), keys),
+                  pl.BlockSpec((1, bk, dv), keys),
+                  pl.BlockSpec((1, bq, bk), lambda i, h, r, j: (
+                      i, r, jnp.minimum(j, (r * bq + bq - 1) // bk)))],
+        out_specs=pl.BlockSpec((1, bq, dv), lambda i, h, r, j: (i, r, h)),
+        scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
+                        pltpu.VMEM((bq, 1), jnp.float32),
+                        pltpu.VMEM((bq, dv), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * b * n_heads * seen * (d + dv),
+            transcendentals=b * n_heads * seen,
+            bytes_accessed=b * t * n_heads * (2 * d + 2 * dv) * itemsize
+            + b * n_heads * seen),
+        interpret=interpret,
+        name=KERNEL_NAME,
+    )(q, k, v, mask)
+
+
+def sparse_tiles(q_shape, v_shape, dtype, n_heads: int) -> bool:
+    """Whether the kernel is the lowering: bf16 or f32, heads of whole lane
+    tiles for the score and for the value, whole blocks of rows."""
+    dtype = jnp.dtype(dtype)
+    t = q_shape[1]
+    return (dtype in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32))
+            and q_shape[-1] % (n_heads * LANES) == 0
+            and v_shape[-1] % (n_heads * LANES) == 0
+            and t % BLOCK_Q == 0 and t % BLOCK_K == 0)
+
+
+latent_sparse_attention_p = Primitive("nns_latent_sparse_attention")
+
+
+def latent_sparse_attention(q, k_nope, k_rope, v, mask, n_heads: int):
+    """``q`` ``[B, T, H * (dn + dr)]``, each head's ``dn`` unrotated dims and
+    then its ``dr`` rotated ones; ``k_nope`` ``[B, T, H * dn]``; ``k_rope``
+    ``[B, T, dr]``, the rotated key part every head shares; ``v`` ``[B, T, H
+    * dv]``; ``mask`` ``[B, T, T]`` int8 from :func:`select_keys`.  Returns
+    ``[B, T, H * dv]``: per head the softmax of ``(q_n . k_n + q_r . k_r) /
+    sqrt(dn + dr)`` over the selected keys alone, times ``v``."""
+    return latent_sparse_attention_p.bind(q, k_nope, k_rope, v, mask,
+                                          n_heads=n_heads)
+
+
+latent_sparse_attention_p.def_impl(jax.jit(
+    latent_sparse_attention_p.bind, static_argnames=("n_heads",)))
+latent_sparse_attention_p.def_abstract_eval(
+    lambda q, k_nope, k_rope, v, mask, **_: v.update(dtype=q.dtype))
+
+
+def _lower_plain(ctx, *operands, n_heads):
+    _count_lowering("latent_sparse_plain")
+    return mlir.lower_fun(functools.partial(_plain, n_heads=n_heads),
+                          multiple_results=False)(ctx, *operands)
+
+
+def _lower_tpu(ctx, *operands, n_heads):
+    q, _, _, v, _ = ctx.avals_in
+    if not (_on_one_device(ctx.module_context.axis_context)
+            and q.dtype == v.dtype
+            and sparse_tiles(q.shape, v.shape, q.dtype, n_heads)):
+        return _lower_plain(ctx, *operands, n_heads=n_heads)
+    _count_lowering("latent_sparse")
+    return mlir.lower_fun(
+        lambda q, k_nope, k_rope, v, mask: sparse_attention_kernel(
+            q, _head_keys(k_nope, k_rope, n_heads), v, mask, n_heads,
+            interpret=False),
+        multiple_results=False)(ctx, *operands)
+
+
+# not cacheable: every call site is lowered, and counted, on its own
+mlir.register_lowering(latent_sparse_attention_p, _lower_plain,
+                       cacheable=False)
+mlir.register_lowering(latent_sparse_attention_p, _lower_tpu, platform="tpu",
+                       cacheable=False)

@@ -174,14 +174,20 @@ def swiglu(x, w_in, w_out):
     return matmul(jax.nn.silu(gate) * up, w_out)
 
 
-def route_top_k(x, router, top_k: int, scaling: float = 1.0):
+def route_top_k(x, router, top_k: int, scaling: float = 1.0, bias=None):
     """``(weights, experts)``, both ``[tokens, top_k]``: sigmoid scores of
     ``x @ router`` in float32, the ``top_k`` highest a token, renormalised
-    to sum 1 and times ``scaling``."""
+    to sum 1 and times ``scaling``.  With ``bias`` ``[experts]`` the choice
+    is by ``score + bias`` and the weights are the chosen experts' unbiased
+    scores (a load-balancing bias that steers the choice alone)."""
     scores = jax.nn.sigmoid(jnp.dot(
         x.astype(jnp.float32), router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
-    top, experts = jax.lax.top_k(scores, top_k)
+    if bias is None:
+        top, experts = jax.lax.top_k(scores, top_k)
+    else:
+        _, experts = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+        top = jnp.take_along_axis(scores, experts, axis=-1)
     return top / top.sum(axis=-1, keepdims=True) * scaling, experts
 
 
@@ -228,16 +234,77 @@ def _routed_fused(x, weights, experts, w_in, w_out, **kernel):
         axis=1, dtype=jnp.float32).astype(x.dtype)
 
 
+# How many sorted rows one pass of a share's walk takes: twice what an even
+# routing sends to the experts held, in whole tiles.
+SHARE_ROW_TILE = 256
+
+
+def share_rows(pairs: int, held: int, total: int) -> int:
+    even = -(-pairs * held // total)
+    return min(pairs, -(-2 * even // SHARE_ROW_TILE) * SHARE_ROW_TILE)
+
+
+def _routed_share(x, weights, experts, w_in, w_out, *, first: int,
+                  total: int):
+    """The part of the layer's result that experts ``first ... first + E``
+    give, ``E`` = ``w_in.shape[0]`` of the ``total`` the router chose among:
+    the pairs of an expert held sort to the front, by expert, the others
+    behind them, and the held ones alone are gathered and multiplied.  The
+    sorted rows are walked in passes of :func:`share_rows` rows, as many
+    passes as the routing needs (a loop whose count is read off the sort:
+    one where the load is near even, ``pairs / rows`` where every pick of
+    every token is held), so the buffer is a pass's and no pair is dropped
+    whatever the routing.  Nothing stands in for the experts held
+    elsewhere: their pairs add zero here."""
+    n, k = experts.shape
+    held = w_in.shape[0]
+    pairs = n * k
+    rows = share_rows(pairs, held, total)
+    local = experts.reshape(-1).astype(jnp.int32) - first
+    by_expert, order = jax.lax.sort(
+        (jnp.where((local >= 0) & (local < held), local, held),
+         jnp.arange(pairs, dtype=jnp.int32)), num_keys=1, is_stable=True)
+    starts = jnp.searchsorted(
+        by_expert, jnp.arange(held + 1, dtype=jnp.int32)).astype(jnp.int32)
+    n_held = starts[-1]
+    back = jnp.argsort(order)                   # where each pair's row went
+    order = jnp.pad(order, (0, rows))           # the last pass may overhang
+    pair_weights = weights.astype(x.dtype)
+
+    def one_pass(c, out):
+        r0 = c * rows
+        idx = jax.lax.dynamic_slice(order, (r0,), (rows,))
+        sizes = jnp.diff(jnp.clip(starts, r0, r0 + rows))
+        gate, up = jnp.split(jax.lax.ragged_dot(x[idx // k], w_in, sizes), 2,
+                             axis=-1)
+        y = jax.lax.ragged_dot((jax.nn.silu(gate) * up).astype(x.dtype),
+                               w_out, sizes)
+        # row ``rows`` is the zero every pair outside this pass reads; the
+        # pass's rows past the held pairs are no expert's and nobody's
+        y = jnp.concatenate([y, jnp.zeros((1, y.shape[-1]), y.dtype)])
+        at = back - r0
+        here = (at >= 0) & (at < rows) & (back < n_held)
+        y = y[jnp.where(here, at, rows)].reshape(n, k, -1)
+        return out + jnp.einsum("nkd,nk->nd", y, pair_weights,
+                                preferred_element_type=jnp.float32)
+
+    out = jax.lax.fori_loop(0, (n_held + rows - 1) // rows, one_pass,
+                            jnp.zeros((n, x.shape[-1]), jnp.float32))
+    return out.astype(x.dtype)
+
+
 # One primitive, as ``ops/fused_attention.attention_p``: a trace does not know
 # what it will be lowered for, so which of the two runs the products is the
-# lowering rule's choice.
+# lowering rule's choice.  ``first``/``total``: the share (``None``: all).
 
 routed_experts_p = Primitive("nns_routed_experts")
-routed_experts_p.def_impl(jax.jit(routed_experts_p.bind))
-routed_experts_p.def_abstract_eval(lambda x, *_: x)
+routed_experts_p.def_impl(jax.jit(routed_experts_p.bind,
+                                  static_argnames=("first", "total")))
+routed_experts_p.def_abstract_eval(lambda x, *_, **__: x)
 
 
-def routed_experts(x, weights, experts, w_in, w_out):
+def routed_experts(x, weights, experts, w_in, w_out,
+                   first: Optional[int] = None, total: Optional[int] = None):
     """Every (token, expert) pair of ``experts`` ``[tokens, k]`` through its
     expert's SwiGLU (``w_in`` ``[E, d, 2f]``, ``w_out`` ``[E, f, d]``), the
     ``k`` results of a token summed under ``weights``.  ``[tokens, d]``.
@@ -246,30 +313,53 @@ def routed_experts(x, weights, experts, w_in, w_out):
     whose shapes the grouped kernel tiles (``ops/grouped_experts.tiles``)
     runs the experts in that kernel; any other program (the CPU's, one that
     GSPMD partitions, small or odd shapes, experts that are no plain arrays)
-    runs them as two ``ragged_dot`` products through XLA."""
+    runs them as two ``ragged_dot`` products through XLA.
+
+    ``first``, ``total``: this layer holds experts ``first ... first + E``
+    of the ``total`` that ``experts`` counts over (one chip's share of an
+    expert-parallel layer); the result is the held experts' part
+    (:func:`_routed_share`).  Left out, or with ``E == total``, the layer
+    holds them all."""
+    if first is None or w_in.shape[0] == total:
+        first = total = None
+    elif not 0 <= first <= total - w_in.shape[0]:
+        raise ValueError(f"experts {first}...{first + w_in.shape[0]} of "
+                         f"{total}")
     if isinstance(w_in, QuantizedWeight) or isinstance(w_out, QuantizedWeight):
+        if first is not None:
+            raise NotImplementedError("a share of quantized experts")
         _count_moe_lowering("grouped")
         return _routed_grouped(x, weights, experts, w_in, w_out)
-    return routed_experts_p.bind(x, weights, experts, w_in, w_out)
+    return routed_experts_p.bind(x, weights, experts, w_in, w_out,
+                                 first=first, total=total)
 
 
-def _lower_grouped(ctx, *operands):
+def _xla_path(first, total):
+    if first is None:
+        return _routed_grouped
+    return functools.partial(_routed_share, first=first, total=total)
+
+
+def _lower_grouped(ctx, *operands, first=None, total=None):
     _count_moe_lowering("grouped")
-    return mlir.lower_fun(_routed_grouped, multiple_results=False)(
+    _say_held(ctx.avals_in[3].shape[0], total)
+    return mlir.lower_fun(_xla_path(first, total), multiple_results=False)(
         ctx, *operands)
 
 
-def _lower_tpu(ctx, *operands):
+def _lower_tpu(ctx, *operands, first=None, total=None):
     from ..ops.fused_attention import _on_one_device
     from ..ops.grouped_experts import tiles
 
     x, _, experts, w_in, w_out = ctx.avals_in
-    if not (_on_one_device(ctx.module_context.axis_context)
+    if not (first is None
+            and _on_one_device(ctx.module_context.axis_context)
             and x.dtype == w_in.dtype == w_out.dtype
             and tiles((experts.size, x.shape[-1]), w_in.shape, w_out.shape,
                       x.dtype)):
-        return _lower_grouped(ctx, *operands)
+        return _lower_grouped(ctx, *operands, first=first, total=total)
     _count_moe_lowering("fused")
+    _say_held(w_in.shape[0], total)
     return mlir.lower_fun(functools.partial(_routed_fused, interpret=False),
                           multiple_results=False)(ctx, *operands)
 
@@ -280,9 +370,20 @@ mlir.register_lowering(routed_experts_p, _lower_tpu, platform="tpu",
                        cacheable=False)
 # derivatives are the XLA path's (no vmap: ``ragged_dot`` has none over its
 # group sizes either)
-ad.primitive_jvps[routed_experts_p] = lambda primals, tangents: jax.jvp(
-    _routed_grouped, primals,
-    tuple(ad.instantiate_zeros(t) for t in tangents))
+ad.primitive_jvps[routed_experts_p] = lambda primals, tangents, **share: \
+    jax.jvp(_xla_path(**share), primals,
+            tuple(ad.instantiate_zeros(t) for t in tangents))
+
+
+def _say_held(held: int, total: Optional[int]) -> None:
+    from ..obs.metrics import REGISTRY
+
+    total = held if total is None else total
+    REGISTRY.gauge(
+        "nnstpu_moe_held_experts",
+        "experts whose weights the last expert layer lowered holds, of the "
+        "experts its router chooses among", labelnames=("of",),
+    ).set(held, of=str(total))
 
 
 def _count_moe_lowering(path: str) -> None:
@@ -298,23 +399,33 @@ def _count_moe_lowering(path: str) -> None:
 
 
 def moe_top_k(params: Params, x, top_k: int, scaling: float = 1.0,
-              token_chunk: Optional[int] = None):
+              token_chunk: Optional[int] = None, first: Optional[int] = None):
     """Top-``top_k`` of ``E`` SwiGLU experts beside a shared one.
 
     ``params``: ``router`` ``[d, E]``, ``w_in`` ``[E, d, 2f]``, ``w_out``
     ``[E, f, d]`` and, if the layer has one, ``shared`` (``w_in`` ``[d,
-    2f]``, ``w_out`` ``[f, d]``), which every token goes through unweighted.
+    2f]``, ``w_out`` ``[f, d]``), which every token goes through unweighted,
+    and ``bias`` ``[E]``, which steers the choice (:func:`route_top_k`).
     ``x``: ``[..., d]`` → the same.  Router scores and the choice are float32
     whatever ``x`` is.  ``token_chunk`` walks the tokens in chunks of that
     many (a ``lax.scan``), so that the ``k``-fold copies of the activations
     that the grouped product reads and writes stay a chunk's size.
+
+    ``first``: the layer is one chip's share of an expert-parallel one:
+    ``w_in`` and ``w_out`` hold experts ``first ... first + w_in.shape[0]``
+    of the router's ``E``.  The router stays ``E`` wide and picks among all
+    of them, the held experts' part of the routed sum is computed
+    (:func:`routed_experts`), and the shared expert whole.
     """
     shape = x.shape
     xt = x.reshape(-1, shape[-1])
+    total = params["router"].shape[-1]
 
     def chunk(xc):
-        w, experts = route_top_k(xc, params["router"], top_k, scaling)
-        out = routed_experts(xc, w, experts, params["w_in"], params["w_out"])
+        w, experts = route_top_k(xc, params["router"], top_k, scaling,
+                                 params.get("bias"))
+        out = routed_experts(xc, w, experts, params["w_in"], params["w_out"],
+                             first, total)
         if "shared" in params:
             out = out + swiglu(xc, params["shared"]["w_in"],
                                params["shared"]["w_out"])

@@ -544,3 +544,36 @@ def test_a_partitioned_program_and_a_toy_take_xlas_grouped_product(v5e_2x2):
     after = moe_lowerings()
     assert after["grouped"] == before.get("grouped", 0) + 2
     assert after.get("fused", 0) == before.get("fused", 0)
+
+
+def test_mosaic_compiles_the_selection_and_the_attention_under_it(v5e_2x2):
+    """GLM-5.2's widths at two windows of 16 384 tokens
+    (``ops/sparse_attention``): one layer's selection and its attention
+    lower their kernels for one v5e chip, counted by path."""
+    from jax.sharding import SingleDeviceSharding
+
+    from nnstreamer_tpu.ops import sparse_attention as sa
+
+    b, t, h = 2, 16384, 64
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(
+            dims, dtype, sharding=SingleDeviceSharding(v5e_2x2[0]))
+
+    def paths():
+        metric = REGISTRY.get("nnstpu_attention_lowerings_total")
+        got = {k[0]: c.value for k, c in metric.children()} if metric else {}
+        return got.get("latent_sparse", 0), got.get("index_select", 0)
+
+    before = paths()
+    attend = jax.jit(lambda *a: sa.latent_sparse_attention(*a, h)).lower(
+        shape(b, t, h * 256), shape(b, t, h * 192), shape(b, t, 64),
+        shape(b, t, h * 256), shape(b, t, t, dtype=jnp.int8)).compile()
+    assert sa.KERNEL_NAME in attend.as_text()
+    select = jax.jit(lambda *a: sa.select_keys(*a, 2048)).lower(
+        shape(b, t, 32 * 128), shape(b, t, 128),
+        shape(b, t, 32, dtype=jnp.float32)).compile()
+    assert sa.INDEX_KERNEL_NAME in select.as_text()
+    assert "sort" not in select.as_text()
+    assert paths() == (before[0] + 1, before[1] + 1)
+

@@ -4,7 +4,7 @@ the analog of the reference's whole-pipeline ``unittest_sink.cpp`` cases."""
 import numpy as np
 import pytest
 
-from nnstreamer_tpu import NegotiationError, Pipeline, parse_launch
+from nnstreamer_tpu import NegotiationError, Pipeline, make, parse_launch
 from nnstreamer_tpu.elements.app import AppSink, AppSrc
 from nnstreamer_tpu.elements.queue import Queue
 from nnstreamer_tpu.elements.sink import TensorSink
@@ -39,6 +39,25 @@ def test_datasrc_to_sink():
     p.run(timeout=10)
     assert sink.num_frames == 5
     assert [int(f.tensor(0)[0]) for f in sink.frames] == [0, 1, 2, 3, 4]
+
+
+def test_sources_start_last_added_first():
+    """Of the sources feeding one collect element the last to start
+    completes the first round and carries every later one: started in this
+    order that is the source on the element's first pad, whatever the run."""
+    p = Pipeline()
+    mux = p.add(make("tensor_mux", sync_mode="nosync"))
+    for i in range(3):
+        p.link(p.add(DataSrc(name=f"s{i}", data=[np.zeros(2, np.float32)])),
+               f"{mux.name}.sink_{i}")
+    p.link(mux, p.add(TensorSink(name="out")))
+    p.start()
+    try:
+        assert [t.name for t in p.threads if t.name.startswith("src:")] == [
+            "src:s2", "src:s1", "src:s0"]
+        p.wait(timeout=10)
+    finally:
+        p.stop()
 
 
 def test_negotiated_specs_propagate():
