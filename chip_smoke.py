@@ -559,7 +559,7 @@ class Smoke:
         # both kernels against the plain walks, the selection bit for bit
         from nnstreamer_tpu.ops import sparse_attention as sa
 
-        t, h, dn, dr, dv, hi, top = ((256, 2, 12, 4, 16, 2, 8)
+        t, h, dn, dr, dv, hi, top = ((256, 2, 64, 64, 128, 2, 8)
                                      if self.rehearsal
                                      else (2048, 4, 192, 64, 256, 4, 256))
 
@@ -580,13 +580,21 @@ class Smoke:
         mask = jnp.asarray(want)
         q, k_n = bf16(2, t, h * (dn + dr)), bf16(2, t, h * dn)
         k_r, v = bf16(2, t, dr), bf16(2, t, h * dv)
+        # the cell's head shape, pairs of heads of 192 | 64 and v 256: the
+        # projections unrotated and the tables, q rotated on the kernel's
+        # blocks, against rotate() and the walk through XLA at float32
+        from nnstreamer_tpu.models.laguna import rotary_tables
+        from nnstreamer_tpu.ops.fused_attention import rotate
+
+        tables = rotary_tables({"rope_theta": 8000000.0}, dr, t)
         got = np.asarray(jax.jit(lambda *a: sa.sparse_attention_kernel(
-            a[0], sa._head_keys(a[1], a[2], h), a[3], a[4], h,
-            interpret=interpret))(q, k_n, k_r, v, mask).astype(jnp.float32))
+            *a, h, tables, interpret=interpret))(
+            q, k_n, k_r, v, mask).astype(jnp.float32))
         with jax.default_matmul_precision("highest"):
             want = np.asarray(jax.jit(lambda *a: sa._plain(
-                *a, n_heads=h))(*(a.astype(jnp.float32)
-                                  for a in (q, k_n, k_r, v)), mask))
+                *a, n_heads=h))(*(a.astype(jnp.float32) for a in (
+                    rotate(q, *tables, h, dn), k_n, rotate(k_r, *tables, 1),
+                    v)), mask))
         np.testing.assert_allclose(got, want, rtol=2e-2, atol=2e-2)
         out["latent_sparse_attention_max_abs_err"] = float(
             np.abs(got - want).max())

@@ -15,7 +15,8 @@ pass over it and returns the logits of the last position, as
   type, interleaved pairs) on q's rope dims and on ``k_r``, which every head
   shares; the softmax of ``(q_n . k_n + q_r . k_r) / sqrt(qk_head_dim)``
   runs over the keys selected for the query alone
-  (``ops/sparse_attention.latent_sparse_attention``);
+  (``ops/sparse_attention.latent_sparse_attention``, which takes the
+  projections unrotated beside the rotary tables);
 - *the selection* (``indexer_types[i]``): a ``full`` layer's indexer, ``q_I =
   c_q W_Iq`` in ``index_n_heads`` heads of ``index_head_dim``, ``k_I =
   LayerNorm(h W_Ik)`` (one head), rotary on the first ``qk_rope_head_dim``
@@ -136,11 +137,8 @@ def layer(cfg: Dict[str, Any], i: int, p, x, selection, tables,
     rank = cfg["kv_lora_rank"]
     h = rms_norm(x, p["attn_norm"], eps)
     c_q = rms_norm(matmul(h, p["w_dq"]), p["q_norm"], eps)
-    q = rotate(matmul(c_q, p["w_uq"]), *tables, heads,
-               cfg["qk_nope_head_dim"])
     down = matmul(h, p["w_dkv"])
     c_kv = rms_norm(down[..., :rank], p["kv_norm"], eps)
-    k_r = rotate(down[..., rank:], *tables, 1)
     if cfg["indexer_types"][i] == "full":
         selection = select(cfg, p["indexer"], h, c_q, tables)
     elif selection is None:
@@ -148,8 +146,12 @@ def layer(cfg: Dict[str, Any], i: int, p, x, selection, tables,
                          "layers built before it makes one")
     else:
         selection = shared_selection(selection)
-    o = latent_sparse_attention(q, matmul(c_kv, p["w_uk"]), k_r,
-                                matmul(c_kv, p["w_uv"]), selection, heads)
+    # q and the rotary key part as the products write them: the attention's
+    # lowering rotates them, q on the kernel's own blocks where it tiles
+    o = latent_sparse_attention(matmul(c_q, p["w_uq"]),
+                                matmul(c_kv, p["w_uk"]), down[..., rank:],
+                                matmul(c_kv, p["w_uv"]), selection, heads,
+                                rotary=tables)
     x = x + matmul(o, p["wo"])
     h = rms_norm(x, p["mlp_norm"], eps)
     if cfg["mlp_layer_types"][i] == "dense":

@@ -251,16 +251,38 @@ def blocked_tiles(q_shape, kv_shape, dtype, n_heads: int, n_kv_heads: int,
             and vmem <= VMEM_BUDGET)
 
 
-def _lane_tables(cos, sin, rows: int):
+def _lane_tables(cos, sin, rows: int, at: int = 0):
     """``rotate``'s arithmetic as ``x * C + partner * S`` over whole lane
-    tiles: ``C`` = ``[cos | cos | 1 ...]``, ``S`` = ``[-sin | sin | 0 ...]``,
-    both ``[rows, 128]`` float32, zero past the tables' T (padded rows)."""
+    tiles: ``C`` = ``[1 ... | cos | cos | 1 ...]``, ``S`` = ``[0 ... | -sin |
+    sin | 0 ...]``, the rotated dims from lane ``at`` on, both ``[rows,
+    128]`` float32, zero past the tables' T (padded rows)."""
     t, half = cos.shape
     cos, sin = cos.astype(jnp.float32), sin.astype(jnp.float32)
-    rest = (t, LANES - 2 * half)
-    return tuple(jnp.pad(jnp.concatenate(parts, -1), ((0, rows - t), (0, 0)))
-                 for parts in ((cos, cos, jnp.ones(rest, jnp.float32)),
-                               (-sin, sin, jnp.zeros(rest, jnp.float32))))
+    before, rest = (t, at), (t, LANES - at - 2 * half)
+
+    def table(a, b, fill):
+        parts = (a, b, fill(rest, jnp.float32))
+        if at:
+            parts = (fill(before, jnp.float32),) + parts
+        return jnp.pad(jnp.concatenate(parts, -1), ((0, rows - t), (0, 0)))
+
+    return table(cos, cos, jnp.ones), table(-sin, sin, jnp.zeros)
+
+
+def _rotated(x, lane, c_ref, s_ref, half: int, at: int = 0):
+    """A ``[rows, 128]`` tile in VMEM rotated against the row block of
+    :func:`_lane_tables`' pair: float32 here and rounded once, as
+    :func:`rotate` does; the partner of a lane is ``half`` lanes on in the
+    rotated dims' first half (from lane ``at``), ``half`` back in the second
+    (``lane``: the tile's lane numbers)."""
+    f = x.astype(jnp.float32)
+    partner = pltpu.roll(f, half, 1)
+    if at or 2 * half != LANES:
+        first = lane < at + half
+        if at:
+            first &= lane >= at
+        partner = jnp.where(first, pltpu.roll(f, LANES - half, 1), partner)
+    return (f * c_ref[...] + partner * s_ref[...]).astype(x.dtype)
 
 
 def _blocked_kernel(q_ref, k_ref, v_ref, *refs, bq: int, bk: int,
@@ -281,14 +303,7 @@ def _blocked_kernel(q_ref, k_ref, v_ref, *refs, bq: int, bk: int,
         lane = jax.lax.broadcasted_iota(jnp.int32, (bq, LANES), 1)
 
         def rotated(x):
-            # float32 here and rounded once, as rotate() does; the partner
-            # of lane j is j + half in the first half, j - half in the second
-            f = x.astype(jnp.float32)
-            partner = pltpu.roll(f, half, 1)
-            if 2 * half != LANES:
-                partner = jnp.where(lane < half,
-                                    pltpu.roll(f, LANES - half, 1), partner)
-            return (f * c_ref[...] + partner * s_ref[...]).astype(x.dtype)
+            return _rotated(x, lane, c_ref, s_ref, half)
 
         q = rotated(q_ref[0])
 
@@ -509,9 +524,10 @@ def _count_lowering(path: str, rotary: Optional[str] = None) -> None:
            "= a selection's scoring pass)", path=path)
     if rotary is not None:
         _count("nnstpu_attention_rotary_total",
-               "attention calls lowered with rotary tables, by where q and k "
-               "are rotated (kernel = on the blocked kernel's own blocks in "
-               "VMEM, outside = rotate() through XLA before the attention)",
+               "attention calls lowered with rotary tables, by where q (and, "
+               "for the blocked kernel, k) is rotated (kernel = on the "
+               "kernel's own blocks in VMEM, outside = rotate() through XLA "
+               "before the attention)",
                where=rotary)
 
 

@@ -22,13 +22,32 @@ layer at two windows of 16 k tokens, and a per-row gather runs at a quarter
 of the chip's bandwidth (PERF.md).
 
 :func:`latent_sparse_attention` is one primitive, like
-``fused_attention.attention``: a one-device TPU program whose shapes
-:func:`sparse_tiles` admits lowers the Pallas kernel
-``nns_latent_sparse_attention`` (key blocks of the causal half streamed
-past a resident query block with a running softmax, the mask block beside
-them), every other program (the CPU's, one GSPMD partitions, odd shapes)
-the plain walk through XLA.  :func:`select_keys` is a primitive of the same
-kind: on the same condition the Pallas kernel ``nns_index_select`` scores a
+``fused_attention.attention``, and like it takes a layer's rotary tables:
+``q`` ``[B, T, H * (dn + dr)]`` and ``k_rope`` ``[B, T, dr]`` then come as
+their products wrote them, beside ``k_nope`` ``[B, T, H * dn]``, ``v`` and
+the selection.  Which lowering a call gets is decided from its operands
+when its program is lowered.  A one-device TPU program whose shapes
+:func:`sparse_tiles` admits (an even number of heads, each ``dn`` = whole
+lane tiles and a half, 64 rotary dims, ``rot`` <= 64) lowers the Pallas
+kernel ``nns_latent_sparse_attention``: a grid step takes a **pair of
+heads**, whose ``2 * dn`` columns of ``k_nope`` are whole lane tiles where
+one head's are not, streams the key blocks of the causal half past the
+pair's resident query block with a running softmax a head, and forms in
+VMEM what it multiplies: at a query block's first key step each head's
+rotary dims are rotated with ``rotate``'s arithmetic (float32, rounded
+once), q scaled, and the second head's dims rolled half a tile on; at each
+key step a head's key block ``[k_n | k_r]`` is its whole tiles of the
+``k_nope`` block and the tile the pair shares with ``k_r`` selected into the
+half that is not the head's own; the selection's block is fetched and
+turned into a predicate once for both heads.  No ``[B, T, H, dn + dr]``
+array of q or of the keys exists in HBM (``k_r`` alone is rotated through
+XLA: 4 MB).  Every other program (the CPU's, one GSPMD partitions, odd
+shapes) lowers ``rotate`` on q and ``k_rope``, :func:`_head_keys` and the
+plain walk through XLA.  ``nnstpu_attention_rotary_total{where}`` counts a
+call with tables by where q is rotated.
+
+:func:`select_keys` is a primitive of the same kind: where
+:func:`index_tiles` holds the Pallas kernel ``nns_index_select`` scores a
 block of query rows (the heads' products, ReLU and weighted sum in VMEM) and
 selects there too, finding each row's ``top_k``-th highest score a bit at a
 time by counting instead of sorting, so that neither the per-head scores
@@ -47,18 +66,19 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.extend.core import Primitive
 from jax.interpreters import mlir
 
-from .fused_attention import (MASKED, _count, _count_lowering,
-                              _on_one_device)
+from .fused_attention import (MASKED, _count, _count_lowering, _lane_tables,
+                              _on_one_device, _rotated, rotate)
 from .pallas_kernels import LANES, _interpret
 
 KERNEL_NAME = "nns_latent_sparse_attention"
 INDEX_KERNEL_NAME = "nns_index_select"
 # Query rows and key rows a grid step of the attention kernel takes: on the
-# v5e, one window of 16 384 tokens and 64 heads of 256, 1024 x 1024 ran in
-# 64.8 ms, 2048 x 512 64.9, 1024 x 512 68.2, 512 x 1024 70.4, 512 x 512 77.4
-# and 256 x 512 108.3 (PERF.md).  And the rows the walks through XLA take.
-BLOCK_Q = 1024
-BLOCK_K = 1024
+# v5e, one window of 16 384 tokens and 64 heads of 192 | 64, a pair of heads a
+# step and q rotated on its blocks, 2048 x 512 ran in 64.2 ms, 1024 x 1024
+# 65.5, 1024 x 512 65.6, 2048 x 1024 67.0, 1024 x 2048 68.6, 512 x 1024 68.8
+# and 512 x 512 69.9 (PERF.md).  And the rows the walks through XLA take.
+BLOCK_Q = 2048
+BLOCK_K = 512
 SELECT_ROWS = 512
 # The selection kernel: query rows a grid step scores and selects for, the
 # keys it scores at a time; its VMEM holds a window's indexer keys twice,
@@ -371,52 +391,106 @@ def _plain(q, k_nope, k_rope, v, mask, *, n_heads: int):
     return jnp.moveaxis(out, 0, 1).reshape(b, t, -1)
 
 
-def _sparse_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, m_ref, l_ref,
-                   acc_ref, *, bq: int, bk: int, scale: float):
-    """One (batch row, head, block of query rows) against one key block of
-    its causal half: the running max, row sum and output live in scratch
-    across the key blocks."""
+def _sparse_kernel(q_ref, kn_ref, kr_ref, v_ref, mask_ref, *refs, bq: int,
+                   bk: int, d: int, dv: int, half: Optional[int]):
+    """One (batch row, pair of heads, block of query rows) against one key
+    block of its causal half: each head's running max, row sum and output
+    live in scratch across the key blocks, and so does the pair's q block as
+    it is multiplied.  A head is ``d - 64`` unrotated dims and 64 rotary
+    ones, so of the pair's ``2 * (d - 64)`` key columns the tile in the
+    middle holds the first head's last 64 and the second's first 64; each
+    head's key block is its whole tiles and that tile with ``k_r`` (which
+    arrives in both half tiles) selected into the half that is not its own.
+    The second head's key thus reads ``[dims 64... | k_r | dims ...64]``,
+    and its q is rolled into the same order once, at the first key step; a
+    score is a sum over dims and does not see it.  With tables (``half``
+    lanes a rotary half) q's rotary dims are rotated there too."""
+    if half is None:
+        o_ref, q_s, m_ref, l_ref, acc_ref = refs
+    else:
+        c_ref, s_ref, o_ref, q_s, m_ref, l_ref, acc_ref = refs
     i, j = pl.program_id(2), pl.program_id(3)
     last = (i * bq + bq - 1) // bk  # the last key block a row here may see
+    own = d - LANES  # a head's whole tiles of unrotated dims
 
     @pl.when(j == 0)
     def _():
         m_ref[...] = jnp.full(m_ref.shape, MASKED, jnp.float32)
         l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
         acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (bq, LANES), 1)
+        scale = d ** -0.5  # a weak scalar: q keeps its type
+
+        def tile(n):
+            x = q_ref[0, :, n * LANES:(n + 1) * LANES]
+            if half is not None and (n + 1) * LANES % d == 0:
+                x = _rotated(x, lane, c_ref, s_ref, half, LANES // 2)
+            return x * scale
+
+        tiles = d // LANES
+        for n in range(tiles):  # the first head, as it lies
+            q_s[:, n * LANES:(n + 1) * LANES] = tile(n)
+        rolled = [pltpu.roll(tile(tiles + n).astype(jnp.float32), LANES // 2,
+                             1) for n in range(tiles)]
+        for n in range(tiles):  # the second, half a tile on
+            q_s[:, d + n * LANES:d + (n + 1) * LANES] = jnp.where(
+                lane < LANES // 2, rolled[n], rolled[(n + 1) % tiles]
+            ).astype(q_s.dtype)
 
     @pl.when(j <= last)
     def _():
-        q = q_ref[0] * scale  # a weak scalar: q keeps its type
-        s = jax.lax.dot_general(q, k_ref[0], (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        s = jnp.where(mask_ref[0].astype(jnp.float32) > 0, s, MASKED)
-        m = m_ref[...]
-        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-        e = jnp.exp(s - m_new)
-        a = jnp.exp(m - m_new)
-        v = v_ref[0]
-        l_ref[...] = a * l_ref[...] + e.sum(axis=-1, keepdims=True)
-        acc_ref[...] = a * acc_ref[...] + jnp.dot(
-            e.astype(v.dtype), v, preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        seen = mask_ref[0].astype(jnp.float32) > 0  # once for both heads
+        low = jax.lax.broadcasted_iota(jnp.int32, (bk, LANES), 1) < LANES // 2
+        mid, shared = kn_ref[0, :, own:own + LANES], kr_ref[0]
+        keys = (jnp.where(low, mid, shared), jnp.where(low, shared, mid))
+        for h, k in enumerate(keys):  # static: the pair
+            if own:
+                at = h * (own + LANES)
+                k = jnp.concatenate([kn_ref[0, :, at:at + own], k], axis=1)
+            s = jax.lax.dot_general(q_s[:, h * d:(h + 1) * d], k,
+                                    (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = jnp.where(seen, s, MASKED)
+            m = m_ref[h]
+            m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+            e = jnp.exp(s - m_new)
+            a = jnp.exp(m - m_new)
+            v = v_ref[0, :, h * dv:(h + 1) * dv]
+            l_ref[h] = a * l_ref[h] + e.sum(axis=-1, keepdims=True)
+            acc_ref[h] = a * acc_ref[h] + jnp.dot(
+                e.astype(v.dtype), v, preferred_element_type=jnp.float32)
+            m_ref[h] = m_new
 
     @pl.when(j == last)
     def _():
-        o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+        for h in range(2):
+            o_ref[0, :, h * dv:(h + 1) * dv] = (
+                acc_ref[h] / l_ref[h]).astype(o_ref.dtype)
 
 
-def sparse_attention_kernel(q, k, v, mask, n_heads: int,
-                            block_q: Optional[int] = None,
+def sparse_attention_kernel(q, k_nope, k_rope, v, mask, n_heads: int,
+                            rotary=None, block_q: Optional[int] = None,
                             block_k: Optional[int] = None,
                             interpret: Optional[bool] = None):
-    """Softmax attention over the keys ``mask`` lets through, token-major:
-    ``q``, ``k`` ``[B, T, H * d]``, ``v`` ``[B, T, H * dv]``, ``mask`` ``[B,
-    T, T]`` int8 with nothing above the diagonal.  A head is a column block
-    of whole lane tiles; key blocks past a query block's last row are
-    neither fetched nor computed."""
+    """Softmax attention over the keys ``mask`` lets through, token-major
+    and on the projections as the products write them: ``q`` ``[B, T, H *
+    (dn + 64)]``, ``k_nope`` ``[B, T, H * dn]``, ``k_rope`` ``[B, T, 64]``,
+    ``v`` ``[B, T, H * dv]``, ``mask`` ``[B, T, T]`` int8 with nothing
+    above the diagonal; :func:`sparse_tiles` says which shapes.  A
+    grid step takes a pair of heads (a head's ``dn`` columns of ``k_nope``
+    are no whole lane tiles, a pair's are) and forms their keys ``[k_n |
+    k_r]`` in VMEM; key blocks past a query block's last row are neither
+    fetched nor computed, and a selection block is fetched and turned into a
+    predicate once for both heads.
+
+    ``rotary`` = ``(cos, sin)``, each ``[T, rot/2]`` float32 (``rot`` <=
+    64): q and ``k_rope`` come unrotated; the kernel applies ``rotate``'s
+    arithmetic to each head's dims ``dn ... dn + rot``, the same roundings
+    in the same order, on the q block it holds, once a block of query rows;
+    ``k_rope``, 64 columns for all heads, goes through :func:`rotate`."""
     b, t, _ = q.shape
     d, dv = q.shape[-1] // n_heads, v.shape[-1] // n_heads
+    dn = k_nope.shape[-1] // n_heads
     bq, bk = min(block_q or BLOCK_Q, t), min(block_k or BLOCK_K, t)
     if interpret is None:
         interpret = _interpret()
@@ -424,21 +498,36 @@ def sparse_attention_kernel(q, k, v, mask, n_heads: int,
     def keys(i, h, r, j):
         return (i, jnp.minimum(j, (r * bq + bq - 1) // bk), h)
 
+    def rows(i, h, r, j):
+        return (i, r, h)
+
+    if rotary is not None:
+        k_rope = rotate(k_rope, *rotary, 1)
+    operands = [q, k_nope, jnp.concatenate([k_rope, k_rope], -1), v, mask]
+    in_specs = [pl.BlockSpec((1, bq, 2 * d), rows),
+                pl.BlockSpec((1, bk, 2 * dn), keys),
+                pl.BlockSpec((1, bk, LANES),
+                             lambda i, h, r, j: (*keys(i, h, r, j)[:2], 0)),
+                pl.BlockSpec((1, bk, 2 * dv), keys),
+                pl.BlockSpec((1, bq, bk),
+                             lambda i, h, r, j: (i, r, keys(i, h, r, j)[1]))]
+    half = None
+    if rotary is not None:
+        half = rotary[0].shape[-1]
+        operands += _lane_tables(*rotary, t, LANES // 2)
+        in_specs += [pl.BlockSpec((bq, LANES), lambda i, h, r, j: (r, 0))] * 2
     itemsize = jnp.dtype(q.dtype).itemsize
     seen = t * (t + 1) // 2
     return pl.pallas_call(
-        functools.partial(_sparse_kernel, bq=bq, bk=bk, scale=d ** -0.5),
+        functools.partial(_sparse_kernel, bq=bq, bk=bk, d=d, dv=dv, half=half),
         out_shape=jax.ShapeDtypeStruct(v.shape, q.dtype),
-        grid=(b, n_heads, t // bq, t // bk),
-        in_specs=[pl.BlockSpec((1, bq, d), lambda i, h, r, j: (i, r, h)),
-                  pl.BlockSpec((1, bk, d), keys),
-                  pl.BlockSpec((1, bk, dv), keys),
-                  pl.BlockSpec((1, bq, bk), lambda i, h, r, j: (
-                      i, r, jnp.minimum(j, (r * bq + bq - 1) // bk)))],
-        out_specs=pl.BlockSpec((1, bq, dv), lambda i, h, r, j: (i, r, h)),
-        scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
-                        pltpu.VMEM((bq, 1), jnp.float32),
-                        pltpu.VMEM((bq, dv), jnp.float32)],
+        grid=(b, n_heads // 2, t // bq, t // bk),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, bq, 2 * dv), rows),
+        scratch_shapes=[pltpu.VMEM((bq, 2 * d), q.dtype),
+                        pltpu.VMEM((2, bq, 1), jnp.float32),
+                        pltpu.VMEM((2, bq, 1), jnp.float32),
+                        pltpu.VMEM((2, bq, dv), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
@@ -447,59 +536,88 @@ def sparse_attention_kernel(q, k, v, mask, n_heads: int,
             flops=2 * b * n_heads * seen * (d + dv),
             transcendentals=b * n_heads * seen,
             bytes_accessed=b * t * n_heads * (2 * d + 2 * dv) * itemsize
-            + b * n_heads * seen),
+            + b * (n_heads // 2) * seen),
         interpret=interpret,
         name=KERNEL_NAME,
-    )(q, k, v, mask)
+    )(*operands)
 
 
-def sparse_tiles(q_shape, v_shape, dtype, n_heads: int) -> bool:
-    """Whether the kernel is the lowering: bf16 or f32, heads of whole lane
-    tiles for the score and for the value, whole blocks of rows."""
+def sparse_tiles(q_shape, k_nope_shape, k_rope_shape, v_shape, dtype,
+                 n_heads: int, rotary_shape=None) -> bool:
+    """Whether the kernel is the lowering: bf16 or f32, pairs of heads whose
+    unrotated dims end half a lane tile in and whose 64 rotary dims fill it
+    (so a pair's ``k_nope`` columns are whole tiles), heads of whole lane
+    tiles for the value, whole blocks of rows and of keys (one block of
+    either where T is shorter); with ``rotary_shape``, the ``[T, rot/2]``
+    of a call's tables, ``rot`` no more than the rotary dims."""
     dtype = jnp.dtype(dtype)
-    t = q_shape[1]
+    t, dr = q_shape[1], k_rope_shape[-1]
+    dn = k_nope_shape[-1] // n_heads
+    if rotary_shape is not None and (
+            len(rotary_shape) != 2 or rotary_shape[0] != t
+            or not 0 < 2 * rotary_shape[1] <= dr):
+        return False
     return (dtype in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32))
-            and q_shape[-1] % (n_heads * LANES) == 0
+            and n_heads % 2 == 0 and dr == LANES // 2
+            and dn % LANES == LANES // 2
+            and k_nope_shape[-1] == n_heads * dn
+            and q_shape[-1] == n_heads * (dn + dr)
             and v_shape[-1] % (n_heads * LANES) == 0
-            and t % BLOCK_Q == 0 and t % BLOCK_K == 0)
+            and t % min(BLOCK_Q, t) == 0 and t % min(BLOCK_K, t) == 0
+            and t % LANES == 0)
 
 
 latent_sparse_attention_p = Primitive("nns_latent_sparse_attention")
 
 
-def latent_sparse_attention(q, k_nope, k_rope, v, mask, n_heads: int):
+def latent_sparse_attention(q, k_nope, k_rope, v, mask, n_heads: int,
+                            rotary=None):
     """``q`` ``[B, T, H * (dn + dr)]``, each head's ``dn`` unrotated dims and
-    then its ``dr`` rotated ones; ``k_nope`` ``[B, T, H * dn]``; ``k_rope``
-    ``[B, T, dr]``, the rotated key part every head shares; ``v`` ``[B, T, H
-    * dv]``; ``mask`` ``[B, T, T]`` int8 from :func:`select_keys`.  Returns
-    ``[B, T, H * dv]``: per head the softmax of ``(q_n . k_n + q_r . k_r) /
-    sqrt(dn + dr)`` over the selected keys alone, times ``v``."""
+    then its ``dr`` rotary ones; ``k_nope`` ``[B, T, H * dn]``; ``k_rope``
+    ``[B, T, dr]``, the rotary key part every head shares; ``v`` ``[B, T, H
+    * dv]``; ``mask`` ``[B, T, T]`` int8 from :func:`select_keys`.  With
+    ``rotary`` = ``(cos, sin)``, each ``[T, rot/2]`` float32, ``q`` and
+    ``k_rope`` come as the products wrote them and the lowering applies
+    ``fused_attention.rotate`` to each head's rotary dims and to ``k_rope``;
+    without, both come rotated.  Returns ``[B, T, H * dv]``: per head the
+    softmax of ``(q_n . k_n + q_r . k_r) / sqrt(dn + dr)`` over the selected
+    keys alone, times ``v``.  See the module's docstring for which lowering
+    a call gets."""
     return latent_sparse_attention_p.bind(q, k_nope, k_rope, v, mask,
-                                          n_heads=n_heads)
+                                          *(rotary or ()), n_heads=n_heads)
 
 
 latent_sparse_attention_p.def_impl(jax.jit(
     latent_sparse_attention_p.bind, static_argnames=("n_heads",)))
 latent_sparse_attention_p.def_abstract_eval(
-    lambda q, k_nope, k_rope, v, mask, **_: v.update(dtype=q.dtype))
+    lambda q, k_nope, k_rope, v, *_, **__: v.update(dtype=q.dtype))
 
 
 def _lower_plain(ctx, *operands, n_heads):
-    _count_lowering("latent_sparse_plain")
-    return mlir.lower_fun(functools.partial(_plain, n_heads=n_heads),
-                          multiple_results=False)(ctx, *operands)
+    _count_lowering("latent_sparse_plain",
+                    "outside" if len(operands) == 7 else None)
+
+    def walk(q, k_nope, k_rope, v, mask, *tables):
+        if tables:
+            q = rotate(q, *tables, n_heads, k_nope.shape[-1] // n_heads)
+            k_rope = rotate(k_rope, *tables, 1)
+        return _plain(q, k_nope, k_rope, v, mask, n_heads=n_heads)
+
+    return mlir.lower_fun(walk, multiple_results=False)(ctx, *operands)
 
 
 def _lower_tpu(ctx, *operands, n_heads):
-    q, _, _, v, _ = ctx.avals_in
+    q, k_nope, k_rope, v, _, *tables = ctx.avals_in
     if not (_on_one_device(ctx.module_context.axis_context)
-            and q.dtype == v.dtype
-            and sparse_tiles(q.shape, v.shape, q.dtype, n_heads)):
+            and q.dtype == k_nope.dtype == k_rope.dtype == v.dtype
+            and sparse_tiles(q.shape, k_nope.shape, k_rope.shape, v.shape,
+                             q.dtype, n_heads,
+                             tables[0].shape if tables else None)):
         return _lower_plain(ctx, *operands, n_heads=n_heads)
-    _count_lowering("latent_sparse")
+    _count_lowering("latent_sparse", "kernel" if tables else None)
     return mlir.lower_fun(
-        lambda q, k_nope, k_rope, v, mask: sparse_attention_kernel(
-            q, _head_keys(k_nope, k_rope, n_heads), v, mask, n_heads,
+        lambda q, k_nope, k_rope, v, mask, *tables: sparse_attention_kernel(
+            q, k_nope, k_rope, v, mask, n_heads, tables or None,
             interpret=False),
         multiple_results=False)(ctx, *operands)
 

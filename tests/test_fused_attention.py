@@ -549,7 +549,9 @@ def test_a_partitioned_program_and_a_toy_take_xlas_grouped_product(v5e_2x2):
 def test_mosaic_compiles_the_selection_and_the_attention_under_it(v5e_2x2):
     """GLM-5.2's widths at two windows of 16 384 tokens
     (``ops/sparse_attention``): one layer's selection and its attention
-    lower their kernels for one v5e chip, counted by path."""
+    lower their kernels for one v5e chip, counted by path; with the layer's
+    rotary tables the attention kernel rotates q itself, and the program
+    holds q and the keys a head a row nowhere."""
     from jax.sharding import SingleDeviceSharding
 
     from nnstreamer_tpu.ops import sparse_attention as sa
@@ -565,11 +567,24 @@ def test_mosaic_compiles_the_selection_and_the_attention_under_it(v5e_2x2):
         got = {k[0]: c.value for k, c in metric.children()} if metric else {}
         return got.get("latent_sparse", 0), got.get("index_select", 0)
 
+    def rotated():
+        metric = REGISTRY.get("nnstpu_attention_rotary_total")
+        return dict(metric.children())[("kernel",)].value if metric else 0
+
     before = paths()
+    operands = (shape(b, t, h * 256), shape(b, t, h * 192), shape(b, t, 64),
+                shape(b, t, h * 256), shape(b, t, t, dtype=jnp.int8))
     attend = jax.jit(lambda *a: sa.latent_sparse_attention(*a, h)).lower(
-        shape(b, t, h * 256), shape(b, t, h * 192), shape(b, t, 64),
-        shape(b, t, h * 256), shape(b, t, t, dtype=jnp.int8)).compile()
+        *operands).compile()
     assert sa.KERNEL_NAME in attend.as_text()
+    in_kernel = rotated()
+    table = shape(t, 32, dtype=jnp.float32)
+    attend = jax.jit(lambda *a: sa.latent_sparse_attention(
+        *a[:5], h, rotary=a[5:])).lower(*operands, table, table).compile()
+    assert sa.KERNEL_NAME in attend.as_text()
+    assert f"[{b},{t},{h},256]" not in attend.as_text()
+    assert rotated() == in_kernel + 1
+    before = before[0] + 1, before[1]
     select = jax.jit(lambda *a: sa.select_keys(*a, 2048)).lower(
         shape(b, t, 32 * 128), shape(b, t, 128),
         shape(b, t, 32, dtype=jnp.float32)).compile()

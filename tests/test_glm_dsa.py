@@ -8,6 +8,7 @@ kernels in interpret mode (compiled by Mosaic for a described v5e in
 ``tests/test_fused_attention.py``, the one file that loads the TPU's
 compiler); and token frames through a launch-string pipeline."""
 
+import functools
 import json
 import os
 import sys
@@ -234,8 +235,10 @@ def test_a_shared_layer_attends_under_the_full_layers_selection():
 
 # -- the attention ------------------------------------------------------------
 
-def attention_operands(t, heads=2, dn=96, dr=32, dv=128, topk=48, b=2,
+def attention_operands(t, heads=2, dn=64, dr=64, dv=128, topk=48, b=2,
                        dtype=jnp.float32):
+    """A head's ``dn | dr`` as the kernel pairs them: 64 rotary dims that
+    fill the half lane tile the unrotated ones leave."""
     ks = jax.random.split(jax.random.PRNGKey(t), 7)
     q = jax.random.normal(ks[0], (b, t, heads * (dn + dr)), dtype)
     k_n = jax.random.normal(ks[1], (b, t, heads * dn), dtype)
@@ -263,19 +266,123 @@ def written_out(q, k_n, k_r, v, mask, heads):
     return jnp.concatenate(out, -1)
 
 
-@pytest.mark.parametrize("blocks", [(128, 128), (128, 64), (256, 128)],
-                         ids=lambda b: f"{b[0]}x{b[1]}")
+BLOCKS = pytest.mark.parametrize(
+    "blocks", [(128, 128), (128, 64), (256, 128)],
+    ids=lambda b: f"{b[0]}x{b[1]}")
+
+
+@BLOCKS
 def test_the_attention_kernel_in_interpret_mode(blocks):
     q, k_n, k_r, v, mask = attention_operands(256)
     with jax.default_matmul_precision("highest"):
         got = sa.sparse_attention_kernel(
-            q, sa._head_keys(k_n, k_r, 2), v, mask, 2, block_q=blocks[0],
-            block_k=blocks[1], interpret=True)
+            q, k_n, k_r, v, mask, 2, block_q=blocks[0], block_k=blocks[1],
+            interpret=True)
         want = written_out(q, k_n, k_r, v, mask, 2)
         plain = sa.latent_sparse_attention(q, k_n, k_r, v, mask, 2)
     assert got.shape == want.shape == (2, 256, 256)
     assert np.abs(np.asarray(got - want)).max() < 1e-5
     assert np.abs(np.asarray(plain - want)).max() < 1e-5
+
+
+def rope_tables(t, rot):
+    return glm_dsa.rotary_tables(config()["rope_parameters"], rot, t)
+
+
+# heads, a head's unrotated dims, the value's, the dims the tables rotate
+HEADS = pytest.mark.parametrize("heads,dn,dv,rot", [
+    (2, 192, 256, 64),   # the published head: 192 | 64, a pair a grid step
+    (4, 192, 128, 64),   # two pairs
+    (4, 64, 128, 64),    # a head of one lane tile: the mixed tile alone
+    (2, 192, 128, 32),   # tables that rotate half of the rotary dims
+], ids=["2x192_64", "4x192_64", "4x64_64", "2x192_rot32"])
+
+
+@BLOCKS
+@HEADS
+def test_the_kernel_rotates_q_as_rotate_does_at_float32(heads, dn, dv, rot,
+                                                        blocks):
+    """The projections as the products write them, and the tables: the
+    kernel's rotation of q on its own blocks and its keys ``[k_n | k_r]``
+    formed in VMEM against ``rotate()`` and the definition."""
+    q, k_n, k_r, v, mask = attention_operands(256, heads, dn, 64, dv)
+    cos, sin = rope_tables(256, rot)
+    with jax.default_matmul_precision("highest"):
+        got = sa.sparse_attention_kernel(
+            q, k_n, k_r, v, mask, heads, (cos, sin), block_q=blocks[0],
+            block_k=blocks[1], interpret=True)
+        k_r = glm_dsa.rotate(k_r, cos, sin, 1)
+        want = written_out(glm_dsa.rotate(q, cos, sin, heads, dn), k_n, k_r,
+                           v, mask, heads)
+    assert got.shape == want.shape == (2, 256, heads * dv)
+    assert np.abs(np.asarray(got - want)).max() < 1e-5
+    # and it does rotate: the unrotated q gives another answer
+    assert np.abs(np.asarray(written_out(q, k_n, k_r, v, mask, heads)
+                             - want)).max() > 1e-2
+
+
+@BLOCKS
+@HEADS
+def test_the_kernel_rotates_q_to_rotates_bits_at_bfloat16(heads, dn, dv, rot,
+                                                          blocks):
+    """bf16: the rotation in the kernel rounds where ``rotate()`` rounds,
+    so the kernel with tables equals the kernel handed a rotated q to the
+    last bit, but where this host's compiler contracts ``x * C + partner *
+    S`` to a fused multiply-add in one program and not in the other (a
+    float32 step, which now and then rounds to another bf16); the plain
+    lowering (float32 scores scaled after the product, a whole-row softmax)
+    lies within a bf16 step of the output."""
+    q, k_n, k_r, v, mask = attention_operands(256, heads, dn, 64, dv,
+                                              dtype=jnp.bfloat16)
+    cos, sin = rope_tables(256, rot)
+    kernel = functools.partial(sa.sparse_attention_kernel, block_q=blocks[0],
+                               block_k=blocks[1], interpret=True)
+    inside = kernel(q, k_n, k_r, v, mask, heads, (cos, sin))
+    outside = kernel(glm_dsa.rotate(q, cos, sin, heads, dn), k_n,
+                     glm_dsa.rotate(k_r, cos, sin, 1), v, mask, heads)
+    plain = sa.latent_sparse_attention(q, k_n, k_r, v, mask, heads,
+                                       rotary=(cos, sin))
+    assert inside.dtype == plain.dtype == jnp.bfloat16
+    inside, outside, plain = (np.asarray(a, np.float32)
+                              for a in (inside, outside, plain))
+    step = 2 ** -7 * np.abs(plain).max()
+    assert (inside != outside).mean() < 1e-3
+    assert np.abs(inside - outside).max() <= step
+    assert np.abs(inside - plain).max() <= step
+
+
+@pytest.mark.parametrize("heads,dn,dr,rot,why", [
+    (2, 192, 64, 64, None),
+    (64, 192, 64, 64, None),
+    (2, 192, 64, 32, None),
+    (3, 192, 64, 64, "an odd number of heads pairs off no last one"),
+    (2, 96, 32, 32, "a pair's 192 unrotated columns are no whole lane tiles"),
+    (2, 128, 128, 128, "no half tile stands free beside whole ones"),
+], ids=["the_pair", "the_published_64", "rot_32", "odd_heads", "dn_96",
+        "dn_128"])
+def test_what_the_kernel_tiles(heads, dn, dr, rot, why):
+    t = 2 * sa.BLOCK_Q
+    shapes = ((1, t, heads * (dn + dr)), (1, t, heads * dn), (1, t, dr),
+              (1, t, heads * 128))
+    assert sa.sparse_tiles(*shapes, jnp.bfloat16, heads, (t, rot // 2)) \
+        == (why is None), why
+    assert not sa.sparse_tiles(*shapes, jnp.int8, heads, (t, rot // 2))
+    # tables of other positions, or that rotate more than the rotary dims
+    assert not sa.sparse_tiles(*shapes, jnp.bfloat16, heads, (t // 2, 32))
+    assert not sa.sparse_tiles(*shapes, jnp.bfloat16, heads, (t, dr))
+    # and where it does not, a TPU's program rotates outside and walks
+    q, k_n, k_r, v = (jax.ShapeDtypeStruct(s, jnp.bfloat16) for s in shapes)
+    mask = jax.ShapeDtypeStruct((1, t, t), jnp.int8)
+    table = jax.ShapeDtypeStruct((t, rot // 2), jnp.float32)
+    before = rotary_counts()
+    text = jax.jit(lambda *a: sa.latent_sparse_attention(
+        *a[:5], heads, rotary=a[5:])).trace(
+        q, k_n, k_r, v, mask, table, table).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert (sa.KERNEL_NAME in text) == (why is None)
+    kernel = int(why is None)
+    assert rotary_counts() == (before[0] + kernel, before[1] + 1 - kernel,
+                               before[2] + kernel, before[3] + 1 - kernel)
 
 
 @pytest.mark.parametrize("b,t,heads,top_k,rows,bk", [
@@ -319,15 +426,44 @@ def test_the_plain_walk_takes_row_blocks(monkeypatch):
     assert np.abs(np.asarray(whole - blocks)).max() < 1e-5
 
 
+def rotary_counts():
+    return tuple(counted(name, label) for name, label in (
+        ("nnstpu_attention_rotary_total", "kernel"),
+        ("nnstpu_attention_rotary_total", "outside"),
+        ("nnstpu_attention_lowerings_total", "latent_sparse"),
+        ("nnstpu_attention_lowerings_total", "latent_sparse_plain")))
+
+
 def test_one_trace_lowers_the_kernels_for_a_tpu_and_the_walks_here():
     """Which lowering a call gets is the lowering rule's choice: the same
-    trace holds both kernels for a TPU and neither for this host."""
+    trace holds both kernels for a TPU and neither for this host.  A call
+    without tables counts no rotation; the model's layers each count one,
+    in the kernel for a TPU and outside here, and a TPU's program holds no
+    ``[B, T, H, dn + dr]`` array of q or of keys for XLA to re-tile."""
     q, k_n, k_r, v, mask = attention_operands(1024, topk=100)
     attend = jax.jit(lambda *a: sa.latent_sparse_attention(*a, 2)).trace(
         q, k_n, k_r, v, mask)
+    before = rotary_counts()
     assert sa.KERNEL_NAME in attend.lower(
         lowering_platforms=("tpu",)).as_text()
     assert "tpu_custom_call" not in attend.lower().as_text()
+    assert rotary_counts() == (before[0], before[1], before[2] + 1,
+                               before[3] + 1)
+    cfg = config([2, 6, 7], qk_nope_head_dim=64, qk_rope_head_dim=64,
+                 v_head_dim=128, index_head_dim=64)
+    model = glm_dsa.build(cfg, seq=1024, batch=2, seed=1)
+    program = jax.jit(model.fn()).trace(
+        jax.ShapeDtypeStruct((2, 1024), jnp.int32))
+    by_head = "tensor<2x1024x4x128x"  # q or the keys, a head a row
+    before = rotary_counts()
+    on_tpu = program.lower(lowering_platforms=("tpu",)).as_text()
+    assert on_tpu.count(sa.KERNEL_NAME) >= 3 and by_head not in on_tpu
+    assert rotary_counts() == (before[0] + 3, before[1], before[2] + 3,
+                               before[3])
+    here = program.lower().as_text()
+    assert "tpu_custom_call" not in here and by_head in here
+    assert rotary_counts() == (before[0] + 3, before[1] + 3, before[2] + 3,
+                               before[3] + 3)
     ks = jax.random.split(jax.random.PRNGKey(1), 3)
     select = jax.jit(lambda *a: sa.select_keys(*a, 100)).trace(
         jax.random.normal(ks[0], (1, 1024, 256)),
