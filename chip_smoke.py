@@ -598,6 +598,27 @@ class Smoke:
         np.testing.assert_allclose(got, want, rtol=2e-2, atol=2e-2)
         out["latent_sparse_attention_max_abs_err"] = float(
             np.abs(got - want).max())
+        # the same attention without a selection, heads of 128 | 64 and v
+        # 128, YaRN tables and a score scale of the caller's: the kernel
+        # over every causal key against rotate() and the walk at float32
+        dn, dv, scale = 128, 128, 0.13
+        q, k_n, v = bf16(2, t, h * (dn + dr)), bf16(2, t, h * dn), \
+            bf16(2, t, h * dv)
+        tables = rotary_tables(
+            {"rope_theta": 10000.0, "rope_type": "yarn", "factor": 32,
+             "original_max_position_embeddings": t // 4, "beta_fast": 32,
+             "beta_slow": 1, "attention_factor": 1.0}, dr, t)
+        got = np.asarray(jax.jit(lambda *a: sa.latent_attention_kernel(
+            *a, h, tables, scale, interpret=interpret))(
+            q, k_n, k_r, v).astype(jnp.float32))
+        with jax.default_matmul_precision("highest"):
+            want = np.asarray(jax.jit(lambda *a: sa._plain(
+                *a, None, n_heads=h, scale=scale))(*(
+                    a.astype(jnp.float32) for a in (
+                        rotate(q, *tables, h, dn), k_n,
+                        rotate(k_r, *tables, 1), v))))
+        np.testing.assert_allclose(got, want, rtol=2e-2, atol=2e-2)
+        out["latent_attention_max_abs_err"] = float(np.abs(got - want).max())
         out["compiled_by"] = "interpreter" if interpret else "mosaic"
         return out
 

@@ -37,6 +37,13 @@ published indices of the layers built (left out: the first
 chip's share of every sparse layer's experts (left out: all).  The router
 keeps ``n_routed_experts`` outputs either way.
 
+The layer body is the family's, and what a sibling lacks it leaves out
+(``models/axk1.py`` builds on it): without ``indexer_types`` no layer
+selects and the softmax runs over every causal key; ``softmax_scale``
+replaces ``1 / sqrt(qk_head_dim)``; ``n_group`` groups of which
+``topk_group`` stand for the router's choice (one group: all of them);
+a ``topk_method`` other than ``noaux_tc`` has no selection bias.
+
 The arrays are held with the rotary pairs split (pair ``i`` = dims ``(i, i
 + rot/2)``): :func:`split_rotary_pairs` reorders a checkpoint's columns once
 on the host, the same way for a query and its key, so every score is the
@@ -115,6 +122,12 @@ def layer_norm(x, scale, bias, eps: float):
             + bias.astype(jnp.float32)).astype(x.dtype)
 
 
+def selects(cfg: Dict[str, Any], i: int) -> Optional[str]:
+    """``full``, ``shared`` or, in a model without a selection, nothing."""
+    kinds = cfg.get("indexer_types")
+    return kinds[i] if kinds else None
+
+
 def select(cfg: Dict[str, Any], p, h, c_q, tables):
     """A ``full`` layer's indexer: the ``[B, T, T]`` selection."""
     heads, width = cfg["index_n_heads"], cfg["index_head_dim"]
@@ -139,7 +152,9 @@ def layer(cfg: Dict[str, Any], i: int, p, x, selection, tables,
     c_q = rms_norm(matmul(h, p["w_dq"]), p["q_norm"], eps)
     down = matmul(h, p["w_dkv"])
     c_kv = rms_norm(down[..., :rank], p["kv_norm"], eps)
-    if cfg["indexer_types"][i] == "full":
+    if selects(cfg, i) is None:
+        selection = None  # every causal key: no mask is made or read
+    elif selects(cfg, i) == "full":
         selection = select(cfg, p["indexer"], h, c_q, tables)
     elif selection is None:
         raise ValueError(f"layer {i} shares a selection and none of the "
@@ -151,14 +166,15 @@ def layer(cfg: Dict[str, Any], i: int, p, x, selection, tables,
     o = latent_sparse_attention(matmul(c_q, p["w_uq"]),
                                 matmul(c_kv, p["w_uk"]), down[..., rank:],
                                 matmul(c_kv, p["w_uv"]), selection, heads,
-                                rotary=tables)
+                                rotary=tables, scale=cfg.get("softmax_scale"))
     x = x + matmul(o, p["wo"])
     h = rms_norm(x, p["mlp_norm"], eps)
     if cfg["mlp_layer_types"][i] == "dense":
         return x + swiglu(h, p["mlp"]["w_in"], p["mlp"]["w_out"]), selection
     return x + moe_top_k(p["moe"], h, cfg["num_experts_per_tok"],
                          cfg["routed_scaling_factor"], token_chunk,
-                         experts_held(cfg)[0]), selection
+                         experts_held(cfg)[0], cfg.get("n_group"),
+                         cfg.get("topk_group")), selection
 
 
 def apply(cfg: Dict[str, Any], params, ids, dtype=jnp.bfloat16,
@@ -205,7 +221,7 @@ def init_params(cfg: Dict[str, Any], seed: int = 0, dtype=jnp.bfloat16):
              "kv_norm": gain(rkv), "w_uk": w(rkv, heads * dn),
              "w_uv": w(rkv, heads * dv), "wo": w(heads * dv, d),
              "mlp_norm": gain()}
-        if cfg["indexer_types"][i] == "full":
+        if selects(cfg, i) == "full":
             width = cfg["index_head_dim"]
             p["indexer"] = {
                 "wq": w(rq, cfg["index_n_heads"] * width), "wk": w(d, width),
@@ -215,9 +231,10 @@ def init_params(cfg: Dict[str, Any], seed: int = 0, dtype=jnp.bfloat16):
             p["mlp"] = glu(cfg["intermediate_size"])
         else:
             f = cfg["moe_intermediate_size"]
-            p["moe"] = dict(glu(f, (held,)), router=w(d, e),
-                            bias=gain(e, 0.0),
-                            shared=glu(f * cfg["n_shared_experts"]))
+            p["moe"] = dict(glu(f, (held,)), router=w(d, e))
+            if cfg.get("topk_method", "noaux_tc") == "noaux_tc":
+                p["moe"]["bias"] = gain(e, 0.0)
+            p["moe"]["shared"] = glu(f * cfg["n_shared_experts"])
         layers.append(p)
     embed = jax.random.normal(next(keys), (cfg["vocab_size"], d), jnp.float32)
     return {"embed": embed.astype(dtype), "layers": layers, "norm": gain(),

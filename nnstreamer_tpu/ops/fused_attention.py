@@ -520,8 +520,9 @@ def _count_lowering(path: str, rotary: Optional[str] = None) -> None:
            "= the whole-row Pallas kernel, blocked = the key-block walk with "
            "grouped heads, plain = full_attention through XLA; "
            "ops/sparse_attention: latent_sparse = the selected-keys kernel, "
-           "latent_sparse_plain = its walk through XLA, index_select[_plain] "
-           "= a selection's scoring pass)", path=path)
+           "latent_sparse_plain = its walk through XLA, latent[_plain] = "
+           "the same over every causal key, index_select[_plain] = a "
+           "selection's scoring pass)", path=path)
     if rotary is not None:
         _count("nnstpu_attention_rotary_total",
                "attention calls lowered with rotary tables, by where q (and, "

@@ -1,4 +1,4 @@
-"""Latent attention under a learned selection of keys.
+"""Latent attention, under a learned selection of keys or over all of them.
 
 Two ops of one mechanism (multi-head latent attention whose softmax runs
 over the ``top_k`` keys a small second attention, the *indexer*, scores
@@ -46,6 +46,22 @@ shapes) lowers ``rotate`` on q and ``k_rope``, :func:`_head_keys` and the
 plain walk through XLA.  ``nnstpu_attention_rotary_total{where}`` counts a
 call with tables by where q is rotated.
 
+The selection is optional: ``mask=None`` is the same attention over every
+key up to the query's own position, and then no ``[B, T, T]`` array is an
+operand or made anywhere.  A one-device TPU program whose shapes
+:func:`latent_tiles` admits (an even number of heads, ``dn`` and ``dv``
+whole lane tiles, 64 rotary dims: heads of 128 | 64 with values of 128)
+lowers the Pallas kernel ``nns_latent_attention``: again a pair of heads a
+step, because a head's 192 columns of q are a lane tile and a half and a
+pair's are three; the key blocks of the causal half stream past the pair's
+resident query block, those wholly under its first row without a predicate,
+those on the diagonal masked by position, those above it neither fetched
+nor computed.  At a query block's first key step each head's q becomes ``[q_n
+| q_r rotated | 0]`` in VMEM (the second head's columns rolled back half a
+tile), scaled by the caller's ``scale``; a head's key block is ``[k_n | k_r
+| 0]``.  Every other program walks row blocks through XLA with the
+predicate made from positions (``latent_plain``).
+
 :func:`select_keys` is a primitive of the same kind: where
 :func:`index_tiles` holds the Pallas kernel ``nns_index_select`` scores a
 block of query rows (the heads' products, ReLU and weighted sum in VMEM) and
@@ -71,6 +87,7 @@ from .fused_attention import (MASKED, _count, _count_lowering, _lane_tables,
 from .pallas_kernels import LANES, _interpret
 
 KERNEL_NAME = "nns_latent_sparse_attention"
+LATENT_KERNEL_NAME = "nns_latent_attention"
 INDEX_KERNEL_NAME = "nns_index_select"
 # Query rows and key rows a grid step of the attention kernel takes: on the
 # v5e, one window of 16 384 tokens and 64 heads of 192 | 64, a pair of heads a
@@ -80,6 +97,12 @@ INDEX_KERNEL_NAME = "nns_index_select"
 BLOCK_Q = 2048
 BLOCK_K = 512
 SELECT_ROWS = 512
+# The same of the kernel without a selection: on the v5e, one window of
+# 16 384 tokens and 64 heads of 128 | 64 with values of 128, 1024 x 1024 ran
+# in 46.4 ms, 2048 x 1024 46.7, 512 x 1024 49.8, 512 x 512 75.4, 1024 x 512
+# 81.1, 2048 x 512 85.2, 2048 x 256 118.4 and 1024 x 256 130.5 (PERF.md).
+LATENT_BLOCK_Q = 1024
+LATENT_BLOCK_K = 1024
 # The selection kernel: query rows a grid step scores and selects for, the
 # keys it scores at a time; its VMEM holds a window's indexer keys twice,
 # the rows' scores against every key once and their one-byte selection twice
@@ -87,6 +110,7 @@ SELECT_ROWS = 512
 INDEX_ROWS = 256
 INDEX_BLOCK_K = 1024
 VMEM_LIMIT = 64 * 2 ** 20
+LATENT_VMEM_LIMIT = 100 * 2 ** 20
 INDEX_VMEM_LIMIT = 100 * 2 ** 20
 
 
@@ -365,21 +389,29 @@ def _head_keys(k_nope, k_rope, n_heads: int):
                            axis=-1).reshape(b, t, -1)
 
 
-def _plain(q, k_nope, k_rope, v, mask, *, n_heads: int):
+def _plain(q, k_nope, k_rope, v, mask, *, n_heads: int,
+           scale: Optional[float] = None):
     """Through XLA: a block of query rows against every key, the mask laid
-    over the scores, a whole-row softmax."""
+    over the scores (without one, the keys up to the query's own position),
+    a whole-row softmax."""
     b, t, _ = q.shape
     k = _head_keys(k_nope, k_rope, n_heads).reshape(b, t, n_heads, -1)
     vh = v.reshape(b, t, n_heads, -1)
-    scale = k.shape[-1] ** -0.5
+    if scale is None:
+        scale = k.shape[-1] ** -0.5
     blocks = row_blocks(t, SELECT_ROWS)
     rows = t // blocks
 
     def block(args):
-        q_b, m_b = args  # [b, rows, H * d], [b, rows, t]
+        q_b, m_b = args  # [b, rows, H * d]; [b, rows, t], or the block's index
         s = jnp.einsum("brhd,bshd->bhrs", q_b.reshape(b, rows, n_heads, -1),
                        k, preferred_element_type=jnp.float32) * scale
-        p = jax.nn.softmax(jnp.where(m_b[:, None] != 0, s, -jnp.inf), axis=-1)
+        if mask is None:
+            at = m_b * rows + jnp.arange(rows, dtype=jnp.int32)
+            seen = jnp.arange(t, dtype=jnp.int32)[None, :] <= at[:, None]
+        else:
+            seen = m_b[:, None] != 0
+        p = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
         return jnp.einsum("bhrs,bshd->brhd", p.astype(v.dtype), vh,
                           preferred_element_type=jnp.float32
                           ).astype(q.dtype).reshape(b, rows, -1)
@@ -387,12 +419,40 @@ def _plain(q, k_nope, k_rope, v, mask, *, n_heads: int):
     def split(a):  # [b, t, x] -> [blocks, b, rows, x]
         return jnp.moveaxis(a.reshape(b, blocks, rows, -1), 1, 0)
 
-    out = jax.lax.map(block, (split(q), split(mask)))
+    out = jax.lax.map(block, (split(q), jnp.arange(blocks, dtype=jnp.int32)
+                              if mask is None else split(mask)))
     return jnp.moveaxis(out, 0, 1).reshape(b, t, -1)
 
 
+def _start(m_ref, l_ref, acc_ref):
+    """A query block's first key step: nothing seen yet."""
+    m_ref[...] = jnp.full(m_ref.shape, MASKED, jnp.float32)
+    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+
+def _take_in(h: int, s, v, m_ref, l_ref, acc_ref):
+    """Head ``h`` of the pair: one key block's scores ``s`` ``[bq, bk]`` and
+    values ``v`` ``[bk, dv]`` into its running max, row sum and output."""
+    m = m_ref[h]
+    m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+    e = jnp.exp(s - m_new)
+    a = jnp.exp(m - m_new)
+    l_ref[h] = a * l_ref[h] + e.sum(axis=-1, keepdims=True)
+    acc_ref[h] = a * acc_ref[h] + jnp.dot(
+        e.astype(v.dtype), v, preferred_element_type=jnp.float32)
+    m_ref[h] = m_new
+
+
+def _write_out(o_ref, l_ref, acc_ref, dv: int):
+    for h in range(2):
+        o_ref[0, :, h * dv:(h + 1) * dv] = (
+            acc_ref[h] / l_ref[h]).astype(o_ref.dtype)
+
+
 def _sparse_kernel(q_ref, kn_ref, kr_ref, v_ref, mask_ref, *refs, bq: int,
-                   bk: int, d: int, dv: int, half: Optional[int]):
+                   bk: int, d: int, dv: int, scale: float,
+                   half: Optional[int]):
     """One (batch row, pair of heads, block of query rows) against one key
     block of its causal half: each head's running max, row sum and output
     live in scratch across the key blocks, and so does the pair's q block as
@@ -415,17 +475,14 @@ def _sparse_kernel(q_ref, kn_ref, kr_ref, v_ref, mask_ref, *refs, bq: int,
 
     @pl.when(j == 0)
     def _():
-        m_ref[...] = jnp.full(m_ref.shape, MASKED, jnp.float32)
-        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
-        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+        _start(m_ref, l_ref, acc_ref)
         lane = jax.lax.broadcasted_iota(jnp.int32, (bq, LANES), 1)
-        scale = d ** -0.5  # a weak scalar: q keeps its type
 
         def tile(n):
             x = q_ref[0, :, n * LANES:(n + 1) * LANES]
             if half is not None and (n + 1) * LANES % d == 0:
                 x = _rotated(x, lane, c_ref, s_ref, half, LANES // 2)
-            return x * scale
+            return x * scale  # a weak scalar: q keeps its type
 
         tiles = d // LANES
         for n in range(tiles):  # the first head, as it lies
@@ -450,28 +507,18 @@ def _sparse_kernel(q_ref, kn_ref, kr_ref, v_ref, mask_ref, *refs, bq: int,
             s = jax.lax.dot_general(q_s[:, h * d:(h + 1) * d], k,
                                     (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32)
-            s = jnp.where(seen, s, MASKED)
-            m = m_ref[h]
-            m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-            e = jnp.exp(s - m_new)
-            a = jnp.exp(m - m_new)
-            v = v_ref[0, :, h * dv:(h + 1) * dv]
-            l_ref[h] = a * l_ref[h] + e.sum(axis=-1, keepdims=True)
-            acc_ref[h] = a * acc_ref[h] + jnp.dot(
-                e.astype(v.dtype), v, preferred_element_type=jnp.float32)
-            m_ref[h] = m_new
+            _take_in(h, jnp.where(seen, s, MASKED),
+                     v_ref[0, :, h * dv:(h + 1) * dv], m_ref, l_ref, acc_ref)
 
-    @pl.when(j == last)
-    def _():
-        for h in range(2):
-            o_ref[0, :, h * dv:(h + 1) * dv] = (
-                acc_ref[h] / l_ref[h]).astype(o_ref.dtype)
+    pl.when(j == last)(functools.partial(_write_out, o_ref, l_ref, acc_ref,
+                                         dv))
 
 
 def sparse_attention_kernel(q, k_nope, k_rope, v, mask, n_heads: int,
                             rotary=None, block_q: Optional[int] = None,
                             block_k: Optional[int] = None,
-                            interpret: Optional[bool] = None):
+                            interpret: Optional[bool] = None,
+                            scale: Optional[float] = None):
     """Softmax attention over the keys ``mask`` lets through, token-major
     and on the projections as the products write them: ``q`` ``[B, T, H *
     (dn + 64)]``, ``k_nope`` ``[B, T, H * dn]``, ``k_rope`` ``[B, T, 64]``,
@@ -487,10 +534,14 @@ def sparse_attention_kernel(q, k_nope, k_rope, v, mask, n_heads: int,
     64): q and ``k_rope`` come unrotated; the kernel applies ``rotate``'s
     arithmetic to each head's dims ``dn ... dn + rot``, the same roundings
     in the same order, on the q block it holds, once a block of query rows;
-    ``k_rope``, 64 columns for all heads, goes through :func:`rotate`."""
+    ``k_rope``, 64 columns for all heads, goes through :func:`rotate`.
+    ``scale``: what a score is multiplied by, ``(dn + 64) ** -0.5`` if left
+    out."""
     b, t, _ = q.shape
     d, dv = q.shape[-1] // n_heads, v.shape[-1] // n_heads
     dn = k_nope.shape[-1] // n_heads
+    if scale is None:
+        scale = d ** -0.5
     bq, bk = min(block_q or BLOCK_Q, t), min(block_k or BLOCK_K, t)
     if interpret is None:
         interpret = _interpret()
@@ -519,7 +570,8 @@ def sparse_attention_kernel(q, k_nope, k_rope, v, mask, n_heads: int,
     itemsize = jnp.dtype(q.dtype).itemsize
     seen = t * (t + 1) // 2
     return pl.pallas_call(
-        functools.partial(_sparse_kernel, bq=bq, bk=bk, d=d, dv=dv, half=half),
+        functools.partial(_sparse_kernel, bq=bq, bk=bk, d=d, dv=dv,
+                          scale=scale, half=half),
         out_shape=jax.ShapeDtypeStruct(v.shape, q.dtype),
         grid=(b, n_heads // 2, t // bq, t // bk),
         in_specs=in_specs,
@@ -542,14 +594,15 @@ def sparse_attention_kernel(q, k_nope, k_rope, v, mask, n_heads: int,
     )(*operands)
 
 
-def sparse_tiles(q_shape, k_nope_shape, k_rope_shape, v_shape, dtype,
-                 n_heads: int, rotary_shape=None) -> bool:
-    """Whether the kernel is the lowering: bf16 or f32, pairs of heads whose
-    unrotated dims end half a lane tile in and whose 64 rotary dims fill it
-    (so a pair's ``k_nope`` columns are whole tiles), heads of whole lane
-    tiles for the value, whole blocks of rows and of keys (one block of
-    either where T is shorter); with ``rotary_shape``, the ``[T, rot/2]``
-    of a call's tables, ``rot`` no more than the rotary dims."""
+def _pair_tiles(q_shape, k_nope_shape, k_rope_shape, v_shape, dtype,
+                n_heads: int, rotary_shape, past_a_tile: int, block_q: int,
+                block_k: int) -> bool:
+    """What both kernels ask of a call: bf16 or f32, pairs of heads of 64
+    rotary dims whose unrotated dims end ``past_a_tile`` lanes past a whole
+    lane tile, heads of whole lane tiles for the value, whole blocks of rows
+    and of keys (one block of either where T is shorter); with
+    ``rotary_shape``, the ``[T, rot/2]`` of a call's tables, ``rot`` no more
+    than the rotary dims."""
     dtype = jnp.dtype(dtype)
     t, dr = q_shape[1], k_rope_shape[-1]
     dn = k_nope_shape[-1] // n_heads
@@ -559,67 +612,245 @@ def sparse_tiles(q_shape, k_nope_shape, k_rope_shape, v_shape, dtype,
         return False
     return (dtype in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32))
             and n_heads % 2 == 0 and dr == LANES // 2
-            and dn % LANES == LANES // 2
+            and dn > 0 and dn % LANES == past_a_tile
             and k_nope_shape[-1] == n_heads * dn
             and q_shape[-1] == n_heads * (dn + dr)
             and v_shape[-1] % (n_heads * LANES) == 0
-            and t % min(BLOCK_Q, t) == 0 and t % min(BLOCK_K, t) == 0
+            and t % min(block_q, t) == 0 and t % min(block_k, t) == 0
             and t % LANES == 0)
+
+
+def sparse_tiles(q_shape, k_nope_shape, k_rope_shape, v_shape, dtype,
+                 n_heads: int, rotary_shape=None) -> bool:
+    """Whether the kernel under a selection is the lowering
+    (:func:`_pair_tiles`): a head's unrotated dims end half a lane tile in
+    and its 64 rotary dims fill it, so a pair's ``k_nope`` columns are whole
+    tiles (192 | 64)."""
+    return _pair_tiles(q_shape, k_nope_shape, k_rope_shape, v_shape, dtype,
+                       n_heads, rotary_shape, LANES // 2, BLOCK_Q, BLOCK_K)
+
+
+# -- the same attention over every causal key ---------------------------------
+
+def _latent_kernel(q_ref, kn_ref, kr_ref, v_ref, *refs, bq: int, bk: int,
+                   dn: int, dv: int, scale: float, half: Optional[int]):
+    """One (batch row, pair of heads, block of query rows) against one key
+    block of its causal half, no selection: causal by position, the blocks
+    wholly under the diagonal unmasked.  A head is ``dn`` unrotated dims
+    (whole lane tiles) and 64 rotary ones, so a pair's q columns are whole
+    tiles where one head's are not: the first head's lie as the product
+    wrote them, the second's start half a tile in and are rolled back half a
+    tile, once, at the first key step.  There each head's q becomes ``[q_n |
+    q_r | 0]`` in scratch, its rotary half tile rotated (with tables), q
+    scaled; a head's key block is ``[k_n | k_r | 0]`` (``k_r`` arrives padded
+    to a tile), so one product gives ``q_n . k_n + q_r . k_r``."""
+    if half is None:
+        o_ref, q_s, m_ref, l_ref, acc_ref = refs
+    else:
+        c_ref, s_ref, o_ref, q_s, m_ref, l_ref, acc_ref = refs
+    i, j = pl.program_id(2), pl.program_id(3)
+    last = (i * bq + bq - 1) // bk  # the last key block a row here may see
+    whole = (i * bq + 1) // bk      # key blocks every row here sees whole
+    n = dn // LANES                 # a head's tiles of unrotated dims
+    d = dn + LANES                  # a head in scratch: those and [q_r | 0]
+
+    @pl.when(j == 0)
+    def _():
+        _start(m_ref, l_ref, acc_ref)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (bq, LANES), 1)
+        low = lane < LANES // 2
+
+        def tile(m):
+            return q_ref[0, :, m * LANES:(m + 1) * LANES]
+
+        def rotary(x):  # [q_r | whatever] -> [q_r rotated and scaled | 0]
+            if half is not None:
+                x = _rotated(x, lane, c_ref, s_ref, half)
+            return jnp.where(low, x * scale, jnp.zeros_like(x))
+
+        for m in range(n):  # the first head, as it lies
+            q_s[:, m * LANES:(m + 1) * LANES] = tile(m) * scale
+        q_s[:, dn:d] = rotary(tile(n))
+        rolled = [pltpu.roll(tile(n + m).astype(jnp.float32), LANES // 2, 1)
+                  for m in range(n + 1)]
+        for m in range(n):  # the second, from half a tile in
+            q_s[:, d + m * LANES:d + (m + 1) * LANES] = (jnp.where(
+                low, rolled[m], rolled[m + 1]) * scale).astype(q_s.dtype)
+        q_s[:, d + dn:2 * d] = rotary(rolled[n].astype(q_s.dtype))
+
+    def step(masked: bool):
+        shared = kr_ref[0]
+        if masked:
+            seen = (j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+                    <= i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk),
+                                                         0))
+        for h in range(2):  # static: the pair
+            k = jnp.concatenate([kn_ref[0, :, h * dn:(h + 1) * dn], shared],
+                                axis=1)
+            s = jax.lax.dot_general(q_s[:, h * d:(h + 1) * d], k,
+                                    (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            if masked:
+                s = jnp.where(seen, s, MASKED)
+            _take_in(h, s, v_ref[0, :, h * dv:(h + 1) * dv], m_ref, l_ref,
+                     acc_ref)
+
+    pl.when(j < whole)(functools.partial(step, False))
+    pl.when((j >= whole) & (j <= last))(functools.partial(step, True))
+    pl.when(j == last)(functools.partial(_write_out, o_ref, l_ref, acc_ref,
+                                         dv))
+
+
+def latent_attention_kernel(q, k_nope, k_rope, v, n_heads: int, rotary=None,
+                            scale: Optional[float] = None,
+                            block_q: Optional[int] = None,
+                            block_k: Optional[int] = None,
+                            interpret: Optional[bool] = None):
+    """Causal softmax attention over every key up to the query's own,
+    token-major and on the projections as the products write them: ``q``
+    ``[B, T, H * (dn + 64)]``, ``k_nope`` ``[B, T, H * dn]``, ``k_rope``
+    ``[B, T, 64]``, ``v`` ``[B, T, H * dv]``; :func:`latent_tiles` says
+    which shapes.  No mask is an operand: a grid step takes a pair of heads
+    (a head's ``dn + 64`` columns of q are no whole lane tiles, a pair's
+    are) and one key block; key blocks past a query block's last row are
+    neither fetched nor computed, and those wholly under its first row need
+    no predicate.  ``rotary`` and ``scale`` as
+    :func:`sparse_attention_kernel` takes them; ``k_rope`` is rotated
+    through :func:`rotate` and padded to a lane tile."""
+    b, t, _ = q.shape
+    d, dv = q.shape[-1] // n_heads, v.shape[-1] // n_heads
+    dn = k_nope.shape[-1] // n_heads
+    if scale is None:
+        scale = d ** -0.5
+    bq = min(block_q or LATENT_BLOCK_Q, t)
+    bk = min(block_k or LATENT_BLOCK_K, t)
+    if interpret is None:
+        interpret = _interpret()
+
+    def keys(i, h, r, j):
+        return (i, jnp.minimum(j, (r * bq + bq - 1) // bk), h)
+
+    def rows(i, h, r, j):
+        return (i, r, h)
+
+    if rotary is not None:
+        k_rope = rotate(k_rope, *rotary, 1)
+    operands = [q, k_nope,
+                jnp.pad(k_rope, ((0, 0), (0, 0), (0, LANES - d + dn))), v]
+    in_specs = [pl.BlockSpec((1, bq, 2 * d), rows),
+                pl.BlockSpec((1, bk, 2 * dn), keys),
+                pl.BlockSpec((1, bk, LANES),
+                             lambda i, h, r, j: (*keys(i, h, r, j)[:2], 0)),
+                pl.BlockSpec((1, bk, 2 * dv), keys)]
+    half = None
+    if rotary is not None:
+        half = rotary[0].shape[-1]
+        operands += _lane_tables(*rotary, t)
+        in_specs += [pl.BlockSpec((bq, LANES), lambda i, h, r, j: (r, 0))] * 2
+    itemsize = jnp.dtype(q.dtype).itemsize
+    seen = t * (t + 1) // 2
+    return pl.pallas_call(
+        functools.partial(_latent_kernel, bq=bq, bk=bk, dn=dn, dv=dv,
+                          scale=scale, half=half),
+        out_shape=jax.ShapeDtypeStruct(v.shape, q.dtype),
+        grid=(b, n_heads // 2, t // bq, t // bk),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, bq, 2 * dv), rows),
+        scratch_shapes=[pltpu.VMEM((bq, 2 * (dn + LANES)), q.dtype),
+                        pltpu.VMEM((2, bq, 1), jnp.float32),
+                        pltpu.VMEM((2, bq, 1), jnp.float32),
+                        pltpu.VMEM((2, bq, dv), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            vmem_limit_bytes=LATENT_VMEM_LIMIT),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * b * n_heads * seen * (d + dv),
+            transcendentals=b * n_heads * seen,
+            bytes_accessed=b * t * n_heads * (2 * d + 2 * dv) * itemsize),
+        interpret=interpret,
+        name=LATENT_KERNEL_NAME,
+    )(*operands)
+
+
+def latent_tiles(q_shape, k_nope_shape, k_rope_shape, v_shape, dtype,
+                 n_heads: int, rotary_shape=None) -> bool:
+    """Whether :func:`latent_attention_kernel` is the lowering
+    (:func:`_pair_tiles`): a head's unrotated dims are whole lane tiles
+    beside its 64 rotary ones (128 | 64 with values of 128 and wider)."""
+    return _pair_tiles(q_shape, k_nope_shape, k_rope_shape, v_shape, dtype,
+                       n_heads, rotary_shape, 0, LATENT_BLOCK_Q,
+                       LATENT_BLOCK_K)
 
 
 latent_sparse_attention_p = Primitive("nns_latent_sparse_attention")
 
 
 def latent_sparse_attention(q, k_nope, k_rope, v, mask, n_heads: int,
-                            rotary=None):
+                            rotary=None, scale: Optional[float] = None):
     """``q`` ``[B, T, H * (dn + dr)]``, each head's ``dn`` unrotated dims and
     then its ``dr`` rotary ones; ``k_nope`` ``[B, T, H * dn]``; ``k_rope``
     ``[B, T, dr]``, the rotary key part every head shares; ``v`` ``[B, T, H
-    * dv]``; ``mask`` ``[B, T, T]`` int8 from :func:`select_keys`.  With
-    ``rotary`` = ``(cos, sin)``, each ``[T, rot/2]`` float32, ``q`` and
-    ``k_rope`` come as the products wrote them and the lowering applies
+    * dv]``; ``mask`` ``[B, T, T]`` int8 from :func:`select_keys`, or
+    ``None``: no selection, every key up to the query's own position (no
+    ``[B, T, T]`` array is an operand then, or made).  With ``rotary`` =
+    ``(cos, sin)``, each ``[T, rot/2]`` float32, ``q`` and ``k_rope`` come
+    as the products wrote them and the lowering applies
     ``fused_attention.rotate`` to each head's rotary dims and to ``k_rope``;
     without, both come rotated.  Returns ``[B, T, H * dv]``: per head the
-    softmax of ``(q_n . k_n + q_r . k_r) / sqrt(dn + dr)`` over the selected
-    keys alone, times ``v``.  See the module's docstring for which lowering
-    a call gets."""
-    return latent_sparse_attention_p.bind(q, k_nope, k_rope, v, mask,
-                                          *(rotary or ()), n_heads=n_heads)
+    softmax of ``(q_n . k_n + q_r . k_r) * scale`` (``scale``: ``(dn + dr)
+    ** -0.5`` if left out) over the selected keys alone, times ``v``.  See
+    the module's docstring for which lowering a call gets."""
+    selected = mask is not None
+    return latent_sparse_attention_p.bind(
+        q, k_nope, k_rope, v, *((mask,) if selected else ()),
+        *(rotary or ()), n_heads=n_heads, selected=selected, scale=scale)
 
 
 latent_sparse_attention_p.def_impl(jax.jit(
-    latent_sparse_attention_p.bind, static_argnames=("n_heads",)))
+    latent_sparse_attention_p.bind,
+    static_argnames=("n_heads", "selected", "scale")))
 latent_sparse_attention_p.def_abstract_eval(
     lambda q, k_nope, k_rope, v, *_, **__: v.update(dtype=q.dtype))
 
 
-def _lower_plain(ctx, *operands, n_heads):
-    _count_lowering("latent_sparse_plain",
-                    "outside" if len(operands) == 7 else None)
+def _lower_plain(ctx, *operands, n_heads, selected, scale):
+    _count_lowering("latent_sparse_plain" if selected else "latent_plain",
+                    "outside" if len(operands) == 6 + selected else None)
 
-    def walk(q, k_nope, k_rope, v, mask, *tables):
+    def walk(q, k_nope, k_rope, v, *rest):
+        mask, tables = (rest[0] if selected else None), rest[selected:]
         if tables:
             q = rotate(q, *tables, n_heads, k_nope.shape[-1] // n_heads)
             k_rope = rotate(k_rope, *tables, 1)
-        return _plain(q, k_nope, k_rope, v, mask, n_heads=n_heads)
+        return _plain(q, k_nope, k_rope, v, mask, n_heads=n_heads,
+                      scale=scale)
 
     return mlir.lower_fun(walk, multiple_results=False)(ctx, *operands)
 
 
-def _lower_tpu(ctx, *operands, n_heads):
-    q, k_nope, k_rope, v, _, *tables = ctx.avals_in
+def _lower_tpu(ctx, *operands, n_heads, selected, scale):
+    q, k_nope, k_rope, v, *rest = ctx.avals_in
+    tables = rest[selected:]
+    tiles = sparse_tiles if selected else latent_tiles
     if not (_on_one_device(ctx.module_context.axis_context)
             and q.dtype == k_nope.dtype == k_rope.dtype == v.dtype
-            and sparse_tiles(q.shape, k_nope.shape, k_rope.shape, v.shape,
-                             q.dtype, n_heads,
-                             tables[0].shape if tables else None)):
-        return _lower_plain(ctx, *operands, n_heads=n_heads)
-    _count_lowering("latent_sparse", "kernel" if tables else None)
-    return mlir.lower_fun(
-        lambda q, k_nope, k_rope, v, mask, *tables: sparse_attention_kernel(
-            q, k_nope, k_rope, v, mask, n_heads, tables or None,
-            interpret=False),
-        multiple_results=False)(ctx, *operands)
+            and tiles(q.shape, k_nope.shape, k_rope.shape, v.shape, q.dtype,
+                      n_heads, tables[0].shape if tables else None)):
+        return _lower_plain(ctx, *operands, n_heads=n_heads,
+                            selected=selected, scale=scale)
+    _count_lowering("latent_sparse" if selected else "latent",
+                    "kernel" if tables else None)
+
+    def kernel(q, k_nope, k_rope, v, *rest):
+        if selected:
+            return sparse_attention_kernel(
+                q, k_nope, k_rope, v, rest[0], n_heads, rest[1:] or None,
+                interpret=False, scale=scale)
+        return latent_attention_kernel(q, k_nope, k_rope, v, n_heads,
+                                       rest or None, scale, interpret=False)
+
+    return mlir.lower_fun(kernel, multiple_results=False)(ctx, *operands)
 
 
 # not cacheable: every call site is lowered, and counted, on its own

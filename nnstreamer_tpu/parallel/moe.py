@@ -174,21 +174,43 @@ def swiglu(x, w_in, w_out):
     return matmul(jax.nn.silu(gate) * up, w_out)
 
 
-def route_top_k(x, router, top_k: int, scaling: float = 1.0, bias=None):
+def route_top_k(x, router, top_k: int, scaling: float = 1.0, bias=None,
+                n_group: Optional[int] = None,
+                topk_group: Optional[int] = None):
     """``(weights, experts)``, both ``[tokens, top_k]``: sigmoid scores of
     ``x @ router`` in float32, the ``top_k`` highest a token, renormalised
     to sum 1 and times ``scaling``.  With ``bias`` ``[experts]`` the choice
     is by ``score + bias`` and the weights are the chosen experts' unbiased
-    scores (a load-balancing bias that steers the choice alone)."""
+    scores (a load-balancing bias that steers the choice alone).
+
+    ``n_group``, ``topk_group``: the group-limited choice.  The experts
+    stand in ``n_group`` groups of equal size in index order, a group's
+    score is the sum of its two highest (biased) scores, the ``topk_group``
+    highest groups are kept (the lower index among equals) and the
+    ``top_k`` experts are chosen among the kept groups' alone.  Left out,
+    or with one group, every expert stands for choice."""
     scores = jax.nn.sigmoid(jnp.dot(
         x.astype(jnp.float32), router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
-    if bias is None:
+    choice = scores if bias is None else scores + bias.astype(jnp.float32)
+    if group_limited(n_group):
+        n, e = choice.shape
+        grouped = choice.reshape(n, n_group, e // n_group)
+        _, kept = jax.lax.top_k(
+            jax.lax.top_k(grouped, 2)[0].sum(axis=-1), topk_group)
+        open_ = (kept[:, :, None] == jnp.arange(n_group)).any(axis=1)
+        choice = jnp.where(open_[:, :, None], grouped, -jnp.inf).reshape(n, e)
+    if choice is scores:
         top, experts = jax.lax.top_k(scores, top_k)
     else:
-        _, experts = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+        _, experts = jax.lax.top_k(choice, top_k)
         top = jnp.take_along_axis(scores, experts, axis=-1)
     return top / top.sum(axis=-1, keepdims=True) * scaling, experts
+
+
+def group_limited(n_group: Optional[int]) -> bool:
+    """Whether a router of ``n_group`` groups limits the choice at all."""
+    return n_group is not None and n_group > 1
 
 
 def _routed_grouped(x, weights, experts, w_in, w_out):
@@ -295,16 +317,18 @@ def _routed_share(x, weights, experts, w_in, w_out, *, first: int,
 
 # One primitive, as ``ops/fused_attention.attention_p``: a trace does not know
 # what it will be lowered for, so which of the two runs the products is the
-# lowering rule's choice.  ``first``/``total``: the share (``None``: all).
+# lowering rule's choice.  ``first``/``total``: the share (``None``: all);
+# ``choice``: how the router chose, for the rule to count.
 
 routed_experts_p = Primitive("nns_routed_experts")
-routed_experts_p.def_impl(jax.jit(routed_experts_p.bind,
-                                  static_argnames=("first", "total")))
+routed_experts_p.def_impl(jax.jit(
+    routed_experts_p.bind, static_argnames=("first", "total", "choice")))
 routed_experts_p.def_abstract_eval(lambda x, *_, **__: x)
 
 
 def routed_experts(x, weights, experts, w_in, w_out,
-                   first: Optional[int] = None, total: Optional[int] = None):
+                   first: Optional[int] = None, total: Optional[int] = None,
+                   choice: str = "global"):
     """Every (token, expert) pair of ``experts`` ``[tokens, k]`` through its
     expert's SwiGLU (``w_in`` ``[E, d, 2f]``, ``w_out`` ``[E, f, d]``), the
     ``k`` results of a token summed under ``weights``.  ``[tokens, d]``.
@@ -319,7 +343,9 @@ def routed_experts(x, weights, experts, w_in, w_out,
     of the ``total`` that ``experts`` counts over (one chip's share of an
     expert-parallel layer); the result is the held experts' part
     (:func:`_routed_share`).  Left out, or with ``E == total``, the layer
-    holds them all."""
+    holds them all.  ``choice``: ``"global"`` or ``"group_limited"``, how
+    :func:`route_top_k` chose ``experts``; the lowering rule counts a layer
+    under it (``nnstpu_moe_routing_total``)."""
     if first is None or w_in.shape[0] == total:
         first = total = None
     elif not 0 <= first <= total - w_in.shape[0]:
@@ -328,10 +354,10 @@ def routed_experts(x, weights, experts, w_in, w_out,
     if isinstance(w_in, QuantizedWeight) or isinstance(w_out, QuantizedWeight):
         if first is not None:
             raise NotImplementedError("a share of quantized experts")
-        _count_moe_lowering("grouped")
+        _count_moe_lowering("grouped", choice)
         return _routed_grouped(x, weights, experts, w_in, w_out)
     return routed_experts_p.bind(x, weights, experts, w_in, w_out,
-                                 first=first, total=total)
+                                 first=first, total=total, choice=choice)
 
 
 def _xla_path(first, total):
@@ -340,14 +366,14 @@ def _xla_path(first, total):
     return functools.partial(_routed_share, first=first, total=total)
 
 
-def _lower_grouped(ctx, *operands, first=None, total=None):
-    _count_moe_lowering("grouped")
+def _lower_grouped(ctx, *operands, first=None, total=None, choice="global"):
+    _count_moe_lowering("grouped", choice)
     _say_held(ctx.avals_in[3].shape[0], total)
     return mlir.lower_fun(_xla_path(first, total), multiple_results=False)(
         ctx, *operands)
 
 
-def _lower_tpu(ctx, *operands, first=None, total=None):
+def _lower_tpu(ctx, *operands, first=None, total=None, choice="global"):
     from ..ops.fused_attention import _on_one_device
     from ..ops.grouped_experts import tiles
 
@@ -357,8 +383,9 @@ def _lower_tpu(ctx, *operands, first=None, total=None):
             and x.dtype == w_in.dtype == w_out.dtype
             and tiles((experts.size, x.shape[-1]), w_in.shape, w_out.shape,
                       x.dtype)):
-        return _lower_grouped(ctx, *operands, first=first, total=total)
-    _count_moe_lowering("fused")
+        return _lower_grouped(ctx, *operands, first=first, total=total,
+                              choice=choice)
+    _count_moe_lowering("fused", choice)
     _say_held(w_in.shape[0], total)
     return mlir.lower_fun(functools.partial(_routed_fused, interpret=False),
                           multiple_results=False)(ctx, *operands)
@@ -370,9 +397,10 @@ mlir.register_lowering(routed_experts_p, _lower_tpu, platform="tpu",
                        cacheable=False)
 # derivatives are the XLA path's (no vmap: ``ragged_dot`` has none over its
 # group sizes either)
-ad.primitive_jvps[routed_experts_p] = lambda primals, tangents, **share: \
-    jax.jvp(_xla_path(**share), primals,
-            tuple(ad.instantiate_zeros(t) for t in tangents))
+ad.primitive_jvps[routed_experts_p] = \
+    lambda primals, tangents, first, total, choice: jax.jvp(
+        _xla_path(first, total), primals,
+        tuple(ad.instantiate_zeros(t) for t in tangents))
 
 
 def _say_held(held: int, total: Optional[int]) -> None:
@@ -386,7 +414,7 @@ def _say_held(held: int, total: Optional[int]) -> None:
     ).set(held, of=str(total))
 
 
-def _count_moe_lowering(path: str) -> None:
+def _count_moe_lowering(path: str, choice: Optional[str] = None) -> None:
     from ..obs.metrics import REGISTRY
 
     REGISTRY.counter(
@@ -396,10 +424,20 @@ def _count_moe_lowering(path: str) -> None:
         "the same through XLA's grouped matrix product, switch = top-1 with "
         "a capacity)", labelnames=("path",),
     ).inc(path=path)
+    if choice is not None:
+        REGISTRY.counter(
+            "nnstpu_moe_routing_total",
+            "top-k expert layers lowered into a program, by how their router "
+            "chooses (global = the k highest of all experts, group_limited = "
+            "the k highest among the experts of the highest-scoring groups)",
+            labelnames=("choice",),
+        ).inc(choice=choice)
 
 
 def moe_top_k(params: Params, x, top_k: int, scaling: float = 1.0,
-              token_chunk: Optional[int] = None, first: Optional[int] = None):
+              token_chunk: Optional[int] = None, first: Optional[int] = None,
+              n_group: Optional[int] = None,
+              topk_group: Optional[int] = None):
     """Top-``top_k`` of ``E`` SwiGLU experts beside a shared one.
 
     ``params``: ``router`` ``[d, E]``, ``w_in`` ``[E, d, 2f]``, ``w_out``
@@ -416,6 +454,10 @@ def moe_top_k(params: Params, x, top_k: int, scaling: float = 1.0,
     of the router's ``E``.  The router stays ``E`` wide and picks among all
     of them, the held experts' part of the routed sum is computed
     (:func:`routed_experts`), and the shared expert whole.
+
+    ``n_group``, ``topk_group``: the router's group-limited choice
+    (:func:`route_top_k`); a token none of whose kept groups holds an expert
+    held here sends nothing to the routed part.
     """
     shape = x.shape
     xt = x.reshape(-1, shape[-1])
@@ -423,9 +465,10 @@ def moe_top_k(params: Params, x, top_k: int, scaling: float = 1.0,
 
     def chunk(xc):
         w, experts = route_top_k(xc, params["router"], top_k, scaling,
-                                 params.get("bias"))
-        out = routed_experts(xc, w, experts, params["w_in"], params["w_out"],
-                             first, total)
+                                 params.get("bias"), n_group, topk_group)
+        out = routed_experts(
+            xc, w, experts, params["w_in"], params["w_out"], first, total,
+            "group_limited" if group_limited(n_group) else "global")
         if "shared" in params:
             out = out + swiglu(xc, params["shared"]["w_in"],
                                params["shared"]["w_out"])

@@ -592,3 +592,44 @@ def test_mosaic_compiles_the_selection_and_the_attention_under_it(v5e_2x2):
     assert "sort" not in select.as_text()
     assert paths() == (before[0] + 1, before[1] + 1)
 
+
+
+def test_mosaic_compiles_the_latent_attention_without_a_selection(v5e_2x2):
+    """A.X-K1's widths at two windows of 16 384 tokens
+    (``ops/sparse_attention``): 64 heads of 128 | 64 with values of 128, no
+    selection.  One layer's attention lowers ``nns_latent_attention`` for
+    one v5e chip, with the layer's YaRN tables (q rotated in the kernel) and
+    without; no mask is an operand, and the program holds neither a ``T x
+    T`` array nor q or the keys a head a row."""
+    from jax.sharding import SingleDeviceSharding
+
+    from nnstreamer_tpu.ops import sparse_attention as sa
+
+    b, t, h = 2, 16384, 64
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(
+            dims, dtype, sharding=SingleDeviceSharding(v5e_2x2[0]))
+
+    def counted(name, label):
+        metric = REGISTRY.get(name)
+        got = {k[0]: c.value for k, c in metric.children()} if metric else {}
+        return got.get(label, 0)
+
+    paths, rotary = ("nnstpu_attention_lowerings_total",
+                     "nnstpu_attention_rotary_total")
+    before = counted(paths, "latent"), counted(rotary, "kernel")
+    operands = (shape(b, t, h * 192), shape(b, t, h * 128), shape(b, t, 64),
+                shape(b, t, h * 128))
+    attend = jax.jit(lambda *a: sa.latent_sparse_attention(
+        *a, None, h, scale=0.130861)).lower(*operands).compile()
+    assert sa.LATENT_KERNEL_NAME in attend.as_text()
+    table = shape(t, 32, dtype=jnp.float32)
+    attend = jax.jit(lambda *a: sa.latent_sparse_attention(
+        *a[:4], None, h, rotary=a[4:], scale=0.130861)).lower(
+        *operands, table, table).compile()
+    text = attend.as_text()
+    assert sa.LATENT_KERNEL_NAME in text and sa.KERNEL_NAME not in text
+    assert f"[{b},{t},{h},192]" not in text and f"{t},{t}]" not in text
+    assert (counted(paths, "latent"), counted(rotary, "kernel")) == (
+        before[0] + 2, before[1] + 1)
