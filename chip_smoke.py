@@ -555,6 +555,44 @@ class Smoke:
             * pair_w[:, None])
         np.testing.assert_allclose(got, want, rtol=2e-2, atol=2e-2)
         out["grouped_experts_max_abs_err"] = float(np.abs(got - want).max())
+        # a share's way back (parallel/moe._routed_share) as this device
+        # lowers it, at the two cells' widths: an even routing (one pass) and
+        # every pick held (eight passes) against the dense masked sum
+        from nnstreamer_tpu.obs.metrics import REGISTRY
+        from nnstreamer_tpu.parallel import moe
+
+        n, top, f = (64, 4, 16) if self.rehearsal else (512, 8, 128)
+        for d, held, total in (((128, 4, 16), (256, 6, 12)) if self.rehearsal
+                               else ((6144, 16, 256), (7168, 12, 192))):
+            x = jnp.asarray(rng.standard_normal((n, d)), jnp.bfloat16)
+            w_in = jnp.asarray(rng.standard_normal((held, d, 2 * f))
+                               * d ** -0.5, jnp.bfloat16)
+            w_out = jnp.asarray(rng.standard_normal((held, f, d))
+                                * 0.5 * f ** -0.5, jnp.bfloat16)
+            share = jax.jit(lambda *a, total=total: moe.routed_experts(
+                *a, first=0, total=total))
+            for load, among in (("even", total), ("all_held", held)):
+                experts = jnp.asarray(np.argsort(
+                    rng.random((n, among)), axis=-1)[:, :top], jnp.int32)
+                w = jnp.asarray(rng.random((n, top)) + 0.5, jnp.float32)
+                w = w / w.sum(axis=-1, keepdims=True)
+                got = np.asarray(share(x, w, experts, w_in, w_out).astype(
+                    jnp.float32))
+                with jax.default_matmul_precision("highest"):
+                    want = np.asarray(sum(
+                        (w * (experts == e)).sum(axis=-1)[:, None]
+                        * moe.swiglu(x.astype(jnp.float32),
+                                     w_in[e].astype(jnp.float32),
+                                     w_out[e].astype(jnp.float32))
+                        for e in range(held)))
+                np.testing.assert_allclose(got, want, rtol=2e-2, atol=2e-2)
+                out[f"share_way_back_d{d}_{load}_max_abs_err"] = float(
+                    np.abs(got - want).max())
+        ways = REGISTRY.get("nnstpu_moe_share_combine_total")
+        out["share_way_back_lowered"] = {
+            "/".join(k): c.value for k, c in ways.children()}
+        check(interpret or out["share_way_back_lowered"] == {"kernel": 2},
+              "a share's way back did not lower to nns_combine_rows")
         # the selection and the attention under it (ops/sparse_attention):
         # both kernels against the plain walks, the selection bit for bit
         from nnstreamer_tpu.ops import sparse_attention as sa

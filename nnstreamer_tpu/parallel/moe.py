@@ -266,8 +266,15 @@ def share_rows(pairs: int, held: int, total: int) -> int:
     return min(pairs, -(-2 * even // SHARE_ROW_TILE) * SHARE_ROW_TILE)
 
 
+def _combine_plain(out, y, token, w):
+    """``out[token[j]] += w[j] * y[j]`` as XLA's scatter-add, product and sum
+    in float32; ``token[j] == len(out)`` is nobody's row and dropped."""
+    return out.at[token].add(
+        y.astype(jnp.float32) * w.astype(jnp.float32)[:, None], mode="drop")
+
+
 def _routed_share(x, weights, experts, w_in, w_out, *, first: int,
-                  total: int):
+                  total: int, combine=_combine_plain):
     """The part of the layer's result that experts ``first ... first + E``
     give, ``E`` = ``w_in.shape[0]`` of the ``total`` the router chose among:
     the pairs of an expert held sort to the front, by expert, the others
@@ -277,38 +284,40 @@ def _routed_share(x, weights, experts, w_in, w_out, *, first: int,
     one where the load is near even, ``pairs / rows`` where every pick of
     every token is held), so the buffer is a pass's and no pair is dropped
     whatever the routing.  Nothing stands in for the experts held
-    elsewhere: their pairs add zero here."""
+    elsewhere: their pairs add zero here.
+
+    A pass's results go back by its own rows alone: row ``j`` is its pair's
+    token's, weighed by the pair's weight (in ``x``'s type) and added to the
+    token's float32 row by ``combine`` (:func:`_combine_plain`, or
+    ``ops/combine_rows`` where the lowering rule takes the kernel); the
+    pass's rows past the held pairs are no expert's and nobody's."""
     n, k = experts.shape
     held = w_in.shape[0]
     pairs = n * k
     rows = share_rows(pairs, held, total)
     local = experts.reshape(-1).astype(jnp.int32) - first
-    by_expert, order = jax.lax.sort(
+    by_expert, order, pair_weights = jax.lax.sort(
         (jnp.where((local >= 0) & (local < held), local, held),
-         jnp.arange(pairs, dtype=jnp.int32)), num_keys=1, is_stable=True)
+         jnp.arange(pairs, dtype=jnp.int32),
+         weights.reshape(-1).astype(x.dtype).astype(jnp.float32)),
+        num_keys=1, is_stable=True)
     starts = jnp.searchsorted(
         by_expert, jnp.arange(held + 1, dtype=jnp.int32)).astype(jnp.int32)
     n_held = starts[-1]
-    back = jnp.argsort(order)                   # where each pair's row went
     order = jnp.pad(order, (0, rows))           # the last pass may overhang
-    pair_weights = weights.astype(x.dtype)
+    pair_weights = jnp.pad(pair_weights, (0, rows))
 
     def one_pass(c, out):
         r0 = c * rows
-        idx = jax.lax.dynamic_slice(order, (r0,), (rows,))
+        token = jax.lax.dynamic_slice(order, (r0,), (rows,)) // k
         sizes = jnp.diff(jnp.clip(starts, r0, r0 + rows))
-        gate, up = jnp.split(jax.lax.ragged_dot(x[idx // k], w_in, sizes), 2,
+        gate, up = jnp.split(jax.lax.ragged_dot(x[token], w_in, sizes), 2,
                              axis=-1)
         y = jax.lax.ragged_dot((jax.nn.silu(gate) * up).astype(x.dtype),
                                w_out, sizes)
-        # row ``rows`` is the zero every pair outside this pass reads; the
-        # pass's rows past the held pairs are no expert's and nobody's
-        y = jnp.concatenate([y, jnp.zeros((1, y.shape[-1]), y.dtype)])
-        at = back - r0
-        here = (at >= 0) & (at < rows) & (back < n_held)
-        y = y[jnp.where(here, at, rows)].reshape(n, k, -1)
-        return out + jnp.einsum("nkd,nk->nd", y, pair_weights,
-                                preferred_element_type=jnp.float32)
+        live = r0 + jnp.arange(rows, dtype=jnp.int32) < n_held
+        return combine(out, y, jnp.where(live, token, n),
+                       jax.lax.dynamic_slice(pair_weights, (r0,), (rows,)))
 
     out = jax.lax.fori_loop(0, (n_held + rows - 1) // rows, one_pass,
                             jnp.zeros((n, x.shape[-1]), jnp.float32))
@@ -342,10 +351,14 @@ def routed_experts(x, weights, experts, w_in, w_out,
     ``first``, ``total``: this layer holds experts ``first ... first + E``
     of the ``total`` that ``experts`` counts over (one chip's share of an
     expert-parallel layer); the result is the held experts' part
-    (:func:`_routed_share`).  Left out, or with ``E == total``, the layer
-    holds them all.  ``choice``: ``"global"`` or ``"group_limited"``, how
-    :func:`route_top_k` chose ``experts``; the lowering rule counts a layer
-    under it (``nnstpu_moe_routing_total``)."""
+    (:func:`_routed_share`: XLA's ``ragged_dot`` in every program; a pass's
+    results go back to their tokens through ``ops/combine_rows``' kernel in
+    a one-device TPU program whose shapes ``combine_rows.tiles()``, as
+    XLA's scatter-add in any other, ``nnstpu_moe_share_combine_total``).
+    Left out, or with ``E == total``, the layer holds them all.
+    ``choice``: ``"global"`` or ``"group_limited"``, how :func:`route_top_k`
+    chose ``experts``; the lowering rule counts a layer under it
+    (``nnstpu_moe_routing_total``)."""
     if first is None or w_in.shape[0] == total:
         first = total = None
     elif not 0 <= first <= total - w_in.shape[0]:
@@ -360,31 +373,45 @@ def routed_experts(x, weights, experts, w_in, w_out,
                                  first=first, total=total, choice=choice)
 
 
-def _xla_path(first, total):
+def _xla_path(first, total, combine=_combine_plain):
     if first is None:
         return _routed_grouped
-    return functools.partial(_routed_share, first=first, total=total)
+    return functools.partial(_routed_share, first=first, total=total,
+                             combine=combine)
 
 
-def _lower_grouped(ctx, *operands, first=None, total=None, choice="global"):
+def _lower_grouped(ctx, *operands, first=None, total=None, choice="global",
+                   combine=_combine_plain):
     _count_moe_lowering("grouped", choice)
+    if first is not None:
+        _count_share_combine(
+            "plain" if combine is _combine_plain else "kernel")
     _say_held(ctx.avals_in[3].shape[0], total)
-    return mlir.lower_fun(_xla_path(first, total), multiple_results=False)(
-        ctx, *operands)
+    return mlir.lower_fun(_xla_path(first, total, combine),
+                          multiple_results=False)(ctx, *operands)
 
 
 def _lower_tpu(ctx, *operands, first=None, total=None, choice="global"):
+    from ..ops import combine_rows
     from ..ops.fused_attention import _on_one_device
     from ..ops.grouped_experts import tiles
 
     x, _, experts, w_in, w_out = ctx.avals_in
-    if not (first is None
-            and _on_one_device(ctx.module_context.axis_context)
-            and x.dtype == w_in.dtype == w_out.dtype
-            and tiles((experts.size, x.shape[-1]), w_in.shape, w_out.shape,
-                      x.dtype)):
-        return _lower_grouped(ctx, *operands, first=first, total=total,
-                              choice=choice)
+    kernels = (_on_one_device(ctx.module_context.axis_context)
+               and x.dtype == w_in.dtype == w_out.dtype)
+    if first is not None:
+        # a share's products are XLA's either way; its way back is the
+        # kernel's where a pass's rows and the tokens' tile
+        rows = share_rows(experts.size, w_in.shape[0], total)
+        return _lower_grouped(
+            ctx, *operands, first=first, total=total, choice=choice,
+            combine=functools.partial(combine_rows.combine_rows,
+                                      interpret=False)
+            if kernels and combine_rows.tiles(x.shape, rows, x.dtype)
+            else _combine_plain)
+    if not (kernels and tiles((experts.size, x.shape[-1]), w_in.shape,
+                              w_out.shape, x.dtype)):
+        return _lower_grouped(ctx, *operands, choice=choice)
     _count_moe_lowering("fused", choice)
     _say_held(w_in.shape[0], total)
     return mlir.lower_fun(functools.partial(_routed_fused, interpret=False),
@@ -412,6 +439,18 @@ def _say_held(held: int, total: Optional[int]) -> None:
         "experts whose weights the last expert layer lowered holds, of the "
         "experts its router chooses among", labelnames=("of",),
     ).set(held, of=str(total))
+
+
+def _count_share_combine(way: str) -> None:
+    from ..obs.metrics import REGISTRY
+
+    REGISTRY.counter(
+        "nnstpu_moe_share_combine_total",
+        "expert layers holding a share of their experts lowered into a "
+        "program, by how a pass's results go back to their tokens (kernel = "
+        "sorted by token and summed as a banded one-hot product in the "
+        "Pallas kernel, plain = XLA's scatter-add)", labelnames=("way",),
+    ).inc(way=way)
 
 
 def _count_moe_lowering(path: str, choice: Optional[str] = None) -> None:

@@ -499,10 +499,24 @@ class ContinuousBatcher:
                 self._check_alive()
                 # safe under _cv: the engine thread needs the lock to
                 # start the next tick, and the last one fully closed
-                cache = np.asarray(jax.device_get(
-                    self._caches[sess.slot].astype(jnp.float32)))
-                pos = int(np.asarray(
-                    jax.device_get(self._poss[sess.slot])).reshape(-1)[0])
+                restored = [r for r in self._restores if r[0] == sess.slot]
+                if restored:
+                    # restored here and snapshotted again before the engine
+                    # thread's next _gather put the restore on the device
+                    # (a handoff onto a worker that is drained meanwhile):
+                    # the slot's state is the pending one, not the arrays'
+                    _, cache, pos = restored[-1]
+                    cache = np.asarray(cache, np.float32)
+                elif sess.slot in self._resets:
+                    # joined and never ticked: the arrays still hold the
+                    # slot's last occupant
+                    cache = np.zeros(self._caches.shape[1:], np.float32)
+                    pos = 0
+                else:
+                    cache = np.asarray(jax.device_get(
+                        self._caches[sess.slot].astype(jnp.float32)))
+                    pos = int(np.asarray(jax.device_get(
+                        self._poss[sess.slot])).reshape(-1)[0])
                 pending_in = []
                 while True:
                     try:

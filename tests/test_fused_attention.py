@@ -633,3 +633,58 @@ def test_mosaic_compiles_the_latent_attention_without_a_selection(v5e_2x2):
     assert f"[{b},{t},{h},192]" not in text and f"{t},{t}]" not in text
     assert (counted(paths, "latent"), counted(rotary, "kernel")) == (
         before[0] + 2, before[1] + 1)
+
+
+@pytest.mark.parametrize("d", [7168, 6144], ids=["axk1", "glm"])
+def test_mosaic_compiles_the_way_back_of_a_shares_pass(v5e_2x2, d):
+    """A pass of 8192 rows into a chunk of 8192 tokens at the two share
+    cells' widths (``ops/combine_rows``): the sort by token, the gather of
+    the pass's own rows and ``nns_combine_rows`` compile for one v5e chip,
+    with the float32 rows aliased in and out."""
+    from jax.sharding import SingleDeviceSharding
+
+    from nnstreamer_tpu.ops import combine_rows
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(
+            dims, dtype, sharding=SingleDeviceSharding(v5e_2x2[0]))
+
+    n = rows = 8192
+    assert combine_rows.tiles((n, d), rows, jnp.bfloat16)
+    compiled = jax.jit(
+        lambda *a: combine_rows.combine_rows(*a, interpret=False),
+        donate_argnums=0).lower(
+        shape(n, d, dtype=jnp.float32), shape(rows, d),
+        shape(rows, dtype=jnp.int32), shape(rows, dtype=jnp.float32)).compile()
+    assert combine_rows.KERNEL_NAME in compiled.as_text()
+    # the gathered rows, and no second copy of the tokens' float32 rows
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.1 * rows * d * 2
+
+
+def test_the_chip_compiles_a_share_without_an_array_of_every_routed_pair(
+        v5e_2x2):
+    """A.X-K1's share as ``routed_experts`` lowers it for one v5e chip (8192
+    tokens, top-8, 12 of 192 experts of 7168 x 2048): the kernel behind
+    XLA's two ``ragged_dot``s, counted, and neither a ``[65536, d]`` nor an
+    ``[8192, 8, d]`` array in the program."""
+    from jax.sharding import SingleDeviceSharding
+
+    from nnstreamer_tpu.ops import combine_rows
+    from nnstreamer_tpu.parallel import moe
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(
+            dims, dtype, sharding=SingleDeviceSharding(v5e_2x2[0]))
+
+    n, d, name = 8192, 7168, "nnstpu_moe_share_combine_total"
+    before = lowerings(name)
+    text = jax.jit(lambda *a: moe.routed_experts(
+        *a, first=0, total=192)).lower(
+        shape(n, d), shape(n, 8, dtype=jnp.float32),
+        shape(n, 8, dtype=jnp.int32), shape(12, d, 4096),
+        shape(12, 2048, d)).compile().as_text()
+    assert combine_rows.KERNEL_NAME in text and "ragged-dot" in text
+    assert f"[{n * 8},{d}]" not in text and f"[{n},8,{d}]" not in text
+    after = lowerings(name)
+    assert after["kernel"] == before.get("kernel", 0) + 1
+    assert after.get("plain", 0) == before.get("plain", 0)

@@ -553,6 +553,230 @@ def test_the_walk_takes_as_many_passes_as_the_routing_needs(monkeypatch):
     assert np.abs(np.asarray(got - want)).max() < 1e-4
 
 
+# -- the way back of a share's pass -------------------------------------------
+
+def gather_share(x, weights, experts, w_in, w_out, first, total):
+    """The way back as it was before it went by the pass's own rows (PR 38's
+    ``_routed_share``): a gather over every routed pair, most of them reading
+    a zero row, and a token's ``k`` rows weighed and summed.  Kept here as
+    the plain reference of the way back."""
+    n, k = experts.shape
+    held = w_in.shape[0]
+    pairs = n * k
+    rows = moe.share_rows(pairs, held, total)
+    local = experts.reshape(-1).astype(jnp.int32) - first
+    by_expert, order = jax.lax.sort(
+        (jnp.where((local >= 0) & (local < held), local, held),
+         jnp.arange(pairs, dtype=jnp.int32)), num_keys=1, is_stable=True)
+    starts = jnp.searchsorted(
+        by_expert, jnp.arange(held + 1, dtype=jnp.int32)).astype(jnp.int32)
+    n_held = starts[-1]
+    back = jnp.argsort(order)
+    order = jnp.pad(order, (0, rows))
+    pair_weights = weights.astype(x.dtype)
+    out = jnp.zeros((n, x.shape[-1]), jnp.float32)
+    for c in range(int(-(-n_held // rows))):
+        r0 = c * rows
+        idx = order[r0:r0 + rows]
+        sizes = jnp.diff(jnp.clip(starts, r0, r0 + rows))
+        gate, up = jnp.split(REAL_RAGGED_DOT(x[idx // k], w_in, sizes), 2,
+                             axis=-1)
+        y = REAL_RAGGED_DOT((jax.nn.silu(gate) * up).astype(x.dtype), w_out,
+                            sizes)
+        y = jnp.concatenate([y, jnp.zeros((1, y.shape[-1]), y.dtype)])
+        at = back - r0
+        here = (at >= 0) & (at < rows) & (back < n_held)
+        y = y[jnp.where(here, at, rows)].reshape(n, k, -1)
+        out = out + jnp.einsum("nkd,nk->nd", y, pair_weights,
+                               preferred_element_type=jnp.float32)
+    return out.astype(x.dtype)
+
+
+REAL_RAGGED_DOT = jax.lax.ragged_dot
+
+
+def garbage_past_the_groups(lhs, rhs, sizes):
+    """``ragged_dot`` whose rows past the groups' are not zeros."""
+    out = REAL_RAGGED_DOT(lhs, rhs, sizes)
+    past = jnp.arange(out.shape[0]) >= sizes.sum()
+    return jnp.where(past[:, None], jnp.nan, out)
+
+
+def picks(held_picks):
+    """``[64, 4]`` distinct experts of 16 of which 0-3 are held: token ``t``
+    picks ``held_picks(t)`` (a tuple of held experts) and fills up with
+    experts held elsewhere."""
+    rows = []
+    for t in range(64):
+        mine = tuple(held_picks(t))
+        rows.append(mine + tuple(range(4 + t % 8, 4 + t % 8 + 4 - len(mine))))
+    return jnp.asarray(rows, jnp.int32)
+
+
+def group_limited_picks():
+    """A.X-K1's choice in small: 16 experts in 4 groups, 2 kept, top-4; the
+    share holds experts 0, 1 (half of group 0), and group 0 is closed to
+    every odd token, which so sends nothing here."""
+    ks = jax.random.split(jax.random.PRNGKey(11), 2)
+    x = jax.random.normal(ks[0], (64, 32)) * 0.1
+    x = x.at[:, 0].set(jnp.where(jnp.arange(64) % 2 == 0, 6.0, -6.0))
+    router = (jax.random.normal(ks[1], (32, 16)) * 0.3).at[0].set(
+        jnp.where(jnp.arange(16) < 4, 1.0, 0.0))
+    _, experts = moe.route_top_k(x, router, 4, 1.0, None, 4, 2)
+    sends = np.asarray((experts < 2).any(axis=1))
+    assert sends[::2].all() and not sends[1::2].any()
+    return experts
+
+
+# name: (the picks, rows a pass, experts held, passes, ragged_dot)
+WAYS_BACK = {
+    "tokens_with_no_one_and_three_held_picks": (
+        lambda: picks(lambda t: ((), (t % 4,), (0, 2, 3))[t // 22]),
+        None, 4, 1, None),
+    "every_pick_held_four_passes": (
+        lambda: picks(lambda t: (0, 1, 2, 3)), 64, 4, 4, None),
+    "a_tokens_pairs_straddle_two_passes": (
+        lambda: picks(lambda t: (0, 3)), 32, 4, 4, None),
+    "garbage_past_the_held_rows_of_the_last_pass": (
+        lambda: picks(lambda t: (t % 4,) if t % 3 else ()), 32, 4, 2,
+        garbage_past_the_groups),
+    "group_limited_half_the_tokens_send_nothing": (
+        group_limited_picks, None, 2, 1, None),
+}
+
+
+@pytest.mark.parametrize("way", ["plain", "kernel"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("case", list(WAYS_BACK))
+def test_a_passes_results_go_back_by_its_own_rows(case, dtype, way,
+                                                  monkeypatch):
+    """The scatter-add and the kernel (interpret mode) against the gather
+    over every routed pair: float32 to 1e-5, bf16 to one bf16 step."""
+    from nnstreamer_tpu.ops.combine_rows import combine_rows
+
+    make, rows, held, passes, ragged = WAYS_BACK[case]
+    experts = make()
+    if rows is not None:
+        monkeypatch.setattr(moe, "share_rows", lambda *_: rows)
+    rows = moe.share_rows(experts.size, held, 16)
+    n_held = int((experts < held).sum())
+    assert -(-n_held // rows) == passes
+    ks = jax.random.split(jax.random.PRNGKey(7), 4)
+    x = jax.random.normal(ks[0], (64, 32)).astype(dtype)
+    weights = jax.random.uniform(ks[1], experts.shape, minval=0.1)
+    w_in = (jax.random.normal(ks[2], (held, 32, 32)) * 0.2).astype(dtype)
+    w_out = (jax.random.normal(ks[3], (held, 16, 32)) * 0.2).astype(dtype)
+    share = {} if way == "plain" else {"combine": functools.partial(
+        combine_rows, interpret=True, token_block=16, row_tile=16)}
+    with jax.default_matmul_precision("highest"):
+        want = gather_share(x, weights, experts, w_in, w_out, 0, 16)
+        if ragged is not None:
+            monkeypatch.setattr(jax.lax, "ragged_dot", ragged)
+        got = jax.jit(functools.partial(
+            moe._routed_share, first=0, total=16, **share))(
+                x, weights, experts, w_in, w_out)
+    got, want = (np.asarray(a, np.float32) for a in (got, want))
+    assert np.abs(want).max() > 0.1 and np.isfinite(got).all()
+    if dtype == jnp.float32:
+        assert np.abs(got - want).max() < 1e-5
+    else:
+        assert (np.abs(got - want)
+                <= 2.0 ** -7 * np.maximum(np.abs(got), np.abs(want))).all()
+    # a token none of whose picks is held gets nothing from here
+    assert not got[~np.asarray((experts < held).any(axis=1))].any()
+
+
+def test_a_shares_derivatives_are_the_plain_way_backs(monkeypatch):
+    """``routed_experts_p``'s jvp runs the share through XLA, the
+    scatter-add its way back: a loss's gradients in ``x`` and the weights
+    are the gather form's, over three passes."""
+    monkeypatch.setattr(moe, "share_rows", lambda *_: 32)
+    experts = picks(lambda t: (t % 4,) if t % 3 else (0, 2))
+    ks = jax.random.split(jax.random.PRNGKey(9), 4)
+    x = jax.random.normal(ks[0], (64, 32))
+    weights = jax.random.uniform(ks[1], experts.shape, minval=0.1)
+    w_in = jax.random.normal(ks[2], (4, 32, 32)) * 0.2
+    w_out = jax.random.normal(ks[3], (4, 16, 32)) * 0.2
+
+    def loss(share):
+        return lambda x, w: (share(x, w, experts, w_in, w_out) ** 2).sum()
+
+    with jax.default_matmul_precision("highest"):
+        got = jax.grad(loss(functools.partial(
+            moe.routed_experts, first=0, total=16)), argnums=(0, 1))(
+                x, weights)
+        want = jax.grad(loss(functools.partial(
+            gather_share, first=0, total=16)), argnums=(0, 1))(x, weights)
+    for a, b in zip(got, want):
+        assert np.abs(np.asarray(b)).max() > 0.1
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
+                                   atol=1e-5)
+
+
+@pytest.mark.parametrize("n,rows,d,dtype,why", [
+    (8192, 8192, 7168, jnp.bfloat16, None),
+    (8192, 8192, 6144, jnp.bfloat16, None),
+    (512, 1024, 128, jnp.float32, None),
+    (8192, 8192, 7168, jnp.float32, None),
+    (8192, 8192, 7168, jnp.int8, "neither bf16 nor float32"),
+    (8192, 8192, 7200, jnp.bfloat16, "d in no whole lane tiles"),
+    (50, 256, 128, jnp.float32, "the tokens in no whole blocks"),
+    (512, 200, 128, jnp.float32, "a pass in no whole row tiles"),
+    (8192, 8192, 32768, jnp.float32, "a visit's blocks over the budget"),
+], ids=["axk1", "glm", "small", "float32", "int8", "odd_d", "odd_tokens",
+        "odd_rows", "wide"])
+def test_what_the_way_backs_kernel_tiles(n, rows, d, dtype, why):
+    from nnstreamer_tpu.ops import combine_rows
+
+    assert combine_rows.tiles((n, d), rows, dtype) == (why is None), why
+
+
+def arrays_of(jaxpr):
+    """The shape of every array a jaxpr makes, those of its inner ones too."""
+    for eqn in jaxpr.eqns:
+        yield from (v.aval.shape for v in eqn.outvars
+                    if hasattr(v.aval, "shape"))
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            yield from arrays_of(inner)
+
+
+@pytest.mark.parametrize("way", ["plain", "kernel"])
+def test_a_share_makes_no_array_of_every_routed_pairs_rows(way):
+    """512 tokens, top-8, 2 of 16 experts held: 4096 routed pairs, a pass of
+    1024 rows.  Neither the trace nor either lowering's text holds a
+    ``[pairs, d]`` or ``[tokens, k, d]`` array, and the TPU's program sums a
+    pass's rows in the kernel."""
+    n, k, d = 512, 8, 128
+    ks = jax.random.split(jax.random.PRNGKey(0), 5)
+    x = jax.random.normal(ks[0], (n, d))
+    weights, experts = moe.route_top_k(x, jax.random.normal(ks[1], (d, 16)),
+                                       k)
+    w_in = jax.random.normal(ks[2], (2, d, 64))
+    w_out = jax.random.normal(ks[3], (2, 32, d))
+    if way == "plain":
+        fn = functools.partial(moe._routed_share, first=4, total=16)
+    else:
+        def fn(*a):
+            return moe.routed_experts(*a, first=4, total=16)
+    traced = jax.jit(fn).trace(x, weights, experts, w_in, w_out)
+    shapes = set(arrays_of(traced.jaxpr.jaxpr))
+    if way == "plain":  # the walk sees into the loop: a pass's rows
+        assert (moe.share_rows(n * k, 2, 16), d) in shapes
+    assert not [s for s in shapes if int(np.prod(s)) >= n * k * d]
+    name = "nnstpu_moe_share_combine_total"
+    before = counted(name, "kernel"), counted(name, "plain")
+    cpu = traced.lower().as_text()
+    tpu = traced.lower(lowering_platforms=("tpu",)).as_text()
+    for text in (cpu, tpu):
+        assert f"{n * k}x{d}x" not in text and f"{n}x{k}x{d}x" not in text
+    assert "nns_combine_rows" not in cpu
+    assert ("nns_combine_rows" in tpu) == (way == "kernel")
+    if way == "kernel":  # the primitive's rule chose, and counted, each
+        assert (counted(name, "kernel"), counted(name, "plain")) == (
+            before[0] + 1, before[1] + 1)
+
+
 def test_the_bias_steers_the_choice_and_stays_out_of_the_weights():
     p = moe_params(jax.random.PRNGKey(3))
     x = jax.random.normal(jax.random.PRNGKey(4), (200, 32))

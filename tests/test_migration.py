@@ -114,6 +114,43 @@ class TestSnapshotRestore:
         assert a.stats()["sessions_migrated_out"] >= 1
         assert b.stats()["sessions_migrated_in"] >= 1
 
+    def test_a_snapshot_right_behind_a_restore_carries_the_restored_state(
+            self, engines):
+        """A session restored on B and snapshotted again before B's engine
+        thread has gathered (a handoff onto a worker that is drained
+        meanwhile; under load the thread loses the race for the lock): the
+        second snapshot is the first one's state, not what the slot's
+        arrays held before, and the stream stays token-identical on A."""
+        a, b = engines
+        prompt, steps = _prompt(seed=5), _steps(4, base=50)
+        ctl = _control_run(prompt, steps)
+        # B's slots hold another stream's state from before
+        other = b.open_session()
+        other.prefill(_prompt(seed=6))
+        other.get(timeout=10)
+        other.close()
+        sa = a.open_session()
+        sa.prefill(prompt)
+        out = [sa.get(timeout=10)]
+        for s in steps[:2]:
+            sa.feed(s)
+            out.append(sa.get(timeout=10))
+        snap = sa.snapshot()
+        sa.close()
+        with b._cv:  # B's engine thread cannot gather in between
+            sb = b.restore_session(snap)
+            again = b.snapshot_session(sb, timeout=5)
+        sb.close()
+        assert again["pos"] == snap["pos"]
+        np.testing.assert_array_equal(again["cache"], snap["cache"])
+        sa = a.restore_session(again)
+        for s in steps[2:]:
+            sa.feed(s)
+            out.append(sa.get(timeout=10))
+        sa.close()
+        for i, (x, y) in enumerate(zip(ctl, out)):
+            np.testing.assert_array_equal(x, y, err_msg=f"output {i}")
+
     def test_snapshot_mid_prefill_restores_position_t(self, engines):
         """A pending (not yet applied) prefill rides the snapshot's
         queue; an APPLIED prefill rides as cache+pos — both continue
