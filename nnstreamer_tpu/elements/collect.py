@@ -65,12 +65,16 @@ class CollectNode(Node):
         # pad->buffer, tensor_common.c:1270+): basepad re-contributes it
         # when a pad's head is outside tolerance, keeping pad-count stable
         self._last: Dict[str, Frame] = {}
+        # per-pad arrival stamps of the queued frames, kept while span
+        # tracing is on: what a frame's ``pad_wait`` record is made of
+        self._waits: Dict[str, _spans.PadWaits] = {}
         self._finished = False
         # ordered emission outside the node lock: tickets are taken under
         # the lock, honored under _emit_cv
         self._emit_cv = threading.Condition()
         self._ticket = 0
         self._emit_next = 0
+        self._emitting = 0  # the ticket whose rounds combine() is called for
 
     # -- collection ---------------------------------------------------------
 
@@ -127,6 +131,11 @@ class CollectNode(Node):
                 if self._finished:
                     return  # stream already ended (a pad ran dry)
                 self._queues.setdefault(pad.name, collections.deque()).append(item)
+                if _spans.enabled:
+                    waits = self._waits.get(pad.name)
+                    if waits is None:
+                        waits = self._waits[pad.name] = _spans.PadWaits()
+                    waits.arrived(item)
                 outs, finish = self._collect_rounds()
                 if finish:
                     self._finished = True
@@ -134,6 +143,17 @@ class CollectNode(Node):
                 return  # nothing to emit: don't serialize behind the chain
             ticket = self._ticket
             self._ticket += 1
+            if self._waits:
+                # the booking thread writes what every frame of its rounds
+                # waited, from its arrival on its pad until this booking;
+                # a frame ``basepad`` contributes again has no stamp left
+                booked = time.perf_counter_ns()
+                for frames in outs:
+                    for name, frame in frames.items():
+                        waits = self._waits.get(name)
+                        if waits:
+                            waits.left(frame, self.name, name, booked,
+                                       ticket=ticket)
         with self._emit_cv:
             if self._emit_next != ticket:
                 # a collected round queues here behind the round before
@@ -159,6 +179,7 @@ class CollectNode(Node):
                 else:
                     # the overridable hook (default: forward downstream)
                     self.on_event(pad, caps_item)
+            self._emitting = ticket
             for frames in outs:
                 out = self.combine(frames)
                 if out is not None:
@@ -172,6 +193,14 @@ class CollectNode(Node):
             with self._emit_cv:
                 self._emit_next += 1
                 self._emit_cv.notify_all()
+
+    def coalesce(self, frames: Dict[str, Frame], meta: dict) -> None:
+        """Stamp ``meta`` (the round's output frame's) with a fresh span,
+        parent-linked to every contributed frame's, under the ticket
+        whose ``pad_wait`` records say how long each waited.  For
+        ``combine()``, behind the ``_spans.enabled`` gate."""
+        _spans.merge_context(frames.values(), meta, self.name,
+                             ticket=self._emitting)
 
     def _ready(self) -> bool:
         for pad in self._linked_sinks():
@@ -338,6 +367,7 @@ class CollectNode(Node):
         self._finished = False
         self._queues.clear()
         self._last.clear()
+        self._waits.clear()
         with self._emit_cv:
             self._ticket = 0
             self._emit_next = 0

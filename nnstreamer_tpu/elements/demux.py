@@ -12,6 +12,7 @@ from typing import Dict, List, Optional
 from ..buffer import Frame
 from ..graph.node import NegotiationError, Node, Pad
 from ..graph.registry import register_element
+from ..obs import spans as _spans
 from ..spec import TensorsSpec
 
 
@@ -67,4 +68,5 @@ class TensorDemux(Node):
                     ),
                 )
             )
+        _spans.carry_context(frame, (cut for _, cut in out))
         return out

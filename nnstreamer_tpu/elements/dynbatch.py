@@ -34,6 +34,7 @@ The model under the filter must accept a polymorphic leading batch dim
 from __future__ import annotations
 
 import threading
+import time
 from typing import Dict, List, Optional
 
 import numpy as np
@@ -96,6 +97,8 @@ class DynBatch(Node):
         self.frames_in = 0
         self._pool = None  # shared staging pool, resolved lazily
         self._mesh_dev = 1  # downstream dispatch-mesh width (configure)
+        # push stamps of the queued frames, kept while span tracing is on
+        self._waits = _spans.PadWaits()
 
     def configure(self, in_specs: Dict[str, TensorsSpec]) -> Dict[str, TensorsSpec]:
         spec = in_specs["sink"]
@@ -159,8 +162,25 @@ class DynBatch(Node):
             self._q = make_frame_queue(self.max_size)
 
     def _dispatch(self, pad: Pad, item) -> None:
-        del pad
+        """The tracer hook points of :meth:`Node._dispatch` around this
+        element's own dispatch, as ``CollectNode._dispatch`` has them
+        (one flag test with no tracer attached)."""
+        if _hooks.enabled:
+            t0 = time.perf_counter_ns()
+            _hooks.emit("dispatch_enter", self, pad, item, t0)
+            try:
+                self._enqueue(item)
+            finally:
+                _hooks.emit("dispatch_exit", self, pad, item,
+                            time.perf_counter_ns() - t0)
+            return
+        self._enqueue(item)
+
+    def _enqueue(self, item) -> None:
         self._ensure_queue()
+        if _spans.enabled and not isinstance(item, Event):
+            # before the push: once it is in, the worker may flush it
+            self._waits.arrived(item)
         rt, task = self._lane_rt, self._lane_task
         if rt is not None and task is not None and not task.promoted:
             rt.backpressure_push(self._q, item, "no", task)
@@ -231,6 +251,11 @@ class DynBatch(Node):
         return self._pool
 
     def _emit_batch(self, frames: List[Frame]) -> None:
+        if self._waits:
+            # a flush: every frame of it waited from its push until now
+            flushed = time.perf_counter_ns()
+            for f in frames:
+                self._waits.left(f, self.name, "sink", flushed)
         n = len(frames)
         b = mesh_bucket(n, self.max_batch, self._mesh_dev)
         pad_rows = b - n
@@ -341,6 +366,7 @@ class DynBatch(Node):
             self._q = None
         self._lane_rt = None
         self._lane_task = None
+        self._waits.clear()
         super().stop()
 
 

@@ -10,7 +10,7 @@ numpy axis of the negotiated rank.
 
 from __future__ import annotations
 
-from typing import Dict, Optional
+from typing import Any, Dict, Optional
 
 import numpy as np
 
@@ -18,6 +18,7 @@ from ..buffer import Frame
 from ..graph.node import NegotiationError
 from ..graph.registry import register_element
 from ..obs import hooks as _hooks
+from ..obs import spans as _spans
 from ..spec import TensorSpec, TensorsSpec
 from .collect import CollectNode
 
@@ -100,4 +101,9 @@ class TensorMerge(CollectNode):
                 _hooks.emit("copy", self, merged.nbytes,
                             1 if merged.pool_fresh else 0)
         pts, dur = self.output_timing(frames)
-        return Frame.of(merged, pts=pts, duration=dur)
+        meta: Dict[str, Any] = {}
+        if _spans.enabled:
+            # as tensor_mux: the merged frame's span names every merged
+            # frame's, so a source frame's trace reaches its device_exec
+            self.coalesce(frames, meta)
+        return Frame(tensors=(merged,), pts=pts, duration=dur, meta=meta)

@@ -49,5 +49,5 @@ class TensorMux(CollectNode):
             # one collection round = one new span, parent-linked to every
             # contributed stream's frame span (their cross-thread flows
             # terminate at this collect point)
-            _spans.merge_context(frames.values(), meta, self.name)
+            self.coalesce(frames, meta)
         return Frame(tensors=tuple(tensors), pts=pts, duration=dur, meta=meta)

@@ -27,6 +27,7 @@ from ..graph.registry import register_element
 from ..native import DROPPED_INCOMING, OK, OK_DROPPED_OLDEST, SHUTDOWN
 from ..native.queue import make_frame_queue
 from ..obs import hooks as _hooks
+from ..obs import spans as _spans
 
 _POLL_MS = 100  # wake periodically so shutdown is never missed
 
@@ -55,6 +56,8 @@ class Queue(Node):
         # cumulative leaky-mode drops; element-level (survives stop(),
         # unlike the backend queue's own counter) — feeds the drops tracer
         self.dropped = 0
+        # push stamps of the queued frames, kept while span tracing is on
+        self._waits = _spans.PadWaits()
 
     @property
     def backend_kind(self) -> str:
@@ -72,6 +75,9 @@ class Queue(Node):
     def _dispatch(self, pad: Pad, item) -> None:
         del pad
         self._ensure_queue()
+        if _spans.enabled and not isinstance(item, Event):
+            # before the push: once it is in, the consumer may pop it
+            self._waits.arrived(item)
         rt, task = self._lane_rt, self._lane_task
         if rt is not None and task is not None and not task.promoted:
             # lane mode: a full queue is backpressure, never a parked
@@ -128,6 +134,8 @@ class Queue(Node):
                 return None  # drained; re-armed by the next push
             if _hooks.enabled:
                 _hooks.emit("queue_pop", self, len(q))
+            if self._waits:
+                self._waits.left(item, self.name, "sink")  # push -> pop
             try:
                 if isinstance(item, Event):
                     if item.kind == "eos":
@@ -161,6 +169,8 @@ class Queue(Node):
                 continue  # timeout poll: retry
             if _hooks.enabled:
                 _hooks.emit("queue_pop", self, len(q))
+            if self._waits:
+                self._waits.left(item, self.name, "sink")  # push -> pop
             try:
                 if isinstance(item, Event):
                     if item.kind == "eos":
@@ -242,4 +252,5 @@ class Queue(Node):
             self._q = None
         self._lane_rt = None
         self._lane_task = None
+        self._waits.clear()
         super().stop()

@@ -17,6 +17,7 @@ from ..buffer import Frame, WireTensor
 from ..graph.node import NegotiationError, Node, Pad
 from ..graph.registry import register_element
 from ..obs import hooks as _hooks
+from ..obs import spans as _spans
 from ..spec import TensorSpec, TensorsSpec
 
 
@@ -114,4 +115,5 @@ class TensorSplit(Node):
             out.append(
                 (pad_name, Frame.of(arr[tuple(sl)], pts=frame.pts, duration=frame.duration))
             )
+        _spans.carry_context(frame, (cut for _, cut in out))
         return out

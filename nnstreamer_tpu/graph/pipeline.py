@@ -805,6 +805,12 @@ class Pipeline:
 
             self._lanes_started.append(
                 DeviceTracer(registry=MetricsRegistry()))
+        if listened:
+            # the witness of a stall of the whole host, beside the reaper:
+            # a beat that notes what the kernel knows when it wakes late
+            from ..obs.hoststall import HostBeat
+
+            self._lanes_started.append(HostBeat())
         for lane in self._lanes_started:
             lane.start(self)
         port = configured_metrics_port()

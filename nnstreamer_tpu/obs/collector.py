@@ -18,7 +18,10 @@ that puts the pieces back together into ONE picture:
   file) carries the whole fleet;
 - :func:`attribute_trace` decomposes one request's joined spans into
   latency legs (queue wait / dispatch / device / wire) — the primitive
-  under the loadgen report (``tools/loadgen.py``).
+  under the loadgen report (``tools/loadgen.py``);
+- :func:`frame_legs` does the same for the streaming path: every source
+  frame of a snapshot followed by the program's own ids through the
+  collectors to its round's ``device_exec`` and its stream's sink.
 
 **Clock alignment.**  Span timestamps are ``time.perf_counter_ns()``
 values — monotonic, but with a *per-process arbitrary epoch*, so two
@@ -333,12 +336,24 @@ SPAN_LEGS = {
     "nnsq_serve": "serve",
     "sched_wait": "queue",
     "slot_wait": "queue",
+    # the streaming path's wait: a frame held in a collect pad, a queue
+    # or a dynbatch until its round (``<element>.pad_wait``, cat ``wait``)
+    "*.pad_wait": "queue",
     "device_invoke": "device",
     "device_exec": "device",
     # dead-time spans from the device utilization lane (obs/device.py):
     # how long the chip sat starved before this trace's dispatch ran
     "device_idle": "device_idle",
 }
+
+
+def _leg_of(name: str) -> Optional[str]:
+    """``SPAN_LEGS`` by the span's name, or by its last part where the
+    first is an element's name (``*.pad_wait``)."""
+    leg = SPAN_LEGS.get(name)
+    if leg is None and "." in name:
+        leg = SPAN_LEGS.get("*" + name[name.rindex("."):])
+    return leg
 
 
 def attribute_trace(records: List[tuple]) -> Dict[str, float]:
@@ -378,7 +393,7 @@ def attribute_trace(records: List[tuple]) -> Dict[str, float]:
     """
     legs: Dict[str, float] = {}
     for r in records:
-        leg = SPAN_LEGS.get(r[4])
+        leg = _leg_of(r[4])
         if leg is not None:
             legs[leg] = legs.get(leg, 0.0) + float(r[2])
     for r in records:
@@ -409,6 +424,84 @@ def attribute_trace(records: List[tuple]) -> Dict[str, float]:
     if serve:
         legs["dispatch"] = max(0.0, serve - queue - device)
     return legs
+
+
+def frame_legs(records: List[tuple], sinks: Dict[str, str]) -> List[dict]:
+    """The legs of every source frame whose chain is whole in ``records``
+    (a flight snapshot), oldest push first.  ``sinks`` names, for each
+    source element, the sink element its answers reach.
+
+    A frame is followed by the ids the program wrote, never by order or
+    by time: its ``<src>.push`` instant gives (trace, span); the
+    ``pad_wait`` records under that span are what it waited; the
+    ``coalesce`` whose ``parents`` name the span is the frame that took
+    it along (and may wait and be coalesced in turn); the ``device_exec``
+    under the last coalesced span is its round; the ``dispatch`` span of
+    its sink under a trace id of the chain is its answer (``tensor_split``
+    and ``tensor_demux`` hand the round's context on; ``tensor_dynunbatch``
+    restores the frame's own).  Per frame, nanoseconds:
+
+    - ``wait_ns``: the sum of its ``pad_wait`` s along the chain
+      (``waits``: each as ``(record name, pad, ns)``);
+    - ``device_ns``: its round's ``device_exec``, the host side of the
+      enqueue to the observed completion (the last shard's, if sharded);
+    - ``return_ns``: the end of that ``device_exec`` to the end of the
+      sink's span (negative where a sink did not wait for the device);
+    - ``forward_ns``: what is left between the push and the enqueue:
+      converters, the collectors' own work, a ticket wait.
+
+    The four sum to ``end_ns - push_ns`` exactly.  A frame whose chain
+    lacks a record (evicted from the ring, still in flight at the
+    snapshot, a source ``sinks`` does not name) is left out.
+    """
+    pushes: List[tuple] = []
+    waits: Dict[Tuple[int, int], List[tuple]] = {}
+    taken_by: Dict[Tuple[int, int], Tuple[int, int]] = {}
+    execs: Dict[Tuple[int, int], list] = {}
+    answers: Dict[Tuple[int, str], int] = {}
+    for r in records:
+        ph, ts, dur, name, cat, trace, sid, parent, args = (
+            r[0], r[1], r[2], r[4], r[5], r[6], r[7], r[8], r[9])
+        if cat == "source" and name.endswith(".push"):
+            pushes.append((ts, trace, sid, name[:-len(".push")]))
+        elif cat == "wait":
+            waits.setdefault((trace, parent), []).append(r)
+        elif cat == "coalesce":
+            for link in (args or {}).get("parents", ()):
+                t, _, s = link.partition("/")
+                taken_by.setdefault((int(t, 16), int(s, 16)), (trace, sid))
+        elif name == "device_exec" and ph == _spans.PH_COMPLETE:
+            seen = execs.setdefault((trace, parent),
+                                    [ts, ts + dur, (args or {}).get("round")])
+            seen[0], seen[1] = min(seen[0], ts), max(seen[1], ts + dur)
+        elif cat == "dispatch" and trace:
+            answers.setdefault((trace, name), ts + dur)
+    out: List[dict] = []
+    for push_ns, trace, sid, source in sorted(pushes):
+        cur: Optional[Tuple[int, int]] = (trace, sid)
+        chain, held, dev = [], [], None
+        while cur is not None and cur not in chain:
+            chain.append(cur)
+            for w in waits.get(cur, ()):
+                held.append((w[4], (w[9] or {}).get("pad"), w[2]))
+            dev = execs.get(cur)
+            if dev is not None:
+                break
+            cur = taken_by.get(cur)
+        sink = sinks.get(source)
+        end_ns = next((answers[(t, sink)] for t, _ in chain
+                       if (t, sink) in answers), None)
+        if dev is None or end_ns is None:
+            continue
+        wait_ns = sum(ns for _, _, ns in held)
+        out.append({
+            "source": source, "trace_id": trace, "span_id": sid,
+            "round": dev[2], "push_ns": push_ns, "end_ns": end_ns,
+            "forward_ns": dev[0] - push_ns - wait_ns, "wait_ns": wait_ns,
+            "device_ns": dev[1] - dev[0], "return_ns": end_ns - dev[1],
+            "waits": held,
+        })
+    return out
 
 
 # -- metrics federation ------------------------------------------------------

@@ -15,9 +15,13 @@ stage-level timelines are what exposes batching and transfer stalls):
   as a potential cross-thread **flow**: a push records a flow-start, and
   whichever thread next touches the frame records the flow-finish —
   pairs that never left their thread are dropped at export time;
-- coalescing elements (``tensor_dynbatch``, ``tensor_mux``) stamp the
-  combined frame with a fresh span whose **parent links** name every
-  constituent frame's span (:func:`merge_context`);
+- coalescing elements (``tensor_dynbatch``, ``tensor_mux``,
+  ``tensor_merge``) stamp the combined frame with a fresh span whose
+  **parent links** name every constituent frame's span
+  (:func:`merge_context`); an element that holds a frame until others
+  arrive (a collect pad, a ``queue``, ``tensor_dynbatch``) writes the
+  time it held it as a ``<element>.pad_wait`` record of cat ``wait``
+  under the frame's own trace (:class:`PadWaits`);
 - records land in a bounded per-thread ring (:class:`~.flight.
   FlightRecorder`) — zero cost when disabled (the ``enabled`` module
   flag is one load + truth test, same discipline as ``obs/hooks.py``,
@@ -41,6 +45,7 @@ QueryServer-side spans attach to the client's trace and a client→server
 
 from __future__ import annotations
 
+import collections
 import itertools
 import os
 import threading
@@ -238,12 +243,13 @@ def _consume_flow(ctx: list, ts: int) -> None:
         ctx[3] = None
 
 
-def merge_context(frames: Iterable, meta: dict, name: str) -> None:
+def merge_context(frames: Iterable, meta: dict, name: str, **args) -> None:
     """Stamp a coalesced frame (dynbatch batch, mux collection round) with
     a fresh span context carrying **parent links** to every constituent
     frame's span.  Constituents' pending cross-thread flows terminate at
     the coalesce point, so Perfetto draws each source stream's arrow into
-    the batch."""
+    the batch.  ``args`` ride on the ``coalesce`` record beside its
+    ``parents`` (a collector's ``ticket``)."""
     if not enabled:
         return
     ts = now_ns()
@@ -262,8 +268,75 @@ def merge_context(frames: Iterable, meta: dict, name: str) -> None:
     sid = next(_ids)
     meta[META_KEY] = [trace_id, sid, 0, None]
     meta[PARENTS_KEY] = tuple(parents)
+    args["parents"] = [f"{t:x}/{s:x}" for t, s in parents]
     _rec(PH_INSTANT, ts, 0, name, "coalesce", trace_id, sid, parents[0][1],
-         {"parents": [f"{t:x}/{s:x}" for t, s in parents]})
+         args)
+
+
+def carry_context(frame, cuts: Iterable) -> None:
+    """Hand ``frame``'s trace context to the frames an element cut out of
+    it (``tensor_split``, ``tensor_demux``): the same trace and span, a
+    flow state of their own, so the way back stays in the round's trace.
+    Nothing where ``frame`` carries none."""
+    ctx = frame.meta.get(META_KEY)
+    if ctx is not None:
+        for cut in cuts:
+            cut.meta[META_KEY] = [ctx[0], ctx[1], 0, None]
+
+
+# -- the wait of a frame an element holds ------------------------------------
+
+class PadWaits:
+    """Arrival stamps of the frames an element holds on one pad (a collect
+    pad's deque, a queue's or a dynbatch's buffer), oldest first.  Frames
+    leave such a buffer in arrival order, so :meth:`left` drops the
+    stamps in front of the one it finds: they are of frames a sync policy
+    or a leaky queue dropped.  Callers sit behind the ``enabled`` gate."""
+
+    __slots__ = ("_held",)
+
+    def __init__(self):
+        self._held: collections.deque = collections.deque()
+
+    def __len__(self) -> int:
+        return len(self._held)
+
+    def clear(self) -> None:
+        self._held.clear()
+
+    def arrived(self, item) -> None:
+        self._held.append((id(item), now_ns()))
+
+    def left(self, item, element: str, pad: str,
+             t1_ns: Optional[int] = None, **args) -> None:
+        """``item`` leaves the buffer now (``t1_ns``): one
+        ``<element>.pad_wait`` record, cat ``wait``, from its arrival on
+        ``pad`` until the round that took it (a collector's booking, a
+        queue's pop, a dynbatch's flush), under the frame's own trace and
+        span.  It starts on one thread and ends on another, so it is no
+        dispatch or stage span and no annotation.  Nothing for an item
+        that arrived unstamped (tracing was off) or is contributed again
+        (``basepad``'s last frame)."""
+        held, key = self._held, id(item)
+        # newest first: a dropped frame's id may be a live frame's by now
+        for i in range(len(held) - 1, -1, -1):
+            if held[i][0] == key:
+                t0_ns = held[i][1]
+                for _ in range(i + 1):
+                    held.popleft()
+                break
+        else:
+            return
+        if not enabled:
+            return
+        ctx = item.meta.get(META_KEY)
+        trace, span = (ctx[0], ctx[1]) if ctx is not None else (0, 0)
+        args["pad"] = pad
+        # straight into the ring: a round writes one of these a frame, on
+        # the thread that carries it
+        _recorder.append((
+            PH_COMPLETE, t0_ns, (t1_ns or now_ns()) - t0_ns, _tid(),
+            element + ".pad_wait", "wait", trace, next(_ids), span, args))
 
 
 # -- explicit spans (query wire, sched, serving) -----------------------------
@@ -607,6 +680,8 @@ def waterfall(records: Optional[List[tuple]] = None, limit: int = 16) -> str:
             extra = ""
             if args and "parents" in args:
                 extra = f"  <- {len(args['parents'])} parent span(s)"
+            elif cat == "wait" and args:
+                extra = f"  pad {args.get('pad')}"
             lines.append(f"  +{off:9.3f}ms {dur_s}  {name:<24} "
                          f"{cat:<9} [{tname}]{extra}")
     if len(traces) > limit:

@@ -341,8 +341,13 @@ class TestDynBatchMesh:
             hooks.disconnect("compile", on_compile)
         assert len(got) == 48
         # buckets are ndev×pow-2 ≤ ndev×max_batch: at most 3 distinct
-        # geometries (8, 16, 32 rows) regardless of 48 frames served
-        assert 1 <= len(misses) <= 3, misses
+        # geometries (8, 16, 32 rows) regardless of 48 frames served.
+        # Which of them a run meets goes by how fast the source pushes;
+        # the negotiation's one-row probe compiles once more, on one
+        # device (its key carries no mesh), and is no bucket
+        meshed = [k for k in misses if k[1] is not None]
+        assert 1 <= len(meshed) <= 3, misses
+        assert len(misses) - len(meshed) <= 1, misses
 
 
 class TestChainedMeshFilters:

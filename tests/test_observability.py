@@ -155,21 +155,30 @@ class TestHookBus:
         hooks.emit("error", None, None, None)  # must not raise
         assert hooks.enabled is False  # bad callback auto-detached
 
-    def test_disabled_hot_loop_overhead(self):
+    @pytest.mark.parametrize("chain", ["nodes", "collect_pad"])
+    def test_disabled_hot_loop_overhead(self, chain):
         """The acceptance guard: with no tracer installed the hook gate
         must add no measurable per-frame cost.  2000 frames through a
-        3-node chain; the bound is generous (100 us/frame) — it catches a
-        regression to unconditional emission (dict/kwargs building,
-        clock reads), not scheduler noise."""
+        3-node chain, or onto a ``CollectNode`` pad (an arrival that
+        completes a round: stamps nothing, writes no wait); the bound is
+        generous (100 us/frame) — it catches a regression to
+        unconditional emission (dict/kwargs building, clock reads), not
+        scheduler noise."""
         assert hooks.enabled is False
+        from nnstreamer_tpu.elements.mux import TensorMux
         from nnstreamer_tpu.graph.node import Node
+        from nnstreamer_tpu.obs import spans
 
-        a, b = Node(), Node()
-        sink = TensorSink()
+        a, sink = Node(), TensorSink()
         ap = a.add_src_pad()
-        b.add_sink_pad()
-        bp = b.add_src_pad()
-        ap.link(b.sink_pads["sink"])
+        if chain == "collect_pad":
+            b = TensorMux(sync_mode="nosync")
+            ap.link(b.add_sink_pad("sink_0"))
+            bp = b.src_pads["src"]
+        else:
+            b = Node()
+            ap.link(b.add_sink_pad())
+            bp = b.add_src_pad()
         bp.link(sink.sink_pads["sink"])
         frame = Frame.of(np.zeros((4,), np.float32))
         n = 2000
@@ -181,6 +190,8 @@ class TestHookBus:
         assert per_frame_ns < 100_000, (
             f"disabled hook bus costs {per_frame_ns:.0f} ns/frame"
         )
+        assert sink.num_frames == n + 1
+        assert not getattr(b, "_waits", None) and not spans.snapshot()
 
 
 class TestMetricsRegistry:
