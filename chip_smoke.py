@@ -530,6 +530,54 @@ class Smoke:
             np.testing.assert_allclose(got, want, rtol=2e-2, atol=2e-2)
             out[f"fused_attention_causal{int(causal)}_max_abs_err"] = float(
                 np.abs(got - want).max())
+        # the blocked kernel at the Laguna sliding layer's shape (16 windows
+        # of 4096, 64 heads over 8, a window of 512 = one block, rot 128 by
+        # the tables) as attention()'s rule lowers it, the band folded,
+        # against rotate() and XLA's walk at float32, a key/value head of a
+        # batch row at a time (all of them at once hold 69 GB of scores)
+        from nnstreamer_tpu.models.laguna import rotary_tables
+        from nnstreamer_tpu.obs.metrics import REGISTRY
+        from nnstreamer_tpu.ops import fused_attention as fa
+
+        b, t, hq, hkv, window = ((1, 512, 2, 1, 128) if self.rehearsal
+                                 else (16, 4096, 64, 8, 512))
+        group = hq // hkv
+        q = jnp.asarray(rng.standard_normal((b, t, hq * 128)), jnp.bfloat16)
+        k_, v = (jnp.asarray(rng.standard_normal((b, t, hkv * 128)),
+                             jnp.bfloat16) for _ in range(2))
+        tables = rotary_tables({"rope_theta": 10000}, 128, t)
+        if interpret:
+            got = jax.jit(lambda *a: fa.blocked_attention(
+                *a, hq, hkv, window, 128, 128, interpret=True,
+                rotary=tables))(q, k_, v)
+        else:
+            got = jax.jit(lambda *a: fa.attention(
+                a[0], hq, True, k=a[1], v=a[2], n_kv_heads=hkv,
+                window=window, rotary=tables))(q, k_, v)
+
+        def by_head(a, heads):
+            return a.reshape(b, t, hkv, heads * 128).transpose(
+                0, 2, 1, 3).reshape(b * hkv, 1, t, heads * 128)
+
+        with jax.default_matmul_precision("highest"):
+            want = jax.jit(lambda q, k_, v: jax.lax.map(
+                lambda a: fa.plain_grouped_attention(
+                    *a, group, 1, causal=True, window=window),
+                (by_head(fa.rotate(q, *tables, hq), group),
+                 by_head(fa.rotate(k_, *tables, hkv), 1), by_head(v, 1))
+            ))(*(a.astype(jnp.float32) for a in (q, k_, v)))
+        want = want.reshape(b, hkv, t, group * 128).transpose(
+            0, 2, 1, 3).reshape(q.shape)
+        err = jnp.abs(got.astype(jnp.float32) - want)
+        check(bool((err <= 2e-2 + 2e-2 * jnp.abs(want)).all()),
+              "nns_blocked_attention (sliding, folded) != XLA's walk")
+        out["blocked_attention_sliding_max_abs_err"] = float(err.max())
+        walks = REGISTRY.get("nnstpu_attention_band_walk_total")
+        out["blocked_attention_band_walk"] = {
+            "/".join(key): c.value for key, c in walks.children()} \
+            if walks else {}
+        check(interpret or out["blocked_attention_band_walk"]
+              == {"folded": 1}, "the sliding layer's band was not folded")
         # the grouped experts (ops/grouped_experts): uneven groups, one of
         # them empty, the last tile cut off, against the two ragged dots
         from nnstreamer_tpu.ops.grouped_experts import grouped_experts

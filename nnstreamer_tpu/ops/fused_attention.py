@@ -24,11 +24,15 @@ walks key blocks with a running softmax: causal, over separate q and k, v
 projections with grouped heads of width 128 (a head is one lane tile of the
 token-major arrays), with a window if the layer has one; it skips the key
 blocks above the diagonal and outside the band, and holds a key/value head's
-whole K and V in VMEM, fetched once a head group.  Given a layer's rotary
-tables it rotates q and k itself, on the blocks it already holds: float32 in
-VMEM only, where XLA's :func:`rotate` around the projections re-tiles each
-``[tokens, heads * 128]`` array to ``[heads, 128]`` through float32 copies in
-HBM.
+whole K and V in VMEM, fetched once a head group.  Where the window is a
+whole number of key blocks, a block of rows past it walks the band's lower
+edge block and its own diagonal block, whose masks are complementary, as one
+tile of scores (one softmax pass where there were two masked ones), in
+straight-line code for the two chains of 256 rows a grid step holds.
+Given a layer's rotary tables it rotates q and k itself, on the blocks it
+already holds: float32 in VMEM only, where XLA's :func:`rotate` around the
+projections re-tiles each ``[tokens, heads * 128]`` array to ``[heads,
+128]`` through float32 copies in HBM.
 
 ``models/transformer.py`` and ``models/laguna.py`` call :func:`attention`, one
 primitive.  Which lowering a call gets is decided when its program is lowered,
@@ -196,6 +200,16 @@ BLOCKED_KERNEL_NAME = "nns_blocked_attention"
 # a step's fixed cost outweighs the masked half.
 BLOCK_Q = 512
 BLOCK_K = 512
+# A window of whole blocks of this many keys is walked in chains of as many
+# rows, the band's edge and diagonal blocks folded into one tile (see
+# _blocked_kernel).  At 16 x 4096 with 64 heads and a window of 512 on the
+# v5e (PERF.md §5): the walk before the fold 27.5 ms a layer, the fold inside
+# that walk's loops 25.8, the band in straight-line code with 512-key chains
+# 19.3, with 256-key chains 18.1.  Two or four heads a grid step ran 17.0 and
+# 16.2 but took Mosaic 1.7 and 4.2 s to compile against 0.8, and the cell's
+# warm set-up with four rose 15-26 s (not explained: ROADMAP S9); 1024 rows a
+# grid step ran 19.3, 128-key chains 29.5.
+BAND_BLOCK_K = 256
 
 
 def _round_up(n: int, to: int) -> int:
@@ -285,14 +299,49 @@ def _rotated(x, lane, c_ref, s_ref, half: int, at: int = 0):
     return (f * c_ref[...] + partner * s_ref[...]).astype(x.dtype)
 
 
+def _blocks(t: int, window: Optional[int] = None,
+            block_q: Optional[int] = None, block_k: Optional[int] = None):
+    """The walk's rows a grid step and key block rows for ``t`` tokens, and
+    the padded T (a whole number of both).  Left to choose the key block, a
+    window that folds at :data:`BAND_BLOCK_K` gets it."""
+    cap = _round_up(t, 128)
+    bq, bk = min(block_q or BLOCK_Q, cap), min(block_k or BLOCK_K, cap)
+    if block_k is None and _folds(window, bq, BAND_BLOCK_K,
+                                  _round_up(t, bq)):
+        bk = BAND_BLOCK_K
+    return bq, bk, _round_up(t, math.lcm(bq, bk))
+
+
+def _folds(window: Optional[int], bq: int, bk: int, tp: int) -> bool:
+    """Whether a row block past the window walks the band's two masked key
+    blocks as one tile of scores: rows in whole key blocks, a window of
+    whole key blocks, and a row block that lies past it."""
+    return (window is not None and bq % bk == 0 and window % bk == 0
+            and window < tp)
+
+
 def _blocked_kernel(q_ref, k_ref, v_ref, *refs, bq: int, bk: int,
-                    window: Optional[int], group: int, half: Optional[int]):
+                    window: Optional[int], group: int, half: Optional[int],
+                    fold: bool):
     """One (batch row, query head, block of query rows): walk the key blocks
     this block's rows may see with a running max, row sum and output.  With
     tables (``half`` lanes a rotary half) q's block is rotated first, and so
     is k's block of the same rows, into the scratch every walk reads, while
     the head group's first head passes: a walk reads no key past its own
-    rows' block that the mask does not throw away."""
+    rows' block that the mask does not throw away.
+
+    Without ``fold`` the block's rows walk as one chain.  With it, every
+    ``bk`` rows are a chain of their own, and a row block at ``q0 >=
+    window`` walks all of its chains at once in straight-line code: a chain
+    at ``c0`` sees key block ``c0 - window`` under the strict upper
+    triangle (key ``c`` of row ``i``, both block-local, iff ``c > i``), the
+    ``window / bk - 1`` blocks after it whole, and its own block under the
+    lower triangle (``c <= i``).  The two triangles leave one live score of
+    two for every (row, column), so the edge and the diagonal block are one
+    tile of ``bk`` keys a row and one softmax pass, and ``P`` goes back to
+    each block's values by the same triangle.  A row block before the
+    window walks every key block up to its own last under the whole mask,
+    all chains at once."""
     q0 = pl.multiple_of(pl.program_id(2) * bq, bq)
     if half is None:
         o_ref, = refs
@@ -312,53 +361,95 @@ def _blocked_kernel(q_ref, k_ref, v_ref, *refs, bq: int, bk: int,
             keys[pl.ds(q0, bq), :] = rotated(k_ref[0, pl.ds(q0, bq), :])
 
     q = q * (LANES ** -0.5)  # a weak scalar: q keeps its type
-    rows = q0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+    rb = bk if fold else bq  # a chain's rows
+    chains = [(q[c * rb:(c + 1) * rb], c) for c in range(bq // rb)]
+    local = jax.lax.broadcasted_iota(jnp.int32, (rb, bk), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (rb, bk), 1)
 
-    def step(j, carry, masked: bool):
+    def scores(q, k0):
+        return jax.lax.dot_general(q, keys[pl.ds(k0, bk), :],
+                                   (((1,), (1,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+
+    def softmax_step(carry, s):
         m, l, acc = carry
+        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+        e = jnp.exp(s - m_new)
+        a = jnp.exp(m - m_new)
+        return m_new, a * l + e.sum(axis=-1, keepdims=True), a * acc, e
+
+    def step(j, carry, q, rows, masked: bool):
         k0 = pl.multiple_of(j * bk, bk)
-        s = jax.lax.dot_general(q, keys[pl.ds(k0, bk), :],
-                                (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+        s = scores(q, k0)
         if masked:
             at = k0 + cols
             seen = at <= rows
             if window is not None:
                 seen &= at > rows - window
             s = jnp.where(seen, s, MASKED)
-        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-        e = jnp.exp(s - m_new)
-        a = jnp.exp(m - m_new)
+        m, l, acc, e = softmax_step(carry, s)
         v = v_ref[0, pl.ds(k0, bk), :]
-        acc = a * acc + jnp.dot(e.astype(v.dtype), v,
-                                preferred_element_type=jnp.float32)
-        return m_new, a * l + e.sum(axis=-1, keepdims=True), acc
+        return m, l, acc + jnp.dot(e.astype(v.dtype), v,
+                                   preferred_element_type=jnp.float32)
 
-    carry = (jnp.full((bq, 1), MASKED, jnp.float32),
-             jnp.zeros((bq, 1), jnp.float32),
-             jnp.zeros((bq, LANES), jnp.float32))
-    # key blocks wholly under the diagonal (and inside the band) need no
-    # mask: [lo, inner) masked at the band's lower edge, [inner, whole)
-    # bare, [whole, hi) masked at the diagonal
-    hi = (q0 + bq - 1) // bk + 1
-    whole = (q0 + 1) // bk  # blocks whose last key is <= the first row
-    if window is None:
-        lo = inner = 0
-    else:
-        lo = jnp.maximum(q0 - window + 1, 0) // bk
-        # first block whose first key every row of the block still sees
-        inner = jnp.minimum(
-            jnp.maximum(q0 + bq - window, 0) // bk
-            + (jnp.maximum(q0 + bq - window, 0) % bk > 0), whole)
-        inner = jnp.maximum(inner, lo)
-    carry = jax.lax.fori_loop(lo, inner, functools.partial(step, masked=True),
-                              carry)
-    carry = jax.lax.fori_loop(inner, whole,
-                              functools.partial(step, masked=False), carry)
-    _, l, acc = jax.lax.fori_loop(whole, hi,
-                                  functools.partial(step, masked=True), carry)
-    o_ref[0] = (acc / l).astype(o_ref.dtype)
+    def walk(carry):  # the block's rows as one chain
+        # key blocks wholly under the diagonal (and inside the band) need
+        # no mask: [lo, inner) masked at the band's lower edge, [inner,
+        # whole) bare, [whole, hi) masked at the diagonal
+        masked, bare = (functools.partial(step, q=q, rows=q0 + local,
+                                          masked=m) for m in (True, False))
+        hi = (q0 + bq - 1) // bk + 1
+        whole = (q0 + 1) // bk  # blocks whose last key is <= the first row
+        if window is None:
+            lo = inner = 0
+        else:
+            lo = jnp.maximum(q0 - window + 1, 0) // bk
+            # first block whose first key every row of the block still sees
+            inner = jnp.minimum(
+                jnp.maximum(q0 + bq - window, 0) // bk
+                + (jnp.maximum(q0 + bq - window, 0) % bk > 0), whole)
+            inner = jnp.maximum(inner, lo)
+        carry = jax.lax.fori_loop(lo, inner, masked, carry)
+        carry = jax.lax.fori_loop(inner, whole, bare, carry)
+        return jax.lax.fori_loop(whole, hi, masked, carry)
+
+    def band(carries):
+        upper = cols > local
+        out = []
+        for (q, c), carry in zip(chains, carries):
+            edge = pl.multiple_of(q0 + c * bk - window, bk)
+            diag = pl.multiple_of(q0 + c * bk, bk)
+            m, l, acc, e = softmax_step(
+                carry, jnp.where(upper, scores(q, edge), scores(q, diag)))
+            p = e.astype(v_ref.dtype)
+            zero = jnp.zeros_like(p)
+            for part, k0 in ((jnp.where(upper, p, zero), edge),
+                             (jnp.where(upper, zero, p), diag)):
+                acc += jnp.dot(part, v_ref[0, pl.ds(k0, bk), :],
+                               preferred_element_type=jnp.float32)
+            out.append((m, l, acc))
+
+        def inside(j, carries):  # the window's whole blocks between
+            return tuple(step(q0 // bk + c - window // bk + j, carry, q,
+                              None, masked=False)
+                         for (q, c), carry in zip(chains, carries))
+
+        return jax.lax.fori_loop(1, window // bk, inside, tuple(out))
+
+    def early(carries):  # every key block up to the row block's last,
+        def masked(j, carries):  # under the whole mask
+            return tuple(step(j, carry, q, q0 + c * bk + local, masked=True)
+                         for (q, c), carry in zip(chains, carries))
+
+        return jax.lax.fori_loop(0, (q0 + bq) // bk, masked, carries)
+
+    carries = tuple((jnp.full((rb, 1), MASKED, jnp.float32),
+                     jnp.zeros((rb, 1), jnp.float32),
+                     jnp.zeros((rb, LANES), jnp.float32)) for _ in chains)
+    carries = (jax.lax.cond(q0 >= window, band, early, carries) if fold
+               else (walk(*carries),))
+    for (_, c), (_, l, acc) in zip(chains, carries):
+        o_ref[0, c * rb:(c + 1) * rb] = (acc / l).astype(o_ref.dtype)
 
 
 def blocked_attention(q, k, v, n_heads: int, n_kv_heads: int,
@@ -384,20 +475,22 @@ def blocked_attention(q, k, v, n_heads: int, n_kv_heads: int,
 
     ``rotary`` = ``(cos, sin)``, each ``[T, rot/2]`` float32 (``rot`` <=
     128): q and k come unrotated and the kernel applies :func:`rotate`'s
-    arithmetic, the same roundings in the same order.  A step's q block is
-    one head's ``[block_q, 128]``, so the rotation is a lane roll and two
-    products against full-width tables (``_lane_tables``), whose row block
+    arithmetic, the same roundings in the same order.  A head's q rows in a
+    step are one ``[block_q, 128]`` tile, so the rotation is a lane roll and
+    two products against full-width tables (``_lane_tables``), whose row block
     arrives with q's; k is rotated once a (batch row, key/value head), a
     row block a step of the group's first head, into a VMEM scratch that
     every walk reads in k's place.  The grid runs a batch row's heads and
     row blocks in order for that (both ``arbitrary``).
+
+    A window of whole key blocks is walked as ``_blocked_kernel`` says under
+    ``fold``, its key block :data:`BAND_BLOCK_K` unless ``block_k`` is
+    given.
     """
     b, t, _ = q.shape
     if interpret is None:
         interpret = _interpret()
-    bq, bk = block_q or BLOCK_Q, block_k or BLOCK_K
-    bq, bk = min(bq, _round_up(t, 128)), min(bk, _round_up(t, 128))
-    tp = _round_up(t, math.lcm(bq, bk))
+    bq, bk, tp = _blocks(t, window, block_q, block_k)
     if tp != t:
         q, k, v = (jnp.pad(a, ((0, 0), (0, tp - t), (0, 0)))
                    for a in (q, k, v))
@@ -416,7 +509,8 @@ def blocked_attention(q, k, v, n_heads: int, n_kv_heads: int,
         scratch = [pltpu.VMEM((tp, LANES), k.dtype)]
     out = pl.pallas_call(
         functools.partial(_blocked_kernel, bq=bq, bk=bk, window=window,
-                          group=group, half=half),
+                          group=group, half=half,
+                          fold=_folds(window, bq, bk, tp)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         grid=(b, n_heads, tp // bq),
         in_specs=in_specs,
@@ -566,6 +660,14 @@ def _lower_tpu(ctx, *operands, n_heads, n_kv_heads, causal, window):
             avals[0].shape, avals[1].shape, avals[0].dtype, n_heads,
             n_kv_heads, causal, avals[3].shape if rotary else None):
         _count_lowering("blocked", "kernel" if rotary else None)
+        if window is not None:
+            _count("nnstpu_attention_band_walk_total",
+                   "windowed blocked-kernel calls lowered, by how a row "
+                   "block past the window walks the band's edge and "
+                   "diagonal key blocks (folded = as one tile of scores, "
+                   "split = as two masked steps)",
+                   walk="folded" if _folds(window, *_blocks(
+                       avals[0].shape[1], window)) else "split")
         return mlir.lower_fun(
             lambda q, k, v, *tables: blocked_attention(
                 q, k, v, n_heads, n_kv_heads, window, interpret=False,

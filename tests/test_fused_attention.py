@@ -411,6 +411,7 @@ def test_mosaic_compiles_the_blocked_kernel_at_16_windows_of_4096(
     tables = (laguna.rotary_tables(laguna_config()["rope_parameters"][kind],
                                    128, 4096) if rotary else None)
     before = rotary_counts()
+    walks = lowerings("nnstpu_attention_band_walk_total")
     compiled = jax.jit(lambda q, k, v: fa.attention(
         q, heads, True, k=k, v=v, n_kv_heads=8, window=window,
         rotary=tables)).lower(q, kv, kv).compile()
@@ -420,8 +421,37 @@ def test_mosaic_compiles_the_blocked_kernel_at_16_windows_of_4096(
     assert (rotary_counts().get("kernel", 0)
             == before.get("kernel", 0) + int(rotary))
     assert rotary_counts().get("outside", 0) == before.get("outside", 0)
+    # the sliding layer's window is one 512-row block: its band is folded
+    risen = {w: n - walks.get(w, 0) for w, n in lowerings(
+        "nnstpu_attention_band_walk_total").items() if n - walks.get(w, 0)}
+    assert risen == ({"folded": 1} if window else {})
     # the scores never reach HBM: no temporary of the [16, heads, T, T] kind
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+
+
+@pytest.mark.parametrize("t,dtype", [(8704, jnp.bfloat16), (4096, jnp.float32)],
+                         ids=["bf16", "f32"])
+def test_mosaic_compiles_the_band_at_the_longest_t_it_tiles(v5e_2x2, t, dtype):
+    """The band's two chains hold their tiles in straight-line code beside
+    the whole K, V and rotated K: at the longest T ``blocked_tiles`` admits
+    with a window and tables they still fit VMEM."""
+    from jax.sharding import SingleDeviceSharding
+
+    from nnstreamer_tpu.models import laguna
+
+    one = SingleDeviceSharding(v5e_2x2[0])
+    q, kv = (jax.ShapeDtypeStruct((1, t, h * 128), dtype, sharding=one)
+             for h in (64, 8))
+    tables = laguna.rotary_tables(
+        laguna_config()["rope_parameters"]["sliding_attention"], 128, t)
+    assert fa.blocked_tiles(q.shape, kv.shape, dtype, 64, 8, True,
+                            tables[0].shape)
+    assert not fa.blocked_tiles((1, t + 512, 64 * 128), (1, t + 512, 1024),
+                                dtype, 64, 8, True, (t + 512, 64))
+    text = jax.jit(lambda q, k, v: fa.attention(
+        q, 64, True, k=k, v=v, n_kv_heads=8, window=512,
+        rotary=tables)).lower(q, kv, kv).compile().as_text()
+    assert fa.BLOCKED_KERNEL_NAME in text
 
 
 @pytest.mark.parametrize("layer", [0, 1], ids=["full_attention",

@@ -346,6 +346,12 @@ def masked_reference(q, k, v, hq, hkv, window):
     (640, 12, 2, 96, 256, 128),     # two key/value heads, unequal blocks
     (200, 8, 1, 512, None, None),   # T under the window: the band is all
     (700, 6, 1, None, None, None),  # the module's own blocks, T padded
+    (640, 8, 1, 128, 128, 128),     # window = block: a folded step a block
+    (700, 8, 1, 256, 128, 128),     # two blocks: folded edges, one bare
+    (1100, 8, 1, 512, None, None),  # the module's band: two 256-row
+                                    # chains a row block, T padded
+    (1024, 6, 1, 256, None, None),  # a window under the row block: its
+                                    # first block walks under the mask
 ])
 def test_blocked_attention_in_interpret_mode(t, hq, hkv, window, bq, bk):
     q, k, v = qkv(t, hq, hkv)
@@ -390,6 +396,44 @@ def test_what_the_blocked_kernel_does_not_tile_lowers_plain():
                      v=jnp.ones((1, 8, 128)), window=4)
 
 
+@pytest.mark.parametrize("t,window,walk", [
+    (1024, 512, "folded"),   # the module's 512-row blocks, a window of one
+    (1536, 1024, "folded"),  # a window of two: one bare block between
+    (1024, 384, "split"),    # a window of no whole number of blocks
+    (512, 512, "split"),     # no row block lies past the window
+    (1024, None, None),      # no window: nothing to count
+])
+def test_the_band_walk_is_counted_by_whether_it_folds(t, window, walk):
+    """One count a windowed call lowered to the kernel, by how its band's
+    edge and diagonal blocks are walked; a CPU's program counts nothing."""
+    q, k, v = qkv(t, 2, 1, jnp.bfloat16, b=1)
+    traced = jax.jit(lambda q, k, v: fa.attention(
+        q, 2, True, k=k, v=v, n_kv_heads=1, window=window)).trace(q, k, v)
+    walks = lambda: {w: counted("nnstpu_attention_band_walk_total", w)
+                     for w in ("folded", "split")}
+    before = walks()
+    assert fa.BLOCKED_KERNEL_NAME in traced.lower(
+        lowering_platforms=("tpu",)).as_text()
+    risen = {w: n - before[w] for w, n in walks().items() if n - before[w]}
+    assert risen == ({walk: 1} if walk else {})
+    traced.lower(lowering_platforms=("cpu",))
+    assert walks() == {w: before[w] + risen.get(w, 0) for w in before}
+
+
+def test_a_window_of_whole_key_blocks_folds_and_takes_the_band_block():
+    assert fa._folds(512, 512, 512, 4096)
+    assert fa._folds(512, 512, 256, 4096)        # two chains a row block
+    assert not fa._folds(512, 256, 512, 4096)    # rows no whole key blocks
+    assert not fa._folds(384, 256, 256, 4096)    # window no whole blocks
+    assert not fa._folds(512, 512, 512, 512)     # nothing past the window
+    assert not fa._folds(None, 512, 512, 4096)
+    assert fa._blocks(4096, 512) == (512, fa.BAND_BLOCK_K, 4096)
+    assert fa._blocks(4096) == fa._blocks(4096, 384) == (512, 512, 4096)
+    assert fa._blocks(700) == (512, 512, 1024)
+    assert fa._blocks(200, 512) == (256, 256, 256)
+    assert fa._blocks(4096, 512, 128, 128) == (128, 128, 4096)
+
+
 # -- rotary inside the lowering ------------------------------------------------
 
 def tables(rope, t):
@@ -403,6 +447,11 @@ ROTARY_CASES = {
     "two_key_value_heads_unequal_blocks": (640, 12, 2, 96, 256, 128, SLIDING),
     "key_blocks_longer_than_row_blocks": (640, 12, 2, None, 128, 256, FULL),
     "the_modules_own_blocks_t_padded": (700, 6, 1, None, None, None, FULL),
+    "window_of_one_block_folded": (640, 8, 1, 128, 128, 128, SLIDING),
+    "window_of_two_blocks_folded_t_padded": (700, 8, 1, 256, 128, 128,
+                                             SLIDING),
+    "t_under_the_window_never_folds": (200, 8, 1, 512, None, None, SLIDING),
+    "the_modules_band_t_padded": (1100, 8, 1, 512, None, None, SLIDING),
 }
 
 
