@@ -718,3 +718,37 @@ def test_the_chip_compiles_a_share_without_an_array_of_every_routed_pair(
     after = lowerings(name)
     assert after["kernel"] == before.get("kernel", 0) + 1
     assert after.get("plain", 0) == before.get("plain", 0)
+
+
+# -- Falcon-H1-34B's published widths (the cell falconh1_34b_l4.ctx8x4k) ----
+
+@pytest.mark.parametrize("low", [False, True], ids=["sound", "control"])
+def test_mosaic_compiles_the_ssd_scan_at_8_windows_of_4096(v5e_2x2, low):
+    """The chunked state-space scan (``ops/ssm_scan``) at the cell's shapes,
+    32 heads of 128 over 2 groups of state 256 in chunks of 128: one layer
+    lowers ``nns_ssd_scan`` for one v5e chip, counted, in the served form
+    and the control's, and the program holds neither a chunk's states nor
+    its decay masks in HBM."""
+    from jax.sharding import SingleDeviceSharding
+
+    from nnstreamer_tpu.ops import ssm_scan
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(
+            dims, dtype, sharding=SingleDeviceSharding(v5e_2x2[0]))
+
+    b, t, name = 8, 4096, "nnstpu_ssm_scan_lowerings_total"
+    before = lowerings(name)
+    compiled = jax.jit(lambda *a: ssm_scan.ssd_scan(
+        *a, chunk=128, n_groups=2, low=low)).lower(
+        shape(b, t, 4096), shape(b, t, 32, dtype=jnp.float32),
+        shape(32, dtype=jnp.float32), shape(b, t, 512), shape(b, t, 512),
+        shape(32, dtype=jnp.float32)).compile()
+    text = compiled.as_text()
+    assert ssm_scan.KERNEL_NAME in text
+    assert ",32,128,256]" not in text and ",32,128,128]" not in text
+    # the segment sums and Δ in both orientations, nothing of a chunk's
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * b * t * 32 * 4
+    after = lowerings(name)
+    assert after["kernel"] == before.get("kernel", 0) + 1
+    assert after.get("plain", 0) == before.get("plain", 0)
