@@ -908,6 +908,15 @@ class JaxBackend(FilterBackend):
 
     # -- invoke -------------------------------------------------------------
 
+    def on_accelerator(self) -> bool:
+        """Whether the active program runs on an accelerator rather than
+        the host's CPU (never once degraded to the CPU)."""
+        if self._degraded is not None:
+            return False
+        devices = (self._mesh.devices.flat if self._mesh is not None
+                   else jax.devices())
+        return next(iter(devices)).platform != "cpu"
+
     def invoke(self, tensors: Tuple) -> Tuple:
         if self._degraded is not None:
             # degraded mode: host inputs place (and executables dispatch)

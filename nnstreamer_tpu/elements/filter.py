@@ -20,6 +20,14 @@ of upload + enqueue) and emits ``device_dispatch``, from which the device
 lane (:mod:`nnstreamer_tpu.obs.device`) observes the completion; that
 enqueue→done time is also the per-node latency of ``Pipeline.stats()``
 when profiling is enabled (:mod:`nnstreamer_tpu.utils.profiling`).
+
+Where the program runs on an accelerator and host consumers read its
+results downstream, the way back can leave the dispatching thread: an
+ordered completion worker (:mod:`nnstreamer_tpu.graph.completion`) pushes
+a round's results downstream once they are ready, while the thread that
+dispatched it carries the next round to the device.  The filter does so
+where its own clock says the way back is worth it; on the host's CPU and
+under dispatcher lanes the way back stays in the dispatching thread.
 """
 
 from __future__ import annotations
@@ -30,11 +38,14 @@ from typing import Dict, Optional
 from .. import faults as _faults
 from ..backends.base import FilterBackend, get_backend
 from ..buffer import Frame
+from ..graph import completion as _completion
 from ..graph.node import NegotiationError, Node, Pad
 from ..graph.registry import register_element
 from ..obs import hooks as _hooks
 from ..obs import spans as _spans
 from ..spec import TensorSpec, TensorsSpec
+
+_UNDECIDED = object()  # a run's completion worker, before its first round
 
 
 @register_element("tensor_filter")
@@ -71,6 +82,10 @@ class TensorFilter(Node):
         self._fused_post: list = []
         self._fusion_dirty = False
         self.dispatches = 0  # traced dispatches: the spans' round id
+        # this run's graph.completion.Completion, or None where the way
+        # back stays in the dispatching thread; decided at the first round
+        self._done = _UNDECIDED
+        self._force_ahead = False  # tests: every round ahead, on any device
 
     def set_fused_transforms(self, pre: list, post: list) -> None:
         """Install transforms fused into this filter's XLA program (called
@@ -115,7 +130,16 @@ class TensorFilter(Node):
                 self.backend.open(self.model, self.custom)
                 self._opened = True
 
+    def interrupt(self) -> None:
+        """``Pipeline.stop``: no more hand-offs to the completion worker
+        (the pipeline joins it with its other threads)."""
+        if isinstance(self._done, _completion.Completion):
+            self._done.close(wait=False)
+
     def stop(self) -> None:
+        done, self._done = self._done, _UNDECIDED
+        if isinstance(done, _completion.Completion):
+            done.close()
         if self._opened:
             self.backend.close()
             self._opened = False
@@ -355,3 +379,38 @@ class TensorFilter(Node):
                 if start is not None:
                     start()  # non-blocking; overlaps the d2h with dispatches
         return frame.with_tensors(outs)
+
+    def _emit(self, result) -> None:
+        if result is None:
+            return
+        done = self._done
+        if done is _UNDECIDED:
+            done = self._done = self._completion()
+        is_round = not self._quarantined
+        if done is None:
+            if is_round:
+                _completion.count_round(self.name, "inline")
+            super()._emit(result)
+            return
+        done.emit(result, time.perf_counter_ns() if is_round else None)
+
+    def _handle_event(self, pad: Pad, event) -> None:
+        if isinstance(self._done, _completion.Completion):
+            self._done.flush()  # every frame before the event goes first
+        super()._handle_event(pad, event)
+
+    def _completion(self) -> Optional[_completion.Completion]:
+        """This run's completion worker, or None where the way back stays
+        in the dispatching thread: a program on the host's CPU (host and
+        "device" are the same cores), results that stay on the device
+        downstream, dispatcher lanes (they schedule the chains
+        themselves)."""
+        if self._force_ahead:
+            return _completion.Completion(
+                self, _completion.AheadRule(ahead=True, fixed=True))
+        pl = self.pipeline
+        on_accelerator = getattr(self.backend, "on_accelerator", None)
+        if (pl is None or pl._lanes is not None or not self._downstream_host
+                or on_accelerator is None or not on_accelerator()):
+            return None
+        return _completion.Completion(self, _completion.AheadRule())

@@ -43,6 +43,7 @@ class TensorSplit(Node):
             self.tensorpick = [int(x) for x in str(tensorpick).split(",")]
         self._axis = 0
         self._offsets: List[slice] = []
+        self._to_host = True  # no src pad's chain keeps frames on the device
 
     def _pad_order(self) -> List[str]:
         return sorted(self.src_pads, key=lambda n: (len(n), n))
@@ -86,6 +87,11 @@ class TensorSplit(Node):
             n = s.shape[axis]
             self._offsets.append(slice(pos, pos + n))
             pos += n
+        from ..graph.residency import pad_device_resident
+
+        self._to_host = not any(
+            pad_device_resident(p, "down") for p in self.src_pads.values()
+            if p.peer is not None)
         sel = self._selected()
         order = self._pad_order()
         if len(order) > len(sel):
@@ -107,6 +113,13 @@ class TensorSplit(Node):
             arr = np.asarray(arr)
             if _hooks.enabled:
                 _hooks.emit("copy", self, arr.nbytes, 1)
+        elif self._to_host and hasattr(arr, "copy_to_host_async"):
+            # a device array whose consumers read it on the host: one
+            # device→host copy (an upstream filter started it at emit) and
+            # numpy views.  Eager slices would be device programs queued
+            # behind whatever the device runs next: the next round, where
+            # a filter has dispatched it ahead of this one's way back
+            arr = np.asarray(arr)
         sel = self._selected()
         out = []
         for i, pad_name in enumerate(self._pad_order()):

@@ -118,16 +118,15 @@ def chain_device_resident(node: Node, direction: str, max_hops: int = 4) -> bool
     device payloads through untouched qualify (queue/tee/batch/unbatch/
     demux/mux/upload); anything else (converter, host transforms, decoders,
     sinks) emits or consumes host numpy and stops the walk."""
-    up = direction == "up"
-    pads = node.sink_pads if up else node.src_pads
+    pads = node.sink_pads if direction == "up" else node.src_pads
     if len(pads) != 1:
         return False
-    pad = hop_plumbing(
-        next(iter(pads.values())).peer, direction, _passthrough_types(), max_hops
-    )
-    if pad is None:
-        return False
-    backend = getattr(pad.node, "backend", None)
-    if backend is None:
-        return False
+    return pad_device_resident(next(iter(pads.values())), direction, max_hops)
+
+
+def pad_device_resident(pad, direction: str, max_hops: int = 4) -> bool:
+    """:func:`chain_device_resident`'s walk from one linked pad of a node
+    (a ``tensor_split`` asks it of each of its src pads)."""
+    end = hop_plumbing(pad.peer, direction, _passthrough_types(), max_hops)
+    backend = getattr(end.node, "backend", None) if end is not None else None
     return bool(getattr(backend, "device_resident", False))

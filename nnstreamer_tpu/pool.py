@@ -181,11 +181,24 @@ class BufferPool:
         with self._lock:
             dq = self._free.get(key)
             if dq:
-                raw = dq.pop()  # LIFO: the warmest pages
-                self._order.remove(key)
-                self._free_bytes -= raw.nbytes
-                self.hits += 1
-            else:
+                # LIFO (the warmest pages) among the buffers no transfer
+                # still reads: with two rounds in flight the newest one
+                # feeds the round on the device, and waiting on it would
+                # hold the next round until this one is done.  A readiness
+                # check may collect a lease whose finalizer gives a buffer
+                # back into this deque: pick from a copy.
+                free = list(dq)
+                settled = [b for b in free if self._settled_locked(b)]
+                pick = settled[-1] if settled else free[-1]
+                for j in range(len(dq) - 1, -1, -1):
+                    if dq[j] is pick:
+                        raw = pick
+                        del dq[j]
+                        self._order.remove(key)
+                        self._free_bytes -= raw.nbytes
+                        self.hits += 1
+                        break
+            if raw is None:
                 self.misses += 1
         self._m_inc("hits" if raw is not None else "misses")
         fresh = raw is None
@@ -288,6 +301,26 @@ class BufferPool:
             self._fences.setdefault(id(raw), []).append(
                 (weakref.ref(raw), inflight, shard_readers)
             )
+
+    def _settled_locked(self, raw: np.ndarray) -> bool:
+        """No transfer issued from ``raw`` may still be reading it: every
+        live reader of its fences says it is ready (one that cannot say
+        counts as reading)."""
+        for wr, head, shard_readers in self._fences.get(id(raw), ()):
+            if wr() is not raw:
+                continue
+            readers = list(shard_readers) if shard_readers else []
+            if isinstance(head, weakref.ref):
+                head = head()
+            if head is not None:
+                readers.append(head)
+            for reader in readers:
+                try:
+                    if not reader.is_ready():
+                        return False
+                except Exception:  # noqa: BLE001 - no readiness to read
+                    return False
+        return True
 
     def _wait_fences(self, raw: np.ndarray) -> None:
         with self._lock:

@@ -244,6 +244,48 @@ class TestSplit:
         np.testing.assert_array_equal(s0.frames[0].tensor(0), x[:1])
         np.testing.assert_array_equal(s1.frames[0].tensor(0), x[1:])
 
+    def test_device_array_cut_on_the_host_for_host_consumers(self, rng):
+        """A filter's output split for host consumers is cut on the host
+        (one device→host copy, numpy views: no device program queued
+        behind the next round); a branch into a device filter keeps the
+        cut on the device."""
+        from nnstreamer_tpu.backends.jax_backend import JaxModel
+        from nnstreamer_tpu.elements.filter import TensorFilter
+
+        x = rng.standard_normal((6, 4)).astype(np.float32)
+        model = JaxModel(apply=lambda params, v: v + 1.0)
+        p = Pipeline()
+        src = p.add(DataSrc(data=[x]))
+        filt = p.add(TensorFilter(framework="jax", model=model))
+        split = p.add(TensorSplit(tensorseg="4:2,4:4"))
+        host = p.add(TensorSink(name="host", collect=True))
+        filt2 = p.add(TensorFilter(framework="jax", model=model))
+        dev = p.add(TensorSink(name="dev", collect=True))
+        p.link_chain(src, filt, split)
+        p.link(f"{split.name}.src_0", host)
+        p.link(f"{split.name}.src_1", filt2)
+        p.link(filt2, dev)
+        p.run(timeout=60)
+        assert split._to_host is False  # one branch reaches a device filter
+        np.testing.assert_allclose(np.asarray(dev.frames[0].tensor(0)),
+                                   x[2:] + 2.0)
+
+        p2 = Pipeline()
+        src = p2.add(DataSrc(data=[x]))
+        filt = p2.add(TensorFilter(framework="jax", model=model))
+        split = p2.add(TensorSplit(tensorseg="4:2,4:4"))
+        s0 = p2.add(TensorSink(name="g0", collect=True))
+        s1 = p2.add(TensorSink(name="g1", collect=True))
+        p2.link_chain(src, filt, split)
+        p2.link(f"{split.name}.src_0", s0)
+        p2.link(f"{split.name}.src_1", s1)
+        p2.run(timeout=60)
+        assert split._to_host is True
+        for sink, rows in ((s0, x[:2]), (s1, x[2:])):
+            got = sink.frames[0].tensor(0)
+            assert isinstance(got, np.ndarray)
+            np.testing.assert_allclose(got, rows + 1.0)
+
 
 class TestAggregator:
     def test_tumbling_window(self):

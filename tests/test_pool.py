@@ -33,6 +33,17 @@ class FakeInflight:
         return self
 
 
+class FakeReady(FakeInflight):
+    """A reader that says whether it is done, as ``jax.Array.is_ready``."""
+
+    def __init__(self, ready):
+        super().__init__()
+        self.ready = ready
+
+    def is_ready(self):
+        return self.ready
+
+
 class TestLeaseRecycle:
     def test_miss_then_hit_reuses_memory(self):
         pool = BufferPool(max_per_class=4, max_bytes=1 << 20)
@@ -167,6 +178,25 @@ class TestFence:
 
     def test_fence_noop_for_unpooled_arrays(self):
         assert fence(np.zeros(4), FakeInflight()) is False
+
+    def test_lease_takes_a_buffer_no_transfer_reads_over_a_newer_one(self):
+        """Two rounds in flight: the newest free buffer feeds the round on
+        the device; an older one whose reader is done is taken instead,
+        with no wait.  Where every free buffer is read, the newest is
+        taken after its fence."""
+        pool = BufferPool(max_per_class=4, max_bytes=1 << 20)
+        old, new = pool.lease((8,), np.float32), pool.lease((8,), np.float32)
+        old_ptr, new_ptr = old.ctypes.data, new.ctypes.data
+        done, running = FakeReady(True), FakeReady(False)
+        fence(old, done)
+        fence(new, running)
+        pool.recycle(old)
+        pool.recycle(new)  # the newest free buffer
+        del old, new
+        b = pool.lease((8,), np.float32)
+        assert b.ctypes.data == old_ptr and running.waits == 0
+        c = pool.lease((8,), np.float32)  # only the read one is left
+        assert c.ctypes.data == new_ptr and running.waits == 1
 
     def test_fresh_lease_never_waits(self):
         pool = BufferPool(max_per_class=4, max_bytes=1 << 20)
