@@ -36,6 +36,11 @@ RMSNorm(x)``:
 The causal conv and the gated norm run through XLA around the scan.
 ``layers`` (published indices) or ``num_hidden_layers`` says how many
 layers are built: all layers are alike, so only their number matters here.
+
+The mixer (:func:`mixer`, :func:`causal_conv`, :func:`gated_norm`) is the
+Mamba-2 mixer of every hybrid here: ``models/nemotron_h.py`` runs it over
+its own config's widths in these keys.  A multiplier the config does not
+hold is 1, and the mixer then multiplies by nothing.
 """
 
 from __future__ import annotations
@@ -67,14 +72,22 @@ def widths(cfg: Dict[str, Any]) -> Dict[str, int]:
             "in": d_ssm + conv + cfg["mamba_n_heads"]}
 
 
-def mup_vector(cfg: Dict[str, Any]) -> np.ndarray:
+def mup_vector(cfg: Dict[str, Any]) -> Optional[np.ndarray]:
     """μ over the in-projection's outputs: ``ssm_multipliers`` over z, x, B,
-    C and dt, float32."""
+    C and dt, float32; ``None`` where the config has none."""
+    if cfg.get("ssm_multipliers") is None:
+        return None
     w = widths(cfg)
     mz, mx, mb, mc, mdt = cfg["ssm_multipliers"]
     parts = [(w["d_ssm"], mz), (w["d_ssm"], mx), (w["bc"], mb), (w["bc"], mc),
              (cfg["mamba_n_heads"], mdt)]
     return np.concatenate([np.full(n, m, np.float32) for n, m in parts])
+
+
+def _times(a, multiplier):
+    """``a · multiplier``; an absent multiplier is 1 and multiplies
+    nothing."""
+    return a if multiplier is None else a * multiplier
 
 
 def causal_conv(x, weight, bias):
@@ -100,11 +113,15 @@ def gated_norm(y, z, gain, groups: int, eps: float):
 
 
 def mixer(cfg: Dict[str, Any], p, h, low: bool = False):
-    """The Mamba-2 branch over the normed ``h`` ``[B, T, d]``."""
+    """The Mamba-2 branch over the normed ``h`` ``[B, T, d]``; the
+    multipliers ``ssm_in_multiplier``, ``ssm_multipliers`` and
+    ``ssm_out_multiplier`` where the config holds them."""
     w = widths(cfg)
     d_ssm, bc, groups = w["d_ssm"], w["bc"], cfg["mamba_n_groups"]
-    zxbcdt = (matmul(h * cfg["ssm_in_multiplier"], p["w_in"]).astype(F32)
-              * mup_vector(cfg)).astype(h.dtype)
+    zxbcdt = matmul(_times(h, cfg.get("ssm_in_multiplier")), p["w_in"])
+    mup = mup_vector(cfg)
+    if mup is not None:
+        zxbcdt = (zxbcdt.astype(F32) * mup).astype(h.dtype)
     z, xbc, dt = jnp.split(zxbcdt, [d_ssm, d_ssm + w["conv"]], axis=-1)
     xbc = jax.nn.silu(causal_conv(
         xbc, p["conv_w"], p["conv_b"] if cfg["mamba_conv_bias"] else None))
@@ -113,7 +130,7 @@ def mixer(cfg: Dict[str, Any], p, h, low: bool = False):
     y = ssd_scan(x, delta, -jnp.exp(p["A_log"].astype(F32)), b, c,
                  p["D"].astype(F32), cfg["mamba_chunk_size"], groups, low)
     g = gated_norm(y, z, p["norm"], groups, cfg["rms_norm_eps"])
-    return matmul(g, p["w_out"]) * cfg["ssm_out_multiplier"]
+    return _times(matmul(g, p["w_out"]), cfg.get("ssm_out_multiplier"))
 
 
 def attention_branch(cfg: Dict[str, Any], p, h, tables):

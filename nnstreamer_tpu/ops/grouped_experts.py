@@ -74,14 +74,17 @@ def vmem_bytes(d: int, f: int, itemsize: int) -> int:
             + 2 * WEIGHT_TILES * TILE_ROWS * 4)
 
 
-def tiles(rows_shape, w_in_shape, w_out_shape, dtype) -> bool:
+def tiles(rows_shape, w_in_shape, w_out_shape, dtype,
+          act: str = "swiglu") -> bool:
     """Whether the kernel is the lowering for sorted rows ``[pairs, d]`` over
     ``w_in`` ``[E, d, 2f]`` and ``w_out`` ``[E, f, d]``, all of ``dtype``:
-    bf16 or f32, ``d`` and ``f`` whole lane tiles, one expert's weights twice
+    SwiGLU experts (the body has no other ``act``), bf16 or f32, ``d`` and
+    ``f`` whole lane tiles, one expert's weights twice
     over beside the tiles within :data:`VMEM_BUDGET`, and a mean of at least
     one row tile an expert (below that most of a visit's rows are another
     expert's, and XLA's ``ragged_dot`` is the better product)."""
-    if len(rows_shape) != 2 or len(w_in_shape) != 3 or len(w_out_shape) != 3:
+    if (act != "swiglu" or len(rows_shape) != 2 or len(w_in_shape) != 3
+            or len(w_out_shape) != 3):
         return False
     dtype = jnp.dtype(dtype)
     if dtype not in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32)):

@@ -22,12 +22,21 @@ one-device TPU program whose shapes :func:`scan_tiles` admits gets the
 Pallas kernel ``nns_ssd_scan``; every other program (a CPU's, one GSPMD
 partitions, other widths) gets :func:`plain_scan`, the same three products
 through XLA with the chunks' states carried by ``lax.scan``.  Both count
-``nnstpu_ssm_scan_lowerings_total{path="kernel"|"plain"}`` as they lower.
+``nnstpu_ssm_scan_lowerings_total{path="kernel"|"plain"}`` as they lower,
+and the kernel ``nnstpu_ssm_scan_bodies_total{body}`` too (below).
 
 The kernel's grid runs (batch row, block of heads, chunk), the chunks in
 order: a block's carried state ``[heads, P, N]`` stays in VMEM from one
 chunk to the next in float32, so neither the chunks' states nor their
-``Q x Q`` decay masks reach HBM.  The products run on the MXU in the
+``Q x Q`` decay masks reach HBM.  It has two bodies, chosen by the head's
+width ``P``: heads of whole lane tiles are taken one at a time, each its own
+``[Q, P]`` slice of the block (*whole_tile*); heads narrower than a tile
+(``P`` divides 128) share one, ``128 / P`` of them a tile (*shared_tile*):
+each head's masked Gram multiplies the whole ``[Q, 128]`` tile of x and
+keeps its own lanes of the product, and the decays, ``Δ`` and ``D`` are
+chosen lane by lane, so no operand narrower than a tile is sliced; the
+tile's heads carry their states stacked as one ``[128, N]`` float32 block,
+a head's ``P`` rows each.  The products run on the MXU in the
 operands' type with float32 accumulation; the segment sums are float32 and
 their exponentials are taken in the kernel; ``Δ`` scales the products and
 ``D`` adds the skip there too.  The within-chunk ``cumsum(Δ A)`` is taken
@@ -142,8 +151,9 @@ def plain_scan(x, dt, A, B, C, D, chunk: int, n_groups: int,
 def scan_tiles(x_shape, dt_shape, b_shape, dtype, n_groups: int,
                chunk: int) -> bool:
     """Whether the kernel is the lowering for these shapes: bf16 or f32,
-    heads of whole lane tiles, state dims of whole lane tiles, chunks of
-    whole lane tiles (a chunk's decays are rows of its lanes), and
+    heads of whole lane tiles or heads whose width divides a lane tile, so
+    many that a step's fill whole tiles, state dims of whole lane tiles,
+    chunks of whole lane tiles (a chunk's decays are rows of its lanes), and
     :data:`HEADS_PER_STEP` heads or a whole group a step."""
     if len(x_shape) != 3 or len(dt_shape) != 3 or len(b_shape) != 3:
         return False
@@ -153,9 +163,17 @@ def scan_tiles(x_shape, dt_shape, b_shape, dtype, n_groups: int,
     if n_groups <= 0 or h % n_groups or b_shape[-1] % n_groups:
         return False
     p, n = x_shape[-1] // h, b_shape[-1] // n_groups
-    return (x_shape[-1] == h * p and p % LANES == 0 and n % LANES == 0
-            and chunk % LANES == 0
-            and (h // n_groups) % _heads_per_step(h // n_groups) == 0)
+    hb = _heads_per_step(h // n_groups)
+    heads_fit = p % LANES == 0 or (LANES % p == 0 and hb * p % LANES == 0)
+    return (x_shape[-1] == h * p and p > 0 and heads_fit
+            and n % LANES == 0 and chunk % LANES == 0
+            and (h // n_groups) % hb == 0)
+
+
+def body(p: int) -> str:
+    """The kernel's body for heads of ``p`` channels: ``whole_tile`` or
+    ``shared_tile`` (the module's docstring)."""
+    return "whole_tile" if p % LANES == 0 else "shared_tile"
 
 
 def _heads_per_step(heads_of_group: int) -> int:
@@ -210,6 +228,70 @@ def _scan_kernel(x_ref, dt_ref, seg_ref, dtT_ref, segT_ref, d_ref, b_ref,
             state_ref.dtype)
 
 
+def _shared_tile_kernel(x_ref, dt_ref, seg_ref, dtT_ref, segT_ref, d_ref,
+                        b_ref, c_ref, o_ref, state_ref, *, hb: int, p: int,
+                        low: bool):
+    """:func:`_scan_kernel`'s work for heads narrower than a lane tile:
+    ``LANES // p`` heads a tile, each head's products over the whole tile
+    and its lanes kept; ``state_ref[u]`` holds tile ``u``'s heads' states,
+    ``p`` rows each."""
+    block, chunk = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(chunk == 0)
+    def _():
+        state_ref[...] = jnp.zeros_like(state_ref)
+
+    q, per = x_ref.shape[1], LANES // p
+    cm, bm = c_ref[0], b_ref[0]                            # [q, n]
+    gram = jax.lax.dot_general(cm, bm, (((1,), (1,)), ((), ())),
+                               preferred_element_type=F32)  # [q, q]
+    causal = (jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
+              <= jax.lax.broadcasted_iota(jnp.int32, (q, q), 0))
+    dt_blk, seg_blk = dt_ref[0], seg_ref[0]                # [q, H]
+    lane = jax.lax.broadcasted_iota(jnp.int32, dt_blk.shape, 1)
+    d_lane = jax.lax.broadcasted_iota(jnp.int32, d_ref.shape, 1)
+    tile_lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1) // p
+    tile_row = jax.lax.broadcasted_iota(jnp.int32, (LANES, 1), 0) // p
+    for u in range(hb * p // LANES):  # static: a lane tile of the block
+        xt = x_ref[0, :, u * LANES:(u + 1) * LANES]        # [q, LANES]
+        y = into = w = jnp.zeros((q, LANES), F32)
+        skip = jnp.zeros((1, LANES), F32)
+        keep = jnp.zeros((LANES, 1), F32)
+        for s in range(per):  # static: the tile's heads, p lanes each
+            head = block * hb + u * per + s
+            pick = lane == head
+            dt_col = jnp.sum(jnp.where(pick, dt_blk, 0.0), axis=1,
+                             keepdims=True)
+            seg_col = _low(jnp.sum(jnp.where(pick, seg_blk, 0.0), axis=1,
+                                   keepdims=True), low)    # [q, 1]
+            dt_row = dtT_ref[0, pl.ds(head, 1), :]         # [1, q]
+            seg_row = _low(segT_ref[0, pl.ds(head, 1), :], low)
+            decay = _low(jnp.exp(jnp.where(causal, seg_col - seg_row,
+                                           MASKED)), low)
+            mine = tile_lane == s                          # [1, LANES]
+            y = jnp.where(mine, jnp.dot((gram * decay * dt_row).astype(
+                xt.dtype), xt, preferred_element_type=F32), y)
+            into = jnp.where(mine, jnp.exp(seg_col), into)
+            last = seg_col[q - 1:q, :]                     # [1, 1]
+            w = jnp.where(mine, _low(dt_col * jnp.exp(last - seg_col), low),
+                          w)
+            skip = jnp.where(mine, jnp.sum(jnp.where(
+                d_lane == head, d_ref[...], 0.0), axis=1, keepdims=True),
+                skip)
+            keep = jnp.where(tile_row == s, _low(jnp.exp(last), low), keep)
+        state = state_ref[u]                               # [LANES, n]
+        y += jax.lax.dot_general(
+            cm, state.astype(cm.dtype), (((1,), (1,)), ((), ())),
+            preferred_element_type=F32) * into
+        y += skip * xt.astype(F32)
+        o_ref[0, :, u * LANES:(u + 1) * LANES] = y.astype(o_ref.dtype)
+        xw = (xt.astype(F32) * w).astype(xt.dtype)
+        added = jax.lax.dot_general(xw, bm, (((0,), (0,)), ((), ())),
+                                    preferred_element_type=F32)  # [LANES, n]
+        state_ref[u] = (keep * state.astype(F32) + added).astype(
+            state_ref.dtype)
+
+
 def ssd_scan_kernel(x, dt, A, B, C, D, chunk: int, n_groups: int,
                     low: bool = False, interpret: Optional[bool] = None):
     """The SSD scan as the Pallas kernel ``nns_ssd_scan`` (arguments as
@@ -232,22 +314,27 @@ def ssd_scan_kernel(x, dt, A, B, C, D, chunk: int, n_groups: int,
     bc_spec = pl.BlockSpec((1, q, n), lambda i, k, c: (i, c, k // per_group))
     chunks = tp // q
     itemsize = jnp.dtype(x.dtype).itemsize
+    whole = body(p) == "whole_tile"
+    # a unit of the carried state: a head, or a lane tile of heads
+    units, rows = (hb, p) if whole else (hb * p // LANES, LANES)
     out = pl.pallas_call(
-        functools.partial(_scan_kernel, hb=hb, p=p, low=low),
+        functools.partial(_scan_kernel if whole else _shared_tile_kernel,
+                          hb=hb, p=p, low=low),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         grid=(b, h // hb, chunks),
         in_specs=[x_spec, col_spec, col_spec, row_spec, row_spec,
                   pl.BlockSpec((1, h), lambda i, k, c: (0, 0)),
                   bc_spec, bc_spec],
         out_specs=x_spec,
-        scratch_shapes=[pltpu.VMEM((hb, p, n),
+        scratch_shapes=[pltpu.VMEM((units, rows, n),
                                    jnp.bfloat16 if low else F32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=VMEM_LIMIT),
         cost_estimate=pl.CostEstimate(
             flops=b * chunks * (2 * q * q * n * (h // hb)
-                                + h * (2 * q * q * p + 4 * q * n * p)),
+                                + h * (2 * q * q * max(p, LANES)
+                                       + 4 * q * n * p)),
             transcendentals=b * chunks * h * (q * q + 2 * q + 1),
             bytes_accessed=b * tp * (2 * hp * itemsize
                                      + 2 * g * n * itemsize * per_group
@@ -279,11 +366,17 @@ ssm_scan_p.def_impl(jax.jit(ssm_scan_p.bind,
 ssm_scan_p.def_abstract_eval(lambda x, *_, **__: x)
 
 
-def _count_lowering(path: str) -> None:
+def _count_lowering(path: str, kernel_body: Optional[str] = None) -> None:
     _count("nnstpu_ssm_scan_lowerings_total",
            "state-space scans lowered into a program, by the path chosen "
            "(kernel = the chunked Pallas kernel nns_ssd_scan, plain = the "
            "same chunks through XLA)", path=path)
+    if kernel_body is not None:
+        _count("nnstpu_ssm_scan_bodies_total",
+               "state-space scans lowered to the kernel, by the body its "
+               "heads took (whole_tile = a head of whole lane tiles at a "
+               "time, shared_tile = heads narrower than a lane tile, two of "
+               "64 channels a tile)", body=kernel_body)
 
 
 def _lower_plain(ctx, *operands, **kw):
@@ -300,7 +393,7 @@ def _lower_tpu(ctx, *operands, chunk, n_groups, low):
                            chunk)):
         return _lower_plain(ctx, *operands, chunk=chunk, n_groups=n_groups,
                             low=low)
-    _count_lowering("kernel")
+    _count_lowering("kernel", body(x.shape[-1] // dt.shape[-1]))
     return mlir.lower_fun(
         functools.partial(ssd_scan_kernel, chunk=chunk, n_groups=n_groups,
                           low=low, interpret=False),

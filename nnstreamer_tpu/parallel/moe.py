@@ -158,7 +158,12 @@ def place_moe_params(params: Params, mesh, axis: str = "ep") -> Params:
 # capacity.  This one keeps every assignment at static shapes: the ``tokens x
 # k`` (token, expert) pairs are sorted by expert, so that each expert's rows
 # lie together, and one grouped matrix product runs every expert over its own
-# rows, whatever their number.
+# rows, whatever their number.  An expert is a feed-forward of one of
+# :data:`ACTIVATIONS`: ``swiglu``, ``w_in`` ``[.., d, 2f]`` = ``[gate | up]``,
+# or ``relu2``, non-gated, ``w_in`` ``[.., d, f]``.
+
+ACTIVATIONS = ("swiglu", "relu2")
+
 
 def matmul(x, w):
     """``x @ w`` in ``x``'s type; a ``QuantizedWeight`` (``ops/quant``) runs
@@ -168,10 +173,27 @@ def matmul(x, w):
     return x @ w.astype(x.dtype)
 
 
+def activate(h, act: str):
+    """An expert's hidden rows from ``h``, the product with its ``w_in``:
+    ``swiglu`` ``silu(gate) * up`` of ``h`` = ``[gate | up]``; ``relu2``
+    ``relu(h)²``, squared in float32; in ``h``'s type."""
+    if act == "swiglu":
+        gate, up = jnp.split(h, 2, axis=-1)
+        return jax.nn.silu(gate) * up
+    if act == "relu2":
+        return jnp.square(jax.nn.relu(h.astype(jnp.float32))).astype(h.dtype)
+    raise ValueError(f"an expert's activation is one of {ACTIVATIONS}, "
+                     f"not {act!r}")
+
+
+def expert_mlp(x, w_in, w_out, act: str = "swiglu"):
+    """``activate(x @ w_in, act) @ w_out``: one expert's feed-forward."""
+    return matmul(activate(matmul(x, w_in), act), w_out)
+
+
 def swiglu(x, w_in, w_out):
     """``(silu(x @ gate) * (x @ up)) @ w_out``, ``w_in`` = ``[gate | up]``."""
-    gate, up = jnp.split(matmul(x, w_in), 2, axis=-1)
-    return matmul(jax.nn.silu(gate) * up, w_out)
+    return expert_mlp(x, w_in, w_out, "swiglu")
 
 
 def route_top_k(x, router, top_k: int, scaling: float = 1.0, bias=None,
@@ -213,33 +235,37 @@ def group_limited(n_group: Optional[int]) -> bool:
     return n_group is not None and n_group > 1
 
 
-def _routed_grouped(x, weights, experts, w_in, w_out):
-    """Through XLA: a ``ragged_dot`` for gate|up, a fusion, a ``ragged_dot``
-    for down, each over the pairs' rows in HBM."""
+def _routed_grouped(x, weights, experts, w_in, w_out, act: str = "swiglu"):
+    """Through XLA: a ``ragged_dot`` for ``w_in``, the activation's fusion, a
+    ``ragged_dot`` for ``w_out``, each over the pairs' rows in HBM."""
     n, k = experts.shape
     e = w_in.shape[0]
     flat = experts.reshape(-1)
     order = jnp.argsort(flat, stable=True)      # assignments, by expert
     sizes = jnp.bincount(flat, length=e).astype(jnp.int32)
     rows = x[order // k]                        # [n*k, d], expert-major
-    gate, up = jnp.split(jax.lax.ragged_dot(rows, w_in, sizes), 2, axis=-1)
-    out = jax.lax.ragged_dot((jax.nn.silu(gate) * up).astype(x.dtype), w_out,
-                             sizes)
+    hidden = activate(jax.lax.ragged_dot(rows, w_in, sizes), act)
+    out = jax.lax.ragged_dot(hidden.astype(x.dtype), w_out, sizes)
     back = jnp.argsort(order)                   # where each pair's row went
     out = out[back].reshape(n, k, -1)
     return jnp.einsum("nkd,nk->nd", out, weights.astype(out.dtype),
                       preferred_element_type=jnp.float32).astype(x.dtype)
 
 
-def _routed_fused(x, weights, experts, w_in, w_out, **kernel):
+def _routed_fused(x, weights, experts, w_in, w_out, act: str = "swiglu",
+                  **kernel):
     """Through ``ops/grouped_experts``: one kernel over the pairs' rows, which
     also weighs each row in float32, so a token's ``k`` rows are only summed.
     The same stable sort by expert, with the pairs' weights carried along as
     an operand of it and the groups' sizes read off the sorted experts: on
     the v5e a gather of 262 144 scalars takes 1.9 ms and ``bincount``'s
     scatter 2.3 ms, a sixth of the kernel's time each (PERF.md, PR 35).
-    ``kernel``: its tile and interpret arguments (the tests')."""
+    ``kernel``: its tile and interpret arguments (the tests').  The kernel's
+    experts are SwiGLU alone."""
     from ..ops.grouped_experts import grouped_experts
+
+    if act != "swiglu":
+        raise NotImplementedError(f"nns_grouped_experts has no {act} body")
 
     n, k = experts.shape
     e = w_in.shape[0]
@@ -274,7 +300,7 @@ def _combine_plain(out, y, token, w):
 
 
 def _routed_share(x, weights, experts, w_in, w_out, *, first: int,
-                  total: int, combine=_combine_plain):
+                  total: int, act: str = "swiglu", combine=_combine_plain):
     """The part of the layer's result that experts ``first ... first + E``
     give, ``E`` = ``w_in.shape[0]`` of the ``total`` the router chose among:
     the pairs of an expert held sort to the front, by expert, the others
@@ -311,10 +337,8 @@ def _routed_share(x, weights, experts, w_in, w_out, *, first: int,
         r0 = c * rows
         token = jax.lax.dynamic_slice(order, (r0,), (rows,)) // k
         sizes = jnp.diff(jnp.clip(starts, r0, r0 + rows))
-        gate, up = jnp.split(jax.lax.ragged_dot(x[token], w_in, sizes), 2,
-                             axis=-1)
-        y = jax.lax.ragged_dot((jax.nn.silu(gate) * up).astype(x.dtype),
-                               w_out, sizes)
+        hidden = activate(jax.lax.ragged_dot(x[token], w_in, sizes), act)
+        y = jax.lax.ragged_dot(hidden.astype(x.dtype), w_out, sizes)
         live = r0 + jnp.arange(rows, dtype=jnp.int32) < n_held
         return combine(out, y, jnp.where(live, token, n),
                        jax.lax.dynamic_slice(pair_weights, (r0,), (rows,)))
@@ -327,26 +351,33 @@ def _routed_share(x, weights, experts, w_in, w_out, *, first: int,
 # One primitive, as ``ops/fused_attention.attention_p``: a trace does not know
 # what it will be lowered for, so which of the two runs the products is the
 # lowering rule's choice.  ``first``/``total``: the share (``None``: all);
-# ``choice``: how the router chose, for the rule to count.
+# ``act``: the experts' activation; ``choice`` and ``latent`` (how the router
+# chose, whether the rows are a latent projection of the tokens): for the
+# rule to count.
 
 routed_experts_p = Primitive("nns_routed_experts")
 routed_experts_p.def_impl(jax.jit(
-    routed_experts_p.bind, static_argnames=("first", "total", "choice")))
+    routed_experts_p.bind,
+    static_argnames=("first", "total", "choice", "act", "latent")))
 routed_experts_p.def_abstract_eval(lambda x, *_, **__: x)
 
 
 def routed_experts(x, weights, experts, w_in, w_out,
                    first: Optional[int] = None, total: Optional[int] = None,
-                   choice: str = "global"):
+                   choice: str = "global", act: str = "swiglu",
+                   latent: bool = False):
     """Every (token, expert) pair of ``experts`` ``[tokens, k]`` through its
-    expert's SwiGLU (``w_in`` ``[E, d, 2f]``, ``w_out`` ``[E, f, d]``), the
-    ``k`` results of a token summed under ``weights``.  ``[tokens, d]``.
+    expert's feed-forward, the ``k`` results of a token summed under
+    ``weights``.  ``[tokens, d]``.  ``act``: ``swiglu`` (``w_in`` ``[E, d,
+    2f]`` = ``[gate | up]``) or ``relu2`` (``w_in`` ``[E, d, f]``); ``w_out``
+    ``[E, f, d]``.
 
     The pairs are sorted by expert either way.  A TPU program for one device
-    whose shapes the grouped kernel tiles (``ops/grouped_experts.tiles``)
-    runs the experts in that kernel; any other program (the CPU's, one that
-    GSPMD partitions, small or odd shapes, experts that are no plain arrays)
-    runs them as two ``ragged_dot`` products through XLA.
+    whose shapes and activation the grouped kernel tiles
+    (``ops/grouped_experts.tiles``: SwiGLU alone) runs the experts in that
+    kernel; any other program (the CPU's, one that GSPMD partitions, small
+    or odd shapes, experts that are no plain arrays, ``relu2`` experts) runs
+    them as two ``ragged_dot`` products through XLA.
 
     ``first``, ``total``: this layer holds experts ``first ... first + E``
     of the ``total`` that ``experts`` counts over (one chip's share of an
@@ -358,7 +389,11 @@ def routed_experts(x, weights, experts, w_in, w_out,
     Left out, or with ``E == total``, the layer holds them all.
     ``choice``: ``"global"`` or ``"group_limited"``, how :func:`route_top_k`
     chose ``experts``; the lowering rule counts a layer under it
-    (``nnstpu_moe_routing_total``)."""
+    (``nnstpu_moe_routing_total``), and under ``act`` and ``latent``, whether
+    ``x`` is the tokens' latent projection (``nnstpu_moe_experts_total``)."""
+    if act not in ACTIVATIONS:
+        raise ValueError(f"an expert's activation is one of {ACTIVATIONS}, "
+                         f"not {act!r}")
     if first is None or w_in.shape[0] == total:
         first = total = None
     elif not 0 <= first <= total - w_in.shape[0]:
@@ -368,30 +403,34 @@ def routed_experts(x, weights, experts, w_in, w_out,
         if first is not None:
             raise NotImplementedError("a share of quantized experts")
         _count_moe_lowering("grouped", choice)
-        return _routed_grouped(x, weights, experts, w_in, w_out)
+        _count_experts(act, latent)
+        return _routed_grouped(x, weights, experts, w_in, w_out, act)
     return routed_experts_p.bind(x, weights, experts, w_in, w_out,
-                                 first=first, total=total, choice=choice)
+                                 first=first, total=total, choice=choice,
+                                 act=act, latent=latent)
 
 
-def _xla_path(first, total, combine=_combine_plain):
+def _xla_path(first, total, act="swiglu", combine=_combine_plain):
     if first is None:
-        return _routed_grouped
+        return functools.partial(_routed_grouped, act=act)
     return functools.partial(_routed_share, first=first, total=total,
-                             combine=combine)
+                             act=act, combine=combine)
 
 
 def _lower_grouped(ctx, *operands, first=None, total=None, choice="global",
-                   combine=_combine_plain):
+                   act="swiglu", latent=False, combine=_combine_plain):
     _count_moe_lowering("grouped", choice)
+    _count_experts(act, latent)
     if first is not None:
         _count_share_combine(
             "plain" if combine is _combine_plain else "kernel")
     _say_held(ctx.avals_in[3].shape[0], total)
-    return mlir.lower_fun(_xla_path(first, total, combine),
+    return mlir.lower_fun(_xla_path(first, total, act, combine),
                           multiple_results=False)(ctx, *operands)
 
 
-def _lower_tpu(ctx, *operands, first=None, total=None, choice="global"):
+def _lower_tpu(ctx, *operands, first=None, total=None, choice="global",
+               act="swiglu", latent=False):
     from ..ops import combine_rows
     from ..ops.fused_attention import _on_one_device
     from ..ops.grouped_experts import tiles
@@ -405,14 +444,17 @@ def _lower_tpu(ctx, *operands, first=None, total=None, choice="global"):
         rows = share_rows(experts.size, w_in.shape[0], total)
         return _lower_grouped(
             ctx, *operands, first=first, total=total, choice=choice,
+            act=act, latent=latent,
             combine=functools.partial(combine_rows.combine_rows,
                                       interpret=False)
             if kernels and combine_rows.tiles(x.shape, rows, x.dtype)
             else _combine_plain)
     if not (kernels and tiles((experts.size, x.shape[-1]), w_in.shape,
-                              w_out.shape, x.dtype)):
-        return _lower_grouped(ctx, *operands, choice=choice)
+                              w_out.shape, x.dtype, act)):
+        return _lower_grouped(ctx, *operands, choice=choice, act=act,
+                              latent=latent)
     _count_moe_lowering("fused", choice)
+    _count_experts(act, latent)
     _say_held(w_in.shape[0], total)
     return mlir.lower_fun(functools.partial(_routed_fused, interpret=False),
                           multiple_results=False)(ctx, *operands)
@@ -425,8 +467,8 @@ mlir.register_lowering(routed_experts_p, _lower_tpu, platform="tpu",
 # derivatives are the XLA path's (no vmap: ``ragged_dot`` has none over its
 # group sizes either)
 ad.primitive_jvps[routed_experts_p] = \
-    lambda primals, tangents, first, total, choice: jax.jvp(
-        _xla_path(first, total), primals,
+    lambda primals, tangents, first, total, choice, act, latent: jax.jvp(
+        _xla_path(first, total, act), primals,
         tuple(ad.instantiate_zeros(t) for t in tangents))
 
 
@@ -453,6 +495,19 @@ def _count_share_combine(way: str) -> None:
     ).inc(way=way)
 
 
+def _count_experts(act: str, latent: bool) -> None:
+    from ..obs.metrics import REGISTRY
+
+    REGISTRY.counter(
+        "nnstpu_moe_experts_total",
+        "top-k expert layers lowered into a program, by their experts' "
+        "activation (swiglu = silu(gate) * up, relu2 = relu(x)^2, non-gated) "
+        "and the width their rows run in (model = the tokens' own, latent = "
+        "a projection of them, combined there and projected back)",
+        labelnames=("act", "width"),
+    ).inc(act=act, width="latent" if latent else "model")
+
+
 def _count_moe_lowering(path: str, choice: Optional[str] = None) -> None:
     from ..obs.metrics import REGISTRY
 
@@ -476,14 +531,23 @@ def _count_moe_lowering(path: str, choice: Optional[str] = None) -> None:
 def moe_top_k(params: Params, x, top_k: int, scaling: float = 1.0,
               token_chunk: Optional[int] = None, first: Optional[int] = None,
               n_group: Optional[int] = None,
-              topk_group: Optional[int] = None):
-    """Top-``top_k`` of ``E`` SwiGLU experts beside a shared one.
+              topk_group: Optional[int] = None, act: str = "swiglu"):
+    """Top-``top_k`` of ``E`` experts beside a shared one, each a
+    feed-forward of activation ``act`` (:func:`activate`).
 
-    ``params``: ``router`` ``[d, E]``, ``w_in`` ``[E, d, 2f]``, ``w_out``
-    ``[E, f, d]`` and, if the layer has one, ``shared`` (``w_in`` ``[d,
-    2f]``, ``w_out`` ``[f, d]``), which every token goes through unweighted,
-    and ``bias`` ``[E]``, which steers the choice (:func:`route_top_k`).
-    ``x``: ``[..., d]`` → the same.  Router scores and the choice are float32
+    ``params``: ``router`` ``[d, E]``, ``w_in`` ``[E, d, 2f]`` (``[E, d,
+    f]`` for ``relu2``), ``w_out`` ``[E, f, d]`` and, if the layer has one,
+    ``shared`` (``w_in`` ``[d, 2f]`` or ``[d, f]``, ``w_out`` ``[f, d]``),
+    which every token goes through unweighted, and ``bias`` ``[E]``, which
+    steers the choice (:func:`route_top_k`).  ``x``: ``[..., d]`` → the
+    same.
+
+    ``latent_in`` ``[d, l]`` and ``latent_out`` ``[l, d]``, if the layer has
+    them: the experts run in the latent width ``l``.  The router still reads
+    ``x``; the routed experts (``w_in`` ``[E, l, ·]``, ``w_out`` ``[E, f,
+    l]``) read ``x @ latent_in``, their weighted sum is taken in the latent
+    width and ``latent_out`` brings it back to ``d``; the shared expert reads
+    ``x``.  Router scores and the choice are float32
     whatever ``x`` is.  ``token_chunk`` walks the tokens in chunks of that
     many (a ``lax.scan``), so that the ``k``-fold copies of the activations
     that the grouped product reads and writes stay a chunk's size.
@@ -502,15 +566,21 @@ def moe_top_k(params: Params, x, top_k: int, scaling: float = 1.0,
     xt = x.reshape(-1, shape[-1])
     total = params["router"].shape[-1]
 
+    latent = "latent_in" in params
+
     def chunk(xc):
         w, experts = route_top_k(xc, params["router"], top_k, scaling,
                                  params.get("bias"), n_group, topk_group)
+        rows = matmul(xc, params["latent_in"]) if latent else xc
         out = routed_experts(
-            xc, w, experts, params["w_in"], params["w_out"], first, total,
-            "group_limited" if group_limited(n_group) else "global")
+            rows, w, experts, params["w_in"], params["w_out"], first, total,
+            "group_limited" if group_limited(n_group) else "global", act,
+            latent)
+        if latent:
+            out = matmul(out, params["latent_out"])
         if "shared" in params:
-            out = out + swiglu(xc, params["shared"]["w_in"],
-                               params["shared"]["w_out"])
+            out = out + expert_mlp(xc, params["shared"]["w_in"],
+                                   params["shared"]["w_out"], act)
         return out
 
     n = xt.shape[0]

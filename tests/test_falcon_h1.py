@@ -149,11 +149,16 @@ def test_bfloat16_operands_keep_the_products_in_float32():
 @pytest.mark.parametrize("x,dt,b,groups,chunk,why", [
     ((8, 4096, 4096), (8, 4096, 32), (8, 4096, 512), 2, 128, None),
     ((1, 256, 256), (1, 256, 2), (1, 256, 128), 1, 128, None),
-    ((1, 256, 128), (1, 256, 2), (1, 256, 128), 1, 128, "heads of 64"),
+    # two heads of 64 share a lane tile (the shared_tile body)
+    ((1, 256, 128), (1, 256, 2), (1, 256, 128), 1, 128, None),
     ((1, 256, 256), (1, 256, 2), (1, 256, 64), 1, 128, "a state of 64"),
     ((1, 256, 256), (1, 256, 2), (1, 256, 256), 1, 64, "chunks of 64"),
     ((1, 256, 256), (1, 256, 2), (1, 256, 384), 3, 128, "heads over groups"),
-], ids=["the_published", "one_group", "p64", "n64", "q64", "uneven"])
+    ((1, 256, 96), (1, 256, 2), (1, 256, 128), 1, 128, "heads of 48"),
+    ((1, 256, 128), (1, 256, 2), (1, 256, 256), 2, 128,
+     "one head of 64 a step: half a lane tile"),
+], ids=["the_published", "one_group", "p64", "n64", "q64", "uneven", "p48",
+        "p64_alone"])
 def test_what_the_kernel_tiles(x, dt, b, groups, chunk, why):
     assert ssm_scan.scan_tiles(x, dt, b, jnp.bfloat16, groups, chunk) == (
         why is None), why

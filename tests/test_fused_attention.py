@@ -752,3 +752,40 @@ def test_mosaic_compiles_the_ssd_scan_at_8_windows_of_4096(v5e_2x2, low):
     after = lowerings(name)
     assert after["kernel"] == before.get("kernel", 0) + 1
     assert after.get("plain", 0) == before.get("plain", 0)
+
+
+# -- Nemotron-3-Super's published widths (nemotron3_super_l11_ep4.ctx8x4k) --
+
+@pytest.mark.parametrize("low", [False, True], ids=["sound", "control"])
+def test_mosaic_compiles_the_narrow_scan_at_8_windows_of_4096(v5e_2x2, low):
+    """The scan's ``shared_tile`` body at the cell's shapes, 128 heads of 64
+    (two a lane tile) over 8 groups of state 128 in chunks of 128: one
+    Mamba layer lowers ``nns_ssd_scan`` for one v5e chip, counted under its
+    body, in the served form and the control's, and the program holds
+    neither a chunk's states nor its decay masks in HBM."""
+    from jax.sharding import SingleDeviceSharding
+
+    from nnstreamer_tpu.ops import ssm_scan
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(
+            dims, dtype, sharding=SingleDeviceSharding(v5e_2x2[0]))
+
+    b, t = 8, 4096
+    paths, bodies = ("nnstpu_ssm_scan_lowerings_total",
+                     "nnstpu_ssm_scan_bodies_total")
+    before = lowerings(paths), lowerings(bodies)
+    compiled = jax.jit(lambda *a: ssm_scan.ssd_scan(
+        *a, chunk=128, n_groups=8, low=low)).lower(
+        shape(b, t, 8192), shape(b, t, 128, dtype=jnp.float32),
+        shape(128, dtype=jnp.float32), shape(b, t, 1024), shape(b, t, 1024),
+        shape(128, dtype=jnp.float32)).compile()
+    text = compiled.as_text()
+    assert ssm_scan.KERNEL_NAME in text
+    assert ",128,128,128]" not in text and ",128,64,128]" not in text
+    # the segment sums and Δ in both orientations, nothing of a chunk's
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * b * t * 128 * 4
+    after = lowerings(paths), lowerings(bodies)
+    assert after[0]["kernel"] == before[0].get("kernel", 0) + 1
+    assert after[0].get("plain", 0) == before[0].get("plain", 0)
+    assert after[1]["shared_tile"] == before[1].get("shared_tile", 0) + 1
