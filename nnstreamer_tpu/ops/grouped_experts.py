@@ -3,10 +3,10 @@ chip.
 
 ``parallel/moe.routed_experts`` sorts a chunk's (token, expert) pairs by
 expert, so that each expert's rows lie together in one ``[pairs, d]`` array.
-Through XLA the experts' SwiGLU is then two ``ragged_dot`` products with a
-fusion between them, and the ``[pairs, 2f]`` gate|up rows and the ``[pairs,
-f]`` hidden rows go out to HBM and come back.  This kernel makes one pass
-over the sorted rows:
+Through XLA the experts' feed-forward is then two ``ragged_dot`` products
+with a fusion between them, and the ``[pairs, 2f]`` gate|up rows (``[pairs,
+f]`` for relu²) and the ``[pairs, f]`` hidden rows go out to HBM and come
+back.  This kernel makes one pass over the sorted rows:
 
 - a grid step (a *visit*) takes one tile of ``tile_rows`` rows and one
   expert.  Which ones comes from scalar-prefetched metadata made from the
@@ -15,14 +15,18 @@ over the sorted rows:
   one after the other so that the output tile stays in VMEM between them and
   each stores only its own expert's rows (megablox's scheme).  The grid has
   ``tiles + experts - 1`` steps, the most there can be; the unused ones at the
-  end repeat the last visit's blocks and do nothing;
-- the expert's ``w_in[e]`` ``[d, 2f]`` and ``w_out[e]`` ``[f, d]`` are blocks
-  picked by the visit's expert, so they stay in VMEM over the expert's
-  consecutive visits and the next expert's are fetched behind the last one;
+  end repeat the last visit's blocks and do nothing.  Rows past the groups'
+  sum are no expert's: no visit reads them, and their output rows are left
+  unwritten;
+- the expert's ``w_in[e]`` and ``w_out[e]`` ``[f, d]`` are blocks picked by
+  the visit's expert, so they stay in VMEM over the expert's consecutive
+  visits and the next expert's are fetched behind the last one;
 - a tile is worked in blocks of ``block_rows`` rows, and a block none of whose
   rows are the visit's expert's is skipped, so a straddled tile costs a block
-  more than its rows, not a tile more.  Per block ``gate|up = x @ w_in[e]`` in
-  float32, ``silu(gate) * up`` cast to the rows' type, ``@ w_out[e]`` in
+  more than its rows, not a tile more.  Per block ``h = x @ w_in[e]`` in
+  float32, the activation of ``h`` (``act``: ``swiglu``, ``w_in[e]`` ``[d,
+  2f]`` = ``[gate | up]``, ``silu(gate) * up``; ``relu2``, ``w_in[e]`` ``[d,
+  f]``, ``relu(h)²``) in float32 cast to the rows' type, ``@ w_out[e]`` in
   float32, each row times its pair's weight in float32, then the cast;
 - the pairs' weights come as a lane-major ``[tiles, tile_rows]`` float32
   array, eight tiles a block; a tile's row of it is turned into a column by a
@@ -65,35 +69,43 @@ VMEM_LIMIT = 96 * 2 ** 20
 WEIGHT_TILES = 8
 
 
-def vmem_bytes(d: int, f: int, itemsize: int) -> int:
+# w_in's width over f, by activation: SwiGLU's gate and up halves, relu²'s one
+HALVES = {"swiglu": 2, "relu2": 1}
+
+
+def vmem_bytes(d: int, f: int, itemsize: int, act: str = "swiglu") -> int:
     """VMEM one visit needs at the module's tile: one expert's ``w_in`` and
     ``w_out`` and the row and output tiles, all double buffered, and a
-    block's float32 gate|up and output rows and its hidden rows."""
-    return (2 * 3 * d * f * itemsize + 2 * 2 * TILE_ROWS * d * itemsize
-            + BLOCK_ROWS * (2 * f * 4 + f * itemsize + d * 4)
+    block's float32 product with ``w_in``, its hidden rows and its float32
+    output rows."""
+    halves = HALVES[act]
+    return (2 * (halves + 1) * d * f * itemsize
+            + 2 * 2 * TILE_ROWS * d * itemsize
+            + BLOCK_ROWS * (halves * f * 4 + f * itemsize + d * 4)
             + 2 * WEIGHT_TILES * TILE_ROWS * 4)
 
 
 def tiles(rows_shape, w_in_shape, w_out_shape, dtype,
           act: str = "swiglu") -> bool:
     """Whether the kernel is the lowering for sorted rows ``[pairs, d]`` over
-    ``w_in`` ``[E, d, 2f]`` and ``w_out`` ``[E, f, d]``, all of ``dtype``:
-    SwiGLU experts (the body has no other ``act``), bf16 or f32, ``d`` and
-    ``f`` whole lane tiles, one expert's weights twice
-    over beside the tiles within :data:`VMEM_BUDGET`, and a mean of at least
-    one row tile an expert (below that most of a visit's rows are another
-    expert's, and XLA's ``ragged_dot`` is the better product)."""
-    if (act != "swiglu" or len(rows_shape) != 2 or len(w_in_shape) != 3
+    ``w_in`` ``[E, d, 2f]`` (SwiGLU) or ``[E, d, f]`` (relu²) and ``w_out``
+    ``[E, f, d]``, all of ``dtype``: an activation the kernel has a body for
+    and a ``w_in`` of its width, bf16 or f32, ``d`` and ``f`` whole lane
+    tiles, one expert's weights twice over beside the tiles within
+    :data:`VMEM_BUDGET`, and at least one row tile an expert (below that
+    most of a visit's rows are another expert's, and XLA's ``ragged_dot`` is
+    the better product)."""
+    if (act not in HALVES or len(rows_shape) != 2 or len(w_in_shape) != 3
             or len(w_out_shape) != 3):
         return False
     dtype = jnp.dtype(dtype)
     if dtype not in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32)):
         return False
     (m, d), e, f = rows_shape, w_in_shape[0], w_out_shape[1]
-    return (tuple(w_in_shape) == (e, d, 2 * f)
+    return (tuple(w_in_shape) == (e, d, HALVES[act] * f)
             and tuple(w_out_shape) == (e, f, d)
             and d % LANES == 0 and f % LANES == 0
-            and vmem_bytes(d, f, dtype.itemsize) <= VMEM_BUDGET
+            and vmem_bytes(d, f, dtype.itemsize, act) <= VMEM_BUDGET
             and m >= e * TILE_ROWS)
 
 
@@ -127,8 +139,16 @@ def _column(row):
     return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
 
 
+def _hidden(h, act: str, f: int):
+    """A block's hidden rows from its float32 product with ``w_in``."""
+    if act == "swiglu":
+        return jax.nn.silu(h[:, :f]) * h[:, f:]
+    return jnp.square(jnp.maximum(h, 0.0))
+
+
 def _kernel(offsets_ref, expert_ref, tile_ref, count_ref, rows_ref, w_in_ref,
-            w_out_ref, weight_ref, out_ref, *, tm: int, tb: int, f: int):
+            w_out_ref, weight_ref, out_ref, *, tm: int, tb: int, f: int,
+            act: str):
     v = pl.program_id(0)
 
     @pl.when(v < count_ref[0])
@@ -143,10 +163,9 @@ def _kernel(offsets_ref, expert_ref, tile_ref, count_ref, rows_ref, w_in_ref,
             @pl.when((r0 < hi) & (r0 + tb > lo))
             def _block(at=at, r0=r0):
                 x = rows_ref[at, :]
-                gate_up = jnp.dot(x, w_in_ref[...],
-                                  preferred_element_type=jnp.float32)
-                h = (jax.nn.silu(gate_up[:, :f]) * gate_up[:, f:]).astype(
-                    x.dtype)
+                h = jnp.dot(x, w_in_ref[...],
+                            preferred_element_type=jnp.float32)
+                h = _hidden(h, act, f).astype(x.dtype)
                 out = jnp.dot(h, w_out_ref[...],
                               preferred_element_type=jnp.float32)
                 out = (out * weights[at, :]).astype(out_ref.dtype)
@@ -166,17 +185,24 @@ def _kernel(offsets_ref, expert_ref, tile_ref, count_ref, rows_ref, w_in_ref,
 def grouped_experts(rows, w_in, w_out, sizes, pair_weights,
                     tile_rows: Optional[int] = None,
                     block_rows: Optional[int] = None,
-                    interpret: Optional[bool] = None):
-    """Every row of ``rows`` ``[m, d]`` through its expert's SwiGLU and times
-    its weight: row ``r`` belongs to expert ``e`` where ``sizes[:e].sum() <=
-    r < sizes[:e + 1].sum()`` (``sizes`` ``[E]`` int32 sums to ``m``), and the
-    result's row is ``(silu(gate) * up) @ w_out[e] * pair_weights[r]`` with
-    ``gate | up = rows[r] @ w_in[e]``.  ``w_in``: ``[E, d, 2f]``, ``w_out``:
-    ``[E, f, d]``, ``pair_weights``: ``[m]`` float32.  ``[m, d]`` in ``rows``'
-    type; products and the scaling accumulate in float32.  ``m`` need not be
-    a multiple of the tile: the last tile is cut off at the array's end."""
+                    interpret: Optional[bool] = None, act: str = "swiglu"):
+    """Every row of ``rows`` ``[m, d]`` through its expert's feed-forward and
+    times its weight: row ``r`` belongs to expert ``e`` where
+    ``sizes[:e].sum() <= r < sizes[:e + 1].sum()`` (``sizes`` ``[E]`` int32),
+    and the result's row is ``act(rows[r] @ w_in[e]) @ w_out[e] *
+    pair_weights[r]``: ``act`` ``swiglu``, ``w_in`` ``[E, d, 2f]`` = ``[gate
+    | up]``, ``silu(gate) * up``; or ``relu2``, ``w_in`` ``[E, d, f]``,
+    ``relu(·)²``.  ``w_out``: ``[E, f, d]``, ``pair_weights``: ``[m]``
+    float32.  ``[m, d]`` in ``rows``' type; products and the scaling
+    accumulate in float32.  ``m`` need not be a multiple of the tile: the
+    last tile is cut off at the array's end.  ``sizes`` may sum to less
+    than ``m``: the rows behind them are no expert's, never read, and their
+    result rows hold anything."""
     m, d = rows.shape
     f = w_out.shape[1]
+    if act not in HALVES:
+        raise ValueError(f"{KERNEL_NAME} has a body for {tuple(HALVES)}, "
+                         f"not {act!r}")
     if interpret is None:
         interpret = _interpret()
     tm = tile_rows or TILE_ROWS
@@ -188,16 +214,17 @@ def grouped_experts(rows, w_in, w_out, sizes, pair_weights,
     weights = jnp.pad(pair_weights.astype(jnp.float32), (
         0, -m % (WEIGHT_TILES * tm))).reshape(-1, tm)
     itemsize = jnp.dtype(rows.dtype).itemsize
+    halves = HALVES[act]
     row_tile = pl.BlockSpec((tm, d), lambda v, o, e, t, c: (t[v], 0))
     return pl.pallas_call(
-        functools.partial(_kernel, tm=tm, tb=tb, f=f),
+        functools.partial(_kernel, tm=tm, tb=tb, f=f, act=act),
         out_shape=jax.ShapeDtypeStruct((m, d), rows.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(expert.shape[0],),
             in_specs=[
                 row_tile,
-                pl.BlockSpec((None, d, 2 * f),
+                pl.BlockSpec((None, d, halves * f),
                              lambda v, o, e, t, c: (e[v], 0, 0)),
                 pl.BlockSpec((None, f, d), lambda v, o, e, t, c: (e[v], 0, 0)),
                 pl.BlockSpec((WEIGHT_TILES, tm),
@@ -208,8 +235,10 @@ def grouped_experts(rows, w_in, w_out, sizes, pair_weights,
             dimension_semantics=("arbitrary",),  # a tile's visits are in turn
             vmem_limit_bytes=VMEM_LIMIT),
         cost_estimate=pl.CostEstimate(
-            flops=6 * m * d * f, transcendentals=m * f,
-            bytes_accessed=(2 * m * d + w_in.shape[0] * 3 * d * f) * itemsize),
+            flops=2 * (halves + 1) * m * d * f,
+            transcendentals=m * f if act == "swiglu" else 0,
+            bytes_accessed=(2 * m * d + w_in.shape[0] * (halves + 1) * d * f)
+            * itemsize),
         interpret=interpret,
         name=KERNEL_NAME,
     )(offsets, expert, tile, count, rows, w_in, w_out, weights)

@@ -235,17 +235,35 @@ def group_limited(n_group: Optional[int]) -> bool:
     return n_group is not None and n_group > 1
 
 
+def _products_xla(rows, w_in, w_out, sizes, act: str):
+    """Sorted rows through their experts as XLA's: a ``ragged_dot`` for
+    ``w_in``, the activation's fusion, a ``ragged_dot`` for ``w_out``, each
+    over the rows in HBM.  In ``rows``' type."""
+    hidden = activate(jax.lax.ragged_dot(rows, w_in, sizes), act)
+    return jax.lax.ragged_dot(hidden.astype(rows.dtype), w_out, sizes)
+
+
+def _products_kernel(rows, w_in, w_out, sizes, act: str, **kernel):
+    """The same products in ``ops/grouped_experts``' kernel, each row
+    weighed by one: the hidden rows stay in VMEM, and the rows past
+    ``sizes``' sum are never visited (their result rows hold anything).
+    ``kernel``: its tile and interpret arguments."""
+    from ..ops.grouped_experts import grouped_experts
+
+    return grouped_experts(rows, w_in, w_out, sizes,
+                           jnp.ones(rows.shape[0], jnp.float32), act=act,
+                           **kernel)
+
+
 def _routed_grouped(x, weights, experts, w_in, w_out, act: str = "swiglu"):
-    """Through XLA: a ``ragged_dot`` for ``w_in``, the activation's fusion, a
-    ``ragged_dot`` for ``w_out``, each over the pairs' rows in HBM."""
+    """Through XLA (:func:`_products_xla`) over the pairs' rows."""
     n, k = experts.shape
     e = w_in.shape[0]
     flat = experts.reshape(-1)
     order = jnp.argsort(flat, stable=True)      # assignments, by expert
     sizes = jnp.bincount(flat, length=e).astype(jnp.int32)
     rows = x[order // k]                        # [n*k, d], expert-major
-    hidden = activate(jax.lax.ragged_dot(rows, w_in, sizes), act)
-    out = jax.lax.ragged_dot(hidden.astype(x.dtype), w_out, sizes)
+    out = _products_xla(rows, w_in, w_out, sizes, act)
     back = jnp.argsort(order)                   # where each pair's row went
     out = out[back].reshape(n, k, -1)
     return jnp.einsum("nkd,nk->nd", out, weights.astype(out.dtype),
@@ -260,12 +278,8 @@ def _routed_fused(x, weights, experts, w_in, w_out, act: str = "swiglu",
     an operand of it and the groups' sizes read off the sorted experts: on
     the v5e a gather of 262 144 scalars takes 1.9 ms and ``bincount``'s
     scatter 2.3 ms, a sixth of the kernel's time each (PERF.md, PR 35).
-    ``kernel``: its tile and interpret arguments (the tests').  The kernel's
-    experts are SwiGLU alone."""
+    ``kernel``: its tile and interpret arguments (the tests')."""
     from ..ops.grouped_experts import grouped_experts
-
-    if act != "swiglu":
-        raise NotImplementedError(f"nns_grouped_experts has no {act} body")
 
     n, k = experts.shape
     e = w_in.shape[0]
@@ -276,7 +290,7 @@ def _routed_fused(x, weights, experts, w_in, w_out, act: str = "swiglu",
     sizes = jnp.diff(jnp.searchsorted(
         by_expert, jnp.arange(e + 1, dtype=jnp.int32))).astype(jnp.int32)
     out = grouped_experts(x[order // k], w_in, w_out, sizes, pair_weights,
-                          **kernel)
+                          act=act, **kernel)
     back = jnp.argsort(order)
     return out[back].reshape(n, k, -1).sum(
         axis=1, dtype=jnp.float32).astype(x.dtype)
@@ -300,7 +314,8 @@ def _combine_plain(out, y, token, w):
 
 
 def _routed_share(x, weights, experts, w_in, w_out, *, first: int,
-                  total: int, act: str = "swiglu", combine=_combine_plain):
+                  total: int, act: str = "swiglu", combine=_combine_plain,
+                  products=_products_xla):
     """The part of the layer's result that experts ``first ... first + E``
     give, ``E`` = ``w_in.shape[0]`` of the ``total`` the router chose among:
     the pairs of an expert held sort to the front, by expert, the others
@@ -312,11 +327,15 @@ def _routed_share(x, weights, experts, w_in, w_out, *, first: int,
     whatever the routing.  Nothing stands in for the experts held
     elsewhere: their pairs add zero here.
 
-    A pass's results go back by its own rows alone: row ``j`` is its pair's
-    token's, weighed by the pair's weight (in ``x``'s type) and added to the
-    token's float32 row by ``combine`` (:func:`_combine_plain`, or
-    ``ops/combine_rows`` where the lowering rule takes the kernel); the
-    pass's rows past the held pairs are no expert's and nobody's."""
+    A pass's rows go through their experts by ``products``
+    (:func:`_products_xla`, or :func:`_products_kernel` where the lowering
+    rule takes the kernel) over the pass's own group sizes, which sum to its
+    held pairs and not to its rows.  Its results go back by its own rows
+    alone: row ``j`` is its pair's token's, weighed by the pair's weight (in
+    ``x``'s type) and added to the token's float32 row by ``combine``
+    (:func:`_combine_plain`, or ``ops/combine_rows`` where the lowering rule
+    takes the kernel); the pass's rows past the held pairs are no expert's
+    and nobody's."""
     n, k = experts.shape
     held = w_in.shape[0]
     pairs = n * k
@@ -337,8 +356,7 @@ def _routed_share(x, weights, experts, w_in, w_out, *, first: int,
         r0 = c * rows
         token = jax.lax.dynamic_slice(order, (r0,), (rows,)) // k
         sizes = jnp.diff(jnp.clip(starts, r0, r0 + rows))
-        hidden = activate(jax.lax.ragged_dot(x[token], w_in, sizes), act)
-        y = jax.lax.ragged_dot(hidden.astype(x.dtype), w_out, sizes)
+        y = products(x[token], w_in, w_out, sizes, act)
         live = r0 + jnp.arange(rows, dtype=jnp.int32) < n_held
         return combine(out, y, jnp.where(live, token, n),
                        jax.lax.dynamic_slice(pair_weights, (r0,), (rows,)))
@@ -373,19 +391,21 @@ def routed_experts(x, weights, experts, w_in, w_out,
     ``[E, f, d]``.
 
     The pairs are sorted by expert either way.  A TPU program for one device
-    whose shapes and activation the grouped kernel tiles
-    (``ops/grouped_experts.tiles``: SwiGLU alone) runs the experts in that
+    whose shapes the grouped kernel tiles (``ops/grouped_experts.tiles``:
+    one expert's weights within its VMEM budget) runs the experts in that
     kernel; any other program (the CPU's, one that GSPMD partitions, small
-    or odd shapes, experts that are no plain arrays, ``relu2`` experts) runs
-    them as two ``ragged_dot`` products through XLA.
+    or odd shapes, experts too large for VMEM, experts that are no plain
+    arrays) runs them as two ``ragged_dot`` products through XLA.
 
     ``first``, ``total``: this layer holds experts ``first ... first + E``
     of the ``total`` that ``experts`` counts over (one chip's share of an
     expert-parallel layer); the result is the held experts' part
-    (:func:`_routed_share`: XLA's ``ragged_dot`` in every program; a pass's
-    results go back to their tokens through ``ops/combine_rows``' kernel in
-    a one-device TPU program whose shapes ``combine_rows.tiles()``, as
-    XLA's scatter-add in any other, ``nnstpu_moe_share_combine_total``).
+    (:func:`_routed_share`: a pass's products in the grouped kernel where
+    ``tiles()`` holds for a pass's rows, as for all experts, and XLA's
+    ``ragged_dot`` elsewhere; its results go back to their tokens through
+    ``ops/combine_rows``' kernel in a one-device TPU program whose shapes
+    ``combine_rows.tiles()``, as XLA's scatter-add in any other,
+    ``nnstpu_moe_share_combine_total``).
     Left out, or with ``E == total``, the layer holds them all.
     ``choice``: ``"global"`` or ``"group_limited"``, how :func:`route_top_k`
     chose ``experts``; the lowering rule counts a layer under it
@@ -410,22 +430,27 @@ def routed_experts(x, weights, experts, w_in, w_out,
                                  act=act, latent=latent)
 
 
-def _xla_path(first, total, act="swiglu", combine=_combine_plain):
+def _layer_ops(first, total, act="swiglu", combine=_combine_plain,
+               products=_products_xla):
+    """The layer as ops (``products`` and ``combine``: a share's; XLA's by
+    default)."""
     if first is None:
         return functools.partial(_routed_grouped, act=act)
     return functools.partial(_routed_share, first=first, total=total,
-                             act=act, combine=combine)
+                             act=act, combine=combine, products=products)
 
 
 def _lower_grouped(ctx, *operands, first=None, total=None, choice="global",
-                   act="swiglu", latent=False, combine=_combine_plain):
-    _count_moe_lowering("grouped", choice)
+                   act="swiglu", latent=False, combine=_combine_plain,
+                   products=_products_xla):
+    _count_moe_lowering(
+        "grouped" if products is _products_xla else "fused", choice)
     _count_experts(act, latent)
     if first is not None:
         _count_share_combine(
             "plain" if combine is _combine_plain else "kernel")
     _say_held(ctx.avals_in[3].shape[0], total)
-    return mlir.lower_fun(_xla_path(first, total, act, combine),
+    return mlir.lower_fun(_layer_ops(first, total, act, combine, products),
                           multiple_results=False)(ctx, *operands)
 
 
@@ -439,16 +464,21 @@ def _lower_tpu(ctx, *operands, first=None, total=None, choice="global",
     kernels = (_on_one_device(ctx.module_context.axis_context)
                and x.dtype == w_in.dtype == w_out.dtype)
     if first is not None:
-        # a share's products are XLA's either way; its way back is the
-        # kernel's where a pass's rows and the tokens' tile
+        # a share's pass runs its products in the grouped kernel where a
+        # pass's rows and one expert's weights tile, and goes back by the
+        # combining kernel where a pass's rows and the tokens tile
         rows = share_rows(experts.size, w_in.shape[0], total)
+        fused = kernels and tiles((rows, x.shape[-1]), w_in.shape,
+                                  w_out.shape, x.dtype, act)
+        back = kernels and combine_rows.tiles(x.shape, rows, x.dtype)
         return _lower_grouped(
             ctx, *operands, first=first, total=total, choice=choice,
             act=act, latent=latent,
             combine=functools.partial(combine_rows.combine_rows,
                                       interpret=False)
-            if kernels and combine_rows.tiles(x.shape, rows, x.dtype)
-            else _combine_plain)
+            if back else _combine_plain,
+            products=functools.partial(_products_kernel, interpret=False)
+            if fused else _products_xla)
     if not (kernels and tiles((experts.size, x.shape[-1]), w_in.shape,
                               w_out.shape, x.dtype, act)):
         return _lower_grouped(ctx, *operands, choice=choice, act=act,
@@ -456,8 +486,9 @@ def _lower_tpu(ctx, *operands, first=None, total=None, choice="global",
     _count_moe_lowering("fused", choice)
     _count_experts(act, latent)
     _say_held(w_in.shape[0], total)
-    return mlir.lower_fun(functools.partial(_routed_fused, interpret=False),
-                          multiple_results=False)(ctx, *operands)
+    return mlir.lower_fun(
+        functools.partial(_routed_fused, act=act, interpret=False),
+        multiple_results=False)(ctx, *operands)
 
 
 # not cacheable: every layer is lowered, and counted, on its own
@@ -468,7 +499,7 @@ mlir.register_lowering(routed_experts_p, _lower_tpu, platform="tpu",
 # group sizes either)
 ad.primitive_jvps[routed_experts_p] = \
     lambda primals, tangents, first, total, choice, act, latent: jax.jvp(
-        _xla_path(first, total, act), primals,
+        _layer_ops(first, total, act), primals,
         tuple(ad.instantiate_zeros(t) for t in tangents))
 
 

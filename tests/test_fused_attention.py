@@ -720,6 +720,40 @@ def test_the_chip_compiles_a_share_without_an_array_of_every_routed_pair(
     assert after.get("plain", 0) == before.get("plain", 0)
 
 
+def test_the_chip_compiles_a_relu2_share_with_its_products_in_the_kernel(
+        v5e_2x2):
+    """Nemotron-3-Super's share as ``routed_experts`` lowers it for one v5e
+    chip (a chunk of 8192 tokens in the latent 1024, top-22, 128 of 512
+    relu² experts of 1024 x 2688): a pass's products in
+    ``nns_grouped_experts``, its way back in ``nns_combine_rows``, no
+    ``ragged-dot``, counted ``fused``, and no pass's hidden rows (``[90112,
+    2688]``) in the program."""
+    from jax.sharding import SingleDeviceSharding
+
+    from nnstreamer_tpu.ops import combine_rows, grouped_experts
+    from nnstreamer_tpu.parallel import moe
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(
+            dims, dtype, sharding=SingleDeviceSharding(v5e_2x2[0]))
+
+    n, lat, f, k = 8192, 1024, 2688, 22
+    rows = moe.share_rows(n * k, 128, 512)
+    assert rows == 90112
+    before = moe_lowerings()
+    text = jax.jit(lambda *a: moe.routed_experts(
+        *a, first=0, total=512, act="relu2", latent=True)).lower(
+        shape(n, lat), shape(n, k, dtype=jnp.float32),
+        shape(n, k, dtype=jnp.int32), shape(128, lat, f),
+        shape(128, f, lat)).compile().as_text()
+    assert grouped_experts.KERNEL_NAME in text
+    assert combine_rows.KERNEL_NAME in text and "ragged-dot" not in text
+    assert f"[{rows},{f}]" not in text
+    after = moe_lowerings()
+    assert after["fused"] == before.get("fused", 0) + 1
+    assert after.get("grouped", 0) == before.get("grouped", 0)
+
+
 # -- Falcon-H1-34B's published widths (the cell falconh1_34b_l4.ctx8x4k) ----
 
 @pytest.mark.parametrize("low", [False, True], ids=["sound", "control"])

@@ -7,9 +7,12 @@ nothing of the program imported) through a launch string and
 against the recurrence; relu² experts through a share's passes and XLA's
 grouped product against a dense loop; four shares of a latent expert layer
 against the uncut layer; the router reading x and not its latent; and what
-a TPU lowering counts beside a CPU lowering.  The kernel compiled by Mosaic
-for a described v5e is in ``tests/test_fused_attention.py``."""
+a TPU lowering counts beside a CPU lowering; the grouped kernel's relu²
+body against each expert's feed-forward, a share's pass through it against
+XLA's, and which shares a TPU lowering sends to it.  The kernels compiled by
+Mosaic for a described v5e are in ``tests/test_fused_attention.py``."""
 
+import functools
 import json
 import os
 import sys
@@ -318,14 +321,130 @@ def test_the_router_reads_x_and_the_experts_its_latent(monkeypatch):
 def test_the_grouped_kernel_refuses_an_activation_it_has_no_body_for():
     shapes = ((65536, 2048), (8, 2048, 1024), (8, 512, 2048))
     assert grouped_experts.tiles(*shapes, jnp.bfloat16)
-    assert not grouped_experts.tiles(*shapes, jnp.bfloat16, "relu2")
-    # a relu² expert's w_in has no gate half: no SwiGLU reading of it
-    assert not grouped_experts.tiles((65536, 2048), (8, 2048, 512),
-                                     (8, 512, 2048), jnp.bfloat16, "relu2")
+    assert not grouped_experts.tiles(*shapes, jnp.bfloat16, "gelu")
+    with pytest.raises(ValueError, match="body"):
+        grouped_experts.grouped_experts(
+            jnp.zeros((8, 8)), jnp.zeros((2, 8, 4)), jnp.zeros((2, 4, 8)),
+            jnp.array([4, 4], jnp.int32), jnp.ones(8), act="gelu")
     with pytest.raises(ValueError, match="activation"):
         moe.routed_experts(jnp.zeros((4, 8)), jnp.ones((4, 2)),
                            jnp.zeros((4, 2), jnp.int32), jnp.zeros((2, 8, 4)),
                            jnp.zeros((2, 4, 8)), act="gelu")
+
+
+@pytest.mark.parametrize("act,halves,admitted", [
+    ("swiglu", 2, True),    # [gate | up]
+    ("swiglu", 1, False),   # no gate half: no SwiGLU reading of it
+    ("relu2", 1, True),     # relu²'s own w_in
+    ("relu2", 2, False),    # a SwiGLU w_in is no relu² expert
+], ids=["swiglu_gate_up", "swiglu_without_a_gate",
+        "relu2_one_half", "relu2_of_a_gate_up"])
+def test_the_grouped_kernel_tiles_each_activation_with_its_own_w_in(
+        act, halves, admitted):
+    rows, d, f, e = 65536, 2048, 512, 8
+    assert grouped_experts.tiles((rows, d), (e, d, halves * f), (e, f, d),
+                                 jnp.bfloat16, act) is admitted
+
+
+@pytest.mark.parametrize("shape,act,admitted", [
+    # a Nemotron pass: 90 112 rows over 128 of 1024 x 2688, ~28.8 MB a visit
+    (((90112, 1024), (128, 1024, 2688), (128, 2688, 1024)), "relu2", True),
+    # GLM's share: one expert is 75.5 MB
+    (((8192, 6144), (16, 6144, 4096), (16, 2048, 6144)), "swiglu", False),
+    # A.X-K1's: 88.1 MB
+    (((8192, 7168), (12, 7168, 4096), (12, 2048, 7168)), "swiglu", False),
+], ids=["nemotron_pass", "glm_share", "axk1_share"])
+def test_one_experts_weights_against_vmem_choose_the_kernel(
+        shape, act, admitted):
+    """relu² counts its one half of ``w_in``: a Nemotron expert's visit fits
+    the budget; a GLM or A.X-K1 expert is refused by its size alone."""
+    assert grouped_experts.tiles(*shape, jnp.bfloat16, act) is admitted
+    (_, d), _, (_, f, _) = shape
+    need = grouped_experts.vmem_bytes(d, f, 2, act)
+    assert (need <= grouped_experts.VMEM_BUDGET) is admitted
+    if act == "relu2":
+        assert need < grouped_experts.vmem_bytes(d, f, 2, "swiglu")
+
+
+def relu2_rows(rows, w_in, w_out, sizes, pair_weights):
+    """Row by row, each through its own expert's ``moe.expert_mlp``."""
+    ends = np.cumsum(sizes)
+    out = []
+    for r in range(int(ends[-1])):
+        e = int(np.searchsorted(ends, r, side="right"))
+        out.append(moe.expert_mlp(rows[r:r + 1], w_in[e], w_out[e], "relu2")
+                   * pair_weights[r])
+    return jnp.concatenate(out)
+
+
+@pytest.mark.parametrize("sizes,m,tile,block", [
+    ([16, 0, 32, 0, 16], 64, 16, 8),     # empty experts, groups whole blocks
+    ([5, 11, 0, 21, 3, 10], 50, 16, 8),  # groups that are no whole blocks
+    ([7, 0, 13, 9], 64, 16, 8),          # 29 live rows of 64: the rest unread
+    ([0, 0, 37], 48, 32, 16),            # one group over two tiles, then none
+], ids=["empty_experts", "partial_blocks", "fewer_than_m", "last_only"])
+def test_the_relu2_body_is_each_experts_mlp_row_for_row(sizes, m, tile, block):
+    e, d, f = len(sizes), 32, 24
+    ks = jax.random.split(jax.random.PRNGKey(m + e), 4)
+    rows = jax.random.normal(ks[0], (m, d))
+    w_in = jax.random.normal(ks[1], (e, d, f)) * 0.3
+    w_out = jax.random.normal(ks[2], (e, f, d)) * 0.3
+    pair_weights = jax.random.uniform(ks[3], (m,))
+    live = sum(sizes)
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(lambda *a: grouped_experts.grouped_experts(
+            *a, tile_rows=tile, block_rows=block, interpret=True,
+            act="relu2"))(rows, w_in, w_out, jnp.asarray(sizes, jnp.int32),
+                          pair_weights)
+        want = relu2_rows(rows, w_in, w_out, sizes, pair_weights)
+    assert got.shape == rows.shape and got.dtype == rows.dtype
+    assert np.abs(np.asarray(got[:live] - want)).max() < 1e-5 * np.abs(
+        np.asarray(want)).max()
+
+
+def pass_operands(dtype, skewed, n=400, k=4, held=4, total=16, seed=0):
+    """``n`` tokens' top-``k`` of ``total`` relu² experts, the first ``held``
+    of them held; skewed, every token picks the held ones, so that the held
+    pairs (1600) outrun a pass (``share_rows``: 1024) and take two."""
+    d, f = 32, 24
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    x = jax.random.normal(ks[0], (n, d)).astype(dtype)
+    router = jax.random.normal(ks[1], (d, total))
+    bias = jnp.zeros(total).at[:held].set(100.0 if skewed else 0.0)
+    w, experts = moe.route_top_k(x, router, k, 5.0, bias)
+    w_in = (jax.random.normal(ks[2], (held, d, f)) * 0.3).astype(dtype)
+    w_out = (jax.random.normal(ks[3], (held, f, d)) * 0.3).astype(dtype)
+    return x, w, experts, w_in, w_out
+
+
+@pytest.mark.parametrize("skewed", [False, True], ids=["one_pass",
+                                                      "two_passes"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_a_shares_pass_through_the_kernel_is_the_ragged_dot_pass(
+        dtype, skewed):
+    """``_routed_share`` with the pass's products in the kernel (unit pair
+    weights, the rows' weights applied on the way back as before) against
+    XLA's two ``ragged_dot``s: the rows past a pass's held pairs are never
+    visited and nothing of them comes back."""
+    ops = pass_operands(dtype, skewed)
+    n_held = int(np.sum(np.asarray(ops[2]) < 4))
+    assert n_held == (1600 if skewed else n_held) and n_held > 0
+    assert (n_held > moe.share_rows(1600, 4, 16)) is skewed
+    kernel = functools.partial(moe._products_kernel, interpret=True,
+                               tile_rows=16, block_rows=8)
+    with jax.default_matmul_precision("highest"):
+        got, want = (np.asarray(jax.jit(lambda *a, p=p: moe._routed_share(
+            *a, first=0, total=16, act="relu2", products=p))(*ops),
+            np.float32) for p in (kernel, moe._products_xla))
+    scale = np.abs(want).max()
+    assert np.isfinite(got).all() and scale > 0
+    if dtype == jnp.float32:
+        np.testing.assert_array_equal(got, want)
+    else:
+        # the XLA path rounds the product with w_in to bf16 before relu²,
+        # the kernel squares it in float32: a few of bf16's 2**-8 steps
+        assert np.abs(got - want).max() < 2 ** -6 * scale
 
 
 # -- lowering -----------------------------------------------------------------
@@ -343,7 +462,7 @@ def test_a_tpu_lowering_counts_what_a_cpu_lowering_does_and_the_kernels():
     traced = jax.jit(lambda p, x: nemotron_h.apply(cfg, p, x)).trace(
         shapes, ids)
     names = ("nnstpu_ssm_scan_lowerings_total", "nnstpu_ssm_scan_bodies_total",
-             "nnstpu_moe_experts_total")
+             "nnstpu_moe_experts_total", "nnstpu_moe_lowerings_total")
 
     def lowered(platform):
         before = [counts(n) for n in names]
@@ -351,15 +470,49 @@ def test_a_tpu_lowering_counts_what_a_cpu_lowering_does_and_the_kernels():
         return text, [{k: v - b.get(k, 0) for k, v in counts(n).items()
                        if v != b.get(k, 0)} for n, b in zip(names, before)]
 
-    tpu, (scans, bodies, experts) = lowered("tpu")
+    tpu, (scans, bodies, experts, paths) = lowered("tpu")
     assert ssm_scan.KERNEL_NAME in tpu
     assert scans == {("kernel",): 2} and bodies == {("shared_tile",): 2}
     assert experts == {("relu2", "latent"): 2}
+    # experts of 16 x 12: no whole lane tiles, so XLA's products either way
+    assert paths == {("grouped",): 2}
+    assert grouped_experts.KERNEL_NAME not in tpu
     assert counts("nnstpu_moe_held_experts")[("16",)] == 4
-    cpu, (scans, bodies, experts) = lowered("cpu")
+    cpu, (scans, bodies, experts, paths) = lowered("cpu")
     assert ssm_scan.KERNEL_NAME not in cpu
     assert scans == {("plain",): 2} and bodies == {}
     assert experts == {("relu2", "latent"): 2}
+    assert paths == {("grouped",): 2}
+
+
+@pytest.mark.parametrize("act,d,f,held,total,k,path", [
+    ("relu2", 1024, 2688, 128, 512, 22, "fused"),    # Nemotron's latent share
+    ("swiglu", 6144, 2048, 16, 256, 8, "grouped"),   # GLM's share
+], ids=["nemotron_relu2_share", "glm_swiglu_share"])
+def test_a_shares_products_take_the_kernel_where_one_expert_fits_vmem(
+        act, d, f, held, total, k, path):
+    """A chunk of 8192 tokens' share, traced here and lowered for a TPU: a
+    Nemotron expert (11 MB) runs in ``nns_grouped_experts`` and no
+    ``ragged_dot`` is left; a GLM expert (75.5 MB) keeps XLA's two
+    ``ragged_dot``s.  Either way the pass goes back by ``nns_combine_rows``,
+    and the layer is counted under the path its products took."""
+    n, wide = 8192, (2 if act == "swiglu" else 1)
+
+    def struct(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    before = counts("nnstpu_moe_lowerings_total")
+    text = jax.jit(lambda *a: moe.routed_experts(
+        *a, first=0, total=total, act=act, latent=act == "relu2")).trace(
+        struct(n, d), struct(n, k, dtype=jnp.float32),
+        struct(n, k, dtype=jnp.int32), struct(held, d, wide * f),
+        struct(held, f, d)).lower(lowering_platforms=("tpu",)).as_text()
+    risen = {key: v - before.get(key, 0) for key, v in counts(
+        "nnstpu_moe_lowerings_total").items() if v != before.get(key, 0)}
+    assert risen == {(path,): 1}
+    assert "nns_combine_rows" in text
+    assert (grouped_experts.KERNEL_NAME in text) is (path == "fused")
+    assert ("ragged_dot" in text) is (path == "grouped")
 
 
 # -- the streaming path -------------------------------------------------------
