@@ -210,7 +210,13 @@ def route_top_k(x, router, top_k: int, scaling: float = 1.0, bias=None,
     score is the sum of its two highest (biased) scores, the ``topk_group``
     highest groups are kept (the lower index among equals) and the
     ``top_k`` experts are chosen among the kept groups' alone.  Left out,
-    or with one group, every expert stands for choice."""
+    or with one group, every expert stands for choice.
+
+    Where the choice is not by the scores themselves (a bias, a group
+    limit), the chosen experts' scores are read by a masked select over the
+    expert axis, one reduce over ``scores``, and not by a gather of
+    ``tokens x top_k`` scalars: ``top_k``'s indices are distinct, so each
+    pick keeps exactly its own score (``nnstpu_moe_biased_routers_total``)."""
     scores = jax.nn.sigmoid(jnp.dot(
         x.astype(jnp.float32), router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
@@ -225,8 +231,12 @@ def route_top_k(x, router, top_k: int, scaling: float = 1.0, bias=None,
     if choice is scores:
         top, experts = jax.lax.top_k(scores, top_k)
     else:
+        _count_biased_router()
         _, experts = jax.lax.top_k(choice, top_k)
-        top = jnp.take_along_axis(scores, experts, axis=-1)
+        # a max, not a sum: XLA merges a sum over E with the sum over k
+        # below into one reduce, and the weights then round otherwise
+        top = jnp.where(experts[..., None] == jnp.arange(scores.shape[-1]),
+                        scores[:, None, :], -jnp.inf).max(axis=-1)
     return top / top.sum(axis=-1, keepdims=True) * scaling, experts
 
 
@@ -537,6 +547,18 @@ def _count_experts(act: str, latent: bool) -> None:
         "a projection of them, combined there and projected back)",
         labelnames=("act", "width"),
     ).inc(act=act, width="latent" if latent else "model")
+
+
+def _count_biased_router() -> None:
+    from ..obs.metrics import REGISTRY
+
+    REGISTRY.counter(
+        "nnstpu_moe_biased_routers_total",
+        "top-k routers traced into a program whose choice is not by the "
+        "scores themselves (a selection bias or a group limit), by how the "
+        "chosen experts' unbiased scores are read (select = a masked select "
+        "over the expert axis)", labelnames=("weights",),
+    ).inc(weights="select")
 
 
 def _count_moe_lowering(path: str, choice: Optional[str] = None) -> None:

@@ -273,9 +273,19 @@ def test_one_group_or_none_is_the_choice_among_all_op_for_op():
         return str(jax.make_jaxpr(lambda x, r: moe.route_top_k(
             x, r, 4, 2.5, *args))(x, router))
 
+    def unbiased(x, r):  # Laguna's: the k highest scores, as they are
+        scores = jax.nn.sigmoid(jnp.dot(
+            x.astype(jnp.float32), r.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST))
+        top, experts = jax.lax.top_k(scores, 4)
+        return top / top.sum(axis=-1, keepdims=True) * 2.5, experts
+
     assert ops() == ops(None, None, None) == ops(None, 1, 1)
+    assert ops() == str(jax.make_jaxpr(unbiased)(x, router))
     assert ops(bias) == ops(bias, 1, 1)
     assert ops(None, 4, 2) != ops()
+    # a biased or limited choice reads its picks' scores by a select
+    assert "gather[" not in ops(bias) and "gather[" not in ops(None, 4, 2)
     assert not moe.group_limited(None) and not moe.group_limited(1)
     assert moe.group_limited(8)
 

@@ -799,6 +799,24 @@ def test_the_bias_steers_the_choice_and_stays_out_of_the_weights():
     assert np.array_equal(np.asarray(same_w), np.asarray(plain_w))
 
 
+@pytest.mark.parametrize("groups", [(None, None), (4, 2)],
+                         ids=["biased", "biased_group_limited"])
+def test_a_biased_router_reads_its_chosen_scores_with_no_gather(groups):
+    """GLM's ``noaux_tc`` router (and A.X-K1's group limit beside a bias):
+    neither the trace, nor its lowering for the CPU or the TPU, nor the
+    compiled module holds a gather of the chosen scores."""
+    p = moe_params(jax.random.PRNGKey(3))
+    x = jax.random.normal(jax.random.PRNGKey(4), (200, 32))
+    traced = jax.jit(lambda x, r, b: moe.route_top_k(
+        x, r, 4, 2.5, b, *groups)).trace(x, p["router"], p["bias"])
+    assert "gather[" not in str(traced.jaxpr)
+    for platform in ("cpu", "tpu"):
+        text = traced.lower(lowering_platforms=(platform,)).as_text()
+        assert "stablehlo.gather" not in text and "dynamic_slice" not in text
+    # the compiled module: no gather op (its metadata names this test)
+    assert " gather(" not in traced.lower().compile().as_text()
+
+
 def test_a_share_is_counted_and_says_what_it_holds():
     name = "nnstpu_moe_lowerings_total"
     p = moe_params(jax.random.PRNGKey(0))

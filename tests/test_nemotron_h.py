@@ -318,6 +318,68 @@ def test_the_router_reads_x_and_the_experts_its_latent(monkeypatch):
     assert np.abs(np.asarray(got - other)).max() > 1e-3
 
 
+def gathered(x, router, k, scaling, bias, n_group, topk_group):
+    """The router with its chosen scores read by ``take_along_axis``, as
+    ``route_top_k`` read them before the masked select."""
+    _, experts = moe.route_top_k(x, router, k, scaling, bias, n_group,
+                                 topk_group)
+    scores = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    top = jnp.take_along_axis(scores, experts, axis=-1)
+    return top / top.sum(axis=-1, keepdims=True) * scaling, experts
+
+
+def router_cases():
+    """bias or none x group-limited or not x f32 / bf16 x E x k, where the
+    choice stands: top-22 of 16 experts is none."""
+    for biased in (False, True):
+        for grouped in (False, True):
+            for dtype in ("float32", "bfloat16"):
+                for e in (16, 64, 192, 512):
+                    for k in (1, 8, 22):
+                        if k <= (8 if e == 16 and grouped else e):
+                            yield pytest.param(
+                                biased, grouped, dtype, e, k,
+                                id=f"{'bias' if biased else 'nobias'}-"
+                                   f"{'grouped' if grouped else 'global'}-"
+                                   f"{dtype}-e{e}-k{k}")
+
+
+@pytest.mark.parametrize("biased,grouped,dtype,e,k", router_cases())
+def test_the_masked_select_is_take_along_axis_bit_for_bit(
+        biased, grouped, dtype, e, k):
+    """Every pick's weight and expert as the gather gave them, to the bit:
+    ``top_k``'s indices are distinct, so the select keeps one score a pick."""
+    ks = jax.random.split(jax.random.PRNGKey(e * 100 + k), 3)
+    x = jax.random.normal(ks[0], (64, 24)).astype(dtype)
+    router = (jax.random.normal(ks[1], (24, e)) * 0.5).astype(dtype)
+    bias = jax.random.normal(ks[2], (e,)) * 0.3 if biased else None
+    groups = ((4, 2) if e == 16 else (8, 4)) if grouped else (None, None)
+    args = (x, router, k, 5.0, bias, *groups)
+    got_w, got = jax.jit(moe.route_top_k, static_argnums=(2, 3, 5, 6))(*args)
+    want_w, want = jax.jit(gathered, static_argnums=(2, 3, 5, 6))(*args)
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+    assert np.array_equal(np.asarray(got_w).view(np.uint32),
+                          np.asarray(want_w).view(np.uint32))
+
+
+def test_a_biased_router_is_counted_where_it_is_traced_and_an_unbiased_not():
+    """One biased expert layer traced: the count rises by one; the same
+    layer without its bias (Laguna's router): it stays."""
+    name = "nnstpu_moe_biased_routers_total"
+    p = share_of(moe_params(jax.random.PRNGKey(0)), 0, 4)
+    x = jnp.ones((8, 32))
+    before = counts(name).get(("select",), 0)
+    jax.jit(lambda x: moe.moe_top_k(p, x, 4, 5.0, first=0,
+                                    act="relu2")).trace(x)
+    assert counts(name)[("select",)] == before + 1
+    unbiased = {key: v for key, v in p.items() if key != "bias"}
+    jax.jit(lambda x: moe.moe_top_k(unbiased, x, 4, 5.0, first=0,
+                                    act="relu2")).trace(x)
+    assert counts(name)[("select",)] == before + 1
+
+
 def test_the_grouped_kernel_refuses_an_activation_it_has_no_body_for():
     shapes = ((65536, 2048), (8, 2048, 1024), (8, 512, 2048))
     assert grouped_experts.tiles(*shapes, jnp.bfloat16)
