@@ -535,7 +535,7 @@ class Smoke:
         # the tables) as attention()'s rule lowers it, the band folded,
         # against rotate() and XLA's walk at float32, a key/value head of a
         # batch row at a time (all of them at once hold 69 GB of scores)
-        from nnstreamer_tpu.models.laguna import rotary_tables
+        from nnstreamer_tpu.models.decoder import rotary_tables
         from nnstreamer_tpu.obs.metrics import REGISTRY
         from nnstreamer_tpu.ops import fused_attention as fa
 
@@ -669,7 +669,7 @@ class Smoke:
         # the cell's head shape, pairs of heads of 192 | 64 and v 256: the
         # projections unrotated and the tables, q rotated on the kernel's
         # blocks, against rotate() and the walk through XLA at float32
-        from nnstreamer_tpu.models.laguna import rotary_tables
+        from nnstreamer_tpu.models.decoder import rotary_tables
         from nnstreamer_tpu.ops.fused_attention import rotate
 
         tables = rotary_tables({"rope_theta": 8000000.0}, dr, t)

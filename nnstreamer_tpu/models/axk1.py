@@ -3,8 +3,7 @@ on the streaming path: multi-head latent attention over every causal key,
 YaRN rotary embeddings, and sparse experts chosen group by group, of which a
 chip may hold its share.
 
-One frame is a window of ``T`` token ids; the program is a whole forward
-pass over it and returns the logits of the last position.  The layer body is
+The program is ``models/decoder.py``'s forward pass; the layer body is
 ``models/glm_dsa.layer``, the same latent attention with no selection in
 front of it; this module reads the family's published ``config.json`` keys
 into the keys that body takes (:func:`latent_config`):
@@ -17,7 +16,7 @@ into the keys that body takes (:func:`latent_config`):
 - *rotary*: ``rope_theta`` beside a ``rope_scaling`` dict of type ``yarn``
   (``factor``, ``original_max_position_embeddings``, ``beta_fast``,
   ``beta_slow``, ``mscale``, ``mscale_all_dim``): the per-pair blend of
-  interpolated and extrapolated frequencies that ``laguna.rotary_tables``
+  interpolated and extrapolated frequencies that ``decoder.rotary_tables``
   computes, cos and sin times ``m(mscale) / m(mscale_all_dim)`` and the
   softmax scale ``(qk_nope_head_dim + qk_rope_head_dim) ** -0.5 *
   m(mscale_all_dim) ** 2``, with ``m(s) = 0.1 s ln(factor) + 1``: the scale
@@ -46,7 +45,7 @@ import jax.numpy as jnp
 
 from ..backends.jax_backend import JaxModel
 from . import glm_dsa
-from .laguna import load_config, quantize_weights
+from .decoder import load_config, quantize_weights
 
 
 def yarn_scale(scaling: Dict[str, Any], key: str) -> float:

@@ -2,10 +2,9 @@
 path: multi-head latent attention under a learned selection of keys, and
 sparse experts of which a chip may hold its share.
 
-One frame is a window of ``T`` token ids; the program is a whole forward
-pass over it and returns the logits of the last position, as
-``models/laguna.py`` does.  The model is read from the published
-``config.json`` keys; layer ``i``, pre-norm RMSNorm, no biases:
+The program is ``models/decoder.py``'s forward pass around these layers,
+read from the published ``config.json`` keys; layer ``i``, pre-norm RMSNorm,
+no biases:
 
 - *latent attention*: ``c_q = RMSNorm(h W_dq)`` (``q_lora_rank``), ``q = c_q
   W_uq`` in ``num_attention_heads`` heads of ``qk_nope_head_dim |
@@ -52,7 +51,7 @@ published one and no activation is ever re-tiled for the interleaved layout.
 
 from __future__ import annotations
 
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -60,22 +59,11 @@ import numpy as np
 
 from ..backends.jax_backend import JaxModel
 from ..ops.fused_attention import rotate
-from ..ops.sparse_attention import (latent_sparse_attention, select_keys,
-                                    shared_selection)
+from ..ops.sparse_attention import latent_sparse_attention, select_keys
 from ..parallel.moe import matmul, moe_top_k, swiglu
-from ..spec import TensorSpec, TensorsSpec
-from .laguna import (load_config, quantize_weights, rms_norm,
-                     rotary_tables)
-
-
-def layer_ids(cfg: Dict[str, Any]) -> List[int]:
-    """The published indices of the layers built."""
-    return list(cfg.get("layers") or range(cfg["num_hidden_layers"]))
-
-
-def experts_held(cfg: Dict[str, Any]) -> Sequence[int]:
-    """``(first, count)`` of the routed experts this chip holds."""
-    return tuple(cfg.get("experts_held") or (0, cfg["n_routed_experts"]))
+from . import decoder
+from .decoder import (experts_held, layer_ids, load_config, quantize_weights,
+                      rms_norm, rotary_tables)
 
 
 def _pairs_split(w, heads: int, at: int, rot: int):
@@ -152,21 +140,21 @@ def layer(cfg: Dict[str, Any], i: int, p, x, selection, tables,
     c_q = rms_norm(matmul(h, p["w_dq"]), p["q_norm"], eps)
     down = matmul(h, p["w_dkv"])
     c_kv = rms_norm(down[..., :rank], p["kv_norm"], eps)
-    if selects(cfg, i) is None:
+    role = selects(cfg, i)
+    if role is None:
         selection = None  # every causal key: no mask is made or read
-    elif selects(cfg, i) == "full":
+    elif role == "full":
         selection = select(cfg, p["indexer"], h, c_q, tables)
     elif selection is None:
         raise ValueError(f"layer {i} shares a selection and none of the "
                          "layers built before it makes one")
-    else:
-        selection = shared_selection(selection)
     # q and the rotary key part as the products write them: the attention's
     # lowering rotates them, q on the kernel's own blocks where it tiles
     o = latent_sparse_attention(matmul(c_q, p["w_uq"]),
                                 matmul(c_kv, p["w_uk"]), down[..., rank:],
                                 matmul(c_kv, p["w_uv"]), selection, heads,
-                                rotary=tables, scale=cfg.get("softmax_scale"))
+                                rotary=tables, scale=cfg.get("softmax_scale"),
+                                shared=role == "shared")
     x = x + matmul(o, p["wo"])
     h = rms_norm(x, p["mlp_norm"], eps)
     if cfg["mlp_layer_types"][i] == "dense":
@@ -182,45 +170,35 @@ def apply(cfg: Dict[str, Any], params, ids, dtype=jnp.bfloat16,
     """``ids`` ``[B, T]`` int32 -> float32 logits ``[B, vocab]`` of the last
     position (one window ``[T]`` -> ``[vocab]``).  ``params``: the pytree
     with the rotary pairs split (:func:`split_rotary_pairs`)."""
-    if ids.ndim == 1:
-        return apply(cfg, params, ids[None], dtype, token_chunk)[0]
-    tables = rotary_tables(cfg["rope_parameters"], cfg["qk_rope_head_dim"],
-                           ids.shape[-1])
-    x = jnp.asarray(params["embed"])[ids].astype(dtype)
-    selection = None
-    for i, p in zip(layer_ids(cfg), params["layers"]):
-        x, selection = layer(cfg, i, p, x, selection, tables, token_chunk)
-    last = rms_norm(x[:, -1], params["norm"], cfg["rms_norm_eps"])
-    return matmul(last, params["head"]).astype(jnp.float32)
+    def layers(x):
+        tables = rotary_tables(cfg["rope_parameters"],
+                               cfg["qk_rope_head_dim"], x.shape[1])
+        selection = None
+        for i, p in zip(layer_ids(cfg), params["layers"]):
+            x, selection = layer(cfg, i, p, x, selection, tables,
+                                 token_chunk)
+        return x
+
+    return decoder.apply(params, ids, dtype, layers, cfg["rms_norm_eps"])
 
 
 def init_params(cfg: Dict[str, Any], seed: int = 0, dtype=jnp.bfloat16):
-    """Seeded random weights in a checkpoint's pytree and layout (small
-    sizes: the arrays are made on the default device)."""
+    """Seeded random weights in a checkpoint's pytree and layout
+    (``decoder.Draws``)."""
     d, heads = cfg["hidden_size"], cfg["num_attention_heads"]
     rq, rkv = cfg["q_lora_rank"], cfg["kv_lora_rank"]
     dn, dr, dv = (cfg["qk_nope_head_dim"], cfg["qk_rope_head_dim"],
                   cfg["v_head_dim"])
     e, (_, held) = cfg["n_routed_experts"], experts_held(cfg)
-    keys = iter(jax.random.split(jax.random.PRNGKey(seed), 4096))
-
-    def w(*shape):
-        return (jax.random.normal(next(keys), shape, jnp.float32)
-                * shape[-2] ** -0.5).astype(dtype)
-
-    def gain(n=d, mean=1.0):
-        return (mean + 0.1 * jax.random.normal(next(keys), (n,))).astype(dtype)
-
-    def glu(width, lead=()):
-        return {"w_in": w(*lead, d, 2 * width), "w_out": w(*lead, width, d)}
-
+    draw = decoder.Draws(seed, dtype)
+    w, gain = draw.matrix, draw.gain
     layers = []
     for i in layer_ids(cfg):
-        p = {"attn_norm": gain(), "w_dq": w(d, rq), "q_norm": gain(rq),
+        p = {"attn_norm": gain(d), "w_dq": w(d, rq), "q_norm": gain(rq),
              "w_uq": w(rq, heads * (dn + dr)), "w_dkv": w(d, rkv + dr),
              "kv_norm": gain(rkv), "w_uk": w(rkv, heads * dn),
              "w_uv": w(rkv, heads * dv), "wo": w(heads * dv, d),
-             "mlp_norm": gain()}
+             "mlp_norm": gain(d)}
         if selects(cfg, i) == "full":
             width = cfg["index_head_dim"]
             p["indexer"] = {
@@ -228,17 +206,15 @@ def init_params(cfg: Dict[str, Any], seed: int = 0, dtype=jnp.bfloat16):
                 "k_norm": {"scale": gain(width), "bias": gain(width, 0.0)},
                 "w_heads": w(d, cfg["index_n_heads"])}
         if cfg["mlp_layer_types"][i] == "dense":
-            p["mlp"] = glu(cfg["intermediate_size"])
+            p["mlp"] = draw.glu(d, cfg["intermediate_size"])
         else:
             f = cfg["moe_intermediate_size"]
-            p["moe"] = dict(glu(f, (held,)), router=w(d, e))
+            p["moe"] = dict(draw.glu(d, f, (held,)), router=w(d, e))
             if cfg.get("topk_method", "noaux_tc") == "noaux_tc":
                 p["moe"]["bias"] = gain(e, 0.0)
-            p["moe"]["shared"] = glu(f * cfg["n_shared_experts"])
+            p["moe"]["shared"] = draw.glu(d, f * cfg["n_shared_experts"])
         layers.append(p)
-    embed = jax.random.normal(next(keys), (cfg["vocab_size"], d), jnp.float32)
-    return {"embed": embed.astype(dtype), "layers": layers, "norm": gain(),
-            "head": w(d, cfg["vocab_size"])}
+    return draw.model(cfg, layers)
 
 
 def build(config, seq: int, batch: Optional[int] = None,
@@ -250,16 +226,13 @@ def build(config, seq: int, batch: Optional[int] = None,
     this is a cut; ``params``: a checkpoint's weights (``init_params``'
     pytree and layout), seeded random ones if left out."""
     cfg = load_config(config)
-    dtype = jnp.dtype(dtype)  # a launch string gives its name
-    if params is None:
-        params = init_params(cfg, seed, dtype)
-    shape = (seq,) if batch is None else (batch, seq)
-    return JaxModel(
-        apply=lambda p, ids: apply(cfg, p, ids, dtype, token_chunk),
-        params=split_rotary_pairs(cfg, params),
-        input_spec=TensorsSpec.of(TensorSpec(dtype=np.int32, shape=shape)),
-        name=f"glm_dsa_{cfg['hidden_size']}x{len(layer_ids(cfg))}",
-    )
+    model = decoder.build(
+        cfg, init_params,
+        lambda p, ids, dtype: apply(cfg, p, ids, dtype, token_chunk),
+        f"glm_dsa_{cfg['hidden_size']}x{len(layer_ids(cfg))}", seq, batch,
+        dtype, seed, params)
+    model.params = split_rotary_pairs(cfg, model.params)
+    return model
 
 
 def build_quantized(**kwargs) -> JaxModel:

@@ -1,9 +1,7 @@
 """Decoder-only token model of the ``laguna`` family on the streaming path.
 
-One frame is a window of ``T`` token ids; the program is a whole forward
-pass over it and returns the logits of the last position (what a serving
-engine calls a prefill-only request).  The model is read from the published
-``config.json`` keys, per layer:
+The program is ``models/decoder.py``'s forward pass around these layers,
+read from the published ``config.json`` keys:
 
 - ``layer_types`` / ``num_attention_heads_per_layer``: a ``full_attention``
   layer is causal with rotary embeddings on the first
@@ -27,57 +25,15 @@ per-layer lists are built, so the whole model is the same call.
 
 from __future__ import annotations
 
-import json
-import math
-import os
 from typing import Any, Dict, Optional
 
-import jax
 import jax.numpy as jnp
-import numpy as np
 
 from ..backends.jax_backend import JaxModel
 from ..ops.fused_attention import attention
 from ..parallel.moe import matmul, moe_top_k, swiglu
-from ..spec import TensorSpec, TensorsSpec
-
-
-def load_config(config) -> Dict[str, Any]:
-    """A ``config.json`` as a dict: a dict, or the path of the file."""
-    if isinstance(config, (str, os.PathLike)):
-        with open(config, "r", encoding="utf-8") as f:
-            return json.load(f)
-    return dict(config)
-
-
-def rotary_tables(rope: Dict[str, Any], head_dim: int, t: int):
-    """``(cos, sin)``, each ``[t, rot/2]`` float32, of one layer kind's
-    ``rope_parameters``; ``rot`` = ``head_dim * partial_rotary_factor``."""
-    rot = int(head_dim * rope.get("partial_rotary_factor", 1))
-    inv = 1.0 / rope["rope_theta"] ** (np.arange(0, rot, 2) / rot)
-    scale = 1.0
-    if rope.get("rope_type", "default") == "yarn":
-        factor, orig = rope["factor"], rope["original_max_position_embeddings"]
-
-        def correction(rotations):
-            return (rot * math.log(orig / (rotations * 2 * math.pi))
-                    / (2 * math.log(rope["rope_theta"])))
-
-        low = max(math.floor(correction(rope["beta_fast"])), 0)
-        high = min(math.ceil(correction(rope["beta_slow"])), rot - 1)
-        ramp = np.clip((np.arange(rot // 2) - low) / max(high - low, 1e-3),
-                       0, 1)
-        inv = inv / factor * ramp + inv * (1 - ramp)
-        scale = rope.get("attention_factor") or 0.1 * math.log(factor) + 1.0
-    angles = np.arange(t)[:, None] * inv[None, :]
-    return (jnp.asarray(np.cos(angles) * scale, jnp.float32),
-            jnp.asarray(np.sin(angles) * scale, jnp.float32))
-
-
-def rms_norm(x, gain, eps: float):
-    h = x.astype(jnp.float32)
-    h = h * jax.lax.rsqrt(jnp.mean(h * h, axis=-1, keepdims=True) + eps)
-    return (h * gain.astype(jnp.float32)).astype(x.dtype)
+from . import decoder
+from .decoder import load_config, quantize_weights, rms_norm, rotary_tables
 
 
 def layer(cfg: Dict[str, Any], i: int, p, x, tables, token_chunk=None):
@@ -105,52 +61,38 @@ def apply(cfg: Dict[str, Any], params, ids, dtype=jnp.bfloat16,
           token_chunk: Optional[int] = None):
     """``ids`` ``[B, T]`` int32 → float32 logits ``[B, vocab]`` of the last
     position (one window ``[T]`` → ``[vocab]``)."""
-    if ids.ndim == 1:
-        return apply(cfg, params, ids[None], dtype, token_chunk)[0]
-    t = ids.shape[-1]
-    tables = {kind: rotary_tables(rope, cfg["head_dim"], t)
-              for kind, rope in cfg["rope_parameters"].items()
-              if isinstance(rope, dict)}
-    x = jnp.asarray(params["embed"])[ids].astype(dtype)
-    for i, p in enumerate(params["layers"]):
-        x = layer(cfg, i, p, x, tables, token_chunk)
-    last = rms_norm(x[:, -1], params["norm"], cfg["rms_norm_eps"])
-    return matmul(last, params["head"]).astype(jnp.float32)
+    def layers(x):
+        tables = {kind: rotary_tables(rope, cfg["head_dim"], x.shape[1])
+                  for kind, rope in cfg["rope_parameters"].items()
+                  if isinstance(rope, dict)}
+        for i, p in enumerate(params["layers"]):
+            x = layer(cfg, i, p, x, tables, token_chunk)
+        return x
+
+    return decoder.apply(params, ids, dtype, layers, cfg["rms_norm_eps"])
 
 
 def init_params(cfg: Dict[str, Any], seed: int = 0, dtype=jnp.bfloat16):
-    """Seeded random weights in the model's pytree (small sizes: the arrays
-    are made on the default device)."""
+    """Seeded random weights in the model's pytree (``decoder.Draws``)."""
     d, dh = cfg["hidden_size"], cfg["head_dim"]
     kv = cfg["num_key_value_heads"] * dh
-    keys = iter(jax.random.split(jax.random.PRNGKey(seed), 4096))
-
-    def w(*shape):
-        return (jax.random.normal(next(keys), shape, jnp.float32)
-                * shape[-2] ** -0.5).astype(dtype)
-
-    def gain():
-        return (1 + 0.1 * jax.random.normal(next(keys), (d,))).astype(dtype)
-
-    def glu(width, lead=()):
-        return {"w_in": w(*lead, d, 2 * width), "w_out": w(*lead, width, d)}
-
+    draw = decoder.Draws(seed, dtype)
     layers = []
     for i in range(cfg["num_hidden_layers"]):
         hq = cfg["num_attention_heads_per_layer"][i] * dh
-        p = {"attn_norm": gain(), "wq": w(d, hq), "wk": w(d, kv),
-             "wv": w(d, kv), "wo": w(hq, d), "mlp_norm": gain()}
+        p = {"attn_norm": draw.gain(d), "wq": draw.matrix(d, hq),
+             "wk": draw.matrix(d, kv), "wv": draw.matrix(d, kv),
+             "wo": draw.matrix(hq, d), "mlp_norm": draw.gain(d)}
         if cfg["mlp_layer_types"][i] == "dense":
-            p["mlp"] = glu(cfg["intermediate_size"])
+            p["mlp"] = draw.glu(d, cfg["intermediate_size"])
         else:
             e = cfg["num_experts"]
-            p["moe"] = dict(glu(cfg["moe_intermediate_size"], (e,)),
-                            router=w(d, e),
-                            shared=glu(cfg["shared_expert_intermediate_size"]))
+            p["moe"] = dict(
+                draw.glu(d, cfg["moe_intermediate_size"], (e,)),
+                router=draw.matrix(d, e),
+                shared=draw.glu(d, cfg["shared_expert_intermediate_size"]))
         layers.append(p)
-    embed = jax.random.normal(next(keys), (cfg["vocab_size"], d), jnp.float32)
-    return {"embed": embed.astype(dtype), "layers": layers, "norm": gain(),
-            "head": w(d, cfg["vocab_size"])}
+    return draw.model(cfg, layers)
 
 
 def build(config, seq: int, batch: Optional[int] = None,
@@ -161,35 +103,11 @@ def build(config, seq: int, batch: Optional[int] = None,
     ``config.json`` (dict or path); ``params``: its weights (``init_params``'
     pytree), seeded random ones if left out."""
     cfg = load_config(config)
-    dtype = jnp.dtype(dtype)  # a launch string gives its name
-    if params is None:
-        params = init_params(cfg, seed, dtype)
-    shape = (seq,) if batch is None else (batch, seq)
-    return JaxModel(
-        apply=lambda p, ids: apply(cfg, p, ids, dtype, token_chunk),
-        params=params,
-        input_spec=TensorsSpec.of(TensorSpec(dtype=np.int32, shape=shape)),
-        name=f"laguna_{cfg['hidden_size']}x{cfg['num_hidden_layers']}",
-    )
-
-
-def quantize_weights(params, keep=("router", "embed")):
-    """``params`` with every 2-D weight W8A8 (``ops/quant``) but the
-    subtrees under the keys in ``keep`` and the routed experts (a ``moe``
-    dict's own ``w_in`` / ``w_out``)."""
-    from ..ops.quant import quantize_weight
-
-    def walk(p, inside_moe=False):
-        if isinstance(p, dict):
-            return {k: (v if k in keep or
-                        (inside_moe and k in ("w_in", "w_out"))
-                        else walk(v, k == "moe"))
-                    for k, v in p.items()}
-        if isinstance(p, list):
-            return [walk(v) for v in p]
-        return quantize_weight(p) if getattr(p, "ndim", 0) == 2 else p
-
-    return walk(params)
+    return decoder.build(
+        cfg, init_params,
+        lambda p, ids, dtype: apply(cfg, p, ids, dtype, token_chunk),
+        f"laguna_{cfg['hidden_size']}x{cfg['num_hidden_layers']}", seq,
+        batch, dtype, seed, params)
 
 
 def build_quantized(**kwargs) -> JaxModel:

@@ -3,13 +3,12 @@ streaming path: a sequence of single-mixer layers, each a Mamba-2 mixer,
 grouped attention or a latent mixture of experts, as
 ``hybrid_override_pattern`` spells them.
 
-One frame is a window of ``T`` token ids; the program is a whole forward
-pass over it and returns the logits of the last position, as
-``models/falcon_h1.py`` does.  The model is read from the published
-``config.json`` keys; every layer is ``x ← x + mixer(RMSNorm(x))`` at
-``layer_norm_epsilon``, its mixer chosen by one character of the pattern:
+The program is ``models/decoder.py``'s forward pass around these layers,
+read from the published ``config.json`` keys; every layer is ``x ← x +
+mixer(RMSNorm(x))`` at ``layer_norm_epsilon``, its mixer chosen by one
+character of the pattern:
 
-- ``M``, *Mamba-2*: ``models/falcon_h1.mixer`` over this config's widths
+- ``M``, *Mamba-2*: ``models/decoder.mixer`` over this config's widths
   (:func:`mixer_config`; the family has no multipliers): ``[z | xBC | dt] =
   h W_in``, ``xBC = SiLU(causal depthwise conv1d(xBC))`` (``conv_kernel``
   taps, a bias if ``use_conv_bias``), x in ``mamba_num_heads`` heads of
@@ -45,26 +44,21 @@ Two keys say what is built here and are no published ones, as in
 
 from __future__ import annotations
 
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Dict, List, Optional
 
-import jax
 import jax.numpy as jnp
-import numpy as np
 
 from ..backends.jax_backend import JaxModel
 from ..ops.fused_attention import attention
+from ..ops.lowering import gauge
 from ..parallel.moe import matmul, moe_top_k
-from ..spec import TensorSpec, TensorsSpec
-from .falcon_h1 import mixer, widths
-from .laguna import load_config, quantize_weights, rms_norm
+from . import decoder
+# widths: read off this module by the benchmark's kind
+from .decoder import (  # noqa: F401
+    experts_held, layer_ids, load_config, mixer, mixer_params,
+    quantize_weights, rms_norm, widths)
 
-F32 = jnp.float32
 KINDS = {"M": "mamba", "*": "attention", "E": "experts"}
-
-
-def layer_ids(cfg: Dict[str, Any]) -> List[int]:
-    """The published indices of the layers built."""
-    return list(cfg.get("layers") or range(cfg["num_hidden_layers"]))
 
 
 def layer_kinds(cfg: Dict[str, Any]) -> List[str]:
@@ -79,13 +73,8 @@ def layer_kinds(cfg: Dict[str, Any]) -> List[str]:
     return kinds
 
 
-def experts_held(cfg: Dict[str, Any]) -> Sequence[int]:
-    """``(first, count)`` of the routed experts this chip holds."""
-    return tuple(cfg.get("experts_held") or (0, cfg["n_routed_experts"]))
-
-
 def mixer_config(cfg: Dict[str, Any]) -> Dict[str, Any]:
-    """This family's Mamba-2 keys in the ones ``falcon_h1.mixer`` reads."""
+    """This family's Mamba-2 keys in the ones ``decoder.mixer`` reads."""
     return {"hidden_size": cfg["hidden_size"],
             "mamba_d_ssm": cfg["mamba_num_heads"] * cfg["mamba_head_dim"],
             "mamba_n_heads": cfg["mamba_num_heads"],
@@ -131,70 +120,42 @@ def apply(cfg: Dict[str, Any], params, ids, dtype=jnp.bfloat16,
           low: bool = False, token_chunk: Optional[int] = None):
     """``ids`` ``[B, T]`` int32 → float32 logits ``[B, vocab]`` of the last
     position (one window ``[T]`` → ``[vocab]``)."""
-    if ids.ndim == 1:
-        return apply(cfg, params, ids[None], dtype, low, token_chunk)[0]
-    x = jnp.asarray(params["embed"])[ids].astype(dtype)
-    for kind, p in zip(layer_kinds(cfg), params["layers"]):
-        x = layer(cfg, kind, p, x, low, token_chunk)
-    last = rms_norm(x[:, -1], params["norm"], cfg["norm_eps"])
-    return matmul(last, params["head"]).astype(F32)
+    def layers(x):
+        for kind, p in zip(layer_kinds(cfg), params["layers"]):
+            x = layer(cfg, kind, p, x, low, token_chunk)
+        return x
+
+    return decoder.apply(params, ids, dtype, layers, cfg["norm_eps"])
 
 
 def init_params(cfg: Dict[str, Any], seed: int = 0, dtype=jnp.bfloat16):
-    """Seeded random weights in the model's pytree (small sizes: the arrays
-    are made on the default device): matrices ``N(0, 1 / fan-in)``, the
-    embedding ``N(0, 1)``, the gains ``N(1, 0.1)``, the selection bias
-    ``N(0, 0.1)``; the mixer's small arrays as Mamba-2 draws them (``A`` in
-    [1, 16], ``Δ`` at the bias in [1e-3, 1e-1], the conv ``U(±1 /
-    sqrt(taps))``), ``D`` in [0.5, 1.5]."""
+    """Seeded random weights in the model's pytree (``decoder.Draws``; the
+    mixers' ``decoder.mixer_params``), the selection bias ``N(0, 0.1)``."""
     d, dh = cfg["hidden_size"], cfg["head_dim"]
     heads, kv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
-    w = widths(mixer_config(cfg))
-    nh, taps = cfg["mamba_num_heads"], cfg["conv_kernel"]
     e, (_, held) = cfg["n_routed_experts"], experts_held(cfg)
     lat, f = cfg["moe_latent_size"], cfg["moe_intermediate_size"]
     fs = cfg["moe_shared_expert_intermediate_size"]
-    keys = iter(jax.random.split(jax.random.PRNGKey(seed), 4096))
-
-    def matrix(*shape):
-        return (jax.random.normal(next(keys), shape, F32)
-                * shape[-2] ** -0.5).astype(dtype)
-
-    def gain(n=d, mean=1.0):
-        return (mean + 0.1 * jax.random.normal(next(keys), (n,))).astype(dtype)
-
-    def uniform(lo, hi, *shape):
-        return jax.random.uniform(next(keys), shape, F32, lo, hi)
-
+    draw = decoder.Draws(seed, dtype)
+    matrix = draw.matrix
     layers = []
     for kind in layer_kinds(cfg):
         if kind == "M":
-            dt0 = jnp.exp(uniform(np.log(1e-3), np.log(1e-1), nh))
-            bound = taps ** -0.5
-            p = {"mixer": {
-                "w_in": matrix(d, w["in"]),
-                "conv_w": uniform(-bound, bound, taps, w["conv"]).astype(dtype),
-                "conv_b": uniform(-bound, bound, w["conv"]).astype(dtype),
-                "dt_bias": (dt0 + jnp.log(-jnp.expm1(-dt0))).astype(dtype),
-                "A_log": jnp.log(uniform(1, 16, nh)).astype(dtype),
-                "D": uniform(0.5, 1.5, nh).astype(dtype),
-                "norm": gain(w["d_ssm"]), "w_out": matrix(w["d_ssm"], d)}}
+            p = {"mixer": mixer_params(draw, mixer_config(cfg))}
         elif kind == "*":
-            p = {"attn": {"wq": matrix(d, heads * dh), "wk": matrix(d, kv * dh),
-                          "wv": matrix(d, kv * dh),
+            p = {"attn": {"wq": matrix(d, heads * dh),
+                          "wk": matrix(d, kv * dh), "wv": matrix(d, kv * dh),
                           "wo": matrix(heads * dh, d)}}
         else:
-            p = {"moe": {"router": matrix(d, e), "bias": gain(e, 0.0),
+            p = {"moe": {"router": matrix(d, e), "bias": draw.gain(e, 0.0),
                          "latent_in": matrix(d, lat),
                          "latent_out": matrix(lat, d),
                          "w_in": matrix(held, lat, f),
                          "w_out": matrix(held, f, lat),
                          "shared": {"w_in": matrix(d, fs),
                                     "w_out": matrix(fs, d)}}}
-        layers.append(dict(p, norm=gain()))
-    embed = jax.random.normal(next(keys), (cfg["vocab_size"], d), F32)
-    return {"embed": embed.astype(dtype), "layers": layers, "norm": gain(),
-            "head": matrix(d, cfg["vocab_size"])}
+        layers.append(dict(p, norm=draw.gain(d)))
+    return draw.model(cfg, layers)
 
 
 def build(config, seq: int, batch: Optional[int] = None,
@@ -208,19 +169,18 @@ def build(config, seq: int, batch: Optional[int] = None,
     bfloat16 (the benchmark's control); ``token_chunk``: the tokens an expert
     layer takes at a time."""
     cfg = load_config(config)
-    dtype = jnp.dtype(dtype)  # a launch string gives its name
-    if params is None:
-        params = init_params(cfg, seed, dtype)
     kinds = layer_kinds(cfg)
-    _say_kinds(kinds)
-    shape = (seq,) if batch is None else (batch, seq)
-    return JaxModel(
-        apply=lambda p, ids: apply(cfg, p, ids, dtype, bool(low),
-                                   token_chunk),
-        params=params,
-        input_spec=TensorsSpec.of(TensorSpec(dtype=np.int32, shape=shape)),
-        name=f"nemotron_h_{cfg['hidden_size']}x{len(kinds)}",
-    )
+    for char, kind in KINDS.items():
+        gauge("nnstpu_hybrid_layers",
+              "layers of the last hybrid model built, by kind (mamba = a "
+              "Mamba-2 mixer, attention = grouped attention, experts = a "
+              "mixture of experts)", kinds.count(char), kind=kind)
+    return decoder.build(
+        cfg, init_params,
+        lambda p, ids, dtype: apply(cfg, p, ids, dtype, bool(low),
+                                    token_chunk),
+        f"nemotron_h_{cfg['hidden_size']}x{len(kinds)}", seq, batch, dtype,
+        seed, params)
 
 
 def build_quantized(**kwargs) -> JaxModel:
@@ -232,15 +192,3 @@ def build_quantized(**kwargs) -> JaxModel:
     model.params = quantize_weights(model.params,
                                     ("router", "embed", "conv_w"))
     return model
-
-
-def _say_kinds(kinds: List[str]) -> None:
-    from ..obs.metrics import REGISTRY
-
-    gauge = REGISTRY.gauge(
-        "nnstpu_hybrid_layers",
-        "layers of the last hybrid model built, by kind (mamba = a Mamba-2 "
-        "mixer, attention = grouped attention, experts = a mixture of "
-        "experts)", labelnames=("kind",))
-    for char, kind in KINDS.items():
-        gauge.set(kinds.count(char), kind=kind)

@@ -34,15 +34,12 @@ already holds: float32 in VMEM only, where XLA's :func:`rotate` around the
 projections re-tiles each ``[tokens, heads * 128]`` array to ``[heads,
 128]`` through float32 copies in HBM.
 
-``models/transformer.py`` and ``models/laguna.py`` call :func:`attention`, one
-primitive.  Which lowering a call gets is decided when its program is lowered,
-from what it is lowered for: the kernel for a TPU program that runs on one
-device (or is the all-manual body of a ``shard_map``) where :func:`tiles`
-(the fused projection) or :func:`blocked_tiles` (separate projections) holds,
+The token models call :func:`attention`, one primitive, whose lowering
+``ops/lowering.py`` chooses: a kernel where :func:`tiles` (the fused
+projection) or :func:`blocked_tiles` (separate projections) holds,
 ``parallel.ring_attention.full_attention`` with the explicit mask through XLA
-(after :func:`rotate`, where the call came with tables) everywhere else
-— a CPU program on a TPU host, a program GSPMD partitions over a mesh, a shape
-the kernel does not tile.  ``full_attention`` stays the reference.  Called
+(after :func:`rotate`, where the call came with tables) for every other
+program or shape.  ``full_attention`` stays the reference.  Called
 directly off-TPU, :func:`fused_attention` executes in Pallas interpret mode
 (the tests), like ``ops/pallas_kernels.py``.
 """
@@ -58,8 +55,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.extend.core import Primitive
-from jax.interpreters import ad, batching, mlir
+from jax.interpreters import ad, batching
 
+from .lowering import count, register
 from .pallas_kernels import LANES, _interpret
 
 KERNEL_NAME = "nns_fused_attention"
@@ -548,12 +546,7 @@ def plain_grouped_attention(q, k, v, n_heads: int, n_kv_heads: int,
                           window=window).reshape(b, t, n_heads * dh)
 
 
-# -- one primitive, its lowerings -------------------------------------------
-#
-# A trace does not know what it will be lowered for: the same jaxpr goes to
-# the TPU, to the CPU under ``jax.default_device`` (the backend's
-# ``cpu_fallback`` retry), or into a program GSPMD partitions because an
-# input arrived with a NamedSharding.  So the choice is a lowering rule's.
+# -- one primitive, its lowerings (``ops/lowering.py`` chooses) -------------
 
 attention_p = Primitive("nns_full_attention")
 
@@ -602,84 +595,56 @@ attention_p.def_impl(jax.jit(
 attention_p.def_abstract_eval(_abstract)
 
 
-def _count(name: str, text: str, **label) -> None:
-    from ..obs.metrics import REGISTRY
-
-    REGISTRY.counter(name, text, labelnames=tuple(label)).inc(**label)
-
-
-def _count_lowering(path: str, rotary: Optional[str] = None) -> None:
-    _count("nnstpu_attention_lowerings_total",
-           "attention calls lowered into a program, by the path chosen (fused "
-           "= the whole-row Pallas kernel, blocked = the key-block walk with "
-           "grouped heads, plain = full_attention through XLA; "
-           "ops/sparse_attention: latent_sparse = the selected-keys kernel, "
-           "latent_sparse_plain = its walk through XLA, latent[_plain] = "
-           "the same over every causal key, index_select[_plain] = a "
-           "selection's scoring pass)", path=path)
+def count_attention(path: str, rotary: Optional[str] = None) -> None:
+    """Count an attention call lowered, by path and, with tables, by where
+    q is rotated (``ops/sparse_attention`` counts its calls here too)."""
+    count("nnstpu_attention_lowerings_total",
+          "attention calls lowered into a program, by the path chosen (fused "
+          "= the whole-row Pallas kernel, blocked = the key-block walk with "
+          "grouped heads, plain = full_attention through XLA; "
+          "ops/sparse_attention: latent_sparse = the selected-keys kernel, "
+          "latent_sparse_plain = its walk through XLA, latent[_plain] = "
+          "the same over every causal key, index_select[_plain] = a "
+          "selection's scoring pass)", path=path)
     if rotary is not None:
-        _count("nnstpu_attention_rotary_total",
-               "attention calls lowered with rotary tables, by where q (and, "
-               "for the blocked kernel, k) is rotated (kernel = on the "
-               "kernel's own blocks in VMEM, outside = rotate() through XLA "
-               "before the attention)",
-               where=rotary)
+        count("nnstpu_attention_rotary_total",
+              "attention calls lowered with rotary tables, by where q (and, "
+              "for the blocked kernel, k) is rotated (kernel = on the "
+              "kernel's own blocks in VMEM, outside = rotate() through XLA "
+              "before the attention)",
+              where=rotary)
 
 
-def _lower_plain(ctx, *operands, **kw):
-    _count_lowering("plain", "outside" if len(operands) == 5 else None)
-    return mlir.lower_fun(functools.partial(_plain, **kw),
-                          multiple_results=False)(ctx, *operands)
+def _lower_plain(avals, **kw):
+    count_attention("plain", "outside" if len(avals) == 5 else None)
+    return functools.partial(_plain, **kw)
 
 
-def _on_one_device(axis_context) -> bool:
-    """Mosaic's own condition for lowering a kernel (jax's
-    ``tpu_custom_call``): the program is for one device, or it is the body
-    of a ``shard_map`` over every axis of its mesh."""
-    if hasattr(axis_context, "num_devices"):  # a jit's ShardingContext
-        return axis_context.num_devices == 1
-    mesh = getattr(axis_context, "mesh", None)  # shard_map's SPMDAxisContext
-    return mesh is not None and (
-        set(axis_context.manual_axes) | set(mesh.manual_axes)
-        == set(mesh.axis_names))
-
-
-def _lower_tpu(ctx, *operands, n_heads, n_kv_heads, causal, window):
-    kw = dict(n_heads=n_heads, n_kv_heads=n_kv_heads, causal=causal,
-              window=window)
-    if not _on_one_device(ctx.module_context.axis_context):
-        return _lower_plain(ctx, *operands, **kw)
-    avals = ctx.avals_in
+def _lower_kernel(avals, *, n_heads, n_kv_heads, causal, window):
     if len(avals) == 1 and tiles(avals[0].shape, avals[0].dtype, n_heads):
-        _count_lowering("fused")
-        return mlir.lower_fun(
-            lambda a: fused_attention(a, n_heads, causal, interpret=False),
-            multiple_results=False)(ctx, *operands)
+        count_attention("fused")
+        return lambda a: fused_attention(a, n_heads, causal,
+                                         interpret=False)
     rotary = len(avals) == 5
     if len(avals) >= 3 and blocked_tiles(
             avals[0].shape, avals[1].shape, avals[0].dtype, n_heads,
             n_kv_heads, causal, avals[3].shape if rotary else None):
-        _count_lowering("blocked", "kernel" if rotary else None)
+        count_attention("blocked", "kernel" if rotary else None)
         if window is not None:
-            _count("nnstpu_attention_band_walk_total",
-                   "windowed blocked-kernel calls lowered, by how a row "
-                   "block past the window walks the band's edge and "
-                   "diagonal key blocks (folded = as one tile of scores, "
-                   "split = as two masked steps)",
-                   walk="folded" if _folds(window, *_blocks(
-                       avals[0].shape[1], window)) else "split")
-        return mlir.lower_fun(
-            lambda q, k, v, *tables: blocked_attention(
-                q, k, v, n_heads, n_kv_heads, window, interpret=False,
-                rotary=tables or None),
-            multiple_results=False)(ctx, *operands)
-    return _lower_plain(ctx, *operands, **kw)
+            count("nnstpu_attention_band_walk_total",
+                  "windowed blocked-kernel calls lowered, by how a row "
+                  "block past the window walks the band's edge and "
+                  "diagonal key blocks (folded = as one tile of scores, "
+                  "split = as two masked steps)",
+                  walk="folded" if _folds(window, *_blocks(
+                      avals[0].shape[1], window)) else "split")
+        return lambda q, k, v, *tables: blocked_attention(
+            q, k, v, n_heads, n_kv_heads, window, interpret=False,
+            rotary=tables or None)
+    return None
 
 
-# not cacheable: every call site is lowered, and counted, on its own
-mlir.register_lowering(attention_p, _lower_plain, cacheable=False)
-mlir.register_lowering(attention_p, _lower_tpu, platform="tpu",
-                       cacheable=False)
+register(attention_p, _lower_plain, _lower_kernel)
 
 
 def _jvp(primals, tangents, **kw):

@@ -40,12 +40,10 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .pallas_kernels import _interpret
+
 # pairwise width/height use the reference's inclusive-pixel convention
 # (x2 - x1 + 1, tensordec-boundingbox.c:744) — see decoders.bounding_boxes.iou
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _pairwise_suppression(xc, yc, wc, hc, xr, yr, wr, hr):

@@ -80,10 +80,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.extend.core import Primitive
-from jax.interpreters import mlir
 
-from .fused_attention import (MASKED, _count, _count_lowering, _lane_tables,
-                              _on_one_device, _rotated, rotate)
+from .fused_attention import (MASKED, _lane_tables, _rotated, count_attention,
+                              rotate)
+from .lowering import count, register
 from .pallas_kernels import LANES, _interpret
 
 KERNEL_NAME = "nns_latent_sparse_attention"
@@ -324,57 +324,22 @@ select_keys_p.def_abstract_eval(
                                      dtype=jnp.dtype(jnp.int8)))
 
 
-def _count_indexer(role: str) -> None:
-    _count("nnstpu_indexer_lowerings_total",
-           "attention layers lowered into a program by where their key "
-           "selection comes from (full = the layer's own indexer scores and "
-           "selects, shared = it reuses an earlier layer's selection)",
-           role=role)
+def _lower_select(avals, *, top_k):
+    count_attention("index_select_plain")
+    return functools.partial(_select, top_k=top_k)
 
 
-def _lower_select(ctx, *operands, top_k):
-    _count_indexer("full")
-    _count_lowering("index_select_plain")
-    return mlir.lower_fun(functools.partial(_select, top_k=top_k),
-                          multiple_results=False)(ctx, *operands)
-
-
-def _lower_select_tpu(ctx, *operands, top_k):
-    q_i, k_i, _ = ctx.avals_in
-    if not (_on_one_device(ctx.module_context.axis_context)
-            and q_i.dtype == k_i.dtype
+def _lower_select_kernel(avals, *, top_k):
+    q_i, k_i, _ = avals
+    if not (q_i.dtype == k_i.dtype
             and index_tiles(q_i.shape, k_i.shape, q_i.dtype, top_k)):
-        return _lower_select(ctx, *operands, top_k=top_k)
-    _count_indexer("full")
-    _count_lowering("index_select")
-    return mlir.lower_fun(
-        lambda q_i, k_i, w: index_select(q_i, k_i, w.astype(jnp.float32),
-                                         top_k, interpret=False),
-        multiple_results=False)(ctx, *operands)
+        return None
+    count_attention("index_select")
+    return lambda q_i, k_i, w: index_select(q_i, k_i, w.astype(jnp.float32),
+                                            top_k, interpret=False)
 
 
-mlir.register_lowering(select_keys_p, _lower_select, cacheable=False)
-mlir.register_lowering(select_keys_p, _lower_select_tpu, platform="tpu",
-                       cacheable=False)
-
-
-shared_selection_p = Primitive("nns_shared_selection")
-
-
-def shared_selection(mask):
-    """``mask`` as a later layer takes it over from the layer that selected:
-    the identity, there to be counted where programs are lowered."""
-    return shared_selection_p.bind(mask)
-
-
-def _lower_shared(ctx, mask):
-    _count_indexer("shared")
-    return [mask]
-
-
-shared_selection_p.def_impl(lambda mask: mask)
-shared_selection_p.def_abstract_eval(lambda mask: mask)
-mlir.register_lowering(shared_selection_p, _lower_shared, cacheable=False)
+register(select_keys_p, _lower_select, _lower_select_kernel)
 
 
 # -- the attention -----------------------------------------------------------
@@ -787,7 +752,8 @@ latent_sparse_attention_p = Primitive("nns_latent_sparse_attention")
 
 
 def latent_sparse_attention(q, k_nope, k_rope, v, mask, n_heads: int,
-                            rotary=None, scale: Optional[float] = None):
+                            rotary=None, scale: Optional[float] = None,
+                            shared: bool = False):
     """``q`` ``[B, T, H * (dn + dr)]``, each head's ``dn`` unrotated dims and
     then its ``dr`` rotary ones; ``k_nope`` ``[B, T, H * dn]``; ``k_rope``
     ``[B, T, dr]``, the rotary key part every head shares; ``v`` ``[B, T, H
@@ -799,24 +765,38 @@ def latent_sparse_attention(q, k_nope, k_rope, v, mask, n_heads: int,
     ``fused_attention.rotate`` to each head's rotary dims and to ``k_rope``;
     without, both come rotated.  Returns ``[B, T, H * dv]``: per head the
     softmax of ``(q_n . k_n + q_r . k_r) * scale`` (``scale``: ``(dn + dr)
-    ** -0.5`` if left out) over the selected keys alone, times ``v``.  See
-    the module's docstring for which lowering a call gets."""
+    ** -0.5`` if left out) over the selected keys alone, times ``v``.
+    ``shared``: ``mask`` is an earlier layer's selection taken over, not
+    this layer's own (``nnstpu_indexer_lowerings_total{role}`` counts a
+    call with a mask by it where the call is lowered).  See the module's
+    docstring for which lowering a call gets."""
     selected = mask is not None
     return latent_sparse_attention_p.bind(
         q, k_nope, k_rope, v, *((mask,) if selected else ()),
-        *(rotary or ()), n_heads=n_heads, selected=selected, scale=scale)
+        *(rotary or ()), n_heads=n_heads, selected=selected, shared=shared,
+        scale=scale)
 
 
 latent_sparse_attention_p.def_impl(jax.jit(
     latent_sparse_attention_p.bind,
-    static_argnames=("n_heads", "selected", "scale")))
+    static_argnames=("n_heads", "selected", "shared", "scale")))
 latent_sparse_attention_p.def_abstract_eval(
     lambda q, k_nope, k_rope, v, *_, **__: v.update(dtype=q.dtype))
 
 
-def _lower_plain(ctx, *operands, n_heads, selected, scale):
-    _count_lowering("latent_sparse_plain" if selected else "latent_plain",
-                    "outside" if len(operands) == 6 + selected else None)
+def _count_indexer(selected: bool, shared: bool) -> None:
+    if selected:
+        count("nnstpu_indexer_lowerings_total",
+              "attention layers lowered into a program by where their key "
+              "selection comes from (full = the layer's own indexer scores "
+              "and selects, shared = it reuses an earlier layer's selection)",
+              role="shared" if shared else "full")
+
+
+def _lower_plain(avals, *, n_heads, selected, shared, scale):
+    count_attention("latent_sparse_plain" if selected else "latent_plain",
+                    "outside" if len(avals) == 6 + selected else None)
+    _count_indexer(selected, shared)
 
     def walk(q, k_nope, k_rope, v, *rest):
         mask, tables = (rest[0] if selected else None), rest[selected:]
@@ -826,21 +806,20 @@ def _lower_plain(ctx, *operands, n_heads, selected, scale):
         return _plain(q, k_nope, k_rope, v, mask, n_heads=n_heads,
                       scale=scale)
 
-    return mlir.lower_fun(walk, multiple_results=False)(ctx, *operands)
+    return walk
 
 
-def _lower_tpu(ctx, *operands, n_heads, selected, scale):
-    q, k_nope, k_rope, v, *rest = ctx.avals_in
+def _lower_kernel(avals, *, n_heads, selected, shared, scale):
+    q, k_nope, k_rope, v, *rest = avals
     tables = rest[selected:]
     tiles = sparse_tiles if selected else latent_tiles
-    if not (_on_one_device(ctx.module_context.axis_context)
-            and q.dtype == k_nope.dtype == k_rope.dtype == v.dtype
+    if not (q.dtype == k_nope.dtype == k_rope.dtype == v.dtype
             and tiles(q.shape, k_nope.shape, k_rope.shape, v.shape, q.dtype,
                       n_heads, tables[0].shape if tables else None)):
-        return _lower_plain(ctx, *operands, n_heads=n_heads,
-                            selected=selected, scale=scale)
-    _count_lowering("latent_sparse" if selected else "latent",
+        return None
+    count_attention("latent_sparse" if selected else "latent",
                     "kernel" if tables else None)
+    _count_indexer(selected, shared)
 
     def kernel(q, k_nope, k_rope, v, *rest):
         if selected:
@@ -850,11 +829,7 @@ def _lower_tpu(ctx, *operands, n_heads, selected, scale):
         return latent_attention_kernel(q, k_nope, k_rope, v, n_heads,
                                        rest or None, scale, interpret=False)
 
-    return mlir.lower_fun(kernel, multiple_results=False)(ctx, *operands)
+    return kernel
 
 
-# not cacheable: every call site is lowered, and counted, on its own
-mlir.register_lowering(latent_sparse_attention_p, _lower_plain,
-                       cacheable=False)
-mlir.register_lowering(latent_sparse_attention_p, _lower_tpu, platform="tpu",
-                       cacheable=False)
+register(latent_sparse_attention_p, _lower_plain, _lower_kernel)

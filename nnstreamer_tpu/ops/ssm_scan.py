@@ -16,12 +16,11 @@ inside a chunk and ``L[i, j] = exp(a_i - a_j)`` for ``j <= i`` (else 0):
 - the state a chunk hands on, ``S_out = exp(a_Q) S_in + Xᵀ diag(Δ_j
   exp(a_Q - a_j)) B``.
 
-``ssd_scan`` is one primitive.  Which lowering a call gets is decided when
-its program is lowered, as ``ops/fused_attention.attention`` decides: a
-one-device TPU program whose shapes :func:`scan_tiles` admits gets the
-Pallas kernel ``nns_ssd_scan``; every other program (a CPU's, one GSPMD
-partitions, other widths) gets :func:`plain_scan`, the same three products
-through XLA with the chunks' states carried by ``lax.scan``.  Both count
+``ssd_scan`` is one primitive, whose lowering ``ops/lowering.py`` chooses:
+where the shapes :func:`scan_tiles` admits, the Pallas kernel
+``nns_ssd_scan``; for every other program or width :func:`plain_scan`, the
+same three products through XLA with the chunks' states carried by
+``lax.scan``.  Both count
 ``nnstpu_ssm_scan_lowerings_total{path="kernel"|"plain"}`` as they lower,
 and the kernel ``nnstpu_ssm_scan_bodies_total{body}`` too (below).
 
@@ -57,9 +56,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.extend.core import Primitive
-from jax.interpreters import mlir
 
-from .fused_attention import MASKED, _count, _on_one_device
+from .fused_attention import MASKED
+from .lowering import count, register
 from .pallas_kernels import LANES, _interpret
 
 KERNEL_NAME = "nns_ssd_scan"
@@ -367,40 +366,32 @@ ssm_scan_p.def_abstract_eval(lambda x, *_, **__: x)
 
 
 def _count_lowering(path: str, kernel_body: Optional[str] = None) -> None:
-    _count("nnstpu_ssm_scan_lowerings_total",
-           "state-space scans lowered into a program, by the path chosen "
-           "(kernel = the chunked Pallas kernel nns_ssd_scan, plain = the "
-           "same chunks through XLA)", path=path)
+    count("nnstpu_ssm_scan_lowerings_total",
+          "state-space scans lowered into a program, by the path chosen "
+          "(kernel = the chunked Pallas kernel nns_ssd_scan, plain = the "
+          "same chunks through XLA)", path=path)
     if kernel_body is not None:
-        _count("nnstpu_ssm_scan_bodies_total",
-               "state-space scans lowered to the kernel, by the body its "
-               "heads took (whole_tile = a head of whole lane tiles at a "
-               "time, shared_tile = heads narrower than a lane tile, two of "
-               "64 channels a tile)", body=kernel_body)
+        count("nnstpu_ssm_scan_bodies_total",
+              "state-space scans lowered to the kernel, by the body its "
+              "heads took (whole_tile = a head of whole lane tiles at a "
+              "time, shared_tile = heads narrower than a lane tile, two of "
+              "64 channels a tile)", body=kernel_body)
 
 
-def _lower_plain(ctx, *operands, **kw):
+def _lower_plain(avals, **kw):
     _count_lowering("plain")
-    return mlir.lower_fun(functools.partial(plain_scan, **kw),
-                          multiple_results=False)(ctx, *operands)
+    return functools.partial(plain_scan, **kw)
 
 
-def _lower_tpu(ctx, *operands, chunk, n_groups, low):
-    x, dt, _, B, C, _ = ctx.avals_in
-    if not (_on_one_device(ctx.module_context.axis_context)
-            and x.dtype == B.dtype == C.dtype
+def _lower_kernel(avals, *, chunk, n_groups, low):
+    x, dt, _, B, C, _ = avals
+    if not (x.dtype == B.dtype == C.dtype
             and scan_tiles(x.shape, dt.shape, B.shape, x.dtype, n_groups,
                            chunk)):
-        return _lower_plain(ctx, *operands, chunk=chunk, n_groups=n_groups,
-                            low=low)
+        return None
     _count_lowering("kernel", body(x.shape[-1] // dt.shape[-1]))
-    return mlir.lower_fun(
-        functools.partial(ssd_scan_kernel, chunk=chunk, n_groups=n_groups,
-                          low=low, interpret=False),
-        multiple_results=False)(ctx, *operands)
+    return functools.partial(ssd_scan_kernel, chunk=chunk, n_groups=n_groups,
+                             low=low, interpret=False)
 
 
-# not cacheable: every call site is lowered, and counted, on its own
-mlir.register_lowering(ssm_scan_p, _lower_plain, cacheable=False)
-mlir.register_lowering(ssm_scan_p, _lower_tpu, platform="tpu",
-                       cacheable=False)
+register(ssm_scan_p, _lower_plain, _lower_kernel)

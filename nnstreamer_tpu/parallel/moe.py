@@ -29,9 +29,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.extend.core import Primitive
-from jax.interpreters import ad, mlir
+from jax.interpreters import ad
 
 from ..models.layers import Params, _normal, dense_init
+from ..ops.lowering import count, gauge, register
 from ..ops.quant import QuantizedWeight, matmul_int8
 
 
@@ -376,12 +377,11 @@ def _routed_share(x, weights, experts, w_in, w_out, *, first: int,
     return out.astype(x.dtype)
 
 
-# One primitive, as ``ops/fused_attention.attention_p``: a trace does not know
-# what it will be lowered for, so which of the two runs the products is the
-# lowering rule's choice.  ``first``/``total``: the share (``None``: all);
-# ``act``: the experts' activation; ``choice`` and ``latent`` (how the router
-# chose, whether the rows are a latent projection of the tokens): for the
-# rule to count.
+# One primitive, whose lowering chooses between kernels and XLA as every
+# kernel's does (``ops/lowering.py``).  ``first``/``total``: the share
+# (``None``: all); ``act``: the experts' activation; ``choice`` and
+# ``latent`` (how the router chose, whether the rows are a latent projection
+# of the tokens): for the rule to count.
 
 routed_experts_p = Primitive("nns_routed_experts")
 routed_experts_p.def_impl(jax.jit(
@@ -400,12 +400,13 @@ def routed_experts(x, weights, experts, w_in, w_out,
     2f]`` = ``[gate | up]``) or ``relu2`` (``w_in`` ``[E, d, f]``); ``w_out``
     ``[E, f, d]``.
 
-    The pairs are sorted by expert either way.  A TPU program for one device
-    whose shapes the grouped kernel tiles (``ops/grouped_experts.tiles``:
-    one expert's weights within its VMEM budget) runs the experts in that
-    kernel; any other program (the CPU's, one that GSPMD partitions, small
-    or odd shapes, experts too large for VMEM, experts that are no plain
-    arrays) runs them as two ``ragged_dot`` products through XLA.
+    The pairs are sorted by expert either way.  Where ``ops/lowering.py``
+    lets a kernel take the call and the grouped kernel tiles its shapes
+    (``ops/grouped_experts.tiles``: one expert's weights within its VMEM
+    budget), the experts run in that kernel; anywhere else (the CPU's
+    program, one that GSPMD partitions, small or odd shapes, experts too
+    large for VMEM, experts that are no plain arrays) as two ``ragged_dot``
+    products through XLA.
 
     ``first``, ``total``: this layer holds experts ``first ... first + E``
     of the ``total`` that ``experts`` counts over (one chip's share of an
@@ -413,8 +414,8 @@ def routed_experts(x, weights, experts, w_in, w_out,
     (:func:`_routed_share`: a pass's products in the grouped kernel where
     ``tiles()`` holds for a pass's rows, as for all experts, and XLA's
     ``ragged_dot`` elsewhere; its results go back to their tokens through
-    ``ops/combine_rows``' kernel in a one-device TPU program whose shapes
-    ``combine_rows.tiles()``, as XLA's scatter-add in any other,
+    ``ops/combine_rows``' kernel where ``combine_rows.tiles()`` holds, as
+    XLA's scatter-add elsewhere,
     ``nnstpu_moe_share_combine_total``).
     Left out, or with ``E == total``, the layer holds them all.
     ``choice``: ``"global"`` or ``"group_limited"``, how :func:`route_top_k`
@@ -450,61 +451,55 @@ def _layer_ops(first, total, act="swiglu", combine=_combine_plain,
                              act=act, combine=combine, products=products)
 
 
-def _lower_grouped(ctx, *operands, first=None, total=None, choice="global",
-                   act="swiglu", latent=False, combine=_combine_plain,
-                   products=_products_xla):
+def _lower_xla(avals, *, first, total, choice, act, latent,
+               combine=_combine_plain, products=_products_xla):
+    """The layer as ops, counted; a share's ``products`` and ``combine``
+    are XLA's unless the TPU rule chose a kernel for either."""
     _count_moe_lowering(
         "grouped" if products is _products_xla else "fused", choice)
     _count_experts(act, latent)
     if first is not None:
         _count_share_combine(
             "plain" if combine is _combine_plain else "kernel")
-    _say_held(ctx.avals_in[3].shape[0], total)
-    return mlir.lower_fun(_layer_ops(first, total, act, combine, products),
-                          multiple_results=False)(ctx, *operands)
+    _say_held(avals[3].shape[0], total)
+    return _layer_ops(first, total, act, combine, products)
 
 
-def _lower_tpu(ctx, *operands, first=None, total=None, choice="global",
-               act="swiglu", latent=False):
+def _lower_kernels(avals, *, first, total, choice, act, latent):
     from ..ops import combine_rows
-    from ..ops.fused_attention import _on_one_device
     from ..ops.grouped_experts import tiles
 
-    x, _, experts, w_in, w_out = ctx.avals_in
-    kernels = (_on_one_device(ctx.module_context.axis_context)
-               and x.dtype == w_in.dtype == w_out.dtype)
+    x, _, experts, w_in, w_out = avals
+    if not x.dtype == w_in.dtype == w_out.dtype:
+        return None
     if first is not None:
         # a share's pass runs its products in the grouped kernel where a
         # pass's rows and one expert's weights tile, and goes back by the
         # combining kernel where a pass's rows and the tokens tile
         rows = share_rows(experts.size, w_in.shape[0], total)
-        fused = kernels and tiles((rows, x.shape[-1]), w_in.shape,
-                                  w_out.shape, x.dtype, act)
-        back = kernels and combine_rows.tiles(x.shape, rows, x.dtype)
-        return _lower_grouped(
-            ctx, *operands, first=first, total=total, choice=choice,
-            act=act, latent=latent,
+        fused = tiles((rows, x.shape[-1]), w_in.shape, w_out.shape, x.dtype,
+                      act)
+        back = combine_rows.tiles(x.shape, rows, x.dtype)
+        if not (fused or back):
+            return None
+        return _lower_xla(
+            avals, first=first, total=total, choice=choice, act=act,
+            latent=latent,
             combine=functools.partial(combine_rows.combine_rows,
                                       interpret=False)
             if back else _combine_plain,
             products=functools.partial(_products_kernel, interpret=False)
             if fused else _products_xla)
-    if not (kernels and tiles((experts.size, x.shape[-1]), w_in.shape,
-                              w_out.shape, x.dtype, act)):
-        return _lower_grouped(ctx, *operands, choice=choice, act=act,
-                              latent=latent)
+    if not tiles((experts.size, x.shape[-1]), w_in.shape, w_out.shape,
+                 x.dtype, act):
+        return None
     _count_moe_lowering("fused", choice)
     _count_experts(act, latent)
     _say_held(w_in.shape[0], total)
-    return mlir.lower_fun(
-        functools.partial(_routed_fused, act=act, interpret=False),
-        multiple_results=False)(ctx, *operands)
+    return functools.partial(_routed_fused, act=act, interpret=False)
 
 
-# not cacheable: every layer is lowered, and counted, on its own
-mlir.register_lowering(routed_experts_p, _lower_grouped, cacheable=False)
-mlir.register_lowering(routed_experts_p, _lower_tpu, platform="tpu",
-                       cacheable=False)
+register(routed_experts_p, _lower_xla, _lower_kernels)
 # derivatives are the XLA path's (no vmap: ``ragged_dot`` has none over its
 # group sizes either)
 ad.primitive_jvps[routed_experts_p] = \
@@ -514,71 +509,49 @@ ad.primitive_jvps[routed_experts_p] = \
 
 
 def _say_held(held: int, total: Optional[int]) -> None:
-    from ..obs.metrics import REGISTRY
-
-    total = held if total is None else total
-    REGISTRY.gauge(
-        "nnstpu_moe_held_experts",
-        "experts whose weights the last expert layer lowered holds, of the "
-        "experts its router chooses among", labelnames=("of",),
-    ).set(held, of=str(total))
+    gauge("nnstpu_moe_held_experts",
+          "experts whose weights the last expert layer lowered holds, of the "
+          "experts its router chooses among", held,
+          of=str(held if total is None else total))
 
 
 def _count_share_combine(way: str) -> None:
-    from ..obs.metrics import REGISTRY
-
-    REGISTRY.counter(
-        "nnstpu_moe_share_combine_total",
-        "expert layers holding a share of their experts lowered into a "
-        "program, by how a pass's results go back to their tokens (kernel = "
-        "sorted by token and summed as a banded one-hot product in the "
-        "Pallas kernel, plain = XLA's scatter-add)", labelnames=("way",),
-    ).inc(way=way)
+    count("nnstpu_moe_share_combine_total",
+          "expert layers holding a share of their experts lowered into a "
+          "program, by how a pass's results go back to their tokens (kernel "
+          "= sorted by token and summed as a banded one-hot product in the "
+          "Pallas kernel, plain = XLA's scatter-add)", way=way)
 
 
 def _count_experts(act: str, latent: bool) -> None:
-    from ..obs.metrics import REGISTRY
-
-    REGISTRY.counter(
-        "nnstpu_moe_experts_total",
-        "top-k expert layers lowered into a program, by their experts' "
-        "activation (swiglu = silu(gate) * up, relu2 = relu(x)^2, non-gated) "
-        "and the width their rows run in (model = the tokens' own, latent = "
-        "a projection of them, combined there and projected back)",
-        labelnames=("act", "width"),
-    ).inc(act=act, width="latent" if latent else "model")
+    count("nnstpu_moe_experts_total",
+          "top-k expert layers lowered into a program, by their experts' "
+          "activation (swiglu = silu(gate) * up, relu2 = relu(x)^2, "
+          "non-gated) and the width their rows run in (model = the tokens' "
+          "own, latent = a projection of them, combined there and projected "
+          "back)", act=act, width="latent" if latent else "model")
 
 
 def _count_biased_router() -> None:
-    from ..obs.metrics import REGISTRY
-
-    REGISTRY.counter(
-        "nnstpu_moe_biased_routers_total",
-        "top-k routers traced into a program whose choice is not by the "
-        "scores themselves (a selection bias or a group limit), by how the "
-        "chosen experts' unbiased scores are read (select = a masked select "
-        "over the expert axis)", labelnames=("weights",),
-    ).inc(weights="select")
+    count("nnstpu_moe_biased_routers_total",
+          "top-k routers traced into a program whose choice is not by the "
+          "scores themselves (a selection bias or a group limit), by how the "
+          "chosen experts' unbiased scores are read (select = a masked "
+          "select over the expert axis)", weights="select")
 
 
 def _count_moe_lowering(path: str, choice: Optional[str] = None) -> None:
-    from ..obs.metrics import REGISTRY
-
-    REGISTRY.counter(
-        "nnstpu_moe_lowerings_total",
-        "expert layers lowered into a program, by the path chosen (fused = "
-        "top-k without drops through the grouped Pallas kernel, grouped = "
-        "the same through XLA's grouped matrix product, switch = top-1 with "
-        "a capacity)", labelnames=("path",),
-    ).inc(path=path)
+    count("nnstpu_moe_lowerings_total",
+          "expert layers lowered into a program, by the path chosen (fused = "
+          "top-k without drops through the grouped Pallas kernel, grouped = "
+          "the same through XLA's grouped matrix product, switch = top-1 "
+          "with a capacity)", path=path)
     if choice is not None:
-        REGISTRY.counter(
-            "nnstpu_moe_routing_total",
-            "top-k expert layers lowered into a program, by how their router "
-            "chooses (global = the k highest of all experts, group_limited = "
-            "the k highest among the experts of the highest-scoring groups)",
-            labelnames=("choice",),
-        ).inc(choice=choice)
+        count("nnstpu_moe_routing_total",
+              "top-k expert layers lowered into a program, by how their "
+              "router chooses (global = the k highest of all experts, "
+              "group_limited = the k highest among the experts of the "
+              "highest-scoring groups)", choice=choice)
 
 
 def moe_top_k(params: Params, x, top_k: int, scaling: float = 1.0,

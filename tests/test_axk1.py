@@ -24,7 +24,7 @@ if ROOT not in sys.path:
 
 from benchmark.references import axk1_plain  # noqa: E402
 from nnstreamer_tpu import parse_launch  # noqa: E402
-from nnstreamer_tpu.models import axk1, glm_dsa, laguna  # noqa: E402
+from nnstreamer_tpu.models import axk1, decoder, glm_dsa  # noqa: E402
 from nnstreamer_tpu.obs.metrics import REGISTRY  # noqa: E402
 from nnstreamer_tpu.ops import sparse_attention as sa  # noqa: E402
 from nnstreamer_tpu.ops.fused_attention import rotate  # noqa: E402
@@ -111,8 +111,8 @@ def test_the_model_is_named_and_holds_neither_indexer_nor_bias():
 
 def test_the_layer_body_is_glm_dsas_and_nothing_is_copied():
     assert axk1.glm_dsa is glm_dsa and not hasattr(axk1, "layer")
-    assert axk1.load_config is laguna.load_config
-    assert axk1.quantize_weights is laguna.quantize_weights
+    assert axk1.load_config is decoder.load_config
+    assert axk1.quantize_weights is decoder.quantize_weights
     cfg = axk1.latent_config(config())
     assert cfg["mlp_layer_types"] == ["dense"] + ["sparse"] * 4
     assert "indexer_types" not in cfg
@@ -154,7 +154,7 @@ def test_the_yarn_tables_and_the_scale_are_the_references(scaling, t):
     cfg = config(rope_scaling=scaling, qk_nope_head_dim=128,
                  qk_rope_head_dim=64)
     read = axk1.latent_config(cfg)
-    cos, sin = laguna.rotary_tables(read["rope_parameters"], 64, t)
+    cos, sin = decoder.rotary_tables(read["rope_parameters"], 64, t)
     want_cos, want_sin = axk1_plain.rotary(cfg, t)
     np.testing.assert_allclose(np.asarray(cos), np.asarray(want_cos),
                                atol=1e-6)
@@ -175,7 +175,7 @@ def test_the_published_yarn_numbers():
                                                                  abs=1e-5)
     assert cfg["softmax_scale"] == pytest.approx(0.130861, abs=1e-6)
     assert cfg["rope_parameters"]["attention_factor"] == 1.0
-    cos, sin = laguna.rotary_tables(cfg["rope_parameters"], 64, 3)
+    cos, sin = decoder.rotary_tables(cfg["rope_parameters"], 64, 3)
     angle = np.arctan2(np.asarray(sin, np.float64)[1],
                        np.asarray(cos, np.float64)[1])
     plain = 10000.0 ** (-np.arange(32) / 32)
@@ -395,7 +395,7 @@ def written_out(q, k_n, k_r, v, heads, scale):
 
 def tables(t, rot=64):
     cfg = axk1.latent_config(config(qk_rope_head_dim=rot))
-    return laguna.rotary_tables(cfg["rope_parameters"], rot, t)
+    return decoder.rotary_tables(cfg["rope_parameters"], rot, t)
 
 
 BLOCKS = pytest.mark.parametrize(
